@@ -28,7 +28,7 @@ from .. import obs
 from ..ops import ibdcf
 from ..protocol.leader_rpc import RpcLeader
 from ..protocol.rpc import CollectorClient
-from ..utils import compile_cache
+from ..utils import compile_cache, require_accelerator
 from ..utils import config as configmod
 from ..workloads import OUTPUT_CSV, rides, sample_points, strings
 
@@ -79,13 +79,15 @@ async def amain() -> None:
     import contextlib
 
     cfg, _, nreqs = configmod.get_args("Leader", get_n_reqs=True)
-    # persistent XLA compile cache (FHH_COMPILE_CACHE): repeat runs skip
-    # the per-bucket program compiles entirely
+    # persistent XLA compile cache (utils/compile_cache.py): repeat runs
+    # skip the per-bucket program compiles entirely
     compile_cache.enable()
     rng = np.random.default_rng()
 
     # backend knob, like bin/server.py: "cpu" pins every uncommitted array
-    # op (keygen here) onto the host backend
+    # op (keygen here) onto the host backend; "tpu" with no accelerator
+    # resolved refuses to run
+    require_accelerator(cfg.backend)
     ctx = (
         jax.default_device(jax.devices("cpu")[0])
         if cfg.backend == "cpu"
@@ -139,7 +141,7 @@ async def _run(cfg, nreqs: int, rng) -> None:
 
     lead = RpcLeader(cfg, c0, c1)
     # per-f_bucket compile warmup (FHH_WARMUP=0 opts out): bucket
-    # recompiles run now — and land in the FHH_COMPILE_CACHE when set —
+    # recompiles run now — and land in the persistent compile cache —
     # instead of billing into the crawl itself.  Needs the key shapes on
     # the servers, so it rides after the upload in both paths below.
     warm = os.environ.get("FHH_WARMUP", "1") != "0"

@@ -40,7 +40,7 @@ import numpy as np
 from .. import obs
 from ..ops import ibdcf
 from ..parallel import mesh as meshmod
-from ..utils import compile_cache
+from ..utils import compile_cache, require_accelerator
 from ..utils import config as configmod
 from ..workloads import OUTPUT_CSV, rides, sample_points
 
@@ -73,7 +73,11 @@ def main() -> None:
 
     if args.platform:
         jax.config.update("jax_platforms", args.platform)
-    # persistent XLA compile cache (FHH_COMPILE_CACHE) — after the
+    else:
+        # no explicit pin: a "tpu" config on a host with no accelerator
+        # refuses to run (opt out with backend: "cpu" or --platform cpu)
+        require_accelerator(cfg.backend)
+    # persistent XLA compile cache (utils/compile_cache.py) — after the
     # platform pin so the cache keys against the platform actually used
     compile_cache.enable()
     if args.processes:

@@ -26,7 +26,7 @@ import time
 
 from .. import obs
 from ..protocol.rpc import CollectorServer
-from ..utils import compile_cache
+from ..utils import compile_cache, require_accelerator
 from ..utils import config as configmod
 
 
@@ -79,12 +79,13 @@ async def amain(cfg, server_id: int) -> None:
     peer_port = port1 + 1
 
     # cfg.backend selects the aggregation device: "cpu" pins every
-    # uncommitted array op onto the host backend (useful where no
-    # accelerator is attached); "tpu" (default) keeps JAX's default device
-    # and, when an accelerator actually resolved (not an XLA:CPU fallback,
-    # where unrolled rounds only inflate compiles), switches the PRG to its
-    # unrolled round loop (faster chip execution — ops/prg.py).
-    if cfg.backend != "cpu" and jax.default_backend() != "cpu":
+    # uncommitted array op onto the host backend (the opt-out where no
+    # accelerator is attached); "tpu" (default) keeps JAX's default device,
+    # REFUSES to start when no accelerator resolved (no silent XLA:CPU
+    # fallback), and switches the PRG to its unrolled round loop (faster
+    # chip execution; on XLA:CPU it only inflates compiles — ops/prg.py).
+    require_accelerator(cfg.backend)
+    if cfg.backend != "cpu":
         from ..ops import prg
 
         prg.CHACHA_UNROLL = True
@@ -157,7 +158,7 @@ def main() -> None:
     cfg, server_id, _ = configmod.get_args("Server", get_server_id=True)
     if server_id not in (0, 1):
         raise SystemExit(f"server_id must be 0 or 1, got {server_id}")
-    # persistent XLA compile cache (FHH_COMPILE_CACHE): a restarted
+    # persistent XLA compile cache (utils/compile_cache.py): a restarted
     # server re-reads its crawl programs instead of recompiling them —
     # recovery cost stays network + restore, not compile churn
     compile_cache.enable()

@@ -1,7 +1,7 @@
 """Structured telemetry for the crawl stack — dependency-free.
 
 Four small pieces, one coherent layer (replacing the ad-hoc ``print``
-taxonomy that left BENCH_r05's rc=124 postmortem with nothing but an XLA
+breakdown that left BENCH_r05's rc=124 postmortem with nothing but an XLA
 platform warning):
 
 - :mod:`.metrics` — named counters, gauges, and phase timers with

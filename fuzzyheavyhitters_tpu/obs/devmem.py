@@ -21,7 +21,7 @@ ladder.  Both become live, named numbers here:
   ``/jax/core/compile/backend_compile_duration`` (fires once per FRESH
   backend compile — persistent-cache hits do not fire it).  The event
   carries no program name, so each compile is attributed to the
-  innermost active obs span (the phase taxonomy IS our program naming:
+  innermost active obs span (the phase breakdown IS our program naming:
   ``level``/``warmup``/``setup``/...), counted as ``fresh_compiles`` +
   ``fresh_compiles:<span>`` on the default registry.  After
   :func:`note_warmup_done` (the warmup verb's last act), compiles also

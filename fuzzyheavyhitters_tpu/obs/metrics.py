@@ -10,7 +10,7 @@ byte counts must stay separable (the run report asserts them consistent
 *between* the two servers, which a process-global bag cannot express).
 
 Every metric takes an optional ``level`` label and keeps both a total
-and a per-level breakdown — the per-level phase taxonomy the reference
+and a per-level breakdown — the per-level phase breakdown the reference
 reports as its headline server cost (collect.rs:412-503) is
 ``timer_add("fss"/"gc_ot"/"field", dt, level=...)`` here.
 

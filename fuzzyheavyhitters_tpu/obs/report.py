@@ -20,7 +20,7 @@ bench.py and postmortems consume instead of scraping stdout.  Schema
 Well-known metric names (what populates them):
 
 - phases ``fss`` / ``gc_ot`` / ``field`` — the reference's per-level
-  3-phase server taxonomy (protocol/rpc.py crawl verbs; trusted mode's
+  3-phase server breakdown (protocol/rpc.py crawl verbs; trusted mode's
   ``gc_ot`` slot is the plaintext exchange), plus ``level`` on the
   leader/driver side and ``upload_keys`` / ``setup`` one-offs.
 - phases ``otext`` / ``garble`` / ``eval`` / ``b2a`` — the secure-kernel
@@ -34,9 +34,9 @@ Well-known metric names (what populates them):
 - counters ``data_bytes_sent`` / ``data_bytes_recv`` /
   ``data_msgs_sent`` — server↔server data plane, per level;
   ``control_bytes_*`` — leader↔server control plane;
-  ``device_fetches`` — device->host transfers (the floor for
-  remote-chip tunnels: fetch COUNT, not byte count — now both are
-  measured); ``gc_tests`` — secure-mode equality tests;
+  ``device_fetches`` — device->host transfers (each a synchronous
+  round trip: the COUNT is a latency term beside the byte count, so
+  both are measured); ``gc_tests`` — secure-mode equality tests;
   ``checkpoint_writes`` / ``checkpoint_restores``.
 - gauges ``ot_batch_size`` (per level), ``survivors`` /
   ``frontier_nodes`` (per level).

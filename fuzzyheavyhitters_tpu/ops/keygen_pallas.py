@@ -85,7 +85,7 @@ def _kernel(derived_bits: bool,
 
     Everything stays uint32 — bit flags as 0/1 words, selects as XOR-masks
     (``b ^ (mask & (a ^ b))`` with ``mask = 0 - flag``).  Mosaic's vector i1
-    paths are what the remote compiler rejects, so no bool vectors appear.
+    paths are what the compiler rejects, so no bool vectors appear.
     """
     from jax.experimental import pallas as pl
 
@@ -201,7 +201,7 @@ def _gen_pallas(init_seeds, alpha_bits, side, derived_bits, interpret=False):
     grid = (tiles, l_blocks)
     kern = partial(_kernel, derived_bits)
     # index maps return i32 zeros: jax_enable_x64 is on package-wide, and
-    # Mosaic's remote compiler rejects i64 block indices
+    # Mosaic rejects i64 block indices
     z = np.int32(0)
     cw_seed, cw_b, cw_y = pl.pallas_call(
         kern,

@@ -80,28 +80,6 @@ _FIXED_KEY = (
 # the evidently *intended* construction, so they ship as the default.
 DERIVED_BITS = True
 
-# jax 0.4.x ships optimization_barrier without a vmap batching rule, so any
-# jax.vmap over an eval path that crosses the fusion fences below (eval_full
-# sweeps, the ibdcf full-domain tests) dies with NotImplementedError.  The
-# barrier is semantically the identity, so the batched form is just the
-# barrier applied to the batched operands with the batch dims unchanged —
-# register that rule once, idempotently, where the fences live.
-from jax._src.lax import lax as _lax_internal  # noqa: E402
-from jax.interpreters import batching as _batching  # noqa: E402
-
-if _lax_internal.optimization_barrier_p not in _batching.primitive_batchers:
-
-    def _optimization_barrier_batcher(args, dims, **params):
-        return (
-            _lax_internal.optimization_barrier_p.bind(*args, **params),
-            dims,
-        )
-
-    _batching.primitive_batchers[_lax_internal.optimization_barrier_p] = (
-        _optimization_barrier_batcher
-    )
-
-
 def _rotl(x, n: int):
     return (x << n) | (x >> (32 - n))
 

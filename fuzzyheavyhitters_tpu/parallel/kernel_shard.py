@@ -64,7 +64,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..ops import gc, gc_pallas, otext, prg
 from ..ops.fields import F255, FE62
 from ..ops.gc_pallas import GROUP, LANES, SUB, padded_tests
-from .mesh import _shard_map, field_psum
+from .mesh import field_psum
 from .server_mesh import DATA, _largest_divisor_leq, _mesh_for
 
 # shard unit: one pallas grid step's worth of tests — the planar wire's
@@ -205,7 +205,7 @@ def _snd_extend_fn(devices: tuple, B: int, S: int):
 
     # fhh-lint: disable=recompile-churn (lru_cached factory: built once per (devices, shape))
     return jax.jit(
-        _shard_map(
+        jax.shard_map(
             body, mesh=ks.mesh,
             in_specs=(P(), P(), P(None, DATA), P()),
             out_specs=P(DATA, None),
@@ -231,7 +231,7 @@ def _rcv_extend_fn(devices: tuple, B: int, S: int):
 
     # fhh-lint: disable=recompile-churn (lru_cached factory: built once per (devices, shape))
     return jax.jit(
-        _shard_map(
+        jax.shard_map(
             body, mesh=ks.mesh,
             in_specs=(P(), P(), P(DATA, None), P()),
             out_specs=(P(None, DATA), P(DATA, None)),
@@ -315,10 +315,10 @@ def _gb_kernel_fn(devices: tuple, field_name: str, B: int, S: int, W: int,
     # pallas_call has no shard_map replication rule — drop the rep check
     # for the Pallas engines (the XLA twins keep it; specs are identical
     # either way and the parity test pins engine equality)
-    kw = {} if engine == "xla" else {"check_rep": False}
+    kw = {} if engine == "xla" else {"check_vma": False}
     # fhh-lint: disable=recompile-churn (lru_cached factory: built once per (devices, shape, path, engine))
     return jax.jit(
-        _shard_map(
+        jax.shard_map(
             body, mesh=ks.mesh,
             in_specs=(P(DATA, None), P(), P(DATA, None), P(), P(), P()),
             out_specs=(
@@ -373,10 +373,10 @@ def _ev_open_fn(devices: tuple, field_name: str, B: int, S: int, W: int,
         return secure.words_to_field(field, pay)
 
     # see _gb_kernel_fn: pallas_call has no replication rule
-    kw = {} if engine == "xla" else {"check_rep": False}
+    kw = {} if engine == "xla" else {"check_vma": False}
     # fhh-lint: disable=recompile-churn (lru_cached factory: built once per (devices, shape, path, engine))
     return jax.jit(
-        _shard_map(
+        jax.shard_map(
             body, mesh=ks.mesh,
             in_specs=(P(None, DATA, None, None), P(DATA, None),
                       P(DATA, None), P()),
@@ -417,7 +417,7 @@ def _share_sums_fn(devices: tuple, field_name: str, F: int, C: int, N: int,
 
     # fhh-lint: disable=recompile-churn (lru_cached factory: built once per (devices, shape))
     return jax.jit(
-        _shard_map(
+        jax.shard_map(
             body, mesh=ks_mesh,
             in_specs=(
                 P(DATA) if limb == () else P(DATA, None),

@@ -46,7 +46,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.ibdcf import EvalState
-from .mesh import _shard_map, field_psum
+from .mesh import field_psum
 
 DATA = "data"
 
@@ -74,7 +74,7 @@ def _counts_fn(devices: tuple):
 
     # fhh-lint: disable=recompile-churn (lru_cached factory: built once per device set)
     return jax.jit(
-        _shard_map(
+        jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(None, DATA), P(None, DATA), P(), P(DATA), P()),
             out_specs=P(),
@@ -95,7 +95,7 @@ def _share_sums_fn(devices: tuple, field_name: str):
 
     # fhh-lint: disable=recompile-churn (lru_cached factory: built once per device set and field)
     return jax.jit(
-        _shard_map(
+        jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(None, None, DATA), P(None, None, DATA)),
             out_specs=P(),
@@ -113,10 +113,7 @@ def resolve_data_devices(requested: int) -> int:
     device count."""
     from ..utils import effective_platform
 
-    try:
-        avail = len(jax.local_devices())
-    except RuntimeError:  # no backend: single-device semantics
-        return 1
+    avail = len(jax.local_devices())
     if requested <= 0:
         return avail if effective_platform() != "cpu" else 1
     return max(1, min(int(requested), avail))

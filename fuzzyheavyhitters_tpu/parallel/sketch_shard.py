@@ -49,7 +49,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..ops.fields import F255, FE62
 from .kernel_shard import assemble, start_host_copies
-from .mesh import _shard_map
 from .server_mesh import DATA, _largest_divisor_leq, _mesh_for
 
 _FIELDS = {"FE62": FE62, "F255": F255}
@@ -129,7 +128,7 @@ def _cor_state_fn(devices: tuple, field_name: str, m: int, N: int, d: int):
 
     # fhh-lint: disable=recompile-churn (lru_cached factory: built once per (devices, shape, field))
     return jax.jit(
-        _shard_map(
+        jax.shard_map(
             body, mesh=_mesh_for(devices),
             in_specs=(
                 P(None, DATA), P(DATA), P(DATA), P(DATA), P(DATA), P(DATA),
@@ -188,7 +187,7 @@ def _out_fn(devices: tuple | None, field_name: str, N: int, d: int,
         return jax.jit(body)
     # fhh-lint: disable=recompile-churn (lru_cached factory: built once per (devices, shape, field, role))
     return jax.jit(
-        _shard_map(
+        jax.shard_map(
             body, mesh=_mesh_for(devices),
             in_specs=(
                 P(DATA), P(DATA), P(DATA), P(DATA), P(DATA), P(DATA),
@@ -215,7 +214,7 @@ def _verdict_fn(devices: tuple | None, field_name: str, N: int, d: int):
         return jax.jit(body)
     # fhh-lint: disable=recompile-churn (lru_cached factory: built once per (devices, shape, field))
     return jax.jit(
-        _shard_map(
+        jax.shard_map(
             body, mesh=_mesh_for(devices),
             in_specs=(P(DATA), P(DATA)),
             out_specs=P(DATA),
@@ -324,7 +323,7 @@ def _stream_parts_fn(devices: tuple | None, field_name: str, m: int,
         return jax.jit(body)
     # fhh-lint: disable=recompile-churn (lru_cached factory: test/bench surface)
     return jax.jit(
-        _shard_map(
+        jax.shard_map(
             body, mesh=_mesh_for(devices), in_specs=(P(), P()),
             out_specs=(P(), P(DATA)),
         )

@@ -154,8 +154,9 @@ class RpcLeader:
     async def warmup(self, f_buckets=None) -> dict:
         """Ask both servers to pre-compile the per-``f_bucket`` crawl
         programs (rpc.CollectorServer.warmup) so bucket recompiles land
-        BEFORE measured crawl time — with ``FHH_COMPILE_CACHE`` set the
-        compiles also persist across processes.  Default bucket plan:
+        BEFORE measured crawl time — with the persistent compile cache
+        on (utils/compile_cache.py) the compiles also persist across
+        processes.  Default bucket plan:
         powers of two from ``min_bucket`` up to ``cfg.f_max`` (the exact
         ladder ``collect.bucket_for`` walks as the frontier grows).
         Call after ``upload_keys`` (the servers need the key shapes);

@@ -161,8 +161,9 @@ class ChaosProxy:
 
     async def stop(self) -> None:
         if self._srv is not None:
-            self._srv.close()
-            await self._srv.wait_closed()
+            self._srv.close()  # stop accepting
+        # cut the accepted connections BEFORE waiting: wait_closed()
+        # returns only once every connection of the listener is gone
         self.sever_now()
         for t in list(self._pumps):
             t.cancel()
@@ -173,6 +174,8 @@ class ChaosProxy:
             # ANY error while being torn down is expected, not reportable)
             except (asyncio.CancelledError, Exception):
                 pass
+        if self._srv is not None:
+            await self._srv.wait_closed()
 
     def sever_now(self) -> None:
         """Imperatively cut every live connection (keeps listening)."""

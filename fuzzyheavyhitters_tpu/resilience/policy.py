@@ -119,7 +119,7 @@ class VerbBudgets:
 
     Budgets bound the WHOLE call — every redial, replay, and the server's
     execution — so they must dominate worst-case legitimate latency, not
-    typical latency: a first ``tree_crawl`` through a remote-chip tunnel
+    typical latency: a first ``tree_crawl`` on a cold compile cache
     pays a multi-minute XLA compile, and ``add_keys`` upload windows ride
     behind hundreds of in-flight peers.  The point is to convert an
     infinite hang (black-holed frames, a wedged peer) into a loud

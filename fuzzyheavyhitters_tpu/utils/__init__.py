@@ -15,3 +15,18 @@ def effective_platform() -> str:
     if dd is not None:
         return getattr(dd, "platform", dd)
     return jax.default_backend()
+
+
+def require_accelerator(backend: str) -> None:
+    """Exit unless the configured aggregation backend resolved: with
+    ``backend: "tpu"`` (the config default) a process that found no
+    accelerator would otherwise carry on on XLA:CPU and look like a very
+    slow chip.  ``backend: "cpu"`` is the explicit opt-out."""
+    import jax
+
+    if backend != "cpu" and jax.default_backend() == "cpu":
+        raise SystemExit(
+            f'config says backend: "{backend}" but JAX resolved no '
+            'accelerator (default backend is "cpu"); to run on the host on '
+            'purpose set "backend": "cpu" in the config'
+        )

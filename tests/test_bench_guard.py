@@ -140,3 +140,47 @@ def test_subprocess_metric_kills_child_on_teardown():
         signal.signal(signal.SIGALRM, old)
     # the child was reaped before the interrupt propagated
     assert _pids_with_cmdline(marker) == []
+
+
+_PARENT_PROBE = """
+import json, sys
+import bench
+
+def fake(code, timeout_s):
+    if "bench_keygen_leg" in code:
+        return {"headline": 1.0, "sweep": {}}
+    return json.loads(sys.argv[1])
+
+bench._subprocess_metric = fake
+rc = bench.main(["--out", "out.json", "--sections", "upload"])
+from jax._src import xla_bridge
+print(json.dumps({"rc": rc, "backend": xla_bridge.backends_are_initialized()}))
+"""
+
+
+@pytest.mark.parametrize(
+    "leg,rc", [({"upload_keys_per_sec": 1.0}, 0), ({"error": "child rc=1"}, 1)]
+)
+def test_parent_stays_off_the_backend_and_errors_set_the_exit_code(
+    tmp_path, leg, rc
+):
+    """One process per chip: ``bench.main`` (the parent of every leg,
+    keygen included) must never initialise a JAX backend — a parent that
+    holds the chip starves its children — and a leg that came back as
+    ``{"error": ...}`` makes the exit code non-zero while the final JSON
+    line still prints (skipped legs stay 0)."""
+    import json
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=repo)
+    p = subprocess.run(
+        [sys.executable, "-c", _PARENT_PROBE, json.dumps(leg)],
+        cwd=tmp_path, env=env, capture_output=True, text=True, timeout=120,
+    )
+    lines = p.stdout.strip().splitlines()
+    assert lines, p.stderr[-2000:]
+    assert json.loads(lines[-1]) == {"rc": rc, "backend": False}
+    final = json.loads(lines[-2])  # bench's own last line still printed
+    assert final["metric"] == "ibdcf_keygen_keys_per_sec_at_data_len_512"

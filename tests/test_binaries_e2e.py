@@ -13,6 +13,7 @@ import subprocess
 import sys
 
 import numpy as np
+import pytest
 
 import jax
 
@@ -76,18 +77,25 @@ def test_binaries_end_to_end(tmp_path):
         env.get("XLA_FLAGS", "") + " --xla_backend_optimization_level=1"
     ).strip()
 
-    def spawn(mod, *args):
+    def spawn(mod, *args, log=None):
+        # only a process whose pipe is READ while it runs may write to one:
+        # the servers log to files (nobody drains them until the leader is
+        # done, and a full 64 KB pipe blocks the writer — XLA:CPU prints a
+        # line for every persistent-cache load)
         return subprocess.Popen(
             [sys.executable, "-m", mod, "--config", str(cfg_path), *args],
-            cwd=tmp_path, env=env, stdout=subprocess.PIPE,
+            cwd=tmp_path, env=env,
+            stdout=subprocess.PIPE if log is None else open(log, "w"),
             stderr=subprocess.STDOUT, text=True,
         )
 
     report_path = tmp_path / "leader_report.json"
     env["FHH_RUN_REPORT"] = str(report_path)  # one shared path: the leader
     # keeps it bare, each server claims a .s<id> sibling at startup
-    s1 = spawn("fuzzyheavyhitters_tpu.bin.server", "--server_id", "1")
-    s0 = spawn("fuzzyheavyhitters_tpu.bin.server", "--server_id", "0")
+    s1 = spawn("fuzzyheavyhitters_tpu.bin.server", "--server_id", "1",
+               log=tmp_path / "s1.log")
+    s0 = spawn("fuzzyheavyhitters_tpu.bin.server", "--server_id", "0",
+               log=tmp_path / "s0.log")
     lead = None
     try:
         lead = spawn("fuzzyheavyhitters_tpu.bin.leader", "-n", str(N_REQS))
@@ -148,6 +156,32 @@ def test_mesh_binary_rides_matches_socket_csv(tmp_path):
     csv_path = tmp_path / "data" / "ride_heavy_hitters.csv"
     assert csv_path.exists(), out.stdout[-2000:]
     assert csv_path.read_text() == _expected_csv(tmp_path)
+
+
+@pytest.mark.parametrize(
+    "argv",
+    [
+        ["fuzzyheavyhitters_tpu.bin.server", "--server_id", "0"],
+        ["fuzzyheavyhitters_tpu.bin.leader", "-n", "4"],
+        ["fuzzyheavyhitters_tpu.bin.mesh", "-n", "4"],
+    ],
+    ids=["server", "leader", "mesh"],
+)
+def test_binaries_refuse_a_tpu_config_without_an_accelerator(tmp_path, argv):
+    """No hidden XLA:CPU fallback: with ``backend: "tpu"`` (the config
+    default) and no accelerator resolved, every binary exits naming the
+    ``backend: "cpu"`` opt-out instead of carrying on on the host."""
+    cfg = {k: v for k, v in CFG.items() if k != "backend"}
+    cfg_path = tmp_path / "tpu.json"
+    cfg_path.write_text(json.dumps(cfg))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env["PYTHONPATH"] = _REPO + os.pathsep + env.get("PYTHONPATH", "")
+    out = subprocess.run(
+        [sys.executable, "-m", argv[0], "--config", str(cfg_path), *argv[1:]],
+        cwd=tmp_path, env=env, capture_output=True, text=True, timeout=120,
+    )
+    assert out.returncode != 0
+    assert '"backend": "cpu"' in out.stderr, out.stderr[-2000:]
 
 
 def test_mesh_binary_refuses_malicious(tmp_path):

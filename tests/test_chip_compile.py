@@ -1,0 +1,244 @@
+"""AOT compiles for a DESCRIBED v5e chip: every kernel chip_smoke.py hits,
+at its shapes, through the TPU compiler installed here — no chip attached.
+
+Tier-1 runs the Pallas engines in interpret mode only, which cannot see
+what Mosaic refuses (an unaligned slice, too much VMEM, a kernel that
+cannot be partitioned) nor whether a program fits 16 GB of HBM.  These
+compiles can.  Nothing runs, so they say nothing about results or times;
+a compile that passes is not a chip run.
+
+The topology is described inside a module-scoped fixture, never at import
+(one process at a time may load libtpu; under xdist only the worker that is
+handed this file does), and the persistent compile cache is off around the
+compiles (an entry written here cannot be read back without a chip).
+"""
+
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from fuzzyheavyhitters_tpu.ops import gc_pallas, keygen_pallas, otext, otext_pallas
+from fuzzyheavyhitters_tpu.ops.ibdcf import EvalState, IbDcfKeyBatch
+from fuzzyheavyhitters_tpu.parallel import kernel_shard
+from fuzzyheavyhitters_tpu.parallel.server_mesh import DATA
+from fuzzyheavyhitters_tpu.protocol import collect, secure
+
+# chip_smoke.py's table
+L = 512
+N_TRUSTED = 131072
+N_SECURE = 16384
+F = 64  # the widest frontier bucket the smoke's crawl reaches
+S = 2  # 1-dim L-inf string pair
+W = 4  # FE62 payload words
+B_SECURE = F * 2 * N_SECURE  # (node, child, client) tests of one level (--chips 4: the same N over four chips)
+
+HBM_BYTES = 16 * 1024**3
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        t = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    # fhh-lint: disable=broad-except (whatever the plugin raises where no TPU compiler is installed means: skip)
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(sharding):
+    """Shape constructor bound to one placement (nothing can be put on a
+    described device, so every argument is a ShapeDtypeStruct)."""
+    return lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+
+
+def _compile(fn, *args, **static):
+    compiled = fn.lower(*args, **static).compile()
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    total = (
+        mem.argument_size_in_bytes + mem.output_size_in_bytes
+        + mem.temp_size_in_bytes
+    )
+    assert total < HBM_BYTES, f"program needs {total / 2**30:.1f} GiB of HBM"
+    return text
+
+
+def test_keygen(one_chip):
+    # one server pair's keys: N clients x (dim, side) = 2N ibDCF keys
+    n = 2 * N_TRUSTED
+    sds = _sds(one_chip)
+    text = _compile(
+        keygen_pallas._gen_pallas,
+        sds((n, 2, 4), jnp.uint32), sds((n, L), jnp.bool_), sds((n,), jnp.bool_),
+        derived_bits=True,
+    )
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("want_children", [True, False])
+def test_expand_level(one_chip, want_children):
+    """One whole expand level as the server jits it — the resident key
+    batch's level slice, the cw pack, and ``expand_pallas.expand_packed``
+    on the plane-major frontier — at the trusted lane's widest bucket."""
+    n, d = N_TRUSTED, 1
+    sds = _sds(one_chip)
+    keys = IbDcfKeyBatch(
+        key_idx=sds((n, d, 2), jnp.bool_),
+        root_seed=sds((n, d, 2, 4), jnp.uint32),
+        cw_seed=sds((n, d, 2, L, 4), jnp.uint32),
+        cw_bits=sds((n, d, 2, L, 2), jnp.bool_),
+        cw_y_bits=sds((n, d, 2, L, 2), jnp.bool_),
+    )
+    frontier = collect.Frontier(
+        states=EvalState(
+            seed=sds((4, d, 2, F, n), jnp.uint32),
+            bit=sds((d, 2, F, n), jnp.bool_),
+            y_bit=sds((d, 2, F, n), jnp.bool_),
+        ),
+        alive=sds((F,), jnp.bool_),
+    )
+    text = _compile(
+        collect._expand_share_bits_jit, keys, frontier, sds((), jnp.int32),
+        derived_bits=True, want_children=want_children, use_pallas=True,
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_ot2s_encrypt_decrypt(one_chip):
+    b = B_SECURE
+    sds = _sds(one_chip)
+    idx = sds((), jnp.uint32)
+    text = _compile(
+        otext_pallas._enc_planar,
+        sds((b, S, 4), jnp.uint32), sds((4,), jnp.uint32),
+        sds((b, S), jnp.bool_), sds((b, W), jnp.uint32),
+        sds((b, W), jnp.uint32), idx,
+        S=S, W=W, domain=secure._OT2S_DOMAIN, interpret=False,
+    )
+    assert "tpu_custom_call" in text
+    msg = (1 << S) * W * gc_pallas.padded_tests(b)
+    text = _compile(
+        otext_pallas._dec_planar,
+        sds((b, S, 4), jnp.uint32), sds((b, S), jnp.bool_),
+        sds((msg,), jnp.uint32), idx,
+        S=S, W=W, domain=secure._OT2S_DOMAIN, interpret=False,
+    )
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("wire", ["planar", "packed"])
+def test_gc_garble_eval(one_chip, wire):
+    """``planar`` is the unit form, ``packed`` the whole-level wire the
+    servers exchange (gc.garble/eval_equality_payload_packed)."""
+    b = B_SECURE
+    sds = _sds(one_chip)
+    idx = sds((), jnp.uint32)
+    garble = (gc_pallas._garble_planar if wire == "planar"
+              else gc_pallas._garble_packed)
+    text = _compile(
+        garble,
+        sds((4,), jnp.uint32), sds((b, S, 4), jnp.uint32),
+        sds((b, S, 4), jnp.uint32), sds((b,), jnp.uint32),
+        sds((b, S), jnp.bool_), sds((b, W), jnp.uint32),
+        sds((b, W), jnp.uint32), idx,
+        S=S, W=W, interpret=False,
+    )
+    assert "tpu_custom_call" in text
+    if wire == "planar":
+        text = _compile(
+            gc_pallas._eval_planar,
+            sds((b, S - 1, 2, 4), jnp.uint32), sds((b, S, 4), jnp.uint32),
+            sds((b,), jnp.bool_), sds((b, S, 4), jnp.uint32),
+            sds((2, b, W), jnp.uint32), idx,
+            S=S, W=W, interpret=False,
+        )
+    else:
+        text = _compile(
+            gc_pallas._eval_packed,
+            sds((gc_pallas.packed_msg_words(b, S, W),), jnp.uint32),
+            sds((b, S, 4), jnp.uint32), idx,
+            S=S, W=W, interpret=False,
+        )
+    assert "tpu_custom_call" in text
+
+
+def test_iknp_extension(one_chip):
+    """The packed butterfly transpose inside both extension roles, at one
+    secure level's OT count."""
+    m = B_SECURE * S
+    w = m // 32
+    sds = _sds(one_chip)
+    seeds = sds((128, 4), jnp.uint32)
+    off = sds((), jnp.uint32)
+    _compile(otext._transpose_pack, sds((128, w), jnp.uint32), m=m)
+    _compile(otext._sender_extend, seeds, sds((128,), jnp.bool_),
+             sds((128, w), jnp.uint32), off, m=m)
+    _compile(otext._receiver_extend, seeds, seeds, sds((m,), jnp.bool_),
+             off, m=m)
+
+
+@pytest.mark.parametrize(
+    "path,field", [("ot2s", "FE62"), ("ot2s", "F255"), ("gc", "FE62")]
+)
+def test_kernel_shard_bodies_on_four_chips(topo, path, field):
+    """The row-sharded secure kernel stage (parallel/kernel_shard.py) as
+    ONE program across the four chips of a 2x2 host: the shard_map bodies
+    wrap the Pallas engines, so they must partition, and the share-sum
+    reduce must lower (the TPU has no 64-bit all-reduce).  F255 is the
+    last level's field."""
+    devices = tuple(topo.devices[:4])
+    b = B_SECURE
+    w = secure.payload_words(kernel_shard._FIELDS[field])
+    limb = kernel_shard._FIELDS[field].limb_shape
+    ks = kernel_shard.KernelShard(devices, b, S)
+    sds = lambda shape, dt, spec: _sds(NamedSharding(ks.mesh, spec))(shape, dt)
+    bp, rows = ks.bp, ks.bp // kernel_shard.GROUP
+    seeds = sds((128, 4), jnp.uint32, P())
+    scalar = sds((), jnp.uint32, P())
+    seed4 = sds((4,), jnp.uint32, P())
+    q = sds((bp * S, 4), jnp.uint32, P(DATA, None))
+    flat = sds((bp, S), jnp.bool_, P(DATA, None))
+    n_planes = kernel_shard.n_msg_planes(path, S, w)
+    planes = sds((n_planes, rows, kernel_shard.SUB, kernel_shard.LANES),
+                 jnp.uint32, P(None, DATA, None, None))
+
+    _compile(kernel_shard._snd_extend_fn(devices, b, S), seeds,
+             sds((128,), jnp.bool_, P()),
+             sds((128, bp * S // 32), jnp.uint32, P(None, DATA)), scalar)
+    _compile(kernel_shard._rcv_extend_fn(devices, b, S),
+             seeds, seeds, flat, scalar)
+    text = _compile(
+        kernel_shard._gb_kernel_fn(devices, field, b, S, w, path, 0, "pallas"),
+        q, seed4, flat, seed4, seed4, scalar,
+    )
+    assert "tpu_custom_call" in text
+    text = _compile(
+        kernel_shard._ev_open_fn(devices, field, b, S, w, path, "pallas"),
+        planes, q, flat, scalar,
+    )
+    assert "tpu_custom_call" in text
+    vals = (sds((bp,), jnp.uint64, P(DATA)) if limb == ()
+            else sds((bp,) + limb, jnp.uint32, P(DATA, None)))
+    text = _compile(
+        kernel_shard._share_sums_fn(devices, field, F, 2, N_SECURE, b, bp),
+        vals, sds((F, 2, N_SECURE), jnp.bool_, P()),
+    )
+    assert "all-reduce" in text

@@ -1,0 +1,102 @@
+"""chip_smoke.py rehearsed at a tiny size on CPU.
+
+The script itself refuses to run without a TPU; its phases are a function of
+the sizes, so the whole control flow — two servers + leader over localhost
+sockets per lane, the plain-count oracle, the tapped secure/gc comparison,
+the zero-compile second crawl — runs here at N=256, data_len=16 on the CPU
+engines.  The platform and engine assertions are patched HERE, never in the
+script.
+"""
+
+import json
+
+import numpy as np
+import pytest
+
+import chip_smoke
+
+PORT = 29231
+TINY = dict(num_sites=8, threshold=0.03, f_max=64)
+
+
+def test_cli_is_seed_and_chips_only(capsys):
+    with pytest.raises(SystemExit) as e:
+        chip_smoke.main(["--n", "8"])
+    assert e.value.code == 2
+    capsys.readouterr()
+    with pytest.raises(SystemExit) as e:
+        chip_smoke.main(["--chips", "2"])
+    assert e.value.code == 2
+
+
+def test_unpatched_run_fails_without_a_tpu(capsys):
+    """On this CPU sandbox the script must fail before it runs anything:
+    non-zero exit (an uncaught SmokeFailure) and no ``ok`` line."""
+    with pytest.raises(chip_smoke.SmokeFailure, match="no TPU"):
+        chip_smoke.main([])
+    assert '"ok"' not in capsys.readouterr().out
+
+
+def test_plain_count_is_a_saturating_ball_count():
+    # 3-bit domain, ball 1: points 0, 0, 7, 3 -> [0,1] x2, [6,7], [2,4]
+    pts = np.array([[0, 0, 0], [0, 0, 0], [1, 1, 1], [0, 1, 1]], bool)[:, None, :]
+    assert chip_smoke.plain_count(pts, 1, 3, 1) == {
+        0: 2, 1: 2, 6: 1, 7: 1, 2: 1, 3: 1, 4: 1,
+    }
+    assert chip_smoke.plain_count(pts, 1, 3, 2) == {0: 2, 1: 2}
+    # depth-1 prefixes: [0,1] x2 and [2,4] touch prefix 0; [2,4], [6,7] prefix 1
+    assert chip_smoke.plain_count(pts, 1, 1, 1) == {0: 3, 1: 2}
+
+
+DEVICE = {"platform": "cpu", "kind": "rehearsal", "count": 1}
+
+
+@pytest.fixture
+def patched(monkeypatch):
+    """The platform and engine assertions, patched in the TEST."""
+    monkeypatch.setattr(chip_smoke, "require_tpu", lambda chips: DEVICE)
+    monkeypatch.setattr(chip_smoke, "check_engines", lambda data_len: {})
+
+
+def test_phases_at_tiny_size(patched, capsys):
+    assert chip_smoke.run_phases(256, 128, 16, 4, port=PORT, **TINY) == DEVICE
+    lines = [json.loads(x) for x in capsys.readouterr().out.strip().splitlines()]
+    # one JSON line per phase, in order, after the start line
+    by_phase = {rec["phase"]: rec for rec in lines}
+    assert list(by_phase) == ["start", "keygen", "trusted", "secure", "secure_gc"]
+    assert len(lines) == 5
+    assert by_phase["keygen"]["n"] == 256
+    trusted, secure, gc = (by_phase[k] for k in ("trusted", "secure", "secure_gc"))
+    assert (trusted["n"], trusted["levels"]) == (256, 16)
+    assert (secure["n"], secure["levels"]) == (128, 16)
+    assert (gc["n"], gc["levels"], gc["ot_path"]) == (128, 4, "gc")
+    # the oracle comparison ran against non-empty sets (run_phases raises
+    # on any mismatch, so reaching here means every set and count agreed)
+    assert trusted["hitters"] > 0 and secure["hitters"] > 0 and gc["frontier"] > 0
+    assert trusted["second_crawl"]["fresh_compiles"] == 0
+    for rec in (trusted, secure, gc):
+        assert rec["seconds"] >= rec["compile_seconds"] >= 0
+        assert rec["engines"]["platform"] == "cpu"
+        assert rec["compile_cache_dir"]
+
+
+def test_last_line_is_the_contract_and_nothing_more(monkeypatch, capsys):
+    monkeypatch.setattr(chip_smoke, "run_phases", lambda *a, **kw: DEVICE)
+    assert chip_smoke.main(["--seed", "3"]) == 0
+    out = capsys.readouterr().out.strip().splitlines()
+    assert json.loads(out[-1]) == {"ok": True, "device": DEVICE}
+
+
+def test_a_wrong_count_fails_the_run(patched, monkeypatch):
+    """The oracle comparison is a hard failure: a reference that disagrees
+    by one count stops the run at the trusted phase."""
+    real = chip_smoke.plain_count
+
+    def off_by_one(*a, **kw):
+        want = real(*a, **kw)
+        k = next(iter(want))
+        return {**want, k: want[k] + 1}
+
+    monkeypatch.setattr(chip_smoke, "plain_count", off_by_one)
+    with pytest.raises(chip_smoke.SmokeFailure, match="disagree"):
+        chip_smoke.run_phases(256, 128, 16, 4, port=PORT + 200, **TINY)

@@ -20,10 +20,7 @@ import jax.numpy as jnp
 from conftest import has_tpu as _has_tpu
 
 
-pytestmark = [
-    pytest.mark.skipif(not _has_tpu(), reason="needs a TPU backend"),
-    pytest.mark.tpu_retry,
-]
+pytestmark = pytest.mark.skipif(not _has_tpu(), reason="needs a TPU backend")
 
 
 def _seed_to_xla(planar):  # [4, d, 2, F, N] -> [F, N, d, 2, 4]

@@ -17,10 +17,7 @@ import pytest
 from conftest import has_tpu as _has_tpu
 
 
-pytestmark = [
-    pytest.mark.skipif(not _has_tpu(), reason="needs a TPU backend"),
-    pytest.mark.tpu_retry,
-]
+pytestmark = pytest.mark.skipif(not _has_tpu(), reason="needs a TPU backend")
 
 
 @pytest.mark.parametrize(

@@ -212,6 +212,8 @@ def test_chaos_flood_duplicates_the_frame():
                     got.append(await rpc._recv(reader))
             except (asyncio.IncompleteReadError, ConnectionResetError):
                 pass
+            finally:
+                writer.close()  # srv.wait_closed() waits for this end
 
         srv = await asyncio.start_server(sink, "127.0.0.1", port_s)
         px = await ChaosProxy(

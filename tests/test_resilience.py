@@ -238,6 +238,8 @@ def test_chaos_proxy_truncate_tears_the_frame():
                 got.append(await rpc._recv(reader))
             except (asyncio.IncompleteReadError, ConnectionResetError) as e:
                 got.append(("torn", type(e).__name__))
+            finally:
+                writer.close()  # srv.wait_closed() waits for this end
 
         srv = await asyncio.start_server(sink, "127.0.0.1", port_s)
         px = await ChaosProxy(
