@@ -34,10 +34,14 @@ the script fails before it runs anything.
 
 ``--chips 4`` runs ONLY the sharded-server comparison: the secure lane
 with ``server_data_devices=0`` (auto: all four chips) against the same
-keys on one device.  N is cut to 16384 there too (planned: 65536): one
-chip measured 296 ms per secure level at N=16384, so two 512-level crawls
-at four times the tests come to ~20 minutes before any compile — past the
-15-minute bound (and, at four chips a second, past this PR's chip budget).
+keys on one device.  N is cut to 2048 there (planned: 65536) by the
+15-minute cold wall clock: PR 25's four-chip run at N=16384 took 1468 s
+for the two lanes — 605 s of backend compiles, which do not shrink with
+N, and 862 s of crawling (0.88 and 0.80 s per level on that host), which
+is taken to shrink with N x frontier.  That leaves ~110 s of crawling
+and ~780 s in all at N=2048.  On four chips the sharded lane at N=2048
+read 205 s (141 s in compiles, 0.124 s per level outside them); the
+one-device lane has not finished there yet (PERF.md, PR 25).
 """
 
 from __future__ import annotations
@@ -67,6 +71,8 @@ from fuzzyheavyhitters_tpu.workloads import sample_points
 DATA_LEN = 512
 N_TRUSTED = 131072
 N_SECURE = 16384
+N_SHARDED = 2048  # --chips 4; planned N_SHARDED_PLANNED, see the docstring
+N_SHARDED_PLANNED = 65536
 GC_LEVELS = 16
 NUM_SITES = 10000
 ZIPF_EXPONENT = 1.03
@@ -110,7 +116,10 @@ def require_tpu(chips: int) -> dict:
 def check_engines(data_len: int) -> dict:
     """Every stage's engine is the chip's, and one expand level really
     lowers to a Mosaic kernel (not an interpret-mode or XLA-twin program)."""
-    tags = rpc.engine_tags()  # best_engine / _expand_engine / _ot2s_pallas_engine / _engine("gc")
+    # the process-wide selectors: best_engine / _expand_engine /
+    # _ot2s_pallas_engine / _engine("gc"); what each lane's servers then
+    # ran is CollectorServer.engine_tags(), printed with the lane
+    tags = rpc.engine_tags()
     _check(tags == dict(platform="tpu", keygen="pallas", expand="pallas",
                         ot2s="pallas", gc="pallas"),
            f"not the chip's engines: {tags}")
@@ -193,35 +202,16 @@ def _hbm_watermark() -> int | None:
     return max(int(ms["peak_bytes_in_use"]) for ms in stats)
 
 
-class _CompileSeconds:
-    """Seconds spent in fresh backend compiles, process-wide — the time
-    beside ``compile_cache.backend_compiles()``'s count (same
-    jax.monitoring event; listeners cannot unregister, so one instance)."""
-
-    def __init__(self):
-        self.total = 0.0
-        jax.monitoring.register_event_duration_secs_listener(self._on)
-
-    def _on(self, name, duration, **_kw):
-        if name == "/jax/core/compile/backend_compile_duration":
-            self.total += duration
-
-
-_compile_seconds: _CompileSeconds | None = None
-
-
 @contextlib.contextmanager
 def _timed(rec: dict):
     """Wall seconds of the block into ``rec``, with the fresh compiles
     inside it and the seconds left when their time is taken out."""
-    global _compile_seconds
-    if _compile_seconds is None:
-        _compile_seconds = _CompileSeconds()
-    n0, s0 = compile_cache.backend_compiles(), _compile_seconds.total
+    n0 = compile_cache.backend_compiles()
+    s0 = compile_cache.backend_compile_seconds()
     t = time.perf_counter()
     yield rec
     rec["seconds"] = time.perf_counter() - t
-    spent = _compile_seconds.total - s0
+    spent = compile_cache.backend_compile_seconds() - s0
     rec["fresh_compiles"] = compile_cache.backend_compiles() - n0
     rec["compile_seconds"] = spent
     rec["seconds_excluding_compile"] = rec["seconds"] - spent
@@ -260,7 +250,8 @@ async def _lane(cfg: Config, port: int, k0, k1, n: int, *, crawls: int = 1,
         await asyncio.gather(*(c.call("reset") for c in clients))
         t = time.perf_counter()
         await lead.upload_keys(k0, k1)
-        out = {"upload_seconds": time.perf_counter() - t, "crawls": []}
+        out = {"upload_seconds": time.perf_counter() - t, "crawls": [],
+               "bytes_in_use_after_ingest": _bytes_in_use()}
         for _ in range(crawls):
             with _timed({"result": None}) as crawl:
                 try:
@@ -269,7 +260,12 @@ async def _lane(cfg: Config, port: int, k0, k1, n: int, *, crawls: int = 1,
                     pass
             out["crawls"].append(crawl)
         out["tapped"] = lead.tapped
-        out["bytes_in_use"] = _bytes_in_use()
+        # the servers place their key planes at the first crawl step, not
+        # at upload: only this second sample shows where they live
+        out["bytes_in_use_keys_resident"] = _bytes_in_use()
+        e0, e1 = s0.engine_tags(), s1.engine_tags()
+        _check(e0 == e1, f"the two servers ran different engines: {e0} {e1}")
+        out["engines"] = e0
         out["key_devices"] = [
             sorted(d.id for d in leaf.sharding.device_set)
             for s in (s0, s1) for leaf in s.keys
@@ -320,6 +316,17 @@ def _keygen(pts, rng) -> tuple:
     return k0, k1
 
 
+def _check_lane_engines(name: str, lane: dict, data_devices: int) -> dict:
+    """What the lane's servers said they ran: a one-device server runs
+    the process's expand engine, a sharded one pins the XLA expand."""
+    tags = lane["engines"]
+    want = "xla" if data_devices > 1 else rpc.engine_tags()["expand"]
+    _check(tags["data_devices"] == data_devices and tags["expand"] == want,
+           f"{name}: servers ran {tags}, want expand={want} on "
+           f"{data_devices} device(s)")
+    return tags
+
+
 def _crawl_fields(crawl: dict) -> dict:
     return {k: v for k, v in crawl.items() if k != "result"}
 
@@ -334,22 +341,22 @@ def _check_crawl(name: str, crawl: dict, data_len: int, want: dict) -> None:
 def _start(chips: int, data_len: int, seed: int, threshold: float,
            f_max: int) -> tuple:
     """What every run does first: the platform check before anything else,
-    the compile cache, the engine checks; prints the start line.  Returns
-    (device for the final line, fields every phase line carries)."""
+    the compile cache, the engine checks; prints the start line (with the
+    process-wide engine selectors).  Returns (device for the final line,
+    fields every phase line carries)."""
     device = require_tpu(chips)
     cache_dir = compile_cache.enable()
     _check(cache_dir is not None,
            "the persistent compile cache could not be set up")
     if jax.default_backend() != "cpu":
         prg.CHACHA_UNROLL = True  # as bin/server does on an accelerator
-    check_engines(data_len)
     _emit(phase="start", device=device, seed=seed,
+          engines=check_engines(data_len),
           deployment="BASELINE.json flagship (1M clients x data_len=512, "
           "zipf(10000, 1.03), ball 2, two servers) cut to one chip by N only",
           threshold=threshold, f_max=f_max)
     return device, {
         "data_len": data_len,
-        "engines": rpc.engine_tags(),
         "compile_cache_dir": cache_dir,
         "native_reservoir": native.available(),
     }
@@ -377,7 +384,8 @@ def run_phases(n_trusted: int, n_secure: int, data_len: int, gc_levels: int,
     on_device = all(isinstance(x, jax.Array) for x in (*k0, *k1))
     _check(on_device == (ibdcf.best_engine() == "pallas"),
            "the Pallas keygen's keys did not stay on the device")
-    phase("keygen", n=n_trusted, **gen, keys_on_device=on_device,
+    phase("keygen", n=n_trusted, **gen, engine=ibdcf.best_engine(),
+          keys_on_device=on_device,
           key_bytes_per_server=sum(int(x.nbytes) for x in k0))
 
     # trusted: all levels, twice; the second crawl compiles nothing.  The
@@ -401,7 +409,8 @@ def run_phases(n_trusted: int, n_secure: int, data_len: int, gc_levels: int,
           hitters=len(want), key_fetch_seconds=fetch["seconds"],
           upload_seconds=lane["upload_seconds"],
           **_crawl_fields(first), second_crawl=_crawl_fields(second),
-          key_plane_bytes=lane["key_plane_bytes"])
+          key_plane_bytes=lane["key_plane_bytes"],
+          engines=_check_lane_engines("trusted", lane, 1))
 
     # secure: the first n_secure of the same points, own keys
     pts_s = pts[:n_secure]
@@ -417,9 +426,12 @@ def run_phases(n_trusted: int, n_secure: int, data_len: int, gc_levels: int,
     at_gc = _as_dict(*lane["tapped"])
     _compare(f"secure at depth {gc_levels}", at_gc,
              plain_count(pts_s, BALL_SIZE, gc_levels, thresh_s))
+    tags = _check_lane_engines("secure", lane, 1)
+    _check(tags["ot_path"] == secure.ot_path(2, "auto"),
+           f"secure lane took ot_path {tags['ot_path']}")
     phase("secure", n=n_secure, levels=data_len, threshold_count=thresh_s,
-          hitters=len(want_s), ot_path=secure.ot_path(2, "auto"),
-          upload_seconds=lane["upload_seconds"], **_crawl_fields(crawl))
+          hitters=len(want_s), upload_seconds=lane["upload_seconds"],
+          **_crawl_fields(crawl), engines=tags)
 
     # secure_gc: the same keys through the garbled-circuit path, to the
     # depth the secure lane was tapped at
@@ -429,8 +441,11 @@ def run_phases(n_trusted: int, n_secure: int, data_len: int, gc_levels: int,
     _check(lane["tapped"] is not None,
            f"secure_gc lane died before depth {gc_levels}")
     _compare("secure_gc", _as_dict(*lane["tapped"]), at_gc)
+    tags = _check_lane_engines("secure_gc", lane, 1)
+    _check(tags["ot_path"] == "gc",
+           f"secure_gc lane took ot_path {tags['ot_path']}")
     phase("secure_gc", n=n_secure, levels=gc_levels, threshold_count=thresh_s,
-          frontier=len(at_gc), ot_path="gc", **_crawl_fields(crawl))
+          frontier=len(at_gc), **_crawl_fields(crawl), engines=tags)
     return device
 
 
@@ -445,6 +460,8 @@ def run_sharded(n: int, data_len: int, *, seed: int = 0,
     Returns the device (for the final line)."""
     device, common = _start(4, data_len, seed, threshold, f_max)
     n_dev = len(jax.local_devices())
+    if data_devices > 0:  # a rehearsal names its count; the chip run is auto
+        n_dev = min(n_dev, data_devices)
     base = dataclasses.replace(
         _config(data_len, num_sites, threshold, f_max), secure_exchange=True
     )
@@ -468,13 +485,17 @@ def run_sharded(n: int, data_len: int, *, seed: int = 0,
                        for m in lane["mesh_devices"]),
                    f"ServerMesh.devices: {lane['mesh_devices']}")
         _emit(
-            phase=name, n=n, levels=data_len, threshold_count=thresh,
-            hitters=len(want), server_data_devices=dd,
-            mesh_devices=lane["mesh_devices"],
+            phase=name, n=n, n_planned=N_SHARDED_PLANNED, levels=data_len,
+            threshold_count=thresh, hitters=len(want),
+            server_data_devices=dd, mesh_devices=lane["mesh_devices"],
             key_plane_devices=lane["key_devices"][0],
-            bytes_in_use_per_device_after_ingest=lane["bytes_in_use"],
+            bytes_in_use_per_device_after_ingest=lane[
+                "bytes_in_use_after_ingest"],
+            bytes_in_use_per_device_keys_resident=lane[
+                "bytes_in_use_keys_resident"],
+            upload_seconds=lane["upload_seconds"],
             **_crawl_fields(crawl), hbm_watermark_bytes=_hbm_watermark(),
-            **common,
+            engines=_check_lane_engines(name, lane, spread), **common,
         )
     return device
 
@@ -485,7 +506,7 @@ def main(argv=None) -> int:
     p.add_argument("--chips", type=int, choices=(1, 4), default=1)
     args = p.parse_args(argv)
     if args.chips == 4:
-        device = run_sharded(N_SECURE, DATA_LEN, seed=args.seed)
+        device = run_sharded(N_SHARDED, DATA_LEN, seed=args.seed)
     else:
         device = run_phases(N_TRUSTED, N_SECURE, DATA_LEN, GC_LEVELS,
                             seed=args.seed)

@@ -314,11 +314,13 @@ def _session_metrics_producer(ref):
 
 
 def engine_tags() -> dict:
-    """Which engine each stage of a crawl runs in THIS process, as the
-    selectors resolve it right now (they follow the effective platform:
-    the Pallas kernels on an accelerator, the NumPy/XLA twins on a CPU
-    host).  Every server emits it once at start so a run's log says
-    which ran; chip_smoke.py asserts the chip's set."""
+    """Which engine each stage resolves to in THIS process, as the
+    selectors stand right now (they follow the effective platform: the
+    Pallas kernels on an accelerator, the NumPy/XLA twins on a CPU
+    host).  What a given server runs can be narrower — a sharded server
+    pins the XLA expand — so a server's log line and chip_smoke.py's
+    phase lines carry :meth:`CollectorServer.engine_tags`; chip_smoke.py
+    asserts this process-wide set before it starts anything."""
     from ..ops import ibdcf
     from ..utils import effective_platform
 
@@ -409,6 +411,34 @@ class CollectorServer:
 
     def _default(self) -> CollectionSession:
         return self._table.default()
+
+    def engine_tags(self) -> dict:
+        """What THIS server's collections run — :func:`engine_tags`
+        narrowed by the session layout its config resolves to (the rules
+        of ``CollectionSession.__init__``/``planar``, read without
+        creating a session): the expand engine is the Pallas one only
+        for a planar session (one device, radix 1), a sharded server's
+        2PC kernels are ``kernel_shard``'s per-shard engines, and a
+        secure server names the equality path its config picks.  Emitted
+        once at start; ``keygen`` is left out (the leader's)."""
+        n = smesh.resolve_data_devices(self.cfg.server_data_devices)
+        radix = int(self.cfg.crawl_radix_bits)
+        tags = {k: v for k, v in engine_tags().items() if k != "keygen"}
+        tags["data_devices"] = n
+        tags["expand"] = (
+            "pallas" if sessions.planar_layout(radix, n == 1) else "xla"
+        )
+        if n > 1:
+            tags["ot2s"] = kernel_shard._engine("ot2s")
+            # the budget once keys are bound (ServerMesh.kernel_budget);
+            # a level that fills fewer planar blocks runs on fewer
+            req = int(self.cfg.secure_kernel_shards)
+            tags["kernel_shards_max"] = n if req <= 0 else max(1, min(req, n))
+        if self.cfg.secure_exchange:
+            tags["ot_path"] = secure.ot_path(
+                2 * self.cfg.n_dims * radix, self.cfg.ot_path
+            )
+        return tags
 
     @property
     def _mesh(self):
@@ -3152,7 +3182,7 @@ class CollectorServer:
         per-session secure handshakes (base-OT etc.) run lazily when each
         collection first touches the plane."""
         self._peer_addr = (peer_host, peer_port)
-        obs.emit("server.engines", server=self.server_id, **engine_tags())
+        obs.emit("server.engines", server=self.server_id, **self.engine_tags())
         with self.obs.span("setup"):
             if self.server_id == 1:
                 srv = await asyncio.start_server(self._on_peer, host, peer_port)

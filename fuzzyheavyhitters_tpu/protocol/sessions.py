@@ -289,6 +289,13 @@ _SESSION_GUARDS = {
 }
 
 
+def planar_layout(radix: int, one_device: bool) -> bool:
+    """The layout rule behind :meth:`CollectionSession.planar`, as a
+    function of what a config resolves to (``CollectorServer.engine_tags``
+    reads it at start, before any session exists)."""
+    return collect._expand_engine() and one_device and int(radix) == 1
+
+
 class CollectionSession:
     """One collection's complete server-side state (see module doc).
 
@@ -494,8 +501,7 @@ class CollectionSession:
         no sharded operands), and under radix > 1 fusion, whose
         multi-step expand is implemented on the interleaved/XLA engine
         only (collect.expand_share_bits_radix)."""
-        return (collect._expand_engine() and self._mesh is None
-                and self._radix == 1)
+        return planar_layout(self._radix, self._mesh is None)
 
     def crawl_radix(self, level) -> int:  # fhh-race: atomic (pure read of init-time state)
         """Fused bit count of the crawl round based at bit ``level``:

@@ -23,7 +23,11 @@ tests/conftest.py all call it at startup.
 
 :func:`backend_compiles` counts fresh XLA backend compiles process-wide
 (via jax.monitoring) — the acceptance instrument for warmup coverage:
-a crawl over warmed shapes must add ZERO to it.
+a crawl over warmed shapes must add ZERO to it.  Under the installed JAX
+the event also fires when the PERSISTENT cache serves the program (PR 25's
+warm chip run counted as many as its cold one); only the in-process
+executable cache keeps the count still, and
+:func:`backend_compile_seconds` shows what a persistent hit saved.
 """
 
 from __future__ import annotations
@@ -41,23 +45,23 @@ _enabled: str | None = None
 # per call, or a warmup coverage hole all break that loudly).
 _compile_lock = threading.Lock()
 _compile_count = 0  # fhh-guard: _compile_count=_compile_lock
+_compile_seconds = 0.0  # fhh-guard: _compile_seconds=_compile_lock
 _listener_on = False  # fhh-guard: _listener_on=_compile_lock
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
-def _on_event_duration(name: str, *_a, **_k) -> None:
-    global _compile_count
+def _on_event_duration(name: str, duration: float = 0.0, *_a, **_k) -> None:
+    global _compile_count, _compile_seconds
     if name == _COMPILE_EVENT:
         with _compile_lock:
             _compile_count += 1
+            _compile_seconds += duration
 
 
-def backend_compiles() -> int:
-    """Process-wide count of fresh XLA backend compiles so far.  The
-    jax.monitoring listener registers on first use (and stays for the
-    process lifetime — listeners cannot unregister portably); snapshot
-    before and after the measured region and compare deltas."""
+def _listen() -> None:
+    """Register the one jax.monitoring listener on first use (it stays
+    for the process lifetime — listeners cannot unregister portably)."""
     global _listener_on
     with _compile_lock:
         if not _listener_on:
@@ -67,7 +71,22 @@ def backend_compiles() -> int:
                 _on_event_duration
             )
             _listener_on = True
+
+
+def backend_compiles() -> int:
+    """Process-wide count of fresh XLA backend compiles so far; snapshot
+    before and after the measured region and compare deltas."""
+    _listen()
+    with _compile_lock:
         return _compile_count
+
+
+def backend_compile_seconds() -> float:
+    """Seconds those compiles took, process-wide (the same events'
+    durations) — what chip_smoke.py takes out of a phase's wall clock."""
+    _listen()
+    with _compile_lock:
+        return _compile_seconds
 
 
 # <checkout>/.jax_cache: beside the package, wherever the checkout sits

@@ -4,8 +4,8 @@ Multi-chip sharding (the 2-server mesh axis plus client data-parallel axis)
 is exercised on virtual CPU devices, per the reference's in-process
 integration-test shape (two servers' state machines in one process,
 ref: tests/collect_test.rs).  The suite never touches an accelerator: the
-Pallas engines would run here only in interpret mode (see interpret_mode_hangs
-below), their Mosaic compiles are pinned
+Pallas engines run here only in interpret mode (tests/test_secure_kernels.py,
+tests/test_kernel_shard.py), their Mosaic compiles are pinned
 by tests/test_chip_compile.py against a DESCRIBED v5e topology, and the
 chip itself is reached only by ``python chip_smoke.py`` through the
 builder's chip tool.
@@ -21,10 +21,18 @@ if "xla_force_host_platform_device_count" not in xla_flags:
     # optimization_level=1: XLA:CPU's default pipeline takes minutes to
     # compile a lax.scan whose body contains the ChaCha expansion (253 s vs
     # 1.4 s measured); level 1 sidesteps the pathological pass.
+    # use_fusion_emitters=false: a Pallas kernel in interpret mode is one
+    # while-loop whose body XLA:CPU fuses into a single ~760-op loop
+    # fusion (the in-kernel ChaCha carries no optimization_barrier).
+    # With the installed jaxlib's fusion emitters, compile() returns in
+    # a second and the first call then spins one core without returning
+    # (20 minutes for a 40-test ot2s encrypt, at every optimization
+    # level); with them off the same call takes 6 ms (PR 25).
     os.environ["XLA_FLAGS"] = (
         xla_flags
         + " --xla_force_host_platform_device_count=8"
         + " --xla_backend_optimization_level=1"
+        + " --xla_cpu_use_fusion_emitters=false"
     ).strip()
 
 import jax  # noqa: E402
@@ -44,22 +52,6 @@ jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.3)
 
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
-
-
-# Pallas interpret mode — the only way a CPU host can EXECUTE the TPU
-# kernels — does not finish under the installed JAX 0.9 / XLA:CPU: a
-# 40-test ot2s encrypt (one 8192-test block, 4 grid steps, lowered and
-# compiled in ~2 s) was still executing after 20 minutes of CPU, at every
-# --xla_backend_optimization_level and with one or eight host devices
-# (PR 25).  The seven parity tests that execute a kernel that way skip
-# with this marker instead of wedging the suite; the same kernels are
-# AOT-compiled for a described v5e by tests/test_chip_compile.py and
-# checked against a plain count on the chip by chip_smoke.py.  Lift the
-# marker when a JAX upgrade makes interpret mode usable again.
-interpret_mode_hangs = pytest.mark.skip(
-    reason="Pallas interpret mode does not finish on this JAX/XLA:CPU "
-    "(see tests/conftest.py)"
-)
 
 
 def has_tpu() -> bool:

@@ -69,7 +69,12 @@ def test_phases_at_tiny_size(patched, capsys):
     trusted, secure, gc = (by_phase[k] for k in ("trusted", "secure", "secure_gc"))
     assert (trusted["n"], trusted["levels"]) == (256, 16)
     assert (secure["n"], secure["levels"]) == (128, 16)
-    assert (gc["n"], gc["levels"], gc["ot_path"]) == (128, 4, "gc")
+    assert (gc["n"], gc["levels"]) == (128, 4)
+    # each lane line says what ITS servers ran (CollectorServer.engine_tags)
+    assert by_phase["keygen"]["engine"] == "np"
+    assert "ot_path" not in trusted["engines"]
+    assert secure["engines"]["ot_path"] == "ot2s"
+    assert gc["engines"]["ot_path"] == "gc"
     # the oracle comparison ran against non-empty sets (run_phases raises
     # on any mismatch, so reaching here means every set and count agreed)
     assert trusted["hitters"] > 0 and secure["hitters"] > 0 and gc["frontier"] > 0
@@ -77,7 +82,33 @@ def test_phases_at_tiny_size(patched, capsys):
     for rec in (trusted, secure, gc):
         assert rec["seconds"] >= rec["compile_seconds"] >= 0
         assert rec["engines"]["platform"] == "cpu"
+        assert rec["engines"]["expand"] == "xla"
+        assert rec["engines"]["data_devices"] == 1
         assert rec["compile_cache_dir"]
+
+
+def test_sharded_comparison_at_tiny_size(patched, capsys):
+    """``--chips 4``'s path on four of the suite's virtual CPU devices:
+    both lanes agree with the plain count, the sharded lane's servers say
+    so (four data devices, XLA expand) and its key planes really spread;
+    bytes in use are sampled right after ingest and again after the
+    crawl, with the key planes resident."""
+    assert chip_smoke.run_sharded(
+        512, 16, data_devices=4, port=PORT + 400, num_sites=8,
+        threshold=0.012, f_max=64,
+    ) == DEVICE
+    lines = [json.loads(x) for x in capsys.readouterr().out.strip().splitlines()]
+    assert [rec["phase"] for rec in lines] == ["start", "sharded", "one_device"]
+    sharded, one = lines[1:]
+    assert sharded["n"] == one["n"] == 512 and sharded["hitters"] > 0
+    assert sharded["engines"]["data_devices"] == 4
+    assert sharded["engines"]["expand"] == "xla"
+    assert sharded["engines"]["kernel_shards_max"] == 4
+    assert len(sharded["key_plane_devices"]) == 4
+    assert one["engines"]["data_devices"] == 1 and one["key_plane_devices"] == [0]
+    for rec in (sharded, one):
+        assert len(rec["bytes_in_use_per_device_after_ingest"]) == 8
+        assert len(rec["bytes_in_use_per_device_keys_resident"]) == 8
 
 
 def test_last_line_is_the_contract_and_nothing_more(monkeypatch, capsys):

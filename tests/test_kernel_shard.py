@@ -17,7 +17,6 @@ import pytest
 import jax
 from jax.sharding import PartitionSpec as P
 
-from conftest import interpret_mode_hangs
 from fuzzyheavyhitters_tpu.ops import baseot, gc, otext
 from fuzzyheavyhitters_tpu.ops.fields import F255, FE62
 from fuzzyheavyhitters_tpu.parallel import kernel_shard
@@ -128,7 +127,6 @@ def test_wire_byte_identity(k, path, field, ot_material, flat_bits):
     assert rcv.consumed == r_cons
 
 
-@interpret_mode_hangs
 @pytest.mark.parametrize("path", ["ot2s", "gc"])
 def test_pallas_under_shard_map_parity(path, ot_material):
     """shard_map-Pallas vs XLA-twin per-shard parity (interpret mode):

@@ -14,7 +14,6 @@ import pytest
 
 import jax.numpy as jnp
 
-from conftest import interpret_mode_hangs
 from fuzzyheavyhitters_tpu.obs import report as obsreport
 from fuzzyheavyhitters_tpu.ops import gc, gc_pallas, ibdcf, otext, otext_pallas
 from fuzzyheavyhitters_tpu.ops.fields import F255, FE62
@@ -208,7 +207,6 @@ def _ot2s_planar_parity(rng, S, field):
     np.testing.assert_array_equal(pay_x, np.where(eq[:, None], m1, m0))
 
 
-@interpret_mode_hangs
 @pytest.mark.parametrize("S", [2, 4])
 @pytest.mark.parametrize("field", [FE62, F255], ids=["FE62", "F255"])
 def test_ot2s_planar_engine_parity(rng, S, field):
@@ -226,7 +224,6 @@ def test_ot2s_planar_engine_parity_s6(rng, field):
     _ot2s_planar_parity(rng, 6, field)
 
 
-@interpret_mode_hangs
 def test_gc_packed_engine_parity(rng):
     """The packed whole-level garbled message is byte-identical between
     the XLA twin and the Pallas kernel, and its eval twins agree."""
