@@ -126,8 +126,7 @@ async def _run(cfg, nreqs: int, rng) -> None:
             0, 2**32, size=(nreqs, cfg.n_dims, 2, 4), dtype=np.uint32
         )
         cseed = rng.integers(0, 2**32, size=4, dtype=np.uint32)
-        with reg.span("sketch_gen"):
-            sk0, sk1 = sketchmod.gen(seeds, pts, FE62, F255, cseed)
+        sk0, sk1 = sketchmod.gen(seeds, pts, FE62, F255, cseed)
 
     h0, p0 = _split(cfg.server0)
     h1, p1 = _split(cfg.server1)

@@ -3,7 +3,7 @@
 One function, :func:`emit`, replaces every crawl-path ``print``:
 
     emit("crawl.done", seconds=3.21)
-    emit("level.phases", severity="debug", level=5, fss=0.12, ...)
+    emit("plane.session_keyed", severity="debug", server=0, epoch=1, ...)
 
 Human mode (default) renders one aligned line per event::
 

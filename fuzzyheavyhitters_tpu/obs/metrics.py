@@ -20,7 +20,8 @@ thread reads to name the active phase and level of a wedged run.  A
 counter incremented inside a span inherits the span's ``level`` when the
 call site doesn't know it (the data-plane byte accounting in
 ``protocol/rpc.py`` attributes bytes to the level whose exchange sent
-them this way).
+them this way), and so does a span opened without one (the wire and
+transfer spans of the same helpers).
 
 Thread-safety: one lock per registry guards every mutation and the
 report snapshot; the heartbeat thread reads span stacks concurrently
@@ -314,15 +315,24 @@ class _SpanCtx:
         self._reg, self._name, self._level = reg, name, level
 
     def __enter__(self) -> Span:
-        self._span = Span(self._name, self._level)
+        sp = self._span = Span(self._name, self._level)
+        with self._reg._lock:
+            if sp.level is None:
+                # like a counter, a span that does not know its level
+                # takes the enclosing span's: the wire and transfer
+                # spans deep in the framing helpers land on the level
+                # whose exchange they served
+                sp.level = self._reg._span_level_locked()
+            self._reg._spans.append(sp)
         # distributed tracing (obs.trace): under an active trace context
         # this span records as a child event in the per-process ring —
         # one enabled() flag read when tracing is off (the pinned
         # zero-overhead contract, like FHH_DEBUG_GUARDS)
-        self._trace = _trace.span_begin() if _trace.enabled() else None
-        with self._reg._lock:
-            self._reg._spans.append(self._span)
-        return self._span
+        self._trace = (
+            _trace.span_begin(self._name, self._reg.name, sp.level)
+            if _trace.enabled() else None
+        )
+        return sp
 
     def __exit__(self, exc_type, exc, tb) -> None:
         dt = self._span.seconds = self._span.elapsed()
@@ -337,11 +347,8 @@ class _SpanCtx:
             # a span unwound by an exception (a severed data plane
             # failing a mid-exchange verb) records error=true instead of
             # dangling open in the merged trace
-            _trace.span_end(
-                self._trace, self._name, self._reg.name,
-                level=self._span.level, error=exc_type is not None,
-            )
-        self._reg.timer_add(self._name, dt, self._level)
+            _trace.span_end(self._trace, error=exc_type is not None)
+        self._reg.timer_add(self._name, dt, self._span.level)
 
 
 def default_registry() -> Registry:

@@ -33,6 +33,12 @@ tie, with the same zero-cost-when-disabled contract as
   :func:`validate` is the structural gate tests and CI assert on:
   every parented event's parent exists, durations are non-negative,
   and clock offsets are finite.
+- **One clock with the device trace** — while tracing is enabled every
+  recorded span also enters a ``jax.profiler.TraceAnnotation`` named
+  ``<comp>:<name>`` (:func:`annotate`), so a profiler capture carries
+  the program's spans on the profiler's own clock, beside the device
+  operations.  ``jax.profiler`` is imported at the first traced span,
+  never while tracing is off.
 - **Chip profiler hooks** — ``FHH_PROFILE=<dir>`` wraps each crawl
   (or only levels named by ``FHH_PROFILE_LEVELS=2,3``) in
   ``jax.profiler`` start/stop, recording the capture alongside the
@@ -319,8 +325,37 @@ def deactivate(token) -> None:
 
 # -- span recording (driven by obs.metrics._SpanCtx) ------------------------
 
+# jax.profiler.TraceAnnotation, imported at the first traced span (obs/
+# stays free of a jax import while tracing is off); False = no profiler
+# in this install, the spans then go to the ring alone
+_ANNOTATION = None
 
-def span_begin() -> "list | None":
+
+def annotate(comp: str, name: str, level=None):
+    """A ``jax.profiler.TraceAnnotation`` named ``<comp>:<name>`` (not
+    yet entered), or None where tracing is off or JAX has no profiler.
+    Outside a capture entering one costs a flag read in the profiler."""
+    global _ANNOTATION
+    if not enabled():
+        return None
+    cls = _ANNOTATION  # set once under _LOCK, like _ENABLED
+    if cls is None:
+        with _LOCK:
+            if _ANNOTATION is None:
+                try:
+                    from jax.profiler import TraceAnnotation
+                except ImportError:
+                    TraceAnnotation = False
+                _ANNOTATION = TraceAnnotation
+            cls = _ANNOTATION
+    if cls is False:
+        return None
+    if level is None:
+        return cls(f"{comp}:{name}")
+    return cls(f"{comp}:{name}", level=int(level))
+
+
+def span_begin(name: str, comp: str, level=None) -> "list | None":
     """Open a trace span under the active context; returns opaque state
     for :func:`span_end`, or None when no trace is active.  Callers
     check :func:`enabled` first — this is the slow path."""
@@ -330,24 +365,42 @@ def span_begin() -> "list | None":
     tid, parent = ctx
     sid = _new_id()
     tok = _CTX.set((tid, sid))
-    return [tid, sid, parent, tok, time.time()]
+    ann = annotate(comp, name, level)
+    if ann is not None:
+        ann.__enter__()
+    return [name, comp, level, tid, sid, parent, tok, ann, time.time()]
 
 
-def span_end(
-    state: list, name: str, comp: str,
-    level=None, error: bool = False,
-) -> None:
-    tid, sid, parent, tok, t0 = state
+def span_end(state: list, error: bool = False) -> None:
+    name, comp, level, tid, sid, parent, tok, ann, t0 = state
+    dur = time.time() - t0
+    if ann is not None:
+        ann.__exit__(None, None, None)
     try:
         _CTX.reset(tok)
     except ValueError:
         pass  # entered/exited across tasks (manually managed span ctx)
+    _span_event(name, comp, t0, dur, tid, sid, parent, level, error)
+
+
+def span_at(name: str, comp: str, ts: float, dur: float, level=None) -> None:
+    """Record a complete span whose times were taken elsewhere (the
+    data-plane pump stamps a frame's read and unpickle; the receiver
+    records them), as a child of the active span.  ``ts`` is wall-clock
+    seconds.  No-op outside a trace."""
+    ctx = _CTX.get()
+    if ctx is None:
+        return
+    _span_event(name, comp, ts, dur, ctx[0], _new_id(), ctx[1], level, False)
+
+
+def _span_event(name, comp, ts, dur, tid, sid, parent, level, error) -> None:
     rec = {
         "ph": "X",
         "name": name,
         "comp": comp,
-        "ts": round(t0, 6),
-        "dur": round(time.time() - t0, 6),
+        "ts": round(ts, 6),
+        "dur": round(max(0.0, dur), 6),
         "trace": tid,
         "span": sid,
     }

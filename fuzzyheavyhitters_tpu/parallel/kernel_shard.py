@@ -437,19 +437,24 @@ def _u32(x) -> jax.Array:
     return jnp.asarray(np.uint32(x & 0xFFFFFFFF))
 
 
-def snd_extend(ks: KernelShard, snd: otext.OtExtSender, u_np):
-    """Sender half of the row-sharded extension: pad the peer's u-matrix
-    to the planar word extent, extend per shard, advance the session
-    cursor exactly like a single-device ``extend``.  Returns (Q rows
-    sharded [bp*S, 4], idx0 — the pre-batch pad index base)."""
+def put_u(ks: KernelShard, u_np) -> jax.Array:
+    """The peer's wire u-matrix padded to the planar word extent and
+    placed column-sharded: the host->device half of :func:`snd_extend`,
+    apart so the server can time it (its ``h2d`` span)."""
+    u_np = np.asarray(u_np, np.uint32)
+    u_pad = np.zeros((128, ks.bp * ks.S // 32), np.uint32)
+    u_pad[:, : u_np.shape[1]] = u_np
+    return jax.device_put(u_pad, ks.sharding(P(None, DATA)))
+
+
+def snd_extend(ks: KernelShard, snd: otext.OtExtSender, u_dev):
+    """Sender half of the row-sharded extension: extend per shard over
+    the peer's u-matrix (:func:`put_u` of the wire array), advance the
+    session cursor exactly like a single-device ``extend``.  Returns
+    (Q rows sharded [bp*S, 4], idx0 — the pre-batch pad index base)."""
     B, S = ks.B, ks.S
     idx0 = snd.consumed
     off = snd.stream_offset
-    u_np = np.asarray(u_np, np.uint32)
-    wp = ks.bp * S // 32
-    u_pad = np.zeros((128, wp), np.uint32)
-    u_pad[:, : u_np.shape[1]] = u_np
-    u_dev = jax.device_put(u_pad, ks.sharding(P(None, DATA)))
     seeds, s_bits = snd.shard_state
     q = _snd_extend_fn(ks.devices, B, S)(seeds, s_bits, u_dev, _u32(off))
     snd.advance(B * S)
@@ -488,20 +493,28 @@ def gb_kernel(ks: KernelShard, s_block, q, flat, gc_seed, b2a_seed, field,
     )
 
 
-def ev_open(ks: KernelShard, t_rows, flat, msg_np, field, path: str,
+def put_msg(ks: KernelShard, msg_np, field, path: str) -> jax.Array:
+    """The wire frame as its plane stack, placed row-sharded (host
+    slices land directly on their devices — no single-device staging):
+    the host->device half of :func:`ev_open`, apart so the server can
+    time it (its ``h2d`` span)."""
+    from ..protocol import secure
+
+    planes = np.asarray(msg_np, np.uint32).reshape(
+        n_msg_planes(path, ks.S, secure.payload_words(field)),
+        ks.bp // GROUP, SUB, LANES,
+    )
+    return jax.device_put(planes, ks.sharding(P(None, DATA, None, None)))
+
+
+def ev_open(ks: KernelShard, t_rows, flat, msg_dev, field, path: str,
             idx0: int, engine: str | None = None):
-    """Receiver whole-level open per shard: uploads the wire frame
-    row-sharded (host slices land directly on their devices — no
-    single-device staging) and opens each shard's slice.  Returns vals
+    """Receiver whole-level open per shard over the wire frame
+    (:func:`put_msg` of it): opens each shard's slice.  Returns vals
     test-sharded."""
     from ..protocol import secure
 
     W = secure.payload_words(field)
-    path_planes = n_msg_planes(path, ks.S, W)
-    planes = np.asarray(msg_np, np.uint32).reshape(
-        path_planes, ks.bp // GROUP, SUB, LANES
-    )
-    msg_dev = jax.device_put(planes, ks.sharding(P(None, DATA, None, None)))
     fn = _ev_open_fn(
         ks.devices, field.__name__, ks.B, ks.S, W, path,
         engine or _engine(path),
@@ -584,13 +597,14 @@ def run_level_pair(ks: KernelShard, snd: otext.OtExtSender,
     vals_rcv) with the vals still test-sharded on device."""
     u, t_rows, idx0_r = rcv_extend(ks, rcv, flat_rcv)
     u_np = u_wire(ks, u)
-    q, idx0_s = snd_extend(ks, snd, u_np)
+    q, idx0_s = snd_extend(ks, snd, put_u(ks, u_np))
     planes, vals_s = gb_kernel(
         ks, snd.s_block, q, flat_snd, gc_seed, b2a_seed, field, garbler,
         path, idx0_s, engine=engine,
     )
     msg_np = msg_wire(ks, planes)
     vals_r = ev_open(
-        ks, t_rows, flat_rcv, msg_np, field, path, idx0_r, engine=engine
+        ks, t_rows, flat_rcv, put_msg(ks, msg_np, field, path), field, path,
+        idx0_r, engine=engine,
     )
     return u_np, msg_np, vals_s, vals_r

@@ -413,28 +413,32 @@ class RpcLeader:
             a0, _ = await self._both("sketch_verify", {"level": level})
             alive_after_verify = np.asarray(a0)
         s0, s1 = await self._crawl_level(level, last)
-        if last:
-            v = np.asarray(F255.sub(s0, s1))  # leader-side reconstruct
-            counts = v[..., 0].astype(np.uint32)  # counts < 2^32 by def
-            if np.any(v[..., 1:]):  # boundary check: must survive -O
-                raise RuntimeError("non-count residue in F255 share")
-        else:
-            v = np.asarray(FE62.canon(FE62.sub(s0, s1)))
-            if np.any(v > nreqs):  # e.g. a share-sign/role mismatch
-                raise RuntimeError("count reconstruction out of range")
-            counts = v.astype(np.uint32)
-        # radix_pattern_order permutes the fused (step-major) child
-        # columns into the order a k=1 crawl would visit them, so
-        # compact_survivors' walk — and therefore any f_max truncation —
-        # is bit-identical to the sequential crawl (identity at r=1)
-        order = collect.radix_pattern_order(d, r)
-        keep = counts[:, order] >= thresh
-        keep[self.n_nodes :, :] = False
-        parent, rank, n_alive = collect.compact_survivors(
-            keep, cfg.f_max, self.min_bucket
-        )
-        pattern = order[rank]
-        pat_bits = collect.pattern_to_bits_radix(pattern, d, r)
+        # the leader's own work of a level, outside the servers' verbs:
+        # reconstruct, threshold, prune (the verb pair), paths
+        with self.obs.span("reconstruct", level=level):
+            if last:
+                v = np.asarray(F255.sub(s0, s1))  # leader-side reconstruct
+                counts = v[..., 0].astype(np.uint32)  # counts < 2^32 by def
+                if np.any(v[..., 1:]):  # boundary check: must survive -O
+                    raise RuntimeError("non-count residue in F255 share")
+            else:
+                v = np.asarray(FE62.canon(FE62.sub(s0, s1)))
+                if np.any(v > nreqs):  # e.g. a share-sign/role mismatch
+                    raise RuntimeError("count reconstruction out of range")
+                counts = v.astype(np.uint32)
+        with self.obs.span("threshold", level=level):
+            # radix_pattern_order permutes the fused (step-major) child
+            # columns into the order a k=1 crawl would visit them, so
+            # compact_survivors' walk — and therefore any f_max truncation
+            # — is bit-identical to the sequential crawl (identity at r=1)
+            order = collect.radix_pattern_order(d, r)
+            keep = counts[:, order] >= thresh
+            keep[self.n_nodes :, :] = False
+            parent, rank, n_alive = collect.compact_survivors(
+                keep, cfg.f_max, self.min_bucket
+            )
+            pattern = order[rank]
+            pat_bits = collect.pattern_to_bits_radix(pattern, d, r)
         self.obs.gauge("survivors", n_alive, level=level)
         if n_alive == 0:
             return None, alive_after_verify
@@ -443,31 +447,35 @@ class RpcLeader:
         # transcript ratchet absorbs the wire bytes — k=1 must stay
         # digest-identical); r > 1 sends the fused [F', r, d] form
         wire_bits = pat_bits[:, 0, :] if r == 1 else pat_bits
-        if last:
-            await self._both(
-                "tree_prune_last",
-                {
-                    "parent_idx": parent,
-                    "pattern_bits": wire_bits,
-                    "n_alive": n_alive,
-                },
+        with self.obs.span("prune", level=level):
+            if last:
+                await self._both(
+                    "tree_prune_last",
+                    {
+                        "parent_idx": parent,
+                        "pattern_bits": wire_bits,
+                        "n_alive": n_alive,
+                    },
+                )
+            else:
+                await self._both(
+                    "tree_prune",
+                    {
+                        "level": level,
+                        "parent_idx": parent,
+                        "pattern_bits": wire_bits,
+                        "n_alive": n_alive,
+                    },
+                )
+        with self.obs.span("paths", level=level):
+            new_paths = np.zeros(
+                (n_alive, d, self.paths.shape[-1] + r), bool
             )
-        else:
-            await self._both(
-                "tree_prune",
-                {
-                    "level": level,
-                    "parent_idx": parent,
-                    "pattern_bits": wire_bits,
-                    "n_alive": n_alive,
-                },
-            )
-        new_paths = np.zeros((n_alive, d, self.paths.shape[-1] + r), bool)
-        for i in range(n_alive):
-            new_paths[i, :, : -r] = self.paths[parent[i]]
-            for t in range(r):
-                new_paths[i, :, -r + t] = pat_bits[i, t]
-        self.paths = new_paths
+            for i in range(n_alive):
+                new_paths[i, :, : -r] = self.paths[parent[i]]
+                for t in range(r):
+                    new_paths[i, :, -r + t] = pat_bits[i, t]
+            self.paths = new_paths
         self.n_nodes = n_alive
         return counts[parent[:n_alive], pattern[:n_alive]], alive_after_verify
 
@@ -1135,11 +1143,8 @@ class WindowedIngest:
                     self.policy.delay(attempt - 1),
                 )
             )
-        if rec["shed"]:
-            # fhh-lint: disable=stale-read-across-await (deliberate snapshot: the stats must label the window this submission actually LANDED in — the admission-time id banked under the lock; a post-await re-read would mislabel it with whatever window is current now)
-            self.obs.count("ingest_shed_subs", level=w)
-        else:
-            # fhh-lint: disable=stale-read-across-await (deliberate snapshot, same contract as the shed branch: the admitted count labels the window this submission LANDED in — the id banked under the lock at gate time, not whatever window is current after the backoff awaits)
+        if not rec["shed"]:
+            # fhh-lint: disable=stale-read-across-await (deliberate snapshot: the admitted count labels the window this submission LANDED in — the id banked under the lock at gate time, not whatever window is current after the backoff awaits)
             self.obs.count("ingest_admitted", n_keys, level=w)
         self.obs.observe("ingest_admit", time.perf_counter() - t_admit)
         return r0

@@ -127,40 +127,63 @@ from .sessions import (  # noqa: F401  (re-exports: wire-format helpers kept imp
 _HDR = struct.Struct("<Q")
 
 
-async def _send(writer: asyncio.StreamWriter, obj, count=None,
+_NO_CTX = contextlib.nullcontext()
+
+
+def _no_span(_name):
+    return _NO_CTX
+
+
+async def _send(writer: asyncio.StreamWriter, obj, reg=None, counter=None,
                 flush: bool = True) -> None:
-    """``count``, when given, is called with the framed byte size — the
-    data-plane accounting hook (obs counters).  ``flush=False`` skips the
+    """``reg``, when given, is the registry of the side doing the work:
+    it times ``wire_pickle`` (``pickle.dumps`` and the header + body
+    join, a second whole-frame copy) and ``wire_write`` (``write`` until
+    ``drain()`` has returned; the write alone on an unflushed frame),
+    and adds the framed byte size to its counter ``counter`` — the
+    data-plane accounting hook.  ``flush=False`` skips the
     ``drain()`` backpressure wait: asyncio delivers the buffered bytes
     regardless (drain only waits when the write buffer tops the
     high-water mark), so a burst of consecutive frames can coalesce into
     ONE drain on its final frame instead of one await per frame — but
     some frame in every burst MUST flush, or a dead peer lets the buffer
     grow without bound."""
-    data = pickle.dumps(obj, protocol=5)
-    if count is not None:
-        count(len(data) + _HDR.size)
-    writer.write(_HDR.pack(len(data)) + data)
-    if flush:
-        await writer.drain()
+    span = _no_span if reg is None else reg.span
+    with span("wire_pickle"):
+        data = pickle.dumps(obj, protocol=5)
+        frame = _HDR.pack(len(data)) + data
+    if counter is not None:
+        reg.count(counter, len(frame))
+    with span("wire_write"):
+        writer.write(frame)
+        if flush:
+            await writer.drain()
 
 
-async def _recv(reader: asyncio.StreamReader, count=None):
+async def _recv(reader: asyncio.StreamReader, reg=None, counter=None):
     """Frame reads are DELIBERATELY unbounded: serve/reader loops wait
     indefinitely for the next frame by design — response waits are
     bounded at the caller (per-verb ``Deadline`` on the pending future)
-    and the data plane by TCP keepalive, not by a read timeout here."""
+    and the data plane by TCP keepalive, not by a read timeout here.
+
+    ``reg`` times ``wire_read`` (header read -> the body is held: a
+    serve loop waits for its next request without a span) and
+    ``wire_unpickle``, and counts the framed bytes under ``counter``."""
+    span = _no_span if reg is None else reg.span
     # fhh-lint: disable=unbounded-await (see docstring)
     hdr = await reader.readexactly(_HDR.size)
     (n,) = _HDR.unpack(hdr)
-    if count is not None:
-        count(n + _HDR.size)
-    # fhh-lint: disable=unbounded-await (see docstring)
-    return pickle.loads(await reader.readexactly(n))
+    if counter is not None:
+        reg.count(counter, n + _HDR.size)
+    with span("wire_read"):
+        # fhh-lint: disable=unbounded-await (see docstring)
+        data = await reader.readexactly(n)
+    with span("wire_unpickle"):
+        return pickle.loads(data)
 
 
 async def _fetch(
-    x, reg: obsmetrics.Registry | None = None, level: int | None = None
+    x, reg: obsmetrics.Registry, level: int | None = None
 ) -> np.ndarray:
     """Device->host fetch OFF the event loop.  A bare ``np.asarray`` on a
     device array blocks the whole loop for a full device->host transfer
@@ -171,12 +194,15 @@ async def _fetch(
 
     ``reg`` counts the fetch: each one is a synchronous device->host
     round trip, so the fetch COUNT is a latency term of its own beside
-    the byte count, and the run report carries both.  ``level`` attributes the fetch when the call
+    the byte count, and the run report carries both.  It also times it
+    as a ``d2h`` span: ``copy_to_host_async`` issued -> the numpy array
+    is held, so the wait for the device program that makes ``x`` and the
+    thread hop are inside.  ``level`` attributes the fetch when the call
     site sits outside any span (span-active callers inherit)."""
-    if reg is not None:
-        reg.count("device_fetches", level=level)
-    _start_host_copy(x)
-    return await asyncio.to_thread(np.asarray, x)
+    reg.count("device_fetches", level=level)
+    with reg.span("d2h", level=level):
+        _start_host_copy(x)
+        return await asyncio.to_thread(np.asarray, x)
 
 
 def _start_host_copy(x) -> None:
@@ -523,15 +549,19 @@ class CollectorServer:
         # base-OT) before the ratchet root commits or any level crawls
         await self._ensure_session_plane(cs)
         root_bucket = int((req or {}).get("root_bucket", 1))
-        cs.concat_keys()
+        # the two halves of what opens every crawl in no level
+        # (dispatch only where nothing forces the device)
+        with cs.obs.span("concat_keys"):
+            cs.concat_keys()
         n = cs.keys.cw_seed.shape[0]
         cs.alive_keys = np.ones(n, bool)
-        if cs._mesh is not None:
-            cs.frontier = cs._mesh.shard_frontier(
-                collect.tree_init(cs.keys, root_bucket, planar=False)
-            )
-        else:
-            cs.frontier = collect.tree_init(cs.keys, root_bucket)
+        with cs.obs.span("frontier_init"):
+            if cs._mesh is not None:
+                cs.frontier = cs._mesh.shard_frontier(
+                    collect.tree_init(cs.keys, root_bucket, planar=False)
+                )
+            else:
+                cs.frontier = collect.tree_init(cs.keys, root_bucket)
         cs._children = None
         cs._shard_children.clear()
         cs._shard_last.clear()
@@ -711,7 +741,8 @@ class CollectorServer:
                 )
                 # cor exchange: per-shard D2H copies assembled positionally
                 # into ONE wire message (sketch_shard.wire starts the DMAs)
-                cor_np = await asyncio.to_thread(sketch_shard.wire, cor)
+                with cs.obs.span("d2h", level=level):
+                    cor_np = await asyncio.to_thread(sketch_shard.wire, cor)
                 peer_cor = await self._swap(cs, cor_np)
                 o = sketch_shard.out_shares(
                     ss, fld, state, cor, peer_cor, bool(self.server_id)
@@ -719,7 +750,8 @@ class CollectorServer:
                 cs.obs.count(
                     "device_fetches", 1 if ss is None else ss.k, level=level
                 )
-                o_np = await asyncio.to_thread(sketch_shard.wire, o)
+                with cs.obs.span("d2h", level=level):
+                    o_np = await asyncio.to_thread(sketch_shard.wire, o)
                 peer_o = await self._swap(cs, o_np)
                 ok_dev = sketch_shard.verdicts(ss, fld, o, peer_o)
                 # per-depth verdicts AND on device; exclusion is a
@@ -763,15 +795,19 @@ class CollectorServer:
         hdr = obstrace.wire_tag() if obstrace.enabled() else None
         frame = (cs.key, obj) if hdr is None else (cs.key, obj, hdr)
         await _send(
-            self._peer_writer, frame,
-            count=lambda n: cs.obs.count("data_bytes_sent", n),
+            self._peer_writer, frame, reg=cs.obs, counter="data_bytes_sent"
         )
 
     async def _dp_recv(self, cs: CollectionSession):
-        # wire waits are what a SECOND tenant's device work can fill:
-        # mark them so the scheduler's stall-fill accounting sees the gap
-        with self._sched.wire_wait(cs.key):
-            return await self._plane.recv(cs.key)
+        # the whole data-plane receive; its children peer_wait,
+        # wire_read and wire_unpickle are recorded by the mux from the
+        # times its pump stamped on the frame
+        with cs.obs.span("wire_wait"):
+            # wire waits are what a SECOND tenant's device work can
+            # fill: mark them so the scheduler's stall-fill accounting
+            # sees the gap
+            with self._sched.wire_wait(cs.key):
+                return await self._plane.recv(cs.key, cs.obs)
 
     async def _swap(self, cs: CollectionSession, obj):
         """Role-ordered data-plane exchange on this session's channel:
@@ -784,24 +820,6 @@ class CollectorServer:
         peer = await self._dp_recv(cs)
         await self._dp_send(cs, obj)
         return peer
-
-    def _emit_level_phases(self, cs, level: int, fss, gc_ot, field) -> None:
-        """Per-level phase line (the successor of the old three prints):
-        structured, severity=debug so a 512-level crawl doesn't spam the
-        console, totals always available in the run report.  Takes the
-        three exited spans — their ``seconds`` is THIS pass's duration,
-        where the registry total would inflate on a re-crawled level."""
-        obs.emit(
-            "level.phases",
-            severity="debug",
-            server=self.server_id,
-            collection=cs.key,
-            level=level,
-            fss_s=fss.seconds,
-            gc_ot_s=gc_ot.seconds,
-            field_s=field.seconds,
-        )
-
 
     # -- expand stage (device) vs open stage (plane I/O) -----------------
     #
@@ -882,7 +900,6 @@ class CollectorServer:
     def _expand_stage(self, cs, level: int, last: bool, shard) -> dict:
         hit = cs._expand_ready.pop((bool(last), int(level), shard), None)
         if hit is not None:
-            cs.obs.count("pipeline_expand_hits", level=int(level))
             return hit
         return self._do_expand(cs, level, last, shard)
 
@@ -915,7 +932,6 @@ class CollectorServer:
             # would otherwise have spent it in (no span: another verb's
             # span may be active on this registry right now)
             cs.obs.timer_add("fss", time.monotonic() - t0, level=level)
-            cs.obs.count("pipeline_pre_expands", level=level)
         except Exception:  # fhh-lint: disable=broad-except (prefetch only: the verb recomputes under the lock and surfaces the real error to the leader)
             cs._expand_ready.pop(key, None)
 
@@ -948,11 +964,11 @@ class CollectorServer:
             masks = collect.pattern_masks_radix(
                 cs.keys.cw_seed.shape[1], cs.crawl_radix(level)
             )
+            peer = self._h2d(cs, level, peer)
             counts = await self._reduced_fetch(
                 cs, level, collect.counts_by_pattern,
                 packed, peer, masks, cs.alive_keys, frontier.alive,
             )
-        self._emit_level_phases(cs, level, sp_fss, sp_gc, sp_field)
         # per-level crawl latency histogram (SLO surface): this PASS's
         # three phases, not the registry total a re-run would inflate
         cs.obs.observe(
@@ -978,6 +994,17 @@ class CollectorServer:
                 out = getattr(cs._mesh, single_fn.__name__)(*args)
                 return await _fetch(out, cs.obs)
         return await _fetch(single_fn(*args), cs.obs)
+
+    @staticmethod
+    def _h2d(cs, level: int, x: np.ndarray):
+        """Host->device copy of a received payload, made explicit so it
+        has a span (``h2d``) of its own; it used to happen inside the
+        first jitted call that took the numpy frame.  No sync: the span
+        is the dispatch plus whatever of the copy the runtime does
+        before ``device_put`` returns; the rest lands in the span of
+        whoever first waits for the consumer."""
+        with cs.obs.span("h2d", level=level):
+            return jax.device_put(x)
 
     async def _phase_sync(self, x) -> None:
         """Device sync at a secure-kernel phase boundary (OFF the event
@@ -1076,6 +1103,8 @@ class CollectorServer:
                     # (parallel/kernel_shard.py); the frame reads back
                     # per shard and reassembles positionally — nothing
                     # gathers onto one device
+                    with cs.obs.span("h2d", level=level):
+                        u = kernel_shard.put_u(ks, u)
                     with cs.obs.span("otext", level=level):
                         q, idx0 = kernel_shard.snd_extend(
                             ks, cs._ot_snd, u
@@ -1095,11 +1124,13 @@ class CollectorServer:
                     )
                     cs.obs.count("device_fetches", ks.k, level=level)
                     # msg_wire starts the per-shard D2H copies itself
-                    msg_np = await asyncio.to_thread(
-                        kernel_shard.msg_wire, ks, planes
-                    )
+                    with cs.obs.span("d2h", level=level):
+                        msg_np = await asyncio.to_thread(
+                            kernel_shard.msg_wire, ks, planes
+                        )
                     await self._dp_send(cs, msg_np)
                 else:
+                    u = self._h2d(cs, level, u)
                     with cs.obs.span("otext", level=level):
                         idx0 = cs._ot_snd.consumed
                         q = cs._ot_snd.extend(B * S, u)
@@ -1126,7 +1157,9 @@ class CollectorServer:
                             )
                             await self._phase_sync(msg)
                         self._zero_phases(cs, level, "eval")
-                    await self._dp_send(cs, await _fetch(msg, cs.obs))
+                    await self._dp_send(
+                        cs, await _fetch(msg, cs.obs, level=level)
+                    )
             else:  # evaluator + OT receiver (inputs stay on device: each
                 # np.asarray here would be a blocking device->host fetch)
                 if ks is not None:
@@ -1134,13 +1167,19 @@ class CollectorServer:
                         u_arr, t_rows, idx0 = kernel_shard.rcv_extend(
                             ks, cs._ot_rcv, flat
                         )
-                        cs.obs.count("device_fetches", ks.k, level=level)
-                        # u_wire starts the per-shard D2H copies itself
+                        await self._phase_sync(u_arr)
+                    cs.obs.count("device_fetches", ks.k, level=level)
+                    # u_wire starts the per-shard D2H copies itself
+                    with cs.obs.span("d2h", level=level):
                         u_np = await asyncio.to_thread(
                             kernel_shard.u_wire, ks, u_arr
                         )
                     await self._dp_send(cs, u_np)
                     bmsg = await self._dp_recv(cs)
+                    with cs.obs.span("h2d", level=level):
+                        bmsg = kernel_shard.put_msg(
+                            ks, bmsg, count_field, path
+                        )
                     kphase = "b2a" if path == "ot2s" else "eval"
                     with cs.obs.span(kphase, level=level):
                         vals = kernel_shard.ev_open(
@@ -1157,9 +1196,12 @@ class CollectorServer:
                         u, t_rows, idx0 = secure.ev_step1_fused(
                             cs._ot_rcv, flat
                         )
-                        u_np = await _fetch(u, cs.obs)  # forces the extension
+                        # the extension, device-synced as on the sender
+                        # side; the fetch below is then the copy alone
+                        await self._phase_sync(u)
+                    u_np = await _fetch(u, cs.obs, level=level)
                     await self._dp_send(cs, u_np)
-                    bmsg = await self._dp_recv(cs)
+                    bmsg = self._h2d(cs, level, await self._dp_recv(cs))
                     if path == "ot2s":
                         with cs.obs.span("b2a", level=level):
                             pay = secure.ot2s_decrypt_packed(
@@ -1197,7 +1239,6 @@ class CollectorServer:
                     cs, level, secure.node_share_sums,
                     count_field, vals, jnp.asarray(w),
                 )
-        self._emit_level_phases(cs, level, sp_fss, sp_gc, sp_field)
         cs.obs.observe(
             "level_latency", sp_fss.seconds + sp_gc.seconds + sp_field.seconds
         )
@@ -1543,7 +1584,6 @@ class CollectorServer:
                 "sketch chunk alongside its keys"
             )
         pool = cs.ingest_pool(window)
-        cs.obs.count("pool_submits")
         prev = pool.verdicts.get(sub_id)
         if prev is not None:
             # at-least-once delivery made safe: a replayed submission
@@ -1584,10 +1624,6 @@ class CollectorServer:
             resp = pool.apply(sub_id, chunk, v)
         if resp.get("admitted"):
             cs.obs.count("pool_admitted_keys", n_keys)
-        elif resp.get("shed"):
-            cs.obs.count("pool_shed_keys", n_keys)
-        else:
-            cs.obs.count("pool_rejected")
         return resp
 
     async def window_seal(self, req, cs: CollectionSession | None = None) -> dict:  # fhh-race: holds=_verb_lock (dispatched only by _dispatch, which holds the session's verb lock; sanitizer-validated)
@@ -1629,7 +1665,6 @@ class CollectorServer:
             # the seal instant starts this window's seal-to-hitters SLO
             # clock (observed at final_shares of the crawl that loads it)
             pool.sealed_at = time.time()
-            cs.obs.count("windows_sealed")
             obs.emit(
                 "ingest.window_sealed",
                 server=self.server_id,
@@ -2539,9 +2574,6 @@ class CollectorServer:
                     # yield between compiles: each can take seconds, and
                     # the control socket must keep answering keepalives
                     await asyncio.sleep(0)
-        cs.obs.count("warmup_shapes", shapes)
-        if ladder_hits:
-            cs.obs.count("warmup_ladder_hits", ladder_hits)
         # the warmup ladder is now the compile baseline: every fresh XLA
         # compile from here on is a NAMED, counted anomaly
         # (devmem.fresh_compiles_post_warmup -> recompile_after_warmup
@@ -2787,7 +2819,6 @@ class CollectorServer:
             sess = self._sessions[sid] = _Session()
         epoch = int((req or {}).get("epoch", 0))
         if epoch > 1:  # epoch 1 is the first connect, not a recovery
-            self.obs.count("session_reconnects")
             obs.emit(
                 "resilience.session_reconnect",
                 server=self.server_id,
@@ -2972,7 +3003,7 @@ class CollectorServer:
                 async with write_lock:
                     await _send(
                         writer, (req_id, resp),
-                        count=lambda n: self.obs.count("control_bytes_sent", n),
+                        reg=self.obs, counter="control_bytes_sent",
                     )
             except (ConnectionResetError, BrokenPipeError):
                 pass  # leader gone; the work itself must still have finished
@@ -2992,8 +3023,7 @@ class CollectorServer:
         try:
             while True:
                 req_id, verb, req = await _recv(
-                    reader,
-                    count=lambda n: self.obs.count("control_bytes_recv", n),
+                    reader, reg=self.obs, counter="control_bytes_recv"
                 )
                 if verb == "__hello__":
                     try:
@@ -3127,16 +3157,27 @@ class CollectorServer:
             if hasattr(socket, opt):
                 sock.setsockopt(socket.IPPROTO_TCP, getattr(socket, opt), val)
 
-    @staticmethod
-    async def _recv_plane_frame(reader):
+    async def _recv_plane_frame(self, reader):
         """One framed data-plane read for the PlaneMux pump: returns
-        (framed byte size, frame).  Byte accounting happens in the mux's
-        route hook (the channel is only known after unpickling)."""
+        (framed byte size, frame, stamps).  Byte accounting happens in
+        the mux's route hook (the channel is only known after
+        unpickling), and so does the timing: ``stamps`` are the wall
+        clock with the header read, the body read and the frame
+        unpickled, which the receiving verb turns into its
+        ``peer_wait``, ``wire_read`` and ``wire_unpickle``
+        (:meth:`~.sessions.PlaneMux.recv`).  Under fhh-trace the read
+        and the unpickle are profiler annotations while they run."""
         # fhh-lint: disable=unbounded-await (serve-loop read: the pump waits indefinitely for the next frame by design; liveness comes from the socket's TCP keepalive)
         hdr = await reader.readexactly(_HDR.size)
+        t_hdr = time.time()
         (n,) = _HDR.unpack(hdr)
-        # fhh-lint: disable=unbounded-await (as above)
-        return n + _HDR.size, pickle.loads(await reader.readexactly(n))
+        with obstrace.annotate(self.obs.name, "wire_read") or _NO_CTX:
+            # fhh-lint: disable=unbounded-await (as above)
+            data = await reader.readexactly(n)
+        t_body = time.time()
+        with obstrace.annotate(self.obs.name, "wire_unpickle") or _NO_CTX:
+            frame = pickle.loads(data)
+        return n + _HDR.size, frame, (t_hdr, t_body, time.time())
 
     def _attach_plane(self, reader, writer) -> None:
         """Bind a fresh peer transport: keepalive on, mux pump attached
@@ -3505,8 +3546,7 @@ class CollectorClient:
             async with self._send_lock:
                 await _send(
                     self._w, (req_id, verb, req or {}),
-                    count=lambda n: self.obs.count("control_bytes_sent", n),
-                    flush=False,
+                    reg=self.obs, counter="control_bytes_sent", flush=False,
                 )
             # coalesced drain: a burst of concurrent frames (the 256-deep
             # upload window, a pipelined level's span verbs) shares ONE
@@ -3555,8 +3595,7 @@ class CollectorClient:
         try:
             while True:
                 req_id, resp = await _recv(
-                    reader,
-                    count=lambda n: self.obs.count("control_bytes_recv", n),
+                    reader, reg=self.obs, counter="control_bytes_recv"
                 )
                 fut = self._pending.pop(req_id, None)
                 if fut is not None and not fut.done():
@@ -3629,7 +3668,6 @@ class CollectorClient:
                             f"{self.budgets.budget(verb):g}s budget "
                             f"(last error: {type(e).__name__}: {e})"
                         ) from e
-                    self.obs.count("call_retries")
                     obs.emit(
                         "resilience.call_retry",
                         severity="debug",

@@ -1178,17 +1178,27 @@ class PlaneMux:
             q = self._queues[chan] = asyncio.Queue(maxsize=self.MAX_DEPTH)
         return q
 
-    async def recv(self, chan: str):
+    async def recv(self, chan: str, reg=None):
         """Next payload on ``chan`` (FIFO per channel).  Raises the
         plane's death as ConnectionError — the same failure shape a
         direct socket read gave, so every existing recovery path
         (plane_reset, shard retry, supervisor rollback) works
-        unchanged."""
+        unchanged.
+
+        The pump reads the frame outside the receiving verb's context
+        and stamps the wall clock on it; ``reg``, the receiver's
+        registry, turns the stamps into its spans ``peer_wait`` (this
+        call -> the frame's header read; 0 where the frame was already
+        there: time the peer's compute, pickle and write own),
+        ``wire_read`` (header -> body held) and ``wire_unpickle``, as
+        the pump took them: a frame read before this call lies before
+        it in the trace too."""
         if self._err is not None:
             raise ConnectionError(
                 f"data plane down: {self._err!r}"
             ) from self._err
         q = self._queue(chan)
+        t0 = time.time()
         # fhh-lint: disable=unbounded-await (deliberately unbounded like the serve-loop reads: response waits are bounded at the caller — per-verb deadlines on the control plane, TCP keepalive on the data plane)
         item = await q.get()
         if isinstance(item, _PlaneFailure):
@@ -1197,8 +1207,21 @@ class PlaneMux:
             raise ConnectionError(
                 f"data plane down: {item.err!r}"
             ) from item.err
-        payload, hdr = item
-        if hdr is not None and obsmod.trace.enabled():
+        payload, hdr, stamps = item
+        tracing = obsmod.trace.enabled()
+        if reg is not None and stamps is not None:
+            t_hdr, t_body, t_done = stamps
+            sp = reg.current_span()
+            level = None if sp is None else sp.level
+            for name, a, b in (
+                ("peer_wait", t0, max(t0, t_hdr)),
+                ("wire_read", t_hdr, t_body),
+                ("wire_unpickle", t_body, t_done),
+            ):
+                reg.timer_add(name, b - a, level)
+                if tracing:
+                    obsmod.trace.span_at(name, reg.name, a, b - a, level)
+        if hdr is not None and tracing:
             # the peer stamped its (trace, span) onto the frame's
             # session header: an arrival instant parented under the
             # SENDER's span ties the two servers' timelines together
@@ -1215,7 +1238,7 @@ class PlaneMux:
         try:
             while True:
                 # fhh-lint: disable=unbounded-await (serve-loop read: waits indefinitely for the next frame by design; liveness comes from TCP keepalive on the peer socket)
-                nbytes, frame = await read_frame(reader)
+                nbytes, frame, *stamps = await read_frame(reader)
                 if epoch != self.epoch:
                     return
                 # frames are (collection, payload) — or, under fhh-trace,
@@ -1225,7 +1248,9 @@ class PlaneMux:
                 hdr = frame[2] if len(frame) > 2 else None
                 if self._route_count is not None:
                     self._route_count(chan, nbytes)
-                self._queue(chan).put_nowait((payload, hdr))
+                self._queue(chan).put_nowait(
+                    (payload, hdr, stamps[0] if stamps else None)
+                )
         except asyncio.CancelledError:
             raise
         # fhh-lint: disable=broad-except (transport boundary: EVERY pump failure — EOF, reset, a QueueFull divergence, a corrupt frame — must surface to the blocked receivers as a plane death)

@@ -92,20 +92,13 @@ class TenantScheduler:
     @contextlib.contextmanager
     def wire_wait(self, key: str):
         """Sync context manager marking a session as blocked on the
-        data plane (wraps the recv awaits in protocol/rpc.py)."""
+        data plane (wraps the recv awaits in protocol/rpc.py, inside the
+        session registry's ``wire_wait`` span): the gap a second
+        tenant's device turn fills."""
         self._wire[key] = self._wire.get(key, 0) + 1
-        # distributed trace: the wire wait is THE gap a second tenant's
-        # device turn fills — record it as a child span of the active
-        # verb so the merged timeline shows the stall being filled
-        st = obstrace.span_begin() if obstrace.enabled() else None
         try:
             yield
         finally:
-            if st is not None:
-                obstrace.span_end(
-                    st, "wire_wait",
-                    self.obs.name if self.obs is not None else "server",
-                )
             n = self._wire.get(key, 1) - 1
             if n <= 0:
                 self._wire.pop(key, None)
@@ -170,7 +163,11 @@ class _DeviceTurn:
         # the span covers lock wait + dispatch: a long device_turn with
         # a short dispatch IS the cross-tenant queueing the scheduler
         # exists to make visible
-        self._trace = obstrace.span_begin() if obstrace.enabled() else None
+        if obstrace.enabled():
+            obs = self._sched.obs
+            self._trace = obstrace.span_begin(
+                "device_turn", obs.name if obs is not None else "server"
+            )
         await self._sched._device_lock.acquire()
         if self._count:
             self._sched._note_turn(self._key)
@@ -179,12 +176,7 @@ class _DeviceTurn:
     async def __aexit__(self, exc_type, exc, tb):
         self._sched._device_lock.release()
         if self._trace is not None:
-            obs = self._sched.obs
-            obstrace.span_end(
-                self._trace, "device_turn",
-                obs.name if obs is not None else "server",
-                error=exc_type is not None,
-            )
+            obstrace.span_end(self._trace, error=exc_type is not None)
         return False
 
 

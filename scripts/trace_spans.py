@@ -1,0 +1,152 @@
+"""What a traced run's span log says, and how well it agrees with the
+profiler's clock.
+
+    python scripts/trace_spans.py <FHH_TRACE_DIR> [--capture <x.xplane.pb>
+        --wall-ns-at-sync <ns> [--sync-event bench_sync]]
+
+Reads the program's JSONL span log (obs/trace.py) and prints one JSON
+object:
+
+- ``span_ms``: per ``<comp>:<name>`` the count, median, mean and largest
+  duration, in milliseconds;
+- ``gc_ot_cover``: per server, over its ``gc_ot`` spans, the share of the
+  span that its leaf spans inside it cover (median and smallest), and the
+  median milliseconds of each leaf inside one ``gc_ot``;
+- ``clock`` (with ``--capture``): the program's spans are also profiler
+  annotations (``<comp>:<name>``) on the profiler's own clock.  The
+  benchmark lays the JSONL lines over a capture by one sync mark
+  (``--sync-event``, entered when the wall clock read ``--wall-ns-at-sync``);
+  this is the check of that shift: for every span in the capture, the
+  annotation's start and end against the JSONL interval shifted by the
+  mark's offset, as median and largest absolute difference in microseconds,
+  over all spans and per name.
+"""
+
+from __future__ import annotations
+
+import argparse
+import bisect
+import json
+import os
+import statistics
+import sys
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from fuzzyheavyhitters_tpu.obs import trace as obstrace  # noqa: E402
+
+LEAVES = (
+    "d2h", "wire_pickle", "wire_write", "peer_wait", "wire_read",
+    "wire_unpickle", "h2d", "otext", "b2a", "garble", "eval",
+)
+
+
+def span_ms(spans: list) -> dict:
+    by_key: dict = {}
+    for e in spans:
+        by_key.setdefault(f"{e['comp']}:{e['name']}", []).append(1e3 * e["dur"])
+    return {
+        k: {"n": len(v), "median": statistics.median(v),
+            "mean": statistics.fmean(v), "max": max(v)}
+        for k, v in sorted(by_key.items())
+    }
+
+
+def gc_ot_cover(spans: list) -> dict:
+    out = {}
+    for comp in sorted({e["comp"] for e in spans if e["name"] == "gc_ot"}):
+        mine = [e for e in spans if e["comp"] == comp]
+        shares, by_leaf = [], {}
+        for g in (e for e in mine if e["name"] == "gc_ot" and e["dur"] > 0):
+            lo, hi = g["ts"], g["ts"] + g["dur"]
+            inside = [e for e in mine if e["name"] in LEAVES
+                      and lo <= e["ts"] and e["ts"] + e["dur"] <= hi + 1e-5]
+            shares.append(sum(e["dur"] for e in inside) / g["dur"])
+            per = {}
+            for e in inside:
+                per[e["name"]] = per.get(e["name"], 0.0) + 1e3 * e["dur"]
+            for name in LEAVES:
+                by_leaf.setdefault(name, []).append(per.get(name, 0.0))
+        if shares:
+            out[comp] = {
+                "gc_ot_spans": len(shares),
+                "share_median": statistics.median(shares),
+                "share_min": min(shares),
+                "leaf_ms_median": {k: statistics.median(v)
+                                   for k, v in by_leaf.items() if any(v)},
+            }
+    return out
+
+
+def clock_check(spans: list, capture: str, wall_ns_at_sync: int,
+                sync_event: str) -> dict:
+    from jax.profiler import ProfileData
+
+    notes, sync = {}, []
+    for plane in ProfileData.from_file(capture).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                name = ev.name.split("#", 1)[0]
+                if name == sync_event:
+                    sync.append(ev.start_ns)
+                elif ":" in name:
+                    notes.setdefault(name, []).append(
+                        (ev.start_ns, ev.start_ns + ev.duration_ns))
+    if not sync:
+        return {"error": f"no {sync_event!r} annotation in the capture"}
+    mark_ns = min(sync)
+    d_start, d_end, by_name = [], [], {}
+    for v in notes.values():
+        v.sort()
+    for e in spans:
+        found = notes.get(f"{e['comp']}:{e['name']}")
+        if not found:
+            continue
+        # relative to the mark first: epoch nanoseconds do not fit a float
+        t0 = (e["ts"] - wall_ns_at_sync / 1e9) * 1e9 + mark_ns
+        t1 = t0 + e["dur"] * 1e9
+        if not found[0][0] - 1e6 <= t0 <= found[-1][0] + 1e6:
+            continue  # a span from before or after the capture
+        i = bisect.bisect_left(found, (t0,))
+        a0, a1 = min(found[max(i - 1, 0):i + 1], key=lambda a: abs(a[0] - t0))
+        ds, de = abs(a0 - t0) / 1e3, abs(a1 - t1) / 1e3
+        d_start.append(ds)
+        d_end.append(de)
+        by_name.setdefault(e["name"], []).append(max(ds, de))
+    if not d_start:
+        return {"error": "no span of the log lies in the capture"}
+    both = d_start + d_end
+    return {
+        "offset_ns": int(mark_ns) - wall_ns_at_sync, "spans_compared": len(d_start),
+        "annotations": sum(len(v) for v in notes.values()),
+        "start_us": {"median": statistics.median(d_start), "max": max(d_start)},
+        "end_us": {"median": statistics.median(d_end), "max": max(d_end)},
+        "all_us": {"median": statistics.median(both), "max": max(both)},
+        "by_name_us": {k: {"n": len(v), "median": statistics.median(v), "max": max(v)}
+                       for k, v in sorted(by_name.items())},
+    }
+
+
+def main(argv=None) -> int:
+    p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    p.add_argument("trace_dir")
+    p.add_argument("--capture")
+    p.add_argument("--wall-ns-at-sync", type=int)
+    p.add_argument("--sync-event", default="bench_sync")
+    args = p.parse_args(argv)
+    spans = [e for e in obstrace.load_events(args.trace_dir) if e.get("ph") == "X"]
+    if not spans:
+        print(f"no span under {args.trace_dir}", file=sys.stderr)
+        return 1
+    out = {"span_ms": span_ms(spans), "gc_ot_cover": gc_ot_cover(spans)}
+    if args.capture:
+        if args.wall_ns_at_sync is None:
+            p.error("--capture needs --wall-ns-at-sync")
+        out["clock"] = clock_check(
+            spans, args.capture, args.wall_ns_at_sync, args.sync_event)
+    print(json.dumps(out))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

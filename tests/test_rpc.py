@@ -226,7 +226,7 @@ def test_send_failure_pops_pending():
 
         real_send = rpc._send
 
-        async def broken_send(writer, obj, count=None, flush=True):
+        async def broken_send(writer, obj, **_kw):
             raise Boom("pickling exploded mid-write")
 
         rpc._send = broken_send

@@ -293,9 +293,9 @@ def test_secure_socket_run_matches_trusted(rng, monkeypatch, eq_ot4):
     real_send = rpc._send
     real_expand = collect.expand_share_bits
 
-    async def spy_send(writer, obj, count=None, flush=True):
+    async def spy_send(writer, obj, **kw):
         sent.append(obj)
-        await real_send(writer, obj, count, flush)
+        await real_send(writer, obj, **kw)
 
     def spy_expand(keys, frontier, level, **kw):
         packed, children = real_expand(keys, frontier, level, **kw)

@@ -227,6 +227,15 @@ def test_trace_disabled_is_structurally_zero_cost(tmp_path, monkeypatch):
     # and the per-span overhead is ONE flag read: span_begin is never
     # called (the _SpanCtx gate is trace.enabled())
     assert tracemod.wire_ctx() is None
+    # no profiler either: obs imports jax.profiler at the first TRACED
+    # span, and enters no annotation before that
+    monkeypatch.setattr(tracemod, "_ANNOTATION", None)
+    with reg.span("gc_ot", level=0):
+        assert tracemod.annotate("t-off", "wire_read") is None
+    tracemod.span_at("wire_read", "t-off", 0.0, 1.0)  # no context: no-op
+    assert tracemod._ANNOTATION is None and tracemod._WRITER is None
+    assert not list(tmp_path.iterdir())
+    assert "jax" not in vars(tracemod) and "jax" not in vars(obsmetrics)
 
 
 def test_trace_bad_dir_degrades_without_killing_telemetry(monkeypatch):
@@ -747,3 +756,146 @@ def test_profile_capture_survives_profiler_failure(tmp_path, monkeypatch):
     monkeypatch.delenv(tracemod.ENV_PROFILE_LEVELS, raising=False)
     with tracemod.profile_capture("crawl") as live:
         assert live is False  # degraded, never raised
+
+
+# ---------------------------------------------------------------------------
+# the host work inside a level: transfer, codec and socket spans
+# ---------------------------------------------------------------------------
+
+# what _send/_recv/_fetch/PlaneMux.recv and the crawl paths record on the
+# side doing the work; wire_wait is the parent of the three receive spans
+_WIRE_SPANS = (
+    "d2h", "wire_pickle", "wire_write", "wire_wait", "peer_wait",
+    "wire_read", "wire_unpickle", "h2d",
+)
+# spans with no span of their own inside: within one gc_ot they are disjoint
+_LEAVES = tuple(n for n in _WIRE_SPANS if n != "wire_wait") + (
+    "otext", "b2a", "garble", "eval",
+)
+_EPS = 5e-6  # ts and dur are rounded to the microsecond
+
+
+def test_span_inherits_level_and_span_at_parents(trace_dir):
+    reg = obsmetrics.Registry("t-inherit")
+    with tracemod.root("crawl"):
+        with reg.span("gc_ot", level=4):
+            with reg.span("wire_pickle") as sp:
+                assert sp.level == 4  # like a counter inside a span
+            t = time.time()
+            tracemod.span_at("wire_read", reg.name, t - 0.5, 0.25, level=4)
+    assert reg.report()["phases"]["wire_pickle"]["by_level"].keys() == {"4"}
+    by_name = {e["name"]: e for e in _events(trace_dir) if e["ph"] == "X"}
+    assert by_name["wire_pickle"]["level"] == 4
+    rd = by_name["wire_read"]
+    assert rd["parent"] == by_name["gc_ot"]["span"] and rd["dur"] == 0.25
+    assert rd["ts"] < by_name["gc_ot"]["ts"]  # as stamped, not as recorded
+    assert tracemod.validate(_events(trace_dir))["ok"]
+
+
+@pytest.mark.parametrize("secure", [False, True], ids=["trusted", "secure"])
+def test_wire_and_transfer_spans_every_level(rng, trace_dir, secure):
+    """One crawl on the CPU pair under FHH_TRACE_DIR: every wire and
+    transfer span is in both servers' timers and in the JSONL at every
+    level, the trace validates, and on each server the leaves inside one
+    gc_ot neither overlap nor sum to more than it."""
+    L, n = 5, 12
+    port = BASE_PORT + (320 if secure else 280)
+    k0, k1 = _client_keys(rng, L, n)
+    cfg = _cfg(port, secure_exchange=secure)
+
+    async def run():
+        lead, c0, c1, live = await _bring_up(cfg, port)
+        res = await lead.run_supervised(n, k0, k1)
+        phases = {
+            name: s.obs.report()["phases"] for name, s in live.items()
+        }
+        lead_phases = lead.obs.report()["phases"]
+        await _teardown((c0, c1), live)
+        return res, phases, lead_phases
+
+    res, phases, lead_phases = asyncio.run(run())
+    assert _hitters(res)
+    levels = {str(lv) for lv in range(L)}
+    for srv, ph in phases.items():
+        for name in _WIRE_SPANS:
+            assert name in ph, (srv, name)
+            assert set(ph[name]["by_level"]) >= levels, (srv, name)
+        # what opens the crawl in no level
+        assert ph["concat_keys"]["count"] == ph["frontier_init"]["count"] == 1
+    # the leader's own work of a level, and its side of the wire
+    for name in ("reconstruct", "threshold", "prune", "paths",
+                 "wire_pickle", "wire_write", "wire_read", "wire_unpickle"):
+        assert set(lead_phases[name]["by_level"]) >= levels, name
+    assert "level.phases" not in json.dumps(sorted(phases["s0"]))
+
+    evs = _events(trace_dir)
+    verdict = tracemod.validate(evs)
+    assert verdict["ok"], verdict["errors"]
+    assert any(e["name"] == "plane_recv" for e in evs if e["ph"] == "i")
+    spans = [e for e in evs if e["ph"] == "X"]
+    by_id = {e["span"]: e for e in spans}
+    for comp in ("server0", "server1"):
+        mine = [e for e in spans if e["comp"] == comp]
+        for name in _WIRE_SPANS:
+            got = {e.get("level") for e in mine if e["name"] == name}
+            assert got >= set(range(L)), (comp, name, got)
+        for name in ("peer_wait", "wire_read", "wire_unpickle"):
+            assert all(
+                by_id[e["parent"]]["name"] == "wire_wait"
+                for e in mine if e["name"] == name
+            ), name
+        outer = [e for e in mine if e["name"] == "gc_ot"]
+        assert len(outer) == L
+        for g in outer:
+            lo, hi = g["ts"] - _EPS, g["ts"] + g["dur"] + _EPS
+            inside = sorted(
+                (e["ts"], e["ts"] + e["dur"], e["name"]) for e in mine
+                if e["name"] in _LEAVES
+                and lo <= e["ts"] and e["ts"] + e["dur"] <= hi
+            )
+            assert {"wire_pickle", "wire_write", "peer_wait"} <= {
+                nm for _, _, nm in inside
+            }
+            for (_, end, a), (start, _, b) in zip(inside, inside[1:]):
+                assert start >= end - _EPS, (comp, g["level"], a, b)
+            total = sum(end - start for start, end, _ in inside)
+            assert total <= g["dur"] + _EPS * len(inside), (comp, g["level"])
+
+
+def test_profiler_capture_holds_the_program_spans(rng, tmp_path, trace_dir):
+    """A jax.profiler capture around a crawl carries the program's
+    spans as ``<comp>:<name>`` annotations on the profiler's own clock,
+    the pump's live read among them."""
+    import glob
+
+    import jax
+
+    L, n = 5, 12
+    port = BASE_PORT + 360
+    k0, k1 = _client_keys(rng, L, n)
+    cfg = _cfg(port)
+    prof = tmp_path / "prof"
+
+    async def run():
+        lead, c0, c1, live = await _bring_up(cfg, port)
+        await lead.upload_keys(k0, k1)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(str(prof), profiler_options=options)
+        try:
+            res = await lead.run(n)
+        finally:
+            jax.profiler.stop_trace()
+        await _teardown((c0, c1), live)
+        return res
+
+    assert _hitters(asyncio.run(run()))
+    files = glob.glob(str(prof / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    assert files
+    data = jax.profiler.ProfileData.from_file(files[-1])
+    names = {
+        ev.name.split("#", 1)[0]
+        for plane in data.planes for line in plane.lines for ev in line.events
+    }
+    assert {"server0:gc_ot", "server1:wire_read", "server0:d2h",
+            "leader:level"} <= names, sorted(names)[:40]
