@@ -293,7 +293,10 @@ class LintConfig:
         "observe",
         "timer_add",
     )
-    # wire boundaries for unmasked-wire: the frame-send entry points
+    # wire boundaries for unmasked-wire: the frame-send entry points.
+    # Every byte still leaves through these two: rpc._send hands the
+    # frame built by wire.encode (pickled metadata + the arrays' own
+    # buffers) to the transport, and nothing else calls the writer
     taint_wire_calls: tuple = ("_send", "_dp_send")
     # declared declassifiers: masking/opening operations whose output
     # is public by protocol argument — pad-XOR encryptions, share

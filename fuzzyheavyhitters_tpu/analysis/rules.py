@@ -745,8 +745,13 @@ class ChunkedDeviceReadback(Rule):
 # ---------------------------------------------------------------------------
 
 # attribute calls whose await can hang forever on a wedged/black-holed
-# peer: stream reads, event/condition waits (incl. asyncio.wait itself)
-_AWAIT_NET_METHODS = {"readexactly", "readuntil", "readline", "read", "wait"}
+# peer: stream reads (protocol/wire.py's FrameReader.readinto, which
+# fills a frame's out-of-band buffer, and wire.read_body, a frame's whole
+# body, among them), event/condition waits (incl. asyncio.wait itself)
+_AWAIT_NET_METHODS = {
+    "readexactly", "readinto", "read_body", "readuntil", "readline", "read",
+    "wait",
+}
 
 
 class UnboundedAwait(Rule):

@@ -34,6 +34,9 @@ Well-known metric names (what populates them):
 - counters ``data_bytes_sent`` / ``data_bytes_recv`` /
   ``data_msgs_sent`` — server↔server data plane, per level;
   ``control_bytes_*`` — leader↔server control plane;
+  ``wire_oob_bytes`` — the part of a registry's sent bytes (either
+  plane) that crossed as raw array buffers, not through pickle
+  (protocol/wire.py);
   ``device_fetches`` — device->host transfers (each a synchronous
   round trip: the COUNT is a latency term beside the byte count, so
   both are measured); ``gc_tests`` — secure-mode equality tests;

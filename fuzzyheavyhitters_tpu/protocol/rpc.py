@@ -6,9 +6,16 @@ communication backend"):
 - **Control plane** — leader connects to both servers and drives the 8-verb
   protocol of the reference's tarpc ``Collector`` service (ref: rpc.rs:56-66):
   ``reset, add_keys, tree_init, tree_crawl, tree_crawl_last, tree_prune,
-  tree_prune_last, final_shares``.  Transport: length-prefixed pickle over
-  TCP via asyncio (the tarpc+bincode analogue; pickle protocol 5 gives
-  zero-copy numpy buffers).
+  tree_prune_last, final_shares``.  Transport: length-prefixed frames over
+  TCP via asyncio (the tarpc+bincode analogue).  A frame is ``<Q n>`` and
+  a body of ``n`` bytes: ``<I k>``, ``k + 1`` lengths (``<Q``), the
+  pickled metadata (protocol 5) and ``k`` raw buffers.  Array payloads of
+  :data:`~.wire.OOB_MIN` bytes or more are never serialised: they cross
+  as their own memory, copied once into the socket and once out of it,
+  into the buffer the received array owns (protocol/wire.py has the
+  layout and the endpoints).  A peer that still speaks the bare-pickle
+  body is refused by the first frame (its lengths cannot sum: a
+  ``FrameError`` here, an ``UnpicklingError`` there).
 - **Data plane** — one server↔server TCP connection carrying the packed
   share-bit tensors per level (server1 listens on ``port+1``, server0 dials
   with retries — the reference's GC-mesh bootstrap order, server.rs:197-262,
@@ -91,7 +98,6 @@ import contextlib
 import os
 import pickle
 import secrets as _secrets
-import struct
 import time
 import weakref
 
@@ -113,7 +119,7 @@ from ..resilience import chaos as reschaos
 from ..resilience import policy as respolicy
 from ..utils import guards, taint_guard
 from ..utils.config import Config
-from . import collect, mpc, secure, sessions, sketch as sketchmod, tenancy
+from . import collect, mpc, secure, sessions, sketch as sketchmod, tenancy, wire
 from .sessions import (  # noqa: F401  (re-exports: wire-format helpers kept importable as rpc.*)
     DEFAULT_COLLECTION,
     SHARED_MASK_SEED,
@@ -124,7 +130,7 @@ from .sessions import (  # noqa: F401  (re-exports: wire-format helpers kept imp
     mask_fe62,
 )
 
-_HDR = struct.Struct("<Q")
+_HDR = wire.HDR
 
 
 _NO_CTX = contextlib.nullcontext()
@@ -134,41 +140,52 @@ def _no_span(_name):
     return _NO_CTX
 
 
-async def _send(writer: asyncio.StreamWriter, obj, reg=None, counter=None,
+async def _send(writer: wire.FrameWriter, obj, reg=None, counter=None,
                 flush: bool = True) -> None:
     """``reg``, when given, is the registry of the side doing the work:
-    it times ``wire_pickle`` (``pickle.dumps`` and the header + body
-    join, a second whole-frame copy) and ``wire_write`` (``write`` until
-    ``drain()`` has returned; the write alone on an unflushed frame),
-    and adds the framed byte size to its counter ``counter`` — the
-    data-plane accounting hook.  ``flush=False`` skips the
-    ``drain()`` backpressure wait: asyncio delivers the buffered bytes
-    regardless (drain only waits when the write buffer tops the
-    high-water mark), so a burst of consecutive frames can coalesce into
-    ONE drain on its final frame instead of one await per frame — but
-    some frame in every burst MUST flush, or a dead peer lets the buffer
-    grow without bound."""
+    it times ``wire_pickle`` (building the frame's header and pickled
+    metadata; arrays of ``wire.OOB_MIN`` bytes or more are not pickled
+    but referenced) and ``wire_write`` (the pieces handed to the
+    transport until ``drain()`` has returned; the hand-over alone on an
+    unflushed frame), adds the framed byte size to its counter
+    ``counter`` — the data-plane accounting hook — and the bytes that
+    crossed as raw buffers to ``wire_oob_bytes``.  The transport's
+    write queue holds views of ``obj``'s arrays until the kernel has
+    them, which is when ``drain()`` returns; ``obj`` must stay
+    unmodified until then (see :func:`wire.encode`: the caller of an
+    unflushed send holds ``obj`` until its coalesced flush).
+    ``flush=False`` skips the ``drain()`` backpressure wait: asyncio
+    delivers the queued bytes regardless, so a burst of consecutive
+    frames can coalesce into ONE drain on its final frame instead of
+    one await per frame — but some frame in every burst MUST flush, or
+    a dead peer lets the queue grow without bound."""
     span = _no_span if reg is None else reg.span
     with span("wire_pickle"):
-        data = pickle.dumps(obj, protocol=5)
-        frame = _HDR.pack(len(data)) + data
+        pieces, nbytes, oob = wire.encode(obj)
     if counter is not None:
-        reg.count(counter, len(frame))
+        reg.count(counter, nbytes)
+        if oob:
+            reg.count("wire_oob_bytes", oob)
+            # the span log carries no counters: under fhh-trace the
+            # engagement is an instant beside the frame's wire_write
+            obstrace.instant("wire_oob", comp=reg.name, oob=oob, framed=nbytes)
     with span("wire_write"):
-        writer.write(frame)
+        writer.writelines(pieces)
         if flush:
             await writer.drain()
 
 
-async def _recv(reader: asyncio.StreamReader, reg=None, counter=None):
+async def _recv(reader: wire.FrameReader, reg=None, counter=None):
     """Frame reads are DELIBERATELY unbounded: serve/reader loops wait
     indefinitely for the next frame by design — response waits are
     bounded at the caller (per-verb ``Deadline`` on the pending future)
     and the data plane by TCP keepalive, not by a read timeout here.
 
-    ``reg`` times ``wire_read`` (header read -> the body is held: a
-    serve loop waits for its next request without a span) and
-    ``wire_unpickle``, and counts the framed bytes under ``counter``."""
+    ``reg`` times ``wire_read`` (header read -> the metadata and the
+    last out-of-band buffer are held: a serve loop waits for its next
+    request without a span) and ``wire_unpickle`` (``pickle.loads``
+    over the buffers, which the arrays then own: no copy), and counts
+    the framed bytes under ``counter``."""
     span = _no_span if reg is None else reg.span
     # fhh-lint: disable=unbounded-await (see docstring)
     hdr = await reader.readexactly(_HDR.size)
@@ -177,9 +194,9 @@ async def _recv(reader: asyncio.StreamReader, reg=None, counter=None):
         reg.count(counter, n + _HDR.size)
     with span("wire_read"):
         # fhh-lint: disable=unbounded-await (see docstring)
-        data = await reader.readexactly(n)
+        meta, bufs = await wire.read_body(reader, n)
     with span("wire_unpickle"):
-        return pickle.loads(data)
+        return pickle.loads(meta, buffers=bufs)
 
 
 async def _fetch(
@@ -397,8 +414,8 @@ class CollectorServer:
         self._sched = tenancy.TenantScheduler(self.obs)
         # peer data plane: one socket, demuxed per collection; sends are
         # (collection, payload) frames, the pump routes receives
-        self._peer_reader: asyncio.StreamReader | None = None
-        self._peer_writer: asyncio.StreamWriter | None = None
+        self._peer_reader: wire.FrameReader | None = None
+        self._peer_writer: wire.FrameWriter | None = None
         self._plane = sessions.PlaneMux(
             route_count=self._plane_count, tag=f"server{server_id}"
         )
@@ -3140,7 +3157,7 @@ class CollectorServer:
             await srv.wait_closed()
 
     @staticmethod
-    def _keepalive(writer: asyncio.StreamWriter) -> None:
+    def _keepalive(writer: wire.FrameWriter) -> None:
         """Aggressive-ish TCP keepalive on the persistent data plane so a
         SILENTLY dead peer (partition, power loss — no FIN/RST) surfaces as
         a connection error within ~2 minutes instead of hanging a blocked
@@ -3162,9 +3179,10 @@ class CollectorServer:
         (framed byte size, frame, stamps).  Byte accounting happens in
         the mux's route hook (the channel is only known after
         unpickling), and so does the timing: ``stamps`` are the wall
-        clock with the header read, the body read and the frame
-        unpickled, which the receiving verb turns into its
-        ``peer_wait``, ``wire_read`` and ``wire_unpickle``
+        clock with the header read, the body read (metadata and every
+        out-of-band buffer held) and the frame unpickled, which the
+        receiving verb turns into its ``peer_wait``, ``wire_read`` and
+        ``wire_unpickle``
         (:meth:`~.sessions.PlaneMux.recv`).  Under fhh-trace the read
         and the unpickle are profiler annotations while they run."""
         # fhh-lint: disable=unbounded-await (serve-loop read: the pump waits indefinitely for the next frame by design; liveness comes from the socket's TCP keepalive)
@@ -3173,10 +3191,10 @@ class CollectorServer:
         (n,) = _HDR.unpack(hdr)
         with obstrace.annotate(self.obs.name, "wire_read") or _NO_CTX:
             # fhh-lint: disable=unbounded-await (as above)
-            data = await reader.readexactly(n)
+            meta, bufs = await wire.read_body(reader, n)
         t_body = time.time()
         with obstrace.annotate(self.obs.name, "wire_unpickle") or _NO_CTX:
-            frame = pickle.loads(data)
+            frame = pickle.loads(meta, buffers=bufs)
         return n + _HDR.size, frame, (t_hdr, t_body, time.time())
 
     def _attach_plane(self, reader, writer) -> None:
@@ -3201,7 +3219,7 @@ class CollectorServer:
 
         async def dial():
             return await asyncio.wait_for(
-                asyncio.open_connection(peer_host, peer_port),
+                wire.open_connection(peer_host, peer_port),
                 respolicy.DIAL_TIMEOUT_S,
             )
 
@@ -3226,7 +3244,7 @@ class CollectorServer:
         obs.emit("server.engines", server=self.server_id, **self.engine_tags())
         with self.obs.span("setup"):
             if self.server_id == 1:
-                srv = await asyncio.start_server(self._on_peer, host, peer_port)
+                srv = await wire.start_server(self._on_peer, host, peer_port)
                 self._peer_ready = asyncio.Event()
                 self._peer_srv = srv
                 # fhh-lint: disable=unbounded-await (startup barrier: a
@@ -3235,7 +3253,7 @@ class CollectorServer:
                 await self._peer_ready.wait()
             else:
                 await self._dial_peer()
-            self._rpc_srv = await asyncio.start_server(
+            self._rpc_srv = await wire.start_server(
                 self._handle_leader, host, port
             )
         return self._rpc_srv
@@ -3422,7 +3440,7 @@ class CollectorClient:
 
             async def dial():
                 return await asyncio.wait_for(
-                    asyncio.open_connection(self._host, self._port),
+                    wire.open_connection(self._host, self._port),
                     respolicy.DIAL_TIMEOUT_S,
                 )
 

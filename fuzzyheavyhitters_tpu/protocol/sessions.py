@@ -1251,6 +1251,11 @@ class PlaneMux:
                 self._queue(chan).put_nowait(
                     (payload, hdr, stamps[0] if stamps else None)
                 )
+                # the queue owns the frame now: a pump that kept it in
+                # its locals until the NEXT frame arrives would pin the
+                # receive buffer (a slab of up to a level's message,
+                # protocol/wire.py) long after its consumer let go
+                frame = payload = None
         except asyncio.CancelledError:
             raise
         # fhh-lint: disable=broad-except (transport boundary: EVERY pump failure — EOF, reset, a QueueFull divergence, a corrupt frame — must surface to the blocked receivers as a plane death)

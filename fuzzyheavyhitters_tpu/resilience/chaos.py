@@ -1,7 +1,9 @@
 """Chaos proxy: deterministic fault injection between crawl sockets.
 
-An asyncio TCP proxy that understands the control/data-plane framing
-(8-byte little-endian length prefix, protocol/rpc.py ``_HDR``) and can
+An asyncio TCP proxy that understands the control/data-plane framing as
+far as a forwarder must (the 8-byte little-endian prefix over a frame's
+whole body, protocol/wire.py ``HDR``; the body's inner layout, pickled
+metadata followed by raw array buffers, is opaque here) and can
 therefore trigger faults at exact FRAME boundaries — "sever the leader's
 link right after the 12th request" is reproducible, where byte- or
 time-triggered faults are not.
@@ -53,13 +55,11 @@ schedules; ``ChaosProxy.sever_now()`` gives imperative test control.
 from __future__ import annotations
 
 import asyncio
-import struct
 import time
 from dataclasses import dataclass, field
 
 from .. import obs
-
-_HDR = struct.Struct("<Q")  # mirror protocol/rpc.py framing
+from ..protocol.wire import HDR as _HDR  # the outer prefix: all a forwarder reads
 
 _ACTIONS = ("sever", "delay", "blackhole", "truncate", "flood", "slowclient")
 _DIRS = ("c2s", "s2c")

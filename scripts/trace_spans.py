@@ -12,6 +12,10 @@ object:
 - ``gc_ot_cover``: per server, over its ``gc_ot`` spans, the share of the
   span that its leaf spans inside it cover (median and smallest), and the
   median milliseconds of each leaf inside one ``gc_ot``;
+- ``wire_oob``: per component, the frames that carried raw array buffers
+  (``wire_oob`` instants, one a frame: protocol/rpc.py ``_send``), their
+  framed bytes, the bytes out of band (the counter ``wire_oob_bytes``) and
+  the share;
 - ``clock`` (with ``--capture``): the program's spans are also profiler
   annotations (``<comp>:<name>``) on the profiler's own clock.  The
   benchmark lays the JSONL lines over a capture by one sync mark
@@ -78,6 +82,20 @@ def gc_ot_cover(spans: list) -> dict:
     return out
 
 
+def wire_oob(events: list) -> dict:
+    out: dict = {}
+    for e in events:
+        if e.get("ph") == "i" and e.get("name") == "wire_oob":
+            row = out.setdefault(
+                e["comp"], {"frames": 0, "framed_bytes": 0, "wire_oob_bytes": 0})
+            row["frames"] += 1
+            row["framed_bytes"] += e["args"]["framed"]
+            row["wire_oob_bytes"] += e["args"]["oob"]
+    for row in out.values():
+        row["share"] = row["wire_oob_bytes"] / row["framed_bytes"]
+    return dict(sorted(out.items()))
+
+
 def clock_check(spans: list, capture: str, wall_ns_at_sync: int,
                 sync_event: str) -> dict:
     from jax.profiler import ProfileData
@@ -134,11 +152,13 @@ def main(argv=None) -> int:
     p.add_argument("--wall-ns-at-sync", type=int)
     p.add_argument("--sync-event", default="bench_sync")
     args = p.parse_args(argv)
-    spans = [e for e in obstrace.load_events(args.trace_dir) if e.get("ph") == "X"]
+    events = obstrace.load_events(args.trace_dir)
+    spans = [e for e in events if e.get("ph") == "X"]
     if not spans:
         print(f"no span under {args.trace_dir}", file=sys.stderr)
         return 1
-    out = {"span_ms": span_ms(spans), "gc_ot_cover": gc_ot_cover(spans)}
+    out = {"span_ms": span_ms(spans), "gc_ot_cover": gc_ot_cover(spans),
+           "wire_oob": wire_oob(events)}
     if args.capture:
         if args.wall_ns_at_sync is None:
             p.error("--capture needs --wall-ns-at-sync")

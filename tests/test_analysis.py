@@ -376,6 +376,22 @@ def test_unbounded_await_reads_and_waits_detected():
     assert all(f.rule == "unbounded-await" for f in found)
 
 
+def test_unbounded_await_frame_reads_detected():
+    """The wire layer's read primitives (protocol/wire.py) are network
+    reads like ``readexactly``: a frame's out-of-band buffer filled by
+    ``readinto``, a whole body by ``read_body``."""
+    src = """
+    import asyncio
+
+    async def f(reader, wire, buf, n):
+        await reader.readinto(buf)
+        meta, bufs = await wire.read_body(reader, n)
+        await asyncio.wait_for(reader.readinto(buf), 5.0)
+    """
+    found = _lint(src, rule="unbounded-await")
+    assert [f.rule for f in found] == ["unbounded-await"] * 2
+
+
 def test_unbounded_await_dial_and_disguised_wait_for_detected():
     src = """
     import asyncio

@@ -25,7 +25,7 @@ from fuzzyheavyhitters_tpu.workloads import rides
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 N_REQS = 32
-PORT = 21701
+PORT = 30531  # a range of its own: 21701 lay inside test_resilience's and test_secure_kernels' offsets
 CFG = {
     "data_len": 16,
     "n_dims": 2,

@@ -23,7 +23,7 @@ from fuzzyheavyhitters_tpu.resilience.chaos import ChaosProxy, parse_faults
 from fuzzyheavyhitters_tpu.utils import bits as bitutils
 from fuzzyheavyhitters_tpu.utils.config import Config
 
-BASE_PORT = 21631
+BASE_PORT = 31631  # a range of its own: 21631 + offsets ran into test_obs, test_ops and test_mesh_multiprocess under xdist
 
 
 @pytest.fixture(autouse=True)

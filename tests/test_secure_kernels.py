@@ -22,7 +22,7 @@ from fuzzyheavyhitters_tpu.protocol.leader_rpc import RpcLeader
 from fuzzyheavyhitters_tpu.utils import bits as bitutils
 from fuzzyheavyhitters_tpu.utils.config import Config
 
-BASE_PORT = 21531
+BASE_PORT = 30131  # a range of its own: 21531 + offsets ran into test_sketch, test_resilience and test_binaries_e2e under xdist
 
 
 @pytest.fixture(autouse=True)

@@ -862,6 +862,102 @@ def test_wire_and_transfer_spans_every_level(rng, trace_dir, secure):
             assert total <= g["dur"] + _EPS * len(inside), (comp, g["level"])
 
 
+def test_wire_spans_of_a_frame_with_two_out_of_band_buffers(trace_dir, monkeypatch):
+    """One data-plane frame of two raw buffers over a loopback socket,
+    through the real pump: the sender's ``wire_pickle`` and
+    ``wire_write``, the receiver's ``peer_wait`` -> ``wire_read`` ->
+    ``wire_unpickle`` end to end under its ``wire_wait``, ``wire_read``
+    ending once the LAST buffer is held, and the ``wire_oob`` instant
+    that ``scripts/trace_spans.py`` sums."""
+    import importlib.util
+    import os
+
+    from fuzzyheavyhitters_tpu.protocol import sessions, wire
+
+    held = []
+    real = wire.FrameReader.readinto
+
+    async def spy(self, buf):
+        await real(self, buf)
+        if isinstance(buf, np.ndarray):
+            held.append(time.time())
+
+    monkeypatch.setattr(wire.FrameReader, "readinto", spy)
+    port = BASE_PORT + 400
+    srv_obj = rpc.CollectorServer(1, _cfg(port))
+    a = np.arange(1 << 18, dtype=np.uint32)
+    b = np.arange(1 << 17, dtype=np.uint64)
+    tx, rx = obsmetrics.Registry("server0"), obsmetrics.Registry("server1")
+
+    async def run():
+        accepted = asyncio.get_running_loop().create_future()
+
+        async def on(r, w):
+            accepted.set_result((r, w))
+
+        srv = await wire.start_server(on, "127.0.0.1", port)
+        _, cw = await wire.open_connection("127.0.0.1", port)
+        sr, sw = await asyncio.wait_for(accepted, 5)
+        mux = sessions.PlaneMux(tag="server1")
+        mux.attach(sr, srv_obj._recv_plane_frame)
+        with tracemod.root("crawl"):
+            async def receive():
+                with rx.span("gc_ot", level=3):
+                    with rx.span("wire_wait"):
+                        return await mux.recv("chan", rx)
+
+            pending = asyncio.ensure_future(receive())
+            await asyncio.sleep(0.05)  # the receiver waits: peer_wait > 0
+            with tx.span("gc_ot", level=3):
+                await rpc._send(
+                    cw, ("chan", (a, b), tracemod.wire_tag()),
+                    reg=tx, counter="data_bytes_sent",
+                )
+            got = await asyncio.wait_for(pending, 10)
+        mux.close()
+        for w in (cw, sw):
+            w.close()
+        srv.close()
+        await asyncio.wait_for(srv.wait_closed(), 5)
+        return got
+
+    got = asyncio.run(run())
+    assert np.array_equal(got[0], a) and np.array_equal(got[1], b)
+    assert len(held) == 2
+    oob = a.nbytes + b.nbytes
+    assert tx.counter_value("wire_oob_bytes", level=3) == oob
+    assert oob > 0.999 * tx.counter_value("data_bytes_sent")
+    evs = _events(trace_dir)
+    assert tracemod.validate(evs)["ok"]
+    by = {(e["comp"], e["name"]): e for e in evs if e["ph"] == "X"}
+    for key in (("server0", "wire_pickle"), ("server0", "wire_write"),
+                ("server1", "peer_wait"), ("server1", "wire_read"),
+                ("server1", "wire_unpickle")):
+        assert by[key]["level"] == 3, key
+    end = lambda e: e["ts"] + e["dur"]
+    pw, rd, up = (by["server1", n] for n in ("peer_wait", "wire_read", "wire_unpickle"))
+    wait = by["server1", "wire_wait"]
+    assert pw["parent"] == rd["parent"] == up["parent"] == wait["span"]
+    assert pw["dur"] >= 0.04  # asked before the frame was sent
+    assert abs(end(pw) - rd["ts"]) <= _EPS and abs(end(rd) - up["ts"]) <= _EPS
+    # wire_read: header read -> BOTH buffers held, and no longer
+    assert rd["ts"] - _EPS <= held[0] <= held[1] <= end(rd) + _EPS
+    assert end(up) <= end(wait) + _EPS
+    # the sender pickled no array: its wire_pickle precedes its write
+    pk, wr = by["server0", "wire_pickle"], by["server0", "wire_write"]
+    assert end(pk) <= wr["ts"] + _EPS and wr["ts"] <= end(rd)
+    inst = [e for e in evs if e["ph"] == "i" and e["name"] == "wire_oob"]
+    assert [e["args"]["oob"] for e in inst] == [oob]
+    spec = importlib.util.spec_from_file_location(
+        "trace_spans", os.path.join(
+            os.path.dirname(os.path.dirname(__file__)), "scripts", "trace_spans.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    row = mod.wire_oob(evs)["server0"]
+    assert row["frames"] == 1 and row["wire_oob_bytes"] == oob
+    assert row["framed_bytes"] == tx.counter_value("data_bytes_sent")
+
+
 def test_profiler_capture_holds_the_program_spans(rng, tmp_path, trace_dir):
     """A jax.profiler capture around a crawl carries the program's
     spans as ``<comp>:<name>`` annotations on the profiler's own clock,

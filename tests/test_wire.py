@@ -1,0 +1,502 @@
+"""The wire format (protocol/wire.py) over real loopback sockets: array
+payloads cross as raw buffers, everything else as pickled metadata."""
+
+import asyncio
+import pickle
+import struct
+
+import numpy as np
+import pytest
+
+from fuzzyheavyhitters_tpu.obs import metrics as obsmetrics
+from fuzzyheavyhitters_tpu.ops import ibdcf
+from fuzzyheavyhitters_tpu.protocol import rpc, sessions, wire
+from fuzzyheavyhitters_tpu.protocol.leader_rpc import RpcLeader
+from fuzzyheavyhitters_tpu.resilience.chaos import ChaosProxy, parse_faults
+from fuzzyheavyhitters_tpu.utils import bits as bitutils
+from fuzzyheavyhitters_tpu.utils.config import Config
+
+BASE_PORT = 25431
+OOB = wire.OOB_MIN
+
+
+def _cfg(port=BASE_PORT + 90, **kw):
+    base = dict(
+        data_len=4, n_dims=1, ball_size=1, addkey_batch_size=1024,
+        num_sites=4, threshold=0.2, zipf_exponent=1.03,
+        server0=f"127.0.0.1:{port}", server1=f"127.0.0.1:{port + 10}",
+        distribution="zipf", f_max=32,
+    )
+    base.update(kw)
+    return Config(**base)
+
+
+def _big(n_bytes, dtype=np.uint32, seed=0):
+    raw = np.random.default_rng(seed).bytes(n_bytes)
+    return np.frombuffer(raw, dtype=dtype).copy()
+
+
+def _readonly(a):
+    a.setflags(write=False)
+    return a
+
+
+# name -> (object, bytes expected out of band)
+_CASES = {
+    "secure-chan-array": (lambda: ("default", _big(4 * OOB)), 4 * OOB),
+    "secure-chan-array-hdr": (
+        lambda: ("default", _big(4 * OOB), ("a1b2c3", "s9")), 4 * OOB),
+    "several-large-arrays": (
+        lambda: (_big(2 * OOB, np.uint64, 1), {"k": _big(3 * OOB, np.uint8, 2)},
+                 [_big(OOB, np.int16, 3).reshape(-1, 64)]),
+        6 * OOB),
+    "fortran-order": (
+        lambda: np.asfortranarray(_big(2 * OOB).reshape(128, -1)), 2 * OOB),
+    "non-contiguous-slice": (lambda: ("c", _big(8 * OOB)[::2]), 0),
+    "read-only-source": (lambda: ("c", _readonly(_big(2 * OOB))), 2 * OOB),
+    "zero-size": (lambda: ("c", np.empty((0, 4), np.uint32)), 0),
+    "zero-d": (lambda: ("c", np.array(7, dtype=np.int64)), 0),
+    "just-under": (lambda: _big(OOB - 4), 0),
+    "just-over": (lambda: _big(OOB + 4), OOB + 4),
+    "exactly": (lambda: _big(OOB), OOB),
+    "bytes": (lambda: b"\x00\x01" * (2 * OOB), 0),
+    "dict": (lambda: {"verb": "status", "n": 3, "nested": {"x": [1, 2.5, None]}}, 0),
+    "str": (lambda: "one", 0),
+}
+
+
+def _same(a, b):
+    """Identical in value, dtype, shape and nesting."""
+    assert type(a) is type(b), (type(a), type(b))
+    if isinstance(a, np.ndarray):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        assert np.array_equal(a, b)
+        assert a.flags.writeable == b.flags.writeable
+    elif isinstance(a, (tuple, list)):
+        assert len(a) == len(b)
+        for x, y in zip(a, b):
+            _same(x, y)
+    elif isinstance(a, dict):
+        assert a.keys() == b.keys()
+        for k in a:
+            _same(a[k], b[k])
+    else:
+        assert a == b
+
+
+async def _pair(port, on_connect=None):
+    """A loopback connection: (client reader, client writer, server
+    reader, server writer, listener)."""
+    accepted = asyncio.get_running_loop().create_future()
+
+    async def on(r, w):
+        accepted.set_result((r, w))
+
+    srv = await wire.start_server(on_connect or on, "127.0.0.1", port)
+    cr, cw = await wire.open_connection("127.0.0.1", port)
+    if on_connect is not None:
+        return cr, cw, None, None, srv
+    sr, sw = await asyncio.wait_for(accepted, 5)
+    return cr, cw, sr, sw, srv
+
+
+async def _close(srv, *writers):
+    for w in writers:
+        if w is not None:
+            w.close()
+    srv.close()
+    await asyncio.wait_for(srv.wait_closed(), 5)
+
+
+@pytest.mark.parametrize("case", sorted(_CASES))
+def test_frame_round_trip(case):
+    make, want_oob = _CASES[case]
+    port = BASE_PORT + sorted(_CASES).index(case)
+
+    async def run():
+        cr, cw, sr, sw, srv = await _pair(port)
+        tx, rx = obsmetrics.Registry("tx"), obsmetrics.Registry("rx")
+        obj = make()
+        await rpc._send(cw, obj, reg=tx, counter="data_bytes_sent")
+        got = await asyncio.wait_for(
+            rpc._recv(sr, reg=rx, counter="data_bytes_recv"), 10)
+        _same(obj, got)
+        # and back, through the other direction of the same connection
+        await rpc._send(sw, got)
+        _same(obj, await asyncio.wait_for(rpc._recv(cr), 10))
+        await _close(srv, cw, sw)
+        return tx.report()["counters"], rx.report()["counters"]
+
+    sent, recv = asyncio.run(run())
+    assert sent["data_bytes_sent"]["total"] == recv["data_bytes_recv"]["total"]
+    assert sent.get("wire_oob_bytes", {"total": 0})["total"] == want_oob
+    pieces, nbytes, oob = wire.encode(make())
+    assert oob == want_oob and nbytes == sent["data_bytes_sent"]["total"]
+    assert nbytes == sum(memoryview(p).nbytes for p in pieces)
+    # the outer prefix covers the whole body: what chaos.py re-frames by
+    assert wire.HDR.unpack(pieces[0][:8])[0] == nbytes - 8
+    # and the frame is the bare pickle's size give or take a few dozen
+    # bytes: wire_bytes_per_level keeps its meaning
+    k = len(pieces) - 2
+    bare = 8 + len(pickle.dumps(make(), protocol=5))
+    assert abs(nbytes - bare) <= 4 + 8 * (k + 1) + 16 * k
+
+
+def test_large_arrays_are_not_pickled():
+    """The metadata of a frame of large arrays holds none of their
+    bytes, and the pieces handed to the transport are the arrays' own
+    memory."""
+    a, b = _big(4 * OOB, seed=4), _big(2 * OOB, np.uint8, seed=5)
+    pieces, nbytes, oob = wire.encode(("chan", (a, b)))
+    assert oob == a.nbytes + b.nbytes and len(pieces) == 4
+    assert len(pieces[0]) + len(pieces[1]) < 512
+    assert nbytes == len(pieces[0]) + len(pieces[1]) + oob
+    assert np.shares_memory(np.frombuffer(pieces[2], np.uint8), a)
+    assert np.shares_memory(np.frombuffer(pieces[3], np.uint8), b)
+
+
+def test_three_frames_back_to_back_arrive_in_order():
+    async def run():
+        cr, cw, sr, sw, srv = await _pair(BASE_PORT + 40)
+        frames = [("c", _big(3 * OOB, seed=i), i) for i in range(3)]
+        for f in frames[:-1]:
+            await rpc._send(cw, f, flush=False)
+        await rpc._send(cw, frames[-1])
+        got = [await asyncio.wait_for(rpc._recv(sr), 10) for _ in frames]
+        await _close(srv, cw, sw)
+        return frames, got
+
+    frames, got = asyncio.run(run())
+    for f, g in zip(frames, got):
+        _same(f, g)
+
+
+def _corrupt(obj, grow=8):
+    """``obj``'s frame with a prefix that its inner lengths do not sum
+    to (the body is padded, so the bytes are all there)."""
+    pieces, nbytes, _ = wire.encode(obj)
+    body = b"".join(bytes(p) for p in pieces)[8:]
+    return wire.HDR.pack(len(body) + grow) + body + b"\0" * grow
+
+
+def test_inner_lengths_not_summing_fail_the_plane():
+    """A corrupt data-plane frame fails the mux with ConnectionError
+    and delivers nothing of itself; the frames before it are intact."""
+    srv_obj = rpc.CollectorServer(0, _cfg())
+
+    async def run():
+        cr, cw, sr, sw, srv = await _pair(BASE_PORT + 41)
+        mux = sessions.PlaneMux()
+        mux.attach(sr, srv_obj._recv_plane_frame)
+        good = ("chan", _big(2 * OOB, seed=9))
+        await rpc._send(cw, good)
+        cw.writelines([_corrupt(("chan", _big(2 * OOB, seed=10)))])
+        await cw.drain()
+        first = await asyncio.wait_for(mux.recv("chan"), 10)
+        errs = []
+        for _ in range(2):  # the failure stays visible
+            with pytest.raises(ConnectionError) as ei:
+                await asyncio.wait_for(mux.recv("chan"), 10)
+            errs.append(ei.value)
+        depth = mux._queue("chan").qsize()
+        mux.close()
+        await _close(srv, cw, sw)
+        return good, first, errs, depth
+
+    good, first, errs, depth = asyncio.run(run())
+    _same(good[1], first)
+    assert isinstance(errs[0].__cause__, wire.FrameError)
+    assert depth == 1  # the failure marker alone: no half-read frame
+
+
+@pytest.mark.parametrize("body", [
+    b"\x80\x05K\x01.",                        # a bare pickle: the old format
+    struct.pack("<IQQ", 1, 5, 70000) + b"x",  # lengths beyond the prefix
+    b"\x01",                                  # too short for a count
+])
+def test_corrupt_control_frame_raises_frame_error(body):
+    """What a control-plane reader sees: a ConnectionError (transient,
+    so the client redials and the serve loop drops the connection)."""
+    async def run():
+        cr, cw, sr, sw, srv = await _pair(BASE_PORT + 42)
+        cw.writelines([wire.HDR.pack(len(body)) + body])
+        with pytest.raises(wire.FrameError):
+            await asyncio.wait_for(rpc._recv(sr), 10)
+        await _close(srv, cw, sw)
+
+    asyncio.run(run())
+    assert issubclass(wire.FrameError, ConnectionError)
+
+
+def test_serve_loop_drops_a_connection_that_sends_a_corrupt_frame():
+    async def run():
+        s0 = rpc.CollectorServer(0, _cfg())
+        s0._rpc_srv = await wire.start_server(
+            s0._handle_leader, "127.0.0.1", BASE_PORT + 43)
+        errors = []
+        asyncio.get_running_loop().set_exception_handler(
+            lambda loop, ctx: errors.append(ctx.get("exception")))
+        r, w = await wire.open_connection("127.0.0.1", BASE_PORT + 43)
+        w.writelines([_corrupt((1, "status", {}))])
+        with pytest.raises((asyncio.IncompleteReadError, ConnectionError)):
+            await asyncio.wait_for(rpc._recv(r), 10)  # EOF or reset: dropped
+        w.close()
+        await s0.aclose()
+        return errors
+
+    errors = asyncio.run(run())
+    assert [type(e) for e in errors] == [wire.FrameError]
+
+
+def test_frame_through_chaos_proxy_with_duplication_and_delay():
+    async def run():
+        got = asyncio.Queue()
+
+        async def sink(r, w):
+            try:
+                while True:
+                    got.put_nowait(await rpc._recv(r))
+            except (asyncio.IncompleteReadError, ConnectionError):
+                pass
+            finally:
+                w.close()
+
+        port_s, port_p = BASE_PORT + 44, BASE_PORT + 45
+        _, _, _, _, srv = await _pair(port_s, on_connect=sink)
+        px = await ChaosProxy(
+            "127.0.0.1", port_p, "127.0.0.1", port_s,
+            parse_faults("x:flood@msg=1,count=2;x:delay@msg=2,ms=100"),
+            link="x",
+        ).start()
+        r, w = await wire.open_connection("127.0.0.1", port_p)
+        one = ("c", _big(3 * OOB, seed=1), _big(2 * OOB, np.uint8, seed=2))
+        two = ("c", _big(5 * OOB, seed=3))
+        await rpc._send(w, one)
+        await rpc._send(w, two)
+        out = [await asyncio.wait_for(got.get(), 10) for _ in range(4)]
+        fired = list(px.fired)
+        w.close()
+        await px.stop()
+        await _close(srv)
+        return one, two, out, fired
+
+    one, two, out, fired = asyncio.run(run())
+    for g in out[:3]:  # the original and its two duplicates
+        _same(one, g)
+    _same(two, out[3])
+    assert [f[0] for f in fired] == ["flood", "delay"]
+
+
+@pytest.mark.parametrize("slabs", [False, True], ids=["new-memory", "slabs"])
+def test_queued_frames_each_own_the_buffer_their_socket_read_filled(monkeypatch, slabs):
+    """Two frames queued on one channel before either is consumed: each
+    received array's memory IS a buffer the socket was read into, no
+    buffer serves two frames, and neither frame changes when the other
+    (or a third) arrives; with buffers of new memory and with slabs
+    (what buffers of 1 MiB and more are)."""
+    if slabs:
+        monkeypatch.setattr(wire, "_SLAB_MIN", OOB)
+    filled = []
+    real = wire.FrameReader.readinto
+
+    async def spy(self, buf):
+        await real(self, buf)
+        if isinstance(buf, np.ndarray):
+            filled.append(buf)
+
+    monkeypatch.setattr(wire.FrameReader, "readinto", spy)
+    srv_obj = rpc.CollectorServer(0, _cfg())
+
+    async def run():
+        cr, cw, sr, sw, srv = await _pair(BASE_PORT + 46 + 4 * slabs)
+        mux = sessions.PlaneMux()
+        mux.attach(sr, srv_obj._recv_plane_frame)
+        sent = [_big(4 * OOB, seed=20 + i) for i in range(3)]
+        await rpc._send(cw, ("chan", sent[0]))
+        await rpc._send(cw, ("chan", sent[1]))
+        while mux._queue("chan").qsize() < 2:  # both queued, none consumed
+            await asyncio.sleep(0.01)
+        first = await mux.recv("chan")
+        keep = first.copy()
+        await rpc._send(cw, ("chan", sent[2]))
+        while mux._queue("chan").qsize() < 2:
+            await asyncio.sleep(0.01)
+        assert np.array_equal(first, keep)  # untouched by the third
+        rest = [await mux.recv("chan"), await mux.recv("chan")]
+        mux.close()
+        await _close(srv, cw, sw)
+        return sent, [first, *rest]
+
+    sent, got = asyncio.run(asyncio.wait_for(run(), 30))
+    assert len(filled) == 3 and len({id(b) for b in filled}) == 3
+    for s, g, buf in zip(sent, got, filled):
+        assert np.array_equal(s, g)
+        assert np.shares_memory(g, buf)
+        assert g.ctypes.data == buf.ctypes.data
+        assert slabs or buf.base is None
+    for i in range(3):
+        for j in range(i + 1, 3):
+            assert not np.shares_memory(got[i], got[j])
+
+
+@pytest.mark.skipif(not wire._LEASES, reason="slabs need PEP 688 (Python 3.12)")
+def test_slab_returns_only_when_its_last_reader_is_gone(monkeypatch, cpu_default):
+    """A slab (a receive buffer of 1 MiB or more; here the threshold
+    is lowered) serves the next frame only once the array made on it,
+    every view of it and a ``jax.device_put`` of it are gone; until
+    then later frames of the same size get other memory and what the
+    readers see does not change."""
+    import jax
+
+    monkeypatch.setattr(wire, "_SLAB_MIN", OOB)
+    wire._free_slabs.clear()
+    srv_obj = rpc.CollectorServer(0, _cfg())
+    size = 8 * OOB
+
+    async def run():
+        cr, cw, sr, sw, srv = await _pair(BASE_PORT + 51)
+        mux = sessions.PlaneMux()
+        mux.attach(sr, srv_obj._recv_plane_frame)
+        sent = [_big(size, seed=40 + i) for i in range(5)]
+
+        async def frame(i):
+            await rpc._send(cw, ("chan", sent[i]))
+            return await asyncio.wait_for(mux.recv("chan"), 10)
+
+        first = await frame(0)
+        addr = first.ctypes.data
+        view = first[1000:2000]
+        del first
+        second = await frame(1)  # `view` holds frame 0's slab
+        on_device = jax.device_put(second)
+        addr2 = second.ctypes.data
+        del second
+        third = await frame(2)  # the device copy may hold frame 1's
+        assert len({addr, addr2, third.ctypes.data}) == 3
+        assert np.array_equal(view, sent[0][1000:2000])
+        del view
+        fourth = await frame(3)  # the newest released slab: frame 0's
+        assert fourth.ctypes.data == addr and np.array_equal(fourth, sent[3])
+        assert np.array_equal(third, sent[2])
+        assert np.array_equal(np.asarray(on_device), sent[1])
+        del third, fourth, on_device
+        # (the list is the process's: another test's slab may land on it)
+        assert 2 <= sum(s.nbytes == size for s in wire._free_slabs) <= 3
+        monkeypatch.setattr(wire, "_SLAB_KEEP", size)  # room for one
+        fifth = await frame(4)
+        assert np.array_equal(fifth, sent[4])
+        assert sum(s.nbytes for s in wire._free_slabs) <= size
+        mux.close()
+        await _close(srv, cw, sw)
+
+    asyncio.run(asyncio.wait_for(run(), 30))
+    wire._free_slabs.clear()
+
+
+def test_read_cancelled_midway_aborts_the_connection():
+    """A frame read cancelled after some of its bytes were taken cannot
+    resume: the connection is aborted, not left out of step."""
+    async def run():
+        cr, cw, sr, sw, srv = await _pair(BASE_PORT + 47)
+        pieces, _, _ = wire.encode(("c", _big(2 * OOB)))
+        cw.writelines([bytes(pieces[0]), bytes(pieces[1]), bytes(pieces[2])[:100]])
+        with pytest.raises(asyncio.TimeoutError):
+            await asyncio.wait_for(rpc._recv(sr), 0.3)
+        with pytest.raises(ConnectionError):
+            await asyncio.wait_for(rpc._recv(sr), 5)
+        # a read cancelled while it waits for a frame's first byte is not
+        cr2, cw2, sr2, sw2, srv2 = await _pair(BASE_PORT + 48)
+        with pytest.raises(asyncio.TimeoutError):
+            await asyncio.wait_for(rpc._recv(sr2), 0.1)
+        await rpc._send(cw2, "still here")
+        assert await asyncio.wait_for(rpc._recv(sr2), 5) == "still here"
+        await _close(srv, cw, sw)
+        await _close(srv2, cw2, sw2)
+
+    asyncio.run(run())
+
+
+def test_writer_backpressure_and_close():
+    """``drain()`` waits while the peer does not read, returns once it
+    does, and raises after the connection is lost."""
+    async def run():
+        cr, cw, sr, sw, srv = await _pair(BASE_PORT + 49)
+        big = _big(64 << 20, np.uint8)  # more than the socket buffers hold
+        send = asyncio.ensure_future(rpc._send(cw, big))
+        await asyncio.sleep(0.3)
+        assert not send.done()  # the receiver has not read: drain waits
+        got = await asyncio.wait_for(rpc._recv(sr), 30)
+        await asyncio.wait_for(send, 10)
+        assert np.array_equal(got, big)
+        # drained means the kernel has it all: no view of `big` is left
+        assert cw.transport.get_write_buffer_size() == 0
+        sw.close()
+        await asyncio.wait_for(sw.wait_closed(), 5)
+        with pytest.raises(asyncio.IncompleteReadError):
+            await asyncio.wait_for(rpc._recv(cr), 5)
+        cw.close()
+        await asyncio.wait_for(cw.wait_closed(), 5)
+        with pytest.raises((ConnectionError, RuntimeError)):
+            await rpc._send(cw, big)
+        await _close(srv)
+
+    asyncio.run(run())
+
+
+# ---------------------------------------------------------------------------
+# the mechanism engages in a crawl
+# ---------------------------------------------------------------------------
+
+
+def _keys(rng, L, n):
+    pts = np.concatenate(
+        [np.full(n - 4, 11), rng.integers(0, 1 << L, size=4)]
+    )[:, None]
+    pts_bits = np.array(
+        [[bitutils.int_to_bits(L, int(v)) for v in row] for row in pts]
+    )
+    return ibdcf.gen_l_inf_ball(pts_bits, 1, rng, engine="np")
+
+
+def test_secure_crawl_sends_its_levels_out_of_band(rng, cpu_default):
+    """A two-server secure crawl on the CPU, wide enough that a level's
+    frames are arrays of 64 KiB and more: over the crawl's levels
+    ``wire_oob_bytes`` is over 99% of ``data_bytes_sent`` on both
+    servers, and the two ends of the stream still agree to the byte."""
+    L, n, port = 4, 4096, BASE_PORT + 60
+    k0, k1 = _keys(rng, L, n)
+    cfg = _cfg(port, data_len=L, secure_exchange=True)
+
+    async def run():
+        s0, s1 = rpc.CollectorServer(0, cfg), rpc.CollectorServer(1, cfg)
+        t1 = asyncio.create_task(
+            s1.start("127.0.0.1", port + 10, "127.0.0.1", port + 11))
+        await asyncio.sleep(0.05)
+        await asyncio.gather(
+            s0.start("127.0.0.1", port, "127.0.0.1", port + 11), t1)
+        c0 = await rpc.CollectorClient.connect("127.0.0.1", port)
+        c1 = await rpc.CollectorClient.connect("127.0.0.1", port + 10)
+        lead = RpcLeader(cfg, c0, c1)
+        await lead._both("reset")
+        res = await lead.run_supervised(n, k0, k1)
+        reps = [s.obs.report()["counters"] for s in (s0, s1)]
+        lead_rep = lead.obs.report()["counters"]
+        for c in (c0, c1):
+            await c.aclose()
+        for s in (s0, s1):
+            await s.aclose()
+        return res, reps, lead_rep
+
+    res, (r0, r1), lead_rep = asyncio.run(asyncio.wait_for(run(), 600))
+    assert len(res.counts) > 0
+    for rep in (r0, r1):
+        sent = rep["data_bytes_sent"]["by_level"]
+        oob = rep["wire_oob_bytes"]["by_level"]
+        assert set(sent) >= {str(lv) for lv in range(L)}
+        for lv, nbytes in sent.items():
+            assert oob.get(lv, 0) > 0.99 * nbytes, (lv, oob.get(lv), nbytes)
+    assert r0["data_bytes_sent"]["total"] == r1["data_bytes_recv"]["total"]
+    assert r1["data_bytes_sent"]["total"] == r0["data_bytes_recv"]["total"]
+    # the upload's key chunks cross out of band too (contiguous slices)
+    assert lead_rep["wire_oob_bytes"]["total"] > 0.5 * (
+        lead_rep["control_bytes_sent"]["total"])
