@@ -33,9 +33,12 @@ failed check raises: non-zero exit, no final ``ok`` line.  Without a TPU
 the script fails before it runs anything.
 
 ``--chips 4`` runs ONLY the sharded-server comparison: the secure lane
-with ``server_data_devices=0`` (auto: all four chips) against the same
-keys on one device.  N is cut to 2048 there (planned: 65536) by the
-15-minute cold wall clock: PR 25's four-chip run at N=16384 took 1468 s
+with each server's client axis sharded over its own half of the host's
+chips (``server_data_devices=2`` there: server 0 on chips 0-1, server 1 on
+chips 2-3, by ``server_mesh.server_devices``, the rule the servers and
+the benchmark's four-chip cell place by; the lane line prints the pairs
+as ``mesh_devices``) against the same keys on one device.  N is cut to
+2048 there (planned: 65536) by the 15-minute cold wall clock: PR 25's four-chip run at N=16384 took 1468 s
 for the two lanes — 605 s of backend compiles, which do not shrink with
 N, and 862 s of crawling (0.88 and 0.80 s per level on that host), which
 is taken to shrink with N x frontier.  That leaves ~110 s of crawling
@@ -264,7 +267,9 @@ async def _lane(cfg: Config, port: int, k0, k1, n: int, *, crawls: int = 1,
         # at upload: only this second sample shows where they live
         out["bytes_in_use_keys_resident"] = _bytes_in_use()
         e0, e1 = s0.engine_tags(), s1.engine_tags()
-        _check(e0 == e1, f"the two servers ran different engines: {e0} {e1}")
+        same = lambda e: {k: v for k, v in e.items() if k != "mesh_devices"}
+        _check(same(e0) == same(e1),
+               f"the two servers ran different engines: {e0} {e1}")
         out["engines"] = e0
         out["key_devices"] = [
             sorted(d.id for d in leaf.sharding.device_set)
@@ -317,10 +322,10 @@ def _keygen(pts, rng) -> tuple:
 
 
 def _check_lane_engines(name: str, lane: dict, data_devices: int) -> dict:
-    """What the lane's servers said they ran: a one-device server runs
-    the process's expand engine, a sharded one pins the XLA expand."""
+    """What the lane's servers said they ran: the process's expand
+    engine, on one device or (once per shard) on a server's mesh."""
     tags = lane["engines"]
-    want = "xla" if data_devices > 1 else rpc.engine_tags()["expand"]
+    want = rpc.engine_tags()["expand"]
     _check(tags["data_devices"] == data_devices and tags["expand"] == want,
            f"{name}: servers ran {tags}, want expand={want} on "
            f"{data_devices} device(s)")
@@ -454,13 +459,13 @@ def run_sharded(n: int, data_len: int, *, seed: int = 0,
                 threshold: float = THRESHOLD, f_max: int = F_MAX,
                 port: int = BASE_PORT + 120) -> dict:
     """``--chips 4``: the secure lane with each server's client axis
-    sharded over every local device (``server_data_devices`` 0 = auto,
-    ``secure_kernel_shards`` auto), then the same keys on one device;
-    hitters and counts identical, and the key planes really spread.
-    Returns the device (for the final line)."""
+    sharded over its own half of the local devices (the two servers on
+    disjoint chips; ``secure_kernel_shards`` auto), then the same keys
+    on one device; hitters and counts identical, and the key planes
+    really spread.  Returns the device (for the final line)."""
     device, common = _start(4, data_len, seed, threshold, f_max)
-    n_dev = len(jax.local_devices())
-    if data_devices > 0:  # a rehearsal names its count; the chip run is auto
+    n_dev = len(jax.local_devices()) // 2
+    if data_devices > 0:  # a rehearsal names its count
         n_dev = min(n_dev, data_devices)
     base = dataclasses.replace(
         _config(data_len, num_sites, threshold, f_max), secure_exchange=True
@@ -470,7 +475,7 @@ def run_sharded(n: int, data_len: int, *, seed: int = 0,
     thresh = max(1, int(threshold * n))
     want = plain_count(pts, BALL_SIZE, data_len, thresh)
     k0, k1 = _keygen(pts, rng)
-    for name, dd, spread, p in (("sharded", data_devices, n_dev, port),
+    for name, dd, spread, p in (("sharded", n_dev, n_dev, port),
                                 ("one_device", 1, 1, port + 40)):
         lane = _run_lane(
             dataclasses.replace(base, server_data_devices=dd), p, k0, k1, n
@@ -481,9 +486,12 @@ def run_sharded(n: int, data_len: int, *, seed: int = 0,
                f"{name}: key planes on devices {lane['key_devices']}, "
                f"want {spread} each")
         if spread > 1:
-            _check(all(m is not None and len(set(m)) == spread
-                       for m in lane["mesh_devices"]),
-                   f"ServerMesh.devices: {lane['mesh_devices']}")
+            m0, m1 = lane["mesh_devices"]
+            _check(m0 is not None and m1 is not None
+                   and len(set(m0)) == len(set(m1)) == spread
+                   and not set(m0) & set(m1),
+                   f"ServerMesh.devices: {lane['mesh_devices']}, want two "
+                   f"disjoint sets of {spread}")
         _emit(
             phase=name, n=n, n_planned=N_SHARDED_PLANNED, levels=data_len,
             threshold_count=thresh, hitters=len(want),

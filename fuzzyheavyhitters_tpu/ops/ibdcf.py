@@ -25,7 +25,7 @@ and ``[x > b]`` for side=False ("right"); share-string equality over
 
 from __future__ import annotations
 
-from functools import partial
+from functools import lru_cache, partial
 from typing import NamedTuple
 
 import jax
@@ -328,6 +328,76 @@ def _gen(engine: str):
     raise ValueError(f"unknown keygen engine {engine!r}")
 
 
+# A device keygen call's working set over the bytes of the batch it
+# returns: the fused kernel writes every correction bit as a 32-bit word
+# in its client-minor tile layout, and the relayout back to [N, L, k]
+# holds both forms at once — 2.65x by the TPU compiler's memory analysis
+# at 2^18 and 2^19 keys x 512 levels; 3 leaves room for what else the
+# device holds.
+_GEN_WORKING_SET = 3
+
+
+def _spread_devices(n_clients: int, batch_bytes: int) -> list:
+    """The local devices one keygen call's client axis is spread over:
+    the first alone — one call on the default device, as ever — unless
+    the call's working set would not fit what that device has free; then
+    as many of the local devices as tile the client axis (the work is
+    independent per client: more devices are more room and less time).
+    Decided from the batch's bytes and the device's ``memory_stats``,
+    never by a caller; XLA:CPU keeps no such stats (host memory), so a
+    CPU host never spreads."""
+    local = jax.local_devices()
+    stats = local[0].memory_stats()
+    if not stats or "bytes_limit" not in stats:
+        return local[:1]
+    free = stats["bytes_limit"] - stats.get("bytes_in_use", 0)
+    if _GEN_WORKING_SET * batch_bytes <= free:
+        return local[:1]
+    return local[: max(k for k in range(1, len(local) + 1) if n_clients % k == 0)]
+
+
+@lru_cache(maxsize=None)
+def _spread_gen(engine: str, devices: tuple):
+    """``_gen(engine)`` as ONE program over ``devices``, client axis
+    sharded: keygen is independent per client, so each device runs the
+    one-device generator on its own span (``shard_map``, as the sharded
+    expand does) and nothing crosses between them.  The parties' batches
+    share every correction word, so the program returns those once."""
+    gen = _gen(engine)
+    spec = jax.sharding.PartitionSpec("clients")
+
+    def body(init_seeds, alpha, side):
+        k0, k1 = gen(init_seeds, alpha, side)
+        return k0, k1.key_idx, k1.root_seed
+
+    # fhh-lint: disable=recompile-churn (lru_cached factory: built once per engine and device set)
+    return jax.jit(
+        jax.shard_map(
+            body, mesh=jax.sharding.Mesh(np.asarray(devices), ("clients",)),
+            in_specs=spec, out_specs=spec,
+            # pallas_call has no shard_map replication rule
+            check_vma=False,
+        )
+    )
+
+
+def _gen_batch(engine: str, init_seeds, alpha, side):
+    """One client batch's keys, leading axis = clients: ``_gen(engine)``
+    in one call, or — a batch one device cannot hold — the same
+    generator once per device over equal spans of the client axis
+    (:func:`_spread_gen`), every leaf ONE array with its client axis
+    sharded over those devices.  The root seeds were drawn on the host,
+    so the keys are bit-identical to the one-call batch;
+    ``block_until_ready`` / ``device_get`` take the result as they take
+    a one-device batch."""
+    # per key and level: a 16-byte seed word and 2 + 2 one-byte bits
+    devices = _spread_devices(alpha.shape[0], int(np.prod(alpha.shape)) * 20)
+    if len(devices) == 1 or engine == "np":  # the host engine has no device
+        return _gen(engine)(init_seeds, alpha, side)
+    k0, idx1, root1 = _spread_gen(engine, tuple(devices))(init_seeds, alpha, side)
+    return k0, k0._replace(key_idx=idx1, root_seed=root1)
+
+
 def gen_interval(
     left_bits, right_bits, rng: np.random.Generator, engine: str = "jax"
 ) -> tuple[tuple[IbDcfKeyBatch, IbDcfKeyBatch], tuple[IbDcfKeyBatch, IbDcfKeyBatch]]:
@@ -382,7 +452,9 @@ def gen_l_inf_ball(
 
     points_bits: bool[N, n_dims, L].  Returns the two parties' key batches of
     shape [N, n_dims, 2] where the trailing axis is (left-DCF, right-DCF) —
-    a client's full submission for one server, as one pytree.
+    a client's full submission for one server, as one pytree.  A batch
+    whose working set one device cannot hold is spread over the local
+    devices (:func:`_gen_batch`): same keys, client axis sharded.
     """
     points = np.asarray(points_bits, bool)
     lo, hi = ball_bounds(points, ball_size)
@@ -391,7 +463,7 @@ def gen_l_inf_ball(
     side = np.broadcast_to(
         np.array([True, False]), alpha.shape[:-1]
     )  # left-DCF then right-DCF
-    return _gen(engine)(_rng_seeds(rng, alpha.shape[:-1]), alpha, side)
+    return _gen_batch(engine, _rng_seeds(rng, alpha.shape[:-1]), alpha, side)
 
 
 def gen_l_inf_ball_from_coords(
@@ -420,4 +492,4 @@ def gen_l_inf_ball_from_coords(
     ).astype(bool)
     alpha = np.stack([to_bits(lo), to_bits(hi)], axis=-2)  # [N, d, 2, 16]
     side = np.broadcast_to(np.array([True, False]), alpha.shape[:-1])
-    return _gen(engine)(_rng_seeds(rng, alpha.shape[:-1]), alpha, side)
+    return _gen_batch(engine, _rng_seeds(rng, alpha.shape[:-1]), alpha, side)

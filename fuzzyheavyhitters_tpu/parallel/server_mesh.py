@@ -30,10 +30,15 @@ This is what turns "millions of users" from a throughput statement into
 a memory-capacity statement: per-chip HBM holds ``N / k`` clients'
 frontier state, and k grows with the server's chip count.
 
-Layout note: the mesh path pins the XLA expand engine (interleaved
-``[F, N, d, 2]`` frontier states — the client axis is a plain named
-axis; the planar Pallas engine's pallas_call does not take sharded
-operands), exactly like the 2-D mesh bodies do.
+Layout note: a sharded session keeps the layout its engine wants, as a
+one-device session does (``sessions.planar_layout``).  On an accelerator
+that is plane-major with the fused Pallas expand, run once per shard
+under ``shard_map`` (:func:`_expand_fn`: ``pallas_call`` takes no sharded
+operands, so GSPMD cannot partition it, but the expansion is independent
+per client); on a CPU host, and under radix > 1, it is the interleaved
+``[F, N, d, 2]`` layout with the XLA expand, which partitions under GSPMD
+as a plain named axis.  Either way the prune's gather and every
+reduction see the client axis sharded and nothing else changed.
 """
 
 from __future__ import annotations
@@ -41,11 +46,11 @@ from __future__ import annotations
 from functools import lru_cache
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..ops.ibdcf import EvalState
+from ..ops import prg
+from ..ops.ibdcf import EvalState, IbDcfKeyBatch
 from .mesh import field_psum
 
 DATA = "data"
@@ -58,7 +63,60 @@ def _mesh_for(devices: tuple) -> Mesh:
     objects keyed on it, so two servers (or a warm run and a live run)
     over the same devices share ONE compiled program per shape instead
     of compiling per instance."""
+    # fhh-lint: disable=host-sync-in-hot-loop (an array of device OBJECTS, no transfer; lru_cached: once per device tuple)
     return Mesh(np.asarray(devices), (DATA,))
+
+
+# client-axis shardings of a frontier's states, by layout (see
+# protocol.collect.Frontier): interleaved ``[F, N, d, 2(, 4)]`` carries the
+# clients second, plane-major ``[(4,) d, 2, F, N]`` last
+_INTERLEAVED = EvalState(seed=P(None, DATA), bit=P(None, DATA),
+                         y_bit=P(None, DATA))
+_PLANAR = EvalState(seed=P(None, None, None, None, DATA),
+                    bit=P(None, None, None, DATA),
+                    y_bit=P(None, None, None, DATA))
+
+
+@lru_cache(maxsize=None)
+def _expand_fn(devices: tuple, derived_bits: bool, want_children: bool):
+    """The level expansion of a PLANAR sharded session: the fused Pallas
+    engine (ops/expand_pallas.py) once per shard under ``shard_map`` —
+    ``pallas_call`` takes no sharded operands, but the expansion is
+    independent per client, so each chip runs the one-device kernel on
+    its own clients (per-shard block shapes, the way kernel_shard.py runs
+    the 2PC stage) and nothing crosses ICI.  Same values as the
+    one-device program by construction: the kernel sees a shorter client
+    axis, not different clients."""
+    from ..ops import ibdcf
+    from ..protocol import collect
+
+    def body(keys, frontier, level):
+        return collect._expand_body(
+            ibdcf.level_cw(keys, level), frontier, derived_bits,
+            want_children, True,
+        )
+
+    children = (
+        collect.PlanarChildren(
+            seed=P(None, None, None, None, None, DATA),
+            flags=P(None, None, None, DATA),
+        )
+        if want_children else None
+    )
+    # fhh-lint: disable=recompile-churn (lru_cached factory: built once per device set and engine mode)
+    return jax.jit(
+        jax.shard_map(
+            body, mesh=_mesh_for(devices),
+            in_specs=(
+                IbDcfKeyBatch(*[P(DATA)] * 5),
+                collect.Frontier(states=_PLANAR, alive=P()),
+                P(),
+            ),
+            out_specs=(P(None, DATA), children),
+            # pallas_call has no shard_map replication rule
+            check_vma=False,
+        )
+    )
 
 
 @lru_cache(maxsize=None)
@@ -119,6 +177,38 @@ def resolve_data_devices(requested: int) -> int:
     return max(1, min(int(requested), avail))
 
 
+def server_devices(server_id: int, k: int) -> tuple:
+    """The ``k`` local devices of server ``server_id``'s mesh — the ONE
+    placement rule, read by the sessions, ``CollectorServer.engine_tags``
+    and ``chip_smoke.py`` alike: the ``k`` local devices starting at
+    ``server_id * k`` where those exist (two servers co-resident on one
+    host take DISJOINT chips: ``[0, k)`` and ``[k, 2k)``), else the first
+    ``k`` (a server alone on its host).  Decided from what the process
+    can observe — no option names a device."""
+    local = jax.local_devices()
+    k = max(1, int(k))
+    lo = int(server_id) * k
+    return tuple(local[lo:lo + k] if lo + k <= len(local) else local[:k])
+
+
+def survives_cache(devices: tuple) -> bool:
+    """Whether the programs of a mesh over ``devices`` may come back
+    from the persistent compile cache.  Read on the chip (PR 30, TPU v5
+    lite 2x2, this JAX): a multi-chip program on chips [2, 3] compiled
+    in the process runs; the same executable loaded from the cache by a
+    later process halts the TPU at its first execution
+    (``FAILED_PRECONDITION: The program continuator has halted
+    unexpectedly``), sharded ``tree_init`` transposes and Pallas bodies
+    alike, with server 0's twins on chips [0, 1] — and one-chip programs
+    on any chip — loading fine.  So: one chip, a CPU host, or a set that
+    starts at the first local chip."""
+    return (
+        len(devices) == 1
+        or devices[0].platform != "tpu"
+        or devices[0] == jax.local_devices()[0]
+    )
+
+
 def _largest_divisor_leq(n: int, k: int) -> int:
     """Largest divisor of ``n`` that is <= ``k`` (shard counts must tile
     the client batch exactly — shard_map and the checkpoint re-shard
@@ -140,8 +230,8 @@ class ServerMesh:
     re-binding (a new collection's batch) rebuilds them.
     """
 
-    def __init__(self, n_devices: int):
-        self.devices = tuple(jax.local_devices()[: max(1, n_devices)])
+    def __init__(self, n_devices: int, server_id: int = 0):
+        self.devices = server_devices(server_id, n_devices)
         self.n_devices = len(self.devices)
         self.shards = 1
         self.n_clients: int | None = None
@@ -165,26 +255,46 @@ class ServerMesh:
     # -- placement --------------------------------------------------------
 
     def put(self, arr, spec: P):
+        """``arr`` (host numpy or a device array) onto the bound mesh.
+        Host arrays go straight to the mesh's devices: a detour through
+        ``jnp.asarray`` would land them on the process's default device
+        first — another server's chip once co-resident servers take
+        disjoint ones."""
         return jax.device_put(arr, NamedSharding(self.mesh, spec))
+
+    def home(self):
+        """Context for SYNCHRONOUS sections (never across an ``await``:
+        the setting is the thread's) whose jitted helpers create arrays
+        from nothing (``jnp.zeros``, constants): those land on this
+        mesh's first device instead of the process default."""
+        return jax.default_device(self.devices[0])
 
     def shard_keys(self, keys):
         """Key batch leaves ``[N, ...]`` -> client axis sharded."""
         return jax.tree.map(lambda a: self.put(a, P(DATA)), keys)
 
-    def shard_frontier(self, frontier):
-        """Interleaved-layout frontier (states ``[F, N, d, 2, ...]``) ->
-        client axis sharded, alive mask replicated.  Used at tree_init
-        and checkpoint restore; mid-crawl the sharding propagates
-        through the jitted advance programs on its own."""
-        st = frontier.states
-        states = EvalState(
-            seed=self.put(st.seed, P(None, DATA)),
-            bit=self.put(st.bit, P(None, DATA)),
-            y_bit=self.put(st.y_bit, P(None, DATA)),
-        )
+    def shard_frontier(self, frontier, planar: bool):
+        """Frontier states -> client axis sharded (the second axis of
+        the interleaved layout, the last of the plane-major one —
+        ``planar`` names the layout, the shapes cannot), alive mask
+        replicated.  Used at tree_init and checkpoint restore; mid-crawl
+        the sharding propagates through the jitted expand and advance
+        programs on its own."""
+        specs = _PLANAR if planar else _INTERLEAVED
         return frontier._replace(
-            states=states, alive=self.put(frontier.alive, P())
+            states=EvalState(*map(self.put, frontier.states, specs)),
+            alive=self.put(frontier.alive, P()),
         )
+
+    def expand_share_bits(self, keys, frontier, level,
+                          want_children: bool = True):
+        """``collect.expand_share_bits`` of a PLANAR sharded session:
+        the Pallas engine per shard (:func:`_expand_fn`).  ``level`` goes
+        in as a host scalar, so one compiled program serves every level
+        and nothing is placed on the process's default device."""
+        return _expand_fn(
+            self._active_devices(), prg.DERIVED_BITS, bool(want_children)
+        )(keys, frontier, np.int32(level))
 
     def gather(self, arr):
         """Collapse a client-axis-sharded array back onto ONE device
@@ -237,9 +347,9 @@ class ServerMesh:
         pins ONE compiled program per shape for warm and live alike."""
         return _counts_fn(self._active_devices())(
             self.put(packed_self, P(None, DATA)),
-            self.put(jnp.asarray(packed_peer), P(None, DATA)),
-            self.put(jnp.asarray(masks), P()),
-            self.put(jnp.asarray(alive_keys), P(DATA)),
+            self.put(packed_peer, P(None, DATA)),
+            self.put(masks, P()),
+            self.put(alive_keys, P(DATA)),
             self.put(alive_nodes, P()),
         )
 
@@ -251,6 +361,6 @@ class ServerMesh:
         (both are the exact sum mod p in canonical form).  Inputs are
         canonically placed first (see :meth:`counts_by_pattern`)."""
         return _share_sums_fn(self._active_devices(), field.__name__)(
-            self.put(jnp.asarray(vals), P(None, None, DATA)),
-            self.put(jnp.asarray(weight), P(None, None, DATA)),
+            self.put(vals, P(None, None, DATA)),
+            self.put(weight, P(None, None, DATA)),
         )

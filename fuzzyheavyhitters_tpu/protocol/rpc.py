@@ -117,7 +117,7 @@ from ..ops.ibdcf import EvalState, IbDcfKeyBatch
 from ..parallel import kernel_shard, server_mesh as smesh, sketch_shard
 from ..resilience import chaos as reschaos
 from ..resilience import policy as respolicy
-from ..utils import guards, taint_guard
+from ..utils import compile_cache, guards, taint_guard
 from ..utils.config import Config
 from . import collect, mpc, secure, sessions, sketch as sketchmod, tenancy, wire
 from .sessions import (  # noqa: F401  (re-exports: wire-format helpers kept importable as rpc.*)
@@ -360,7 +360,7 @@ def engine_tags() -> dict:
     """Which engine each stage resolves to in THIS process, as the
     selectors stand right now (they follow the effective platform: the
     Pallas kernels on an accelerator, the NumPy/XLA twins on a CPU
-    host).  What a given server runs can be narrower — a sharded server
+    host).  What a given server runs can be narrower — radix > 1 fusion
     pins the XLA expand — so a server's log line and chip_smoke.py's
     phase lines carry :meth:`CollectorServer.engine_tags`; chip_smoke.py
     asserts this process-wide set before it starts anything."""
@@ -399,6 +399,20 @@ class CollectorServer:
         self.server_id = server_id
         self.cfg = cfg
         self.ckpt_dir = ckpt_dir
+        # decided here, where a process takes a server on, and not by a
+        # mesh: JAX's cache switch is the process's.  The second server
+        # of a co-resident sharded pair runs multi-chip programs off the
+        # first local chip, and those do not survive the persistent
+        # cache (smesh.survives_cache), so its process compiles anew
+        n = smesh.resolve_data_devices(cfg.server_data_devices)
+        if n > 1 and not smesh.survives_cache(
+            smesh.server_devices(server_id, n)
+        ):
+            compile_cache.suspend(
+                f"server {server_id}: multi-chip programs on devices "
+                f"{[d.id for d in smesh.server_devices(server_id, n)]} do "
+                f"not survive the persistent cache"
+            )
         # telemetry: ONE registry per server for shared-plane accounting
         # (control bytes, replay dedup, plane resets, tenant scheduler);
         # the DEFAULT collection session shares it — so single-tenant
@@ -460,7 +474,8 @@ class CollectorServer:
         narrowed by the session layout its config resolves to (the rules
         of ``CollectionSession.__init__``/``planar``, read without
         creating a session): the expand engine is the Pallas one only
-        for a planar session (one device, radix 1), a sharded server's
+        for a planar session (radix 1; a sharded server runs it once
+        per shard, on the chips ``mesh_devices`` names), a sharded server's
         2PC kernels are ``kernel_shard``'s per-shard engines, and a
         secure server names the equality path its config picks.  Emitted
         once at start; ``keygen`` is left out (the leader's)."""
@@ -468,9 +483,12 @@ class CollectorServer:
         radix = int(self.cfg.crawl_radix_bits)
         tags = {k: v for k, v in engine_tags().items() if k != "keygen"}
         tags["data_devices"] = n
-        tags["expand"] = (
-            "pallas" if sessions.planar_layout(radix, n == 1) else "xla"
-        )
+        if n > 1:
+            # which chips: the sessions' own rule (ServerMesh.__init__)
+            tags["mesh_devices"] = [
+                d.id for d in smesh.server_devices(self.server_id, n)
+            ]
+        tags["expand"] = "pallas" if sessions.planar_layout(radix) else "xla"
         if n > 1:
             tags["ot2s"] = kernel_shard._engine("ot2s")
             # the budget once keys are bound (ServerMesh.kernel_budget);
@@ -573,12 +591,7 @@ class CollectorServer:
         n = cs.keys.cw_seed.shape[0]
         cs.alive_keys = np.ones(n, bool)
         with cs.obs.span("frontier_init"):
-            if cs._mesh is not None:
-                cs.frontier = cs._mesh.shard_frontier(
-                    collect.tree_init(cs.keys, root_bucket, planar=False)
-                )
-            else:
-                cs.frontier = collect.tree_init(cs.keys, root_bucket)
+            cs.frontier = cs.init_frontier(root_bucket)
         cs._children = None
         cs._shard_children.clear()
         cs._shard_last.clear()
@@ -860,10 +873,7 @@ class CollectorServer:
         # — r = 1 is exactly the pre-radix program (expand_share_bits_radix
         # and child_strings_radix delegate to the radix-1 entry points)
         r = cs.crawl_radix(level)
-        packed, children = collect.expand_share_bits_radix(
-            cs.keys, frontier, level, r, want_children=not last,
-            use_pallas=False if cs._mesh is not None else None,
-        )
+        packed, children = cs.expand(frontier, level, r, not last)
         out = {"packed": packed, "children": children, "frontier": frontier}
         if self.cfg.secure_exchange:
             d = cs.keys.cw_seed.shape[1]
@@ -981,7 +991,7 @@ class CollectorServer:
             masks = collect.pattern_masks_radix(
                 cs.keys.cw_seed.shape[1], cs.crawl_radix(level)
             )
-            peer = self._h2d(cs, level, peer)
+            peer = self._h2d(cs, level, peer, smesh.P(None, smesh.DATA))
             counts = await self._reduced_fetch(
                 cs, level, collect.counts_by_pattern,
                 packed, peer, masks, cs.alive_keys, frontier.alive,
@@ -1013,7 +1023,7 @@ class CollectorServer:
         return await _fetch(single_fn(*args), cs.obs)
 
     @staticmethod
-    def _h2d(cs, level: int, x: np.ndarray):
+    def _h2d(cs, level: int, x: np.ndarray, spec=None):
         """Host->device copy of a received payload, made explicit so it
         has a span (``h2d``) of its own; it used to happen inside the
         first jitted call that took the numpy frame.  No sync: the span
@@ -1021,7 +1031,17 @@ class CollectorServer:
         before ``device_put`` returns; the rest lands in the span of
         whoever first waits for the consumer."""
         with cs.obs.span("h2d", level=level):
-            return jax.device_put(x)
+            if cs._mesh is None:
+                return jax.device_put(x)
+            # a sharded server touches only its own chips: never via
+            # the process's default device, which is another server's
+            # chip once co-resident servers take disjoint ones.  With a
+            # ``spec`` the payload lands sharded as its consumer takes
+            # it; without, on the mesh's first device, where the
+            # degraded one-device kernel stage runs
+            if spec is None:
+                return cs._mesh.gather(x)
+            return cs._mesh.put(x, spec)
 
     async def _phase_sync(self, x) -> None:
         """Device sync at a secure-kernel phase boundary (OFF the event
@@ -1456,13 +1476,10 @@ class CollectorServer:
         elif r == 1:  # prune without a preceding crawl: re-expand
             cs.frontier = collect.advance(
                 cs.keys, cs.frontier, level, parent, pb1, n_alive,
-                use_pallas=False if cs._mesh is not None else None,
+                use_pallas=cs.planar(),
             )
         else:  # fused prune without a crawl cache: re-expand r bits
-            _, children = collect.expand_share_bits_radix(
-                cs.keys, cs.frontier, level, r, want_children=True,
-                use_pallas=False if cs._mesh is not None else None,
-            )
+            _, children = cs.expand(cs.frontier, level, r, True)
             cs.frontier = collect.advance_from_children_radix(
                 children, parent, pat_bits, n_alive, r
             )
@@ -2388,28 +2405,29 @@ class CollectorServer:
         # refuses before ANY state mutates); None = pre-streaming blob
         parsed_ing = cs.ingest_validate(z, path)
         # -- all checks passed: mutate ------------------------------------
-        states = EvalState(
-            seed=jax.device_put(z["seed"]),
-            bit=jax.device_put(z["bit"]),
-            y_bit=jax.device_put(z["y_bit"]),
-        )
-        saved_planar, planar = bool(z["planar"]), cs.planar()
-        if saved_planar != planar:
-            states = (
-                collect.to_interleaved(states)
-                if saved_planar
-                else collect.to_planar(states)
+        with cs.home():
+            states = EvalState(
+                seed=jax.device_put(z["seed"]),
+                bit=jax.device_put(z["bit"]),
+                y_bit=jax.device_put(z["y_bit"]),
             )
-        cs.alive_keys = alive_keys
-        cs.frontier = collect.Frontier(
-            states=states, alive=jax.device_put(z["alive"])
-        )
+            saved_planar, planar = bool(z["planar"]), cs.planar()
+            if saved_planar != planar:
+                states = (
+                    collect.to_interleaved(states)
+                    if saved_planar
+                    else collect.to_planar(states)
+                )
+            cs.alive_keys = alive_keys
+            cs.frontier = collect.Frontier(
+                states=states, alive=jax.device_put(z["alive"])
+            )
         if cs._mesh is not None:
             # re-shard from the host-side blob: the frontier lands
             # client-axis-sharded across whatever local devices are
             # live — this is the device-loss recovery primitive (a lost
             # device is re-covered by re-placement, not a server restart)
-            cs.frontier = cs._mesh.shard_frontier(cs.frontier)
+            cs.frontier = cs._mesh.shard_frontier(cs.frontier, planar)
         cs._children = None
         cs._last_shares = None
         cs._shard_children.clear()
@@ -2584,7 +2602,9 @@ class CollectorServer:
                         )
                     }
                 for fb in sorted(sizes | {b}):
-                    if self._warm_bucket(cs, fb, L, ot_path):
+                    with cs.home():
+                        fresh = self._warm_bucket(cs, fb, L, ot_path)
+                    if fresh:
                         shapes += 1
                     else:
                         ladder_hits += 1
@@ -2644,12 +2664,7 @@ class CollectorServer:
         if tenancy.warmed(ladder_key):
             return False
         mesh = cs._mesh
-        if mesh is not None:
-            fr = mesh.shard_frontier(
-                collect.tree_init(cs.keys, fb, planar=False)
-            )
-        else:
-            fr = collect.tree_init(cs.keys, fb)
+        fr = cs.init_frontier(fb)
         d = cs.keys.cw_seed.shape[1]
         # radix-2^k fusion: the live crawl dispatches at most two fused
         # shapes — (r = k, inner level, FE62, with children) and
@@ -2666,10 +2681,7 @@ class CollectorServer:
         )
         for r, last in steps:
             level = base_last if last else 0
-            packed, _ = collect.expand_share_bits_radix(
-                cs.keys, fr, level, r, want_children=not last,
-                use_pallas=False if mesh is not None else None,
-            )
+            packed, _ = cs.expand(fr, level, r, not last)
             if self.cfg.secure_exchange:
                 N = cs.keys.cw_seed.shape[0]
                 ks = (

@@ -37,6 +37,7 @@ namespaces refuses to restore (validate-before-mutate, PR-4 contract).
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import hashlib
 import os
 import re
@@ -289,11 +290,13 @@ _SESSION_GUARDS = {
 }
 
 
-def planar_layout(radix: int, one_device: bool) -> bool:
+def planar_layout(radix: int) -> bool:
     """The layout rule behind :meth:`CollectionSession.planar`, as a
     function of what a config resolves to (``CollectorServer.engine_tags``
-    reads it at start, before any session exists)."""
-    return collect._expand_engine() and one_device and int(radix) == 1
+    reads it at start, before any session exists): plane-major with the
+    Pallas expand wherever the process engine is the Pallas one, on one
+    device or sharded — except under radix > 1 fusion."""
+    return collect._expand_engine() and int(radix) == 1
 
 
 class CollectionSession:
@@ -406,7 +409,7 @@ class CollectionSession:
         # kernels are lru-cached at module level, so sessions share every
         # compiled program)
         k = smesh.resolve_data_devices(cfg.server_data_devices)
-        self._mesh = smesh.ServerMesh(k) if k > 1 else None
+        self._mesh = smesh.ServerMesh(k, server_id) if k > 1 else None
         self._verb_lock = asyncio.Lock()
         # LAST: the sanitizer (a no-op unless FHH_DEBUG_GUARDS=1 or
         # cfg.debug_guards) wraps the already-constructed guarded state
@@ -494,14 +497,47 @@ class CollectionSession:
 
     # -- engine/layout ----------------------------------------------------
 
-    def planar(self) -> bool:  # fhh-race: atomic (pure read of init-time state: _mesh/_radix are set at session construction)
-        """This session's frontier LAYOUT: the process expand engine,
-        except under the multi-chip mesh, which pins interleaved/XLA
-        (the client axis must be a plain named axis — pallas_call takes
-        no sharded operands), and under radix > 1 fusion, whose
-        multi-step expand is implemented on the interleaved/XLA engine
-        only (collect.expand_share_bits_radix)."""
-        return planar_layout(self._radix, self._mesh is None)
+    def planar(self) -> bool:  # fhh-race: atomic (pure read of init-time state: _radix is set at session construction)
+        """This session's frontier LAYOUT, and with it its expand engine
+        (one engine per layout): the process expand engine, except under
+        radix > 1 fusion, whose multi-step expand is implemented on the
+        interleaved/XLA engine only (collect.expand_share_bits_radix).
+        A sharded session keeps the rule: its planar expand runs the
+        Pallas engine per shard (``ServerMesh.expand_share_bits``)."""
+        return planar_layout(self._radix)
+
+    def expand(self, frontier, level, radix: int, want_children: bool):  # fhh-race: atomic (dispatch-only device work on init-time layout state; reached from the crawl verbs and the frame-arrival pre-expand)
+        """One level's expansion of ``frontier`` on this session's
+        engine: ``(packed, children)`` as ``collect.expand_share_bits``.
+        The one place the engine is chosen from the layout rule."""
+        if self._mesh is not None and self.planar():
+            return self._mesh.expand_share_bits(
+                self.keys, frontier, level, want_children
+            )
+        return collect.expand_share_bits_radix(
+            self.keys, frontier, level, radix,
+            want_children=want_children, use_pallas=self.planar(),
+        )
+
+    def init_frontier(self, f_bucket: int):  # fhh-race: atomic (dispatch-only device work on init-time layout state)
+        """The root frontier at bucket ``f_bucket`` in this session's
+        layout, client axis sharded on a sharded session."""
+        with self.home():
+            fr = collect.tree_init(self.keys, f_bucket, planar=self.planar())
+            if self._mesh is not None:
+                fr = self._mesh.shard_frontier(fr, self.planar())
+        return fr
+
+    def home(self):  # fhh-race: atomic (pure read of init-time state)
+        """Context for this session's SYNCHRONOUS device sections
+        (``tree_init``, ``tree_restore``, ``warmup``): what their jitted
+        helpers create from nothing lands on the session's own mesh
+        (:meth:`ServerMesh.home`), not on the process's default device.
+        A one-device session runs on the default device: no-op."""
+        return (
+            self._mesh.home() if self._mesh is not None
+            else contextlib.nullcontext()
+        )
 
     def crawl_radix(self, level) -> int:  # fhh-race: atomic (pure read of init-time state)
         """Fused bit count of the crawl round based at bit ``level``:
@@ -514,7 +550,11 @@ class CollectionSession:
         """Materialize ``self.keys`` from the uploaded chunks (shared by
         ``tree_init`` and ``tree_restore``).  Under the multi-chip mesh
         the batch binds the active shard count and the key planes land
-        client-axis-sharded across the local devices."""
+        client-axis-sharded across the server's own devices.  The
+        ``key_place`` span is the second half of it — the copies, after
+        the host concatenate — and ends when the planes are RESIDENT,
+        not when the copies are queued (the sync costs nothing: level
+        0's expand cannot start before the keys are there)."""
         self.keys = IbDcfKeyBatch(
             *[
                 # fhh-lint: disable=chunked-device-readback,host-sync-in-hot-loop (wire input: the uploaded chunks are host numpy already — np.asarray is a no-copy view; runs once per collection/restore, never per level)
@@ -522,13 +562,16 @@ class CollectionSession:
                 for i in range(len(self.keys_parts[0]))
             ]
         )
-        if self._mesh is not None:
-            self._mesh.bind(self.keys.cw_seed.shape[0])
-            self.keys = self._mesh.shard_keys(self.keys)
-        else:
-            # resident on the (effective default) device: left as host
-            # numpy, every level's expand would re-upload the whole batch
-            self.keys = jax.device_put(self.keys)
+        with self.obs.span("key_place"):
+            if self._mesh is not None:
+                self._mesh.bind(self.keys.cw_seed.shape[0])
+                self.keys = self._mesh.shard_keys(self.keys)
+            else:
+                # resident on the (effective default) device: left as host
+                # numpy, every level's expand would re-upload the whole batch
+                self.keys = jax.device_put(self.keys)
+            # fhh-lint: disable=host-sync-in-hot-loop (once per collection/restore, never per level)
+            jax.block_until_ready(self.keys)
         # key-plane residency (obs.devmem): the flagship's "1.51 chips
         # of key storage" risk as a live per-collection gauge — set at
         # the one place the materialized plane changes size

@@ -89,6 +89,34 @@ def backend_compile_seconds() -> float:
         return _compile_seconds
 
 
+_suspended: str | None = None  # fhh-guard: _suspended=_compile_lock
+
+
+def suspend(reason: str) -> None:
+    """Stop THIS process reading and writing the persistent cache from
+    here on: what it compiled or loaded so far stays in the in-process
+    executable cache, everything new compiles fresh.  For a process
+    that is about to run programs whose cached form does not survive
+    (``parallel.server_mesh.ServerMesh``: a multi-chip program on a
+    device set that does not start at the first local chip halts the
+    TPU when its executable comes back from the cache).  Idempotent;
+    :func:`enable` keeps returning the directory it established."""
+    global _suspended
+    with _compile_lock:
+        if _suspended is not None:
+            return
+        _suspended = reason
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from .. import obs
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    # the cache's "is it in use" answer is memoised at the first compile
+    compilation_cache.reset_cache()
+    obs.emit("compile_cache.suspended", severity="warn", reason=reason)
+
+
 # <checkout>/.jax_cache: beside the package, wherever the checkout sits
 DEFAULT_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),

@@ -23,7 +23,12 @@ object:
   this is the check of that shift: for every span in the capture, the
   annotation's start and end against the JSONL interval shifted by the
   mark's offset, as median and largest absolute difference in microseconds,
-  over all spans and per name.
+  over all spans and per name;
+- ``device_busy`` (with ``--capture``): per device plane of the capture,
+  the seconds it ran anything inside the window of ``bench_level``
+  annotations, by ``benchmark/trace_reduce.py``'s rule (the benchmark
+  reports their mean as ``busy_s``; a sharded server's chips show one by
+  one here).
 """
 
 from __future__ import annotations
@@ -145,6 +150,26 @@ def clock_check(spans: list, capture: str, wall_ns_at_sync: int,
     }
 
 
+def device_busy(capture: str) -> dict:
+    """The benchmark's own reduction (``benchmark/trace_reduce.py``, its
+    public ``read_capture`` / ``reduce``) of the capture cut to one device
+    plane at a time: that plane's ``busy_s``, the window the same."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, os.path.join(root, "benchmark"))
+    import trace_reduce
+
+    cap = trace_reduce.read_capture(capture)
+    by_plane = {
+        plane: trace_reduce.reduce({**cap, "devices": {plane: events}})
+        for plane, events in sorted(cap["devices"].items())
+    }
+    ran = {plane: r for plane, r in by_plane.items() if r is not None}
+    if not ran:
+        return {"error": "no device plane with operations"}
+    return {"window_s": next(iter(ran.values()))["window_s"],
+            "busy_s": {plane: r["busy_s"] for plane, r in ran.items()}}
+
+
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     p.add_argument("trace_dir")
@@ -164,6 +189,7 @@ def main(argv=None) -> int:
             p.error("--capture needs --wall-ns-at-sync")
         out["clock"] = clock_check(
             spans, args.capture, args.wall_ns_at_sync, args.sync_event)
+        out["device_busy"] = device_busy(args.capture)
     print(json.dumps(out))
     return 0
 

@@ -23,7 +23,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from fuzzyheavyhitters_tpu.ops import gc_pallas, keygen_pallas, otext, otext_pallas
 from fuzzyheavyhitters_tpu.ops.ibdcf import EvalState, IbDcfKeyBatch
-from fuzzyheavyhitters_tpu.parallel import kernel_shard
+from fuzzyheavyhitters_tpu.parallel import kernel_shard, server_mesh
 from fuzzyheavyhitters_tpu.parallel.server_mesh import DATA
 from fuzzyheavyhitters_tpu.protocol import collect, secure
 
@@ -37,6 +37,10 @@ W = 4  # FE62 payload words
 B_SECURE = F * 2 * N_SECURE  # (node, child, client) tests of one level (--chips 4: the same N over four chips)
 
 HBM_BYTES = 16 * 1024**3
+
+# the four-chip benchmark cell (flagship-trusted-4chip): N clients a server,
+# client axis sharded over the server's two chips
+N_4CHIP = 524288
 
 
 @pytest.fixture(scope="module")
@@ -242,3 +246,137 @@ def test_kernel_shard_bodies_on_four_chips(topo, path, field):
         vals, sds((F, 2, N_SECURE), jnp.bool_, P()),
     )
     assert "all-reduce" in text
+
+
+@pytest.mark.parametrize("bucket", [32, 64, 128])
+def test_sharded_trusted_level_on_a_two_chip_submesh(topo, bucket):
+    """The sharded trusted lane's level step at the four-chip cell's
+    shapes (N=524,288 a server, its client axis over two of the 2x2
+    host's chips; the steady bucket, the widest usual one, and the one
+    that one seed in ten meets for a level): the fused Pallas expand
+    once per shard under ``shard_map`` (it must partition) with and
+    without the child cache, the prune's gather from that cache, and
+    the counts with their psum over the local data axis — each as the
+    server dispatches it (``CollectionSession.expand``, ``tree_prune``,
+    ``ServerMesh.counts_by_pattern``), each beside the resident key
+    planes within one chip's HBM.  At bucket 64 the interleaved XLA
+    expand this replaced needs 15.0 GB a chip by the same analysis, and
+    at 128 it does not compile (16.6 GB)."""
+    devices = tuple(topo.devices[2:4])  # server 1's pair
+    mesh = server_mesh._mesh_for(devices)
+    n, d, f = N_4CHIP, 1, bucket
+    sds = lambda shape, dt, *spec: _sds(NamedSharding(mesh, P(*spec)))(shape, dt)
+    last = (None,) * 3 + (DATA,)  # [d, 2, F, N]: the clients last
+    keys = IbDcfKeyBatch(
+        key_idx=sds((n, d, 2), jnp.bool_, DATA),
+        root_seed=sds((n, d, 2, 4), jnp.uint32, DATA),
+        cw_seed=sds((n, d, 2, L, 4), jnp.uint32, DATA),
+        cw_bits=sds((n, d, 2, L, 2), jnp.bool_, DATA),
+        cw_y_bits=sds((n, d, 2, L, 2), jnp.bool_, DATA),
+    )
+    key_bytes = n * d * 2 * (L * 20 + 17) // len(devices)  # a chip's share
+    frontier = collect.Frontier(
+        states=EvalState(
+            seed=sds((4, d, 2, f, n), jnp.uint32, None, *last),
+            bit=sds((d, 2, f, n), jnp.bool_, *last),
+            y_bit=sds((d, 2, f, n), jnp.bool_, *last),
+        ),
+        alive=sds((f,), jnp.bool_),
+    )
+
+    def fits(compiled, resident=0):
+        m = compiled.memory_analysis()
+        total = (m.argument_size_in_bytes + m.output_size_in_bytes
+                 + m.temp_size_in_bytes + resident)
+        assert total < 15e9, f"{total / 1e9:.1f} GB a chip"
+        return compiled.as_text()
+
+    for want_children in (True, False):
+        text = fits(server_mesh._expand_fn(devices, True, want_children).lower(
+            keys, frontier, sds((), jnp.int32)).compile())  # keys among its arguments
+        assert "tpu_custom_call" in text  # the Pallas engine, per shard
+        assert "all-gather" not in text and "all-reduce" not in text
+    children = collect.PlanarChildren(
+        seed=sds((2, 4, d, 2, f, n), jnp.uint32, None, None, *last),
+        flags=sds((d, 2, f, n), jnp.uint32, *last),
+    )
+    text = fits(collect._advance_children_jit.lower(
+        children, sds((f,), jnp.int32), sds((f, d), jnp.bool_),
+        sds((), jnp.int32), planar=True,
+    ).compile(), key_bytes + frontier.states.seed.size * 18 // 16 // len(devices))
+    assert "all-gather" not in text  # the gather is over nodes, a shard's own
+    packed = sds((f, n), jnp.uint32, None, DATA)
+    text = fits(server_mesh._counts_fn(devices).lower(
+        packed, packed, sds((1 << d,), jnp.uint32),
+        sds((n,), jnp.bool_, DATA), sds((f,), jnp.bool_),
+    ).compile(), key_bytes)
+    assert "all-reduce" in text
+
+
+def test_keygen_spread_over_four_chips(topo):
+    """The four-chip cell's key batch (2 x 524,288 keys of 512 levels:
+    21.5 GB, more than one chip holds) as ``ibdcf._gen_batch`` makes it:
+    ONE program over the host's four chips, the fused keygen kernel once
+    per chip on its own quarter of the clients, nothing between chips,
+    the parties' shared correction words returned once."""
+    from fuzzyheavyhitters_tpu.ops import ibdcf
+
+    devices = tuple(topo.devices)
+    fn = ibdcf._spread_gen("pallas", devices)
+    try:
+        mesh = jax.sharding.Mesh(devices, ("clients",))
+        sds = _sds(NamedSharding(mesh, P("clients")))
+        n, d = N_4CHIP, 1
+        text = _compile(
+            fn, sds((n, d, 2, 2, 4), jnp.uint32), sds((n, d, 2, L), jnp.bool_),
+            sds((n, d, 2), jnp.bool_),
+        )
+    finally:
+        ibdcf._spread_gen.cache_clear()  # keyed on described devices
+    assert "tpu_custom_call" in text
+    assert "all-gather" not in text and "all-reduce" not in text
+    assert "collective-permute" not in text and "all-to-all" not in text
+
+
+def test_sharded_secure_level_hands_planar_bits_to_the_kernel_stage(topo):
+    """What ``chip_smoke.py --chips 4`` runs since PR 30, on server 1's
+    pair of chips at the smoke's sharded shapes: the plane-major Pallas
+    expand once per shard, the flat equality strings built from its
+    packed share bits where they lie (client axis sharded), and the
+    garbling kernel row-sharded over the same two chips."""
+    from chip_smoke import N_SHARDED
+
+    devices = tuple(topo.devices[2:4])
+    mesh = server_mesh._mesh_for(devices)
+    n, d = N_SHARDED, 1
+    sds = lambda shape, dt, *spec: _sds(NamedSharding(mesh, P(*spec)))(shape, dt)
+    last = (None,) * 3 + (DATA,)
+    keys = IbDcfKeyBatch(
+        key_idx=sds((n, d, 2), jnp.bool_, DATA),
+        root_seed=sds((n, d, 2, 4), jnp.uint32, DATA),
+        cw_seed=sds((n, d, 2, L, 4), jnp.uint32, DATA),
+        cw_bits=sds((n, d, 2, L, 2), jnp.bool_, DATA),
+        cw_y_bits=sds((n, d, 2, L, 2), jnp.bool_, DATA),
+    )
+    frontier = collect.Frontier(
+        states=EvalState(
+            seed=sds((4, d, 2, F, n), jnp.uint32, None, *last),
+            bit=sds((d, 2, F, n), jnp.bool_, *last),
+            y_bit=sds((d, 2, F, n), jnp.bool_, *last),
+        ),
+        alive=sds((F,), jnp.bool_),
+    )
+    text = _compile(server_mesh._expand_fn(devices, True, True),
+                    keys, frontier, sds((), jnp.int32))
+    assert "tpu_custom_call" in text
+    b = F * 2 * n
+    ks = kernel_shard.KernelShard(devices, b, S)
+    _compile(kernel_shard._flat_fn(d, F, n, ks.bp, 1),
+             sds((F, n), jnp.uint32, None, DATA))
+    seed4, scalar = sds((4,), jnp.uint32), sds((), jnp.uint32)
+    text = _compile(
+        kernel_shard._gb_kernel_fn(devices, "FE62", b, S, W, "ot2s", 0, "pallas"),
+        sds((ks.bp * S, 4), jnp.uint32, DATA, None), seed4,
+        sds((ks.bp, S), jnp.bool_, DATA, None), seed4, seed4, scalar,
+    )
+    assert "tpu_custom_call" in text

@@ -10,6 +10,8 @@ ref: tests/ibdcf_tests.rs) but with real assertions:
 3. both PRG bit modes (reference-observed constants and derived bits).
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -274,3 +276,72 @@ def test_prefix_semantics_internal_levels(rng):
         got = shares[0] ^ shares[1]
         want = np.arange(n) < (b >> (L - plen))
         np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("engine", ["jax", "pallas"])
+def test_l_inf_ball_spread_over_devices_is_bit_identical(
+        engine, monkeypatch, request):
+    """A key batch one device cannot hold is generated one span of the
+    client axis a device, by one program (ibdcf._gen_batch), and comes
+    back as ONE array a leaf, client axis sharded: bit-identical to the
+    one-device call for the same seed, the parties' shared correction
+    words one array still, and taken by ``block_until_ready`` /
+    ``device_get`` as the one-device batch is.  The decision reads the batch's bytes and
+    the devices' ``memory_stats`` (none on XLA:CPU: never spread here
+    unless the probe is stood in for, as this test does)."""
+    L, n = 6, 8
+    pts = np.random.default_rng(5).integers(0, 1 << L, size=(n, 1))
+    pts_bits = np.stack(
+        [np.stack([int_bits(L, int(v)) for v in row]) for row in pts]
+    )  # [N, 1, L]
+    if engine == "pallas":  # the chip's engine, its kernel in interpret mode
+        from fuzzyheavyhitters_tpu.ops import keygen_pallas
+
+        monkeypatch.setattr(
+            keygen_pallas, "gen_pair_pallas",
+            functools.partial(keygen_pallas.gen_pair_pallas, interpret=True),
+        )
+        ibdcf._spread_gen.cache_clear()  # the program is built from the engine
+        request.addfinalizer(ibdcf._spread_gen.cache_clear)
+    one = ibdcf.gen_l_inf_ball(pts_bits, 2, np.random.default_rng(9), engine)
+    assert all(len(a.devices()) == 1 for a in jax.tree.leaves(one))
+
+    devices = jax.local_devices()[:4]
+    monkeypatch.setattr(ibdcf, "_spread_devices", lambda n_, b_: devices)
+    k0, k1 = spread = ibdcf.gen_l_inf_ball(
+        pts_bits, 2, np.random.default_rng(9), engine)
+    jax.block_until_ready(spread)
+    assert all(a.devices() == set(devices) for a in jax.tree.leaves(spread))
+    assert k0.cw_seed.shape == one[0].cw_seed.shape == (n, 1, 2, L, 4)
+    assert {s.data.shape[0] for s in k0.cw_seed.addressable_shards} == {n // 4}
+    assert k1.cw_seed is k0.cw_seed and k1.root_seed is not k0.root_seed
+    for got, want in zip(jax.tree.leaves(jax.device_get(spread)),
+                         jax.tree.leaves(jax.device_get(one))):
+        assert isinstance(got, np.ndarray) and got.dtype == want.dtype
+        np.testing.assert_array_equal(got, want)
+
+
+def test_keygen_spread_decision(monkeypatch):
+    """One device while the working set (3x the batch's bytes) fits what
+    it has free; else every local device that tiles the client axis."""
+    class Dev:
+        def __init__(self, limit, used=0):
+            self._st = {"bytes_limit": limit, "bytes_in_use": used}
+
+        def memory_stats(self):
+            return self._st
+
+    gib = 1 << 30
+    four = [Dev(int(15.75 * gib)) for _ in range(4)]
+    monkeypatch.setattr(jax, "local_devices", lambda: four)
+    per_client = 2 * 512 * 20  # n_dims=1: two keys of 512 levels a client
+    pick = lambda n: len(ibdcf._spread_devices(n, n * per_client))
+    assert pick(131072) == 1     # the one-chip cell: 8.05 GB of 16.9
+    assert pick(262144) == 1     # 16.1 GB: fits an empty chip
+    assert pick(524288) == 4     # the four-chip cell: 32.2 GB
+    assert pick(524286) == 3     # ... tiled by what divides the batch
+    four[0]._st["bytes_in_use"] = 2 * gib
+    assert pick(262144) == 4     # the same batch beside 2 GiB of tenants
+    monkeypatch.undo()
+    # XLA:CPU keeps no memory stats: host memory, never spread
+    assert ibdcf._spread_devices(1 << 21, 1 << 40) == jax.local_devices()[:1]

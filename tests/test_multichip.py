@@ -410,3 +410,252 @@ def test_warmed_multichip_crawl_zero_fresh_compiles(client_keys):
     _run(_cfg(port + 1200, **kw), port + 1200, k0, k1, warmup=True)
     fresh = compile_cache.backend_compiles() - before
     assert fresh == 0, f"{fresh} fresh compiles in a warmed multichip crawl"
+
+
+# -- two sharded servers co-resident on one host (the four-chip cell) -------
+
+
+def test_server_devices_rule(monkeypatch):
+    """THE placement rule: server ``i`` of a co-resident pair takes the
+    ``k`` local devices from ``i * k`` where those exist, else the first
+    ``k`` (a server alone on its host)."""
+    import jax
+
+    local = jax.local_devices()
+    ids = lambda devs: [d.id for d in devs]
+    assert ids(server_mesh.server_devices(0, 2)) == ids(local[0:2])
+    assert ids(server_mesh.server_devices(1, 2)) == ids(local[2:4])
+    assert ids(server_mesh.server_devices(1, 4)) == ids(local[4:8])
+    assert ids(server_mesh.ServerMesh(2, 1).devices) == ids(local[2:4])
+    # server 1 alone on a two-device view: no devices [2, 4) to take
+    monkeypatch.setattr(jax, "local_devices", lambda: local[:2])
+    assert ids(server_mesh.server_devices(1, 2)) == ids(local[:2])
+    assert ids(server_mesh.ServerMesh(2, 1).devices) == ids(local[:2])
+
+
+def _plain_reference():
+    import importlib.util
+    import os
+
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmark", "references", "linf_ball_1d.py",
+    )
+    spec = importlib.util.spec_from_file_location("linf_ball_1d", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+CO_RESIDENT_LANES = ["trusted", "secure", "trusted_planar", "secure_planar"]
+
+
+@pytest.mark.parametrize("lane", CO_RESIDENT_LANES)
+def test_co_resident_sharded_servers_keep_to_their_own_devices(
+        lane, client_keys, monkeypatch):
+    """Two ``CollectorServer``s with ``server_data_devices=2`` in ONE
+    process (the four-chip benchmark cell's layout): server 0 holds
+    devices [0, 1], server 1 holds [2, 3]; every array a level produces
+    on a server — key planes, frontier, child cache, whatever it
+    fetches (packed share bits, counts before the fetch) and the peer's
+    bits placed back — lies on that server's own pair; and the whole
+    crawl through ``RpcLeader`` equals the plain reference at every
+    level, counts included.  The process's default device is a device
+    of NEITHER server here, so a default-device placement shows.
+
+    The ``_planar`` cases are the lanes as an accelerator host runs
+    them: the plane-major layout with the fused Pallas expand once per
+    shard under ``shard_map`` (``ServerMesh.expand_share_bits``) — here
+    with the engine selector stood in for and the kernel in interpret
+    mode, both in the test.  ``secure_planar`` is what
+    ``chip_smoke.py --chips 4`` drives: the planar packed bits handed to
+    the sharded 2PC stage (``kernel_shard``)."""
+    import functools
+
+    import jax
+    from jax.experimental import pallas as pl
+
+    from fuzzyheavyhitters_tpu.protocol import collect, sessions
+
+    pts_bits, (k0, k1) = client_keys
+    ref = _plain_reference()
+    secure, planar = lane.startswith("secure"), lane.endswith("_planar")
+    port = BASE_PORT + 8000 + 40 * CO_RESIDENT_LANES.index(lane)
+    cfg = _cfg(port, server_data_devices=2, secure_exchange=secure)
+    if planar:
+        monkeypatch.setattr(collect, "_expand_engine", lambda: True)
+        monkeypatch.setattr(
+            pl, "pallas_call", functools.partial(pl.pallas_call, interpret=True)
+        )
+    own = {"server0": {0, 1}, "server1": {2, 3}}
+    seen = {"server0": [], "server1": []}  # (what, device ids)
+
+    def note(reg, what, tree):
+        for leaf in jax.tree.leaves(tree):
+            if isinstance(leaf, jax.Array):
+                seen[reg].append((what, {d.id for d in leaf.devices()}))
+
+    real_fetch = rpc._fetch
+
+    async def fetch(x, reg, level=None):
+        note(reg.name, "fetched", x)
+        return await real_fetch(x, reg, level)
+
+    monkeypatch.setattr(rpc, "_fetch", fetch)
+    real_h2d = rpc.CollectorServer._h2d
+
+    def h2d(cs, level, x, spec=None):
+        out = real_h2d(cs, level, x, spec)
+        note(cs.obs.name, "h2d", out)
+        return out
+
+    monkeypatch.setattr(rpc.CollectorServer, "_h2d", staticmethod(h2d))
+    real_stash = sessions.CollectionSession.stash_children
+
+    def stash(self, level, shard, children):
+        note(self.obs.name, "children", children)
+        return real_stash(self, level, shard, children)
+
+    monkeypatch.setattr(sessions.CollectionSession, "stash_children", stash)
+
+    held = []  # (depth, {prefix: count}) after every level
+
+    class TapLeader(RpcLeader):
+        servers = ()
+
+        async def _run_one_level(self, level, nreqs, thresh):
+            counts, alive = await super()._run_one_level(level, nreqs, thresh)
+            for s in self.servers:
+                cs = s._default()
+                note(s.obs.name, "keys", tuple(cs.keys))
+                note(s.obs.name, "frontier", cs.frontier)
+            if counts is not None:
+                held.append((level + 1,
+                             ref.crawl_frontier(self.paths, counts)))
+            return counts, alive
+
+    async def go():
+        s0, s1 = rpc.CollectorServer(0, cfg), rpc.CollectorServer(1, cfg)
+        assert s0.engine_tags()["mesh_devices"] == [0, 1]
+        assert s1.engine_tags()["mesh_devices"] == [2, 3]
+        assert s0.engine_tags()["expand"] == ("pallas" if planar else "xla")
+        assert s0._default().planar() == planar
+        t1 = asyncio.create_task(
+            s1.start("127.0.0.1", port + 10, "127.0.0.1", port + 11))
+        await asyncio.sleep(0.05)
+        t0 = asyncio.create_task(
+            s0.start("127.0.0.1", port, "127.0.0.1", port + 11))
+        await asyncio.gather(t0, t1)
+        c0 = await rpc.CollectorClient.connect("127.0.0.1", port)
+        c1 = await rpc.CollectorClient.connect("127.0.0.1", port + 10)
+        lead = TapLeader(cfg, c0, c1)
+        lead.servers = (s0, s1)
+        try:
+            await lead._both("reset")
+            await lead.upload_keys(k0, k1)
+            await lead.warmup()
+            res = await lead.run(N_CLIENTS)
+            meshes = [[d.id for d in s._mesh.devices] for s in (s0, s1)]
+        finally:
+            for c in (c0, c1):
+                await c.aclose()
+            for s in (s0, s1):
+                await s.aclose()
+        return res, meshes
+
+    with jax.default_device(jax.devices()[7]):
+        res, meshes = asyncio.run(go())
+    assert meshes == [[0, 1], [2, 3]]
+    for reg, notes in seen.items():
+        kinds = {what for what, _ in notes}
+        assert {"keys", "frontier", "children", "fetched"} <= kinds, kinds
+        if not secure:
+            assert "h2d" in kinds
+        stray = [(what, ids) for what, ids in notes if not ids <= own[reg]]
+        assert not stray, f"{reg} placed arrays off its own devices: {stray[:6]}"
+    # every level against the plain reference, counts included
+    thresh = max(1, int(cfg.threshold * N_CLIENTS))
+    want = ref.frontiers(pts_bits, 1, thresh, L)
+    assert [depth for depth, _ in held] == list(range(1, L + 1))
+    for depth, got in held:
+        assert got == want[depth], (depth, got, want[depth])
+    assert res.paths.shape[0] == len(want[L])
+
+
+def test_a_server_off_the_first_chip_gives_the_persistent_cache_up(monkeypatch):
+    """Read on the chip in PR 30: a multi-chip program on chips [2, 3]
+    runs when compiled in the process and halts the TPU when its
+    executable comes back from the persistent cache.  So a process that
+    takes on a sharded ``CollectorServer`` whose TPU devices do not start
+    at the first local chip suspends the cache (``compile_cache.suspend``:
+    reads and writes both; everything compiles fresh, as in the run that
+    works) — decided where the process is composed, from the devices
+    alone; never by a mesh, for a one-device server or on a CPU host."""
+    import jax
+
+    from fuzzyheavyhitters_tpu.utils import compile_cache
+
+    class Chip:
+        def __init__(self, i, platform="tpu"):
+            self.id, self.platform = i, platform
+
+    chips = [Chip(i) for i in range(4)]
+    monkeypatch.setattr(jax, "local_devices", lambda: chips)
+    ok = server_mesh.survives_cache
+    assert ok(tuple(chips[0:2])) and ok(tuple(chips[0:4]))
+    assert ok((chips[3],))                       # one-chip programs load fine
+    assert not ok(tuple(chips[2:4])) and not ok(tuple(chips[1:3]))
+    cpus = [Chip(i, "cpu") for i in range(4)]
+    monkeypatch.setattr(jax, "local_devices", lambda: cpus)
+    assert ok(tuple(cpus[2:4]))
+    monkeypatch.undo()                           # the real (CPU) devices again
+    said = []
+    monkeypatch.setattr(compile_cache, "suspend", said.append)
+    sharded = _cfg(BASE_PORT, server_data_devices=2)
+    rpc.CollectorServer(1, sharded)              # virtual CPU devices [2, 3]
+    assert said == []
+    monkeypatch.setattr(server_mesh, "survives_cache", lambda devs: False)
+    server_mesh.ServerMesh(2, 1)                 # a mesh decides nothing
+    rpc.CollectorServer(1, _cfg(BASE_PORT))      # nor does a one-device server
+    assert said == []
+    rpc.CollectorServer(1, sharded)
+    assert len(said) == 1 and "[2, 3]" in said[0]
+
+
+def test_suspending_the_persistent_cache(tmp_path):
+    """``compile_cache.suspend``: from there on the process neither
+    reads nor writes the persistent cache (a fresh program leaves no
+    entry behind), once, with one warning; ``enable()`` keeps its
+    answer.  The test puts the cache back as it found it."""
+    import os
+
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from fuzzyheavyhitters_tpu.utils import compile_cache
+
+    was_dir = compile_cache.enable()
+    was_min = jax.config.jax_persistent_cache_min_compile_time_secs
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    compilation_cache.reset_cache()
+    try:
+        # fhh-lint: disable=recompile-churn (the fresh program IS the test: it must reach the cache)
+        jax.jit(lambda x: x * 3 + 11)(jnp.arange(7)).block_until_ready()
+        written = set(os.listdir(tmp_path))
+        assert written  # the cache was live
+        compile_cache.suspend("a test")
+        compile_cache.suspend("again")  # idempotent
+        assert compile_cache._suspended == "a test"
+        assert not jax.config.jax_enable_compilation_cache
+        # fhh-lint: disable=recompile-churn (a fresh program again: it must NOT reach the cache)
+        jax.jit(lambda x: x * 5 + 13)(jnp.arange(9)).block_until_ready()
+        assert set(os.listdir(tmp_path)) == written
+        assert compile_cache.enable() == was_dir
+    finally:
+        compile_cache._suspended = None
+        jax.config.update("jax_enable_compilation_cache", True)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", was_min)
+        jax.config.update("jax_compilation_cache_dir", was_dir)
+        compilation_cache.reset_cache()

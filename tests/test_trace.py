@@ -995,3 +995,32 @@ def test_profiler_capture_holds_the_program_spans(rng, tmp_path, trace_dir):
     }
     assert {"server0:gc_ot", "server1:wire_read", "server0:d2h",
             "leader:level"} <= names, sorted(names)[:40]
+
+
+def test_trace_spans_reports_busy_time_by_device_plane(tmp_path):
+    """``scripts/trace_spans.py --capture``: the seconds each device plane
+    ran anything inside the capture's window of levels, plane by plane
+    (the benchmark's ``busy_s`` is their mean), on the recorded capture
+    the benchmark's own tests reduce."""
+    import importlib.util
+    import os
+
+    from jax.profiler import ProfileData
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "trace_spans", os.path.join(root, "scripts", "trace_spans.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    data = os.path.join(root, "benchmark", "tests", "data")
+    with open(os.path.join(data, "trusted_capture.textproto"), encoding="utf-8") as f:
+        blob = ProfileData.text_proto_to_serialized_xspace(f.read())
+    path = tmp_path / "cut.xplane.pb"
+    path.write_bytes(blob)
+    out = mod.device_busy(str(path))
+    assert list(out["busy_s"]) == ["/device:TPU:0"]
+    import trace_reduce
+
+    want = trace_reduce.reduce(trace_reduce.read_capture(str(path)))
+    assert out["window_s"] == pytest.approx(want["window_s"])
+    assert out["busy_s"]["/device:TPU:0"] == pytest.approx(want["busy_s"])
