@@ -28,7 +28,8 @@ Well-known metric names (what populates them):
   1-of-2^S path — and payload-table/open + field conversion); they are
   a BREAKDOWN of gc_ot, not additive with it, and the wire wait is the
   gc_ot remainder.  Counters ``ot_path_ot2s`` / ``ot_path_gc`` count
-  levels by the equality-test engine taken.  Rolled up across
+  levels by the equality-test engine taken, ``secure_chunks`` the
+  chunks a level's two messages crossed in (1 = whole).  Rolled up across
   registries into a top-level ``secure_kernels`` section whenever a
   secure crawl ran.
 - counters ``data_bytes_sent`` / ``data_bytes_recv`` /
@@ -320,6 +321,7 @@ def _secure_kernel_summary(registries: dict) -> dict | None:
     totals = dict.fromkeys(names, 0.0)
     by_level: dict = {}
     paths = {"ot2s": 0, "gc": 0}
+    chunks: dict = {}
     kshards = None
     kgather = 0.0
     seen = False
@@ -339,6 +341,11 @@ def _secure_kernel_summary(registries: dict) -> dict | None:
             if c is not None:
                 seen = True
                 paths[p] += c.get("total", 0)
+        # both servers cut a level alike: one registry's count a level
+        # (a level crawled again counts again)
+        c = snap.get("counters", {}).get("secure_chunks")
+        for lvl, k in (c or {}).get("by_level", {}).items():
+            chunks[lvl] = max(chunks.get(lvl, 0), k)
         g = snap.get("gauges", {}).get("kernel_shards")
         if g is not None:
             kshards = g.get("last") if kshards is None else max(
@@ -359,6 +366,11 @@ def _secure_kernel_summary(registries: dict) -> dict | None:
         "ot_path": ot_path,
         "levels_ot2s": paths["ot2s"],
         "levels_gc": paths["gc"],
+        # chunks each level's two messages crossed in (protocol/rpc.py
+        # ``_ev_chunks``; 1 = the level went whole)
+        "chunks_by_level": dict(
+            sorted(chunks.items(), key=lambda kv: int(kv[0]))
+        ),
         # kernel-stage layout (multi-chip servers only; None/0.0 on a
         # single-device crawl — see the mesh section for the per-level
         # breakdown): the phase seconds above are the SHARDED kernels'

@@ -50,6 +50,9 @@ Events are small dicts, one JSON object per line::
      "trace": "crawl-ab12-1", "span": "ab12-7", "parent": "ab12-3",
      "level": 5, "error": false}
 
+(and ``"chunk": k`` on a span of chunk k of a secure level that crossed
+in more than one, :func:`chunk`)
+
 ``ph``: "X" complete span, "i" instant, "C" clock offset.  ``ts``/"dur"
 are SECONDS (epoch / elapsed); merge converts to Chrome-trace µs.
 """
@@ -79,6 +82,13 @@ _DEFAULT_RING = 200_000
 _CTX: contextvars.ContextVar = contextvars.ContextVar(
     # fhh-lint: disable=metric-naming (contextvar name, not a series)
     "fhh_trace_ctx", default=None
+)
+
+# which chunk of a chunked secure level the running task works on
+# (:func:`chunk`); None outside one
+_CHUNK: contextvars.ContextVar = contextvars.ContextVar(
+    # fhh-lint: disable=metric-naming (contextvar name, not a series)
+    "fhh_trace_chunk", default=None
 )
 
 _LOCK = threading.Lock()
@@ -394,6 +404,22 @@ def span_at(name: str, comp: str, ts: float, dur: float, level=None) -> None:
     _span_event(name, comp, ts, dur, ctx[0], _new_id(), ctx[1], level, False)
 
 
+@contextlib.contextmanager
+def chunk(k: "int | None"):
+    """Label every span the running task records inside with
+    ``chunk=k``: the secure level's chunk pipeline (protocol/rpc.py)
+    names the chunk each of its spans worked on.  ``None`` (a level
+    that goes whole) and a disabled trace label nothing."""
+    if k is None or not enabled():
+        yield
+        return
+    tok = _CHUNK.set(int(k))
+    try:
+        yield
+    finally:
+        _CHUNK.reset(tok)
+
+
 def _span_event(name, comp, ts, dur, tid, sid, parent, level, error) -> None:
     rec = {
         "ph": "X",
@@ -408,6 +434,9 @@ def _span_event(name, comp, ts, dur, tid, sid, parent, level, error) -> None:
         rec["parent"] = parent
     if level is not None:
         rec["level"] = level
+    k = _CHUNK.get()
+    if k is not None:
+        rec["chunk"] = k
     if error:
         rec["error"] = True
     _event(rec)
@@ -642,7 +671,7 @@ def to_chrome(events: list) -> dict:
         ts_us = (e.get("ts", 0.0) - _offset_for(comp, offsets)) * 1e6
         args = {
             k: e[k]
-            for k in ("trace", "span", "parent", "level", "error")
+            for k in ("trace", "span", "parent", "level", "chunk", "error")
             if k in e
         }
         args.update(e.get("args") or {})

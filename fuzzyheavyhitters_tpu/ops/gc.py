@@ -508,6 +508,46 @@ def garble_equality_payload_packed(R, Y0, seed, x_bits, m_v0, m_v1,
     )
 
 
+@partial(jax.jit, static_argnames=("n_words", "B", "pallas"))
+def _garble_rows_packed(R, Y0, seed, x_bits, m_v0, m_v1, n_words: int,
+                        idx_offset, B: int, t0, pallas: bool):
+    from . import gc_pallas
+
+    x_bits = jnp.asarray(x_bits, bool)
+    n, S = x_bits.shape
+    bp = gc_pallas.padded_tests(n)
+    # this range's slice of the level's ONE label/mask draw, zero past
+    # the level's last test like the whole-level twin's _pad_tests
+    X0, mask = _carve_label_words_shard(seed, B, S, t0, bp)
+    args = (
+        jnp.asarray(R, jnp.uint32), _pad_tests(jnp.asarray(Y0, jnp.uint32), bp),
+        X0, mask, _pad_tests(x_bits, bp),
+        _pad_tests(jnp.asarray(m_v0, jnp.uint32), bp),
+        _pad_tests(jnp.asarray(m_v1, jnp.uint32), bp), n_words, idx_offset,
+    )
+    if pallas:
+        return gc_pallas.garble_packed_planes(*args)
+    return _garble_packed_planes_xla(*args)
+
+
+def garble_equality_payload_packed_rows(R, Y0, seed, x_bits, m_v0, m_v1,
+                                        n_words: int, idx_offset, B: int,
+                                        t0):
+    """Tests ``[t0, t0 + n)`` of a ``B``-test level's packed garble
+    (:func:`garble_equality_payload_packed`), every input already cut
+    to those ``n`` tests and ``idx_offset`` the level's pad index base
+    plus ``t0``: the label and mask words are that range's slice of the
+    level's one stream draw (:func:`_carve_label_words_shard`), so the
+    ranges' buffers, each its own planar blocks, are the level's
+    message block for block.  ``t0`` is a multiple of the planar block
+    and may be traced; ``t0 = 0, n = B`` is the whole level."""
+    S = jnp.asarray(x_bits).shape[1]
+    return _garble_rows_packed(
+        R, Y0, jnp.asarray(seed, jnp.uint32), x_bits, m_v0, m_v1, n_words,
+        idx_offset, B, t0, S >= 2 and _pallas_engine(),
+    )
+
+
 def eval_equality_payload_packed(msg, ev_labels, n_words: int, idx_offset):
     """Engine dispatcher twin of :func:`garble_equality_payload_packed`.
     Returns (e bool[B], payload uint32[B, n_words])."""

@@ -345,9 +345,22 @@ class OtExtSender:
         self._off += -(-w // 16)  # blocks consumed from each column stream
         self._sent += m
 
+    def extend_rows(self, m: int, u_cols, base_off: int, row0: int) -> jax.Array:
+        """Q rows ``[row0, row0 + m)`` of the batch that began at stream
+        offset ``base_off``, from the matching column words of the
+        peer's u-matrix; the cursors do not move (the caller
+        moves them past the whole batch with :meth:`advance`).  ``row0`` is a
+        multiple of 512 (see :func:`sender_extend_rows`); it is a traced
+        scalar of the one program :meth:`extend` runs, so a later row
+        range of a batch compiles nothing new."""
+        return _sender_extend(
+            self._seeds, self._s_dev, jnp.asarray(u_cols),
+            base_off + row0 // 512, m,
+        )
+
     def extend(self, m: int, u_msg) -> jax.Array:
         """Peer's u-matrix -> Q rows uint32[m, 4] (Q_j = T_j ^ r_j·s)."""
-        q = _sender_extend(self._seeds, self._s_dev, jnp.asarray(u_msg), self._off, m)
+        q = self.extend_rows(m, u_msg, self._off, 0)
         self.advance(m)
         return q
 
@@ -411,11 +424,21 @@ class OtExtReceiver:
     def extend(self, choices) -> tuple[jax.Array, jax.Array]:
         """choices bool[m] -> (u message uint32[128, ceil(m/32)],
         T rows uint32[m, 4]).  T_j is the Δ-OT label for choice r_j."""
-        choices = jnp.asarray(choices, bool)
-        m = choices.shape[0]
-        u, t = _receiver_extend(self._seeds0, self._seeds1, choices, self._off, m)
-        self.advance(m)
+        u, t = self.extend_rows(choices, self._off, 0)
+        self.advance(t.shape[0])
         return u, t
+
+    def extend_rows(self, choices, base_off: int, row0: int):
+        """(u column words, T rows) for rows ``[row0, row0 + m)`` of the
+        batch that began at stream offset ``base_off``, ``choices`` being
+        those rows' m choice bits: the receiver twin of
+        :meth:`OtExtSender.extend_rows` (same alignment, same program
+        as :meth:`extend`, cursors untouched)."""
+        choices = jnp.asarray(choices, bool)
+        return _receiver_extend(
+            self._seeds0, self._seeds1, choices, base_off + row0 // 512,
+            choices.shape[0],
+        )
 
     def pads(self, t_rows: jax.Array, n_words: int, idx_offset: int) -> jax.Array:
         """uint32[m, n_words] — the receiver's chosen pad H(j, T_j)."""

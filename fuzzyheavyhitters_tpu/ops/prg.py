@@ -226,10 +226,11 @@ def stream_blocks(seed: jax.Array, n_blocks: int, offset=0) -> jax.Array:
     return jax.lax.optimization_barrier(chacha_block(blocks))
 
 
-def stream_words(seed: jax.Array, n_words: int) -> jax.Array:
-    """uint32[..., 4] seed -> uint32[..., n_words] pseudorandom words."""
+def stream_words(seed: jax.Array, n_words: int, offset=0) -> jax.Array:
+    """uint32[..., 4] seed -> uint32[..., n_words] pseudorandom words,
+    from block ``offset`` of the stream on (:func:`stream_blocks`)."""
     n_blocks = -(-n_words // 16)
-    out = stream_blocks(seed, n_blocks)
+    out = stream_blocks(seed, n_blocks, offset)
     return out.reshape(out.shape[:-2] + (n_blocks * 16,))[..., :n_words]
 
 

@@ -224,12 +224,17 @@ class RpcLeader:
                "ot_path": self.cfg.ot_path}
         spans = collect.shard_spans(self._f_bucket, self.cfg.crawl_shard_nodes)
         if self.cfg.secure_exchange and self.cfg.secure_whole_level:
-            # whole-level secure batching: every (node, client) wire of
-            # the level garbles/evaluates as ONE device program per
-            # f_bucket rung — node-sharding the GC/OT batch into
-            # host-sized chunks (and pipelining those chunks) loses more
-            # to fragmented kernels than the overlap wins.  A mid-level
-            # fault then re-runs the level, not a span
+            # whole-level secure batching: one verb a level, no node
+            # spans cut here.  The servers cut the level themselves,
+            # by ROWS of its test batch into chunks of whole planar
+            # blocks inside this one verb (rpc ``_ev_chunks`` /
+            # ``_gb_chunks``), which costs no verb round trip, expand
+            # dispatch or reassembly a span.  What this comment said
+            # until PR 31 — "pipelining those chunks loses more to
+            # fragmented kernels than the overlap wins" — was read off
+            # CPU runs of host-sized node spans; what the chip said of
+            # chunked kernels is in PERF.md section 6 (PR 31).  A
+            # mid-level fault then re-runs the level, not a span
             # (cfg.secure_whole_level=False restores span granularity).
             return await self._both(verb, req)
         if len(spans) == 1:

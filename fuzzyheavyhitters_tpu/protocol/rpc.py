@@ -95,6 +95,7 @@ from __future__ import annotations
 import asyncio
 import collections as _collections
 import contextlib
+import functools
 import os
 import pickle
 import secrets as _secrets
@@ -111,7 +112,7 @@ from ..obs import devmem as obsdevmem
 from ..obs import exporter as obsexporter
 from ..obs import metrics as obsmetrics
 from ..obs import trace as obstrace
-from ..ops import baseot, dpf, gc, otext
+from ..ops import baseot, dpf, otext
 from ..ops.fields import F255, FE62
 from ..ops.ibdcf import EvalState, IbDcfKeyBatch
 from ..parallel import kernel_shard, server_mesh as smesh, sketch_shard
@@ -1061,6 +1062,225 @@ class CollectorServer:
         for n in names:
             cs.obs.timer_add(n, 0.0, level=level)
 
+    # -- the secure level as a stream of row chunks -------------------------
+    #
+    # ``secure.level_chunks`` cuts the level's test batch into K runs of
+    # whole planar blocks, and each of the two messages crosses as K
+    # frames.  Each server runs its stages as tasks with a short queue
+    # between them — kernel, fetch and send of what it makes
+    # (``_chunk_senders``), receive and kernel of what it is sent — so
+    # chunk k+1's kernel and chunk k's fetch run while chunk k-1 is on
+    # the socket and the peer works on chunk k-2.  Every server has a
+    # task that only receives, so someone always reads: the ``_swap``
+    # deadlock of two sides writing past the socket buffers cannot form.
+    # Fetches stay on threads (``_fetch``), a send is a whole frame on
+    # the one writer, and ``PlaneMux`` holds frames ahead of their
+    # reader in order.  One chunk (K = 1) is the whole level: the same
+    # calls, one after the other as they always were.
+
+    @staticmethod
+    def _chunk_frame(k: int, K: int, arr: np.ndarray):
+        """What chunk ``k`` of ``K`` crosses as: the array alone where
+        the level goes whole (the frame it always was), else tagged."""
+        return arr if K == 1 else (k, K, arr)
+
+    @staticmethod
+    def _chunk_label(k: int, K: int):
+        """Under fhh-trace, ``chunk=k`` on the spans inside; a level
+        that goes whole labels nothing."""
+        return obstrace.chunk(k if K > 1 else None)
+
+    async def _chunk_recv(self, cs, k: int, K: int) -> np.ndarray:
+        """The array of chunk ``k`` of ``K`` off this session's channel.
+        A peer that cut the level differently sent something else: the
+        streams have diverged for good, so this end of the plane is
+        closed (the peer's blocked receives fail with it, nobody hangs)
+        and the verb fails like one on a severed plane."""
+        got = await self._dp_recv(cs)
+        if K == 1 and isinstance(got, np.ndarray):
+            return got
+        if (
+            K > 1 and isinstance(got, tuple) and len(got) == 3
+            and got[:2] == (k, K)
+        ):
+            return got[2]
+        tag = got[:2] if isinstance(got, tuple) else "an untagged frame"
+        await self.plane_break(None)
+        raise ConnectionError(
+            f"secure level: expected chunk {k} of {K} from the peer, "
+            f"got {tag}"
+        )
+
+    @staticmethod
+    async def _chunk_tasks(*coros) -> list:
+        """Run a level's chunk tasks to their end.  The first to fail
+        cancels its siblings and its error fails the verb (a cancelled
+        verb cancels them all); none outlives this call."""
+        tasks = [asyncio.ensure_future(c) for c in coros]
+        try:
+            # fhh-lint: disable=unbounded-await (the tasks' own waits are the data plane's, bounded by TCP keepalive like every exchange; a verb deadline cancels this await and the tasks with it)
+            await asyncio.wait(tasks, return_when=asyncio.FIRST_EXCEPTION)
+        finally:
+            for t in tasks:
+                t.cancel()
+            await asyncio.gather(*tasks, return_exceptions=True)
+        for t in tasks:
+            if not t.cancelled() and t.exception() is not None:
+                raise t.exception()
+        return [t.result() for t in tasks]
+
+    def _chunk_senders(self, cs, level: int, K: int, made: asyncio.Queue,
+                       sent: asyncio.Queue | None = None):
+        """The two tasks that take a level's K chunk arrays from the
+        device to the peer, in order: one fetches, one sends, two
+        chunks at most between them, so chunk k+1's fetch runs while
+        chunk k is on the socket.  ``made`` yields ``(array, token)``;
+        the token goes on to ``sent`` once that chunk's frame is with
+        the kernel."""
+        fetched: asyncio.Queue = asyncio.Queue(maxsize=2)
+
+        async def fetch():
+            for k in range(K):
+                # fhh-lint: disable=unbounded-await (fed by a sibling task, which _chunk_tasks cancels with this one)
+                arr, token = await made.get()
+                with self._chunk_label(k, K):
+                    # fhh-lint: disable=chunked-device-readback (the point of the chunks: chunk k's fetch runs while chunk k-1 is on the socket and the peer works on it; one whole-level fetch put 72 ms of a 173 ms level in series, PERF.md PR 31)
+                    arr = await _fetch(arr, cs.obs, level=level)
+                # fhh-lint: disable=unbounded-await (drained by a sibling task, as above)
+                await fetched.put((arr, token))
+
+        async def send():
+            for k in range(K):
+                # fhh-lint: disable=unbounded-await (fed by a sibling task, as above)
+                arr, token = await fetched.get()
+                with self._chunk_label(k, K):
+                    await self._dp_send(cs, self._chunk_frame(k, K, arr))
+                if sent is not None:
+                    sent.put_nowait(token)
+
+        return fetch(), send()
+
+    async def _ev_chunks(
+        self, cs, level: int, flat, chunks, B: int, W: int, path: str,
+        count_field,
+    ):
+        """Evaluator and OT receiver of a level: one task extends chunk
+        after chunk, two carry each u to the peer
+        (:meth:`_chunk_senders`), one opens each table as it arrives.
+        Returns the level's field values [B(, limbs)] on the device."""
+        S, K = flat.shape[1], len(chunks)
+        rcv = cs._ot_rcv
+        # the cursors move past the whole batch up front: a level that
+        # fails midway leaves them where the whole-level flow would
+        idx0, off = rcv.consumed, rcv.stream_offset
+        rcv.advance(B * S)
+        made: asyncio.Queue = asyncio.Queue(maxsize=2)
+        # chunks whose u is on the wire, for the task that opens them
+        sent: asyncio.Queue = asyncio.Queue(maxsize=K)
+
+        async def extend():
+            for k, (t0, n) in enumerate(chunks):
+                with self._chunk_label(k, K):
+                    with cs.obs.span("otext", level=level):
+                        y = secure.chunk_rows(flat, t0, n)
+                        u, t_rows = rcv.extend_rows(
+                            y.reshape(n * S), off, t0 * S
+                        )
+                        # the extension, device-synced as on the sender
+                        # side; the fetch is then the copy alone
+                        await self._phase_sync(u)
+                # fhh-lint: disable=unbounded-await (drained by a sibling task, which _chunk_tasks cancels with this one)
+                await made.put((u, (y, t_rows)))
+
+        async def consume():
+            vals = []
+            for k, (t0, _) in enumerate(chunks):
+                # fhh-lint: disable=unbounded-await (fed by the sibling task, which _chunk_tasks cancels with this one)
+                y, t_rows = await sent.get()
+                with self._chunk_label(k, K):
+                    bmsg = self._h2d(
+                        cs, level, await self._chunk_recv(cs, k, K)
+                    )
+                    open_ = functools.partial(
+                        secure.ev_chunk_words,
+                        t_rows, y, bmsg, W, path, idx0, t0,
+                    )
+                    if path != "ot2s":
+                        with cs.obs.span("eval", level=level):
+                            pay = open_()
+                            await self._phase_sync(pay)
+                    with cs.obs.span("b2a", level=level):
+                        v = secure.words_to_field(
+                            count_field, open_() if path == "ot2s" else pay
+                        )
+                        await self._phase_sync(v)
+                    vals.append(v)
+            return vals
+
+        *_, vals = await self._chunk_tasks(
+            extend(), *self._chunk_senders(cs, level, K, made, sent),
+            consume(),
+        )
+        self._zero_phases(
+            cs, level, "garble", *(("eval",) if path == "ot2s" else ())
+        )
+        return vals[0] if K == 1 else jnp.concatenate(vals)
+
+    async def _gb_chunks(
+        self, cs, level: int, flat, chunks, B: int, W: int, path: str,
+        count_field, garbler: int, gc_seed, b2a_seed,
+    ):
+        """Garbler and OT sender of a level: one task receives each u
+        and builds that chunk's message, two carry it to the peer
+        (:meth:`_chunk_senders`), two chunks at most between each, so
+        chunk k's fetch overlaps chunk k+1's kernel and chunk k-1's
+        write.  Returns the level's share values
+        [B(, limbs)] on the device."""
+        S, K = flat.shape[1], len(chunks)
+        snd = cs._ot_snd
+        idx0, off = snd.consumed, snd.stream_offset
+        snd.advance(B * S)  # see _ev_chunks
+        built: asyncio.Queue = asyncio.Queue(maxsize=2)
+
+        async def build():
+            vals = []
+            for k, (t0, n) in enumerate(chunks):
+                with self._chunk_label(k, K):
+                    u = self._h2d(
+                        cs, level, await self._chunk_recv(cs, k, K)
+                    )
+                    with cs.obs.span("otext", level=level):
+                        q = snd.extend_rows(n * S, u, off, t0 * S)
+                        await self._phase_sync(q)
+                    with cs.obs.span("b2a", level=level):
+                        v, w0, w1 = secure.b2a_payload_pair(
+                            count_field, b2a_seed, n, garbler, t0
+                        )
+                        build_msg = functools.partial(
+                            secure.gb_chunk_msg, snd.s_block, q,
+                            secure.chunk_rows(flat, t0, n), w0, w1, gc_seed,
+                            W, path, idx0, B, t0,
+                        )
+                        if path == "ot2s":
+                            msg = build_msg()
+                        await self._phase_sync(msg if path == "ot2s" else w1)
+                    if path != "ot2s":
+                        with cs.obs.span("garble", level=level):
+                            msg = build_msg()
+                            await self._phase_sync(msg)
+                    vals.append(v)
+                # fhh-lint: disable=unbounded-await (drained by a sibling task, which _chunk_tasks cancels with this one)
+                await built.put((msg, None))
+            return vals
+
+        vals, *_ = await self._chunk_tasks(
+            build(), *self._chunk_senders(cs, level, K, built)
+        )
+        self._zero_phases(
+            cs, level, "eval", *(("garble",) if path == "ot2s" else ())
+        )
+        return vals[0] if K == 1 else jnp.concatenate(vals)
+
     async def _crawl_counts_secure(
         self, cs, level: int, count_field, last: bool = False, garbler: int = 0,
         shard=None, ot_path=None,
@@ -1074,15 +1294,30 @@ class CollectorServer:
         alternates it per level — the reference's ``gc_sender`` flag,
         rpc.rs:20-23 — so garbling cost splits across the servers); each
         direction runs its own OT-extension session (``_setup_secure``).
-        Every data-plane message is ONE packed array and a level is ONE
-        protocol round trip with exactly one device fetch per message:
-        ev u -> sender's whole-level planar message — the 1-of-2^S
-        payload table when ``secure.ot_path`` picks "ot2s" (no garbled
-        circuit at all), the packed garbled batch with the b2a payloads
-        riding the OUTPUT wire labels otherwise — built by ONE fused
-        device program per side (secure.gb_step_level/ev_open_level; the
-        reference runs per-core GC then a separate OT round here,
-        collect.rs:419-482).
+        A level is ONE protocol round trip, ev u -> sender's planar
+        message — the 1-of-2^S payload table when ``secure.ot_path``
+        picks "ot2s" (no garbled circuit at all), the packed garbled
+        batch with the b2a payloads riding the OUTPUT wire labels
+        otherwise (the reference runs per-core GC then a separate OT
+        round here, collect.rs:419-482) — and both messages cross as a
+        STREAM OF ROW CHUNKS: ``secure.level_chunks`` cuts the level's
+        ``B = F*C*N`` tests into K runs of whole planar blocks, from
+        ``(B, S, W, path)`` alone, so that the larger of a chunk's two
+        frames weighs about ``secure.CHUNK_FRAME_BYTES``, and each
+        server runs its stages as tasks (``_ev_chunks`` / ``_gb_chunks``)
+        so that chunk k+1's kernel and chunk k's fetch run while chunk
+        k-1 is on the socket and the peer works on what it has.  One
+        device fetch and one frame a chunk and message; a frame is the
+        array alone at K = 1 and ``(k, K, array)`` otherwise.  Chunk k takes rows of the level's
+        ONE extension, b2a stream and label draw, so every share is
+        what the level gone whole (K = 1: buckets under 16 in the
+        benchmark's cell, every small test) gives, bit for bit, and the
+        frames side by side are its two messages
+        (tests/test_secure_chunks.py).  The counter ``secure_chunks``
+        says K, level by level.  The row-sharded kernel stage
+        (``ks``, parallel/kernel_shard.py) keeps one frame a message: a
+        sharded server and an unsharded peer agree only while the level
+        is under two chunks.
 
         The ``gc_ot`` span splits into the secure-kernel phases
         ``otext`` (extension), ``garble``/``eval`` (circuit work — zero
@@ -1124,6 +1359,12 @@ class CollectorServer:
             cs.obs.count(f"ot_path_{path}", level=level)
             W = secure.payload_words(count_field)
             ks = ex.get("kernel")
+            # the row-sharded stage keeps its one frame a message
+            chunks = (
+                [(0, B)] if ks is not None
+                else secure.level_chunks(B, S, W, path)
+            )
+            cs.obs.count("secure_chunks", len(chunks), level=level)
             if cs._mesh is not None:
                 # per-level kernel layout: the active row-shard count (1
                 # = the degraded gather path) feeds the mesh report
@@ -1133,8 +1374,8 @@ class CollectorServer:
                     level=level,
                 )
             if self.server_id == garbler:  # garbler/sender + OT-ext sender
-                u = await self._dp_recv(cs)
                 if ks is not None:
+                    u = await self._dp_recv(cs)
                     # ROW-SHARDED kernel stage: extension, payload pair,
                     # and the equality kernel all run per mesh shard
                     # (parallel/kernel_shard.py); the frame reads back
@@ -1167,35 +1408,9 @@ class CollectorServer:
                         )
                     await self._dp_send(cs, msg_np)
                 else:
-                    u = self._h2d(cs, level, u)
-                    with cs.obs.span("otext", level=level):
-                        idx0 = cs._ot_snd.consumed
-                        q = cs._ot_snd.extend(B * S, u)
-                        await self._phase_sync(q)
-                    with cs.obs.span("b2a", level=level):
-                        vals, w0, w1 = secure.b2a_payload_pair(
-                            count_field, b2a_seed, B, garbler
-                        )
-                        if path == "ot2s":
-                            msg = secure.ot2s_encrypt_packed(
-                                q.reshape(B, S, 4),
-                                jnp.asarray(cs._ot_snd.s_block), flat,
-                                w1, w0, W, idx0,
-                            )
-                        await self._phase_sync(w1 if path != "ot2s" else msg)
-                    if path == "ot2s":
-                        self._zero_phases(cs, level, "garble", "eval")
-                    else:
-                        with cs.obs.span("garble", level=level):
-                            msg, _ = gc.garble_equality_payload_packed(
-                                jnp.asarray(cs._ot_snd.s_block),
-                                q.reshape(B, S, 4), jnp.asarray(gc_seed),
-                                flat, w1, w0, W, idx0,
-                            )
-                            await self._phase_sync(msg)
-                        self._zero_phases(cs, level, "eval")
-                    await self._dp_send(
-                        cs, await _fetch(msg, cs.obs, level=level)
+                    vals = await self._gb_chunks(
+                        cs, level, flat, chunks, B, W, path, count_field,
+                        garbler, gc_seed, b2a_seed,
                     )
             else:  # evaluator + OT receiver (inputs stay on device: each
                 # np.asarray here would be a blocking device->host fetch)
@@ -1229,36 +1444,9 @@ class CollectorServer:
                         *(("eval",) if path == "ot2s" else ("b2a",)),
                     )
                 else:
-                    with cs.obs.span("otext", level=level):
-                        u, t_rows, idx0 = secure.ev_step1_fused(
-                            cs._ot_rcv, flat
-                        )
-                        # the extension, device-synced as on the sender
-                        # side; the fetch below is then the copy alone
-                        await self._phase_sync(u)
-                    u_np = await _fetch(u, cs.obs, level=level)
-                    await self._dp_send(cs, u_np)
-                    bmsg = self._h2d(cs, level, await self._dp_recv(cs))
-                    if path == "ot2s":
-                        with cs.obs.span("b2a", level=level):
-                            pay = secure.ot2s_decrypt_packed(
-                                jnp.asarray(t_rows).reshape(B, S, 4), flat,
-                                bmsg, W, idx0,
-                            )
-                            vals = secure.words_to_field(count_field, pay)
-                            await self._phase_sync(vals)
-                        self._zero_phases(cs, level, "garble", "eval")
-                    else:
-                        with cs.obs.span("eval", level=level):
-                            _, pay = gc.eval_equality_payload_packed(
-                                bmsg, jnp.asarray(t_rows).reshape(B, S, 4),
-                                W, idx0,
-                            )
-                            await self._phase_sync(pay)
-                        with cs.obs.span("b2a", level=level):
-                            vals = secure.words_to_field(count_field, pay)
-                            await self._phase_sync(vals)
-                        self._zero_phases(cs, level, "garble")
+                    vals = await self._ev_chunks(
+                        cs, level, flat, chunks, B, W, path, count_field
+                    )
         with cs.obs.span("field", level=level) as sp_field:
             if ks is not None:
                 # test-sharded b2a shares: scatter into the (F, C, N)

@@ -196,16 +196,22 @@ def ev_step3(rcv: otext.OtExtReceiver, e_bits):
     return u2, t2, idx0
 
 
-def b2a_payload_pair(field, b2a_seed, B: int, garbler: int):
+def b2a_payload_pair(field, b2a_seed, B: int, garbler: int, t0: int = 0):
     """The sender's b2a share pair, in ONE place for every flow: sample
     ``r0`` from the seed stream, keep ``r1 = r0 ± 1`` — +1 when server 0
     is the sender, −1 when server 1 is — so the leader's uniform
     ``v0 - v1`` reconstruction holds whichever server sends
     (collect.rs:439-456's ordering with the alternating-garbler sign).
+    ``t0`` seeks: the pair of tests ``[t0, t0 + B)`` of the seed's
+    stream (test ``t`` owns words ``[t*W, (t+1)*W)``; ``t0*W`` a
+    multiple of 16, as every multiple of a planar block is), the same
+    words a draw from 0 holds there.
     Returns (r1 — the sender's additive shares, w0, w1 — the two payloads
     as OT words)."""
     W = payload_words(field)
-    r_words = prg.stream_words(jnp.asarray(b2a_seed, jnp.uint32), B * W).reshape(B, W)
+    r_words = prg.stream_words(
+        jnp.asarray(b2a_seed, jnp.uint32), B * W, t0 * W // 16
+    ).reshape(B, W)
     r0 = field.sample(r_words)
     one = field.from_int(1)
     r1 = field.sub(r0, one) if garbler else field.add(r0, one)
@@ -725,6 +731,87 @@ def alive_weight(alive_nodes, alive_keys, C: int) -> np.ndarray:
 
 
 # ---------------------------------------------------------------------------
+# The level as a stream of row chunks
+# ---------------------------------------------------------------------------
+#
+# protocol/rpc.py sends a level's two messages as K frames each, so that
+# kernel, fetch, socket and the peer's kernel of consecutive chunks
+# overlap.  A chunk is a run of whole planar blocks of the level's test
+# batch (the last one takes the rest, padded to a block like the level
+# itself), so chunk k's frames are the level's planar blocks in order
+# and the u-matrix's column words in order.  It uses rows
+# ``[t0*S, (t0+n)*S)`` of the level's ONE extension
+# (``OtExtSender.extend_rows`` / ``OtExtReceiver.extend_rows``), tests
+# ``[t0, t0+n)`` of its one b2a stream (:func:`b2a_payload_pair`) and
+# label draw (``gc.garble_equality_payload_packed_rows``), and pad
+# indices from ``idx0 + t0``: every share is the whole-level flow's, bit
+# for bit, and the whole level is the plan of one chunk.
+
+# What the LARGER of a chunk's two frames may weigh.  Both servers cut a
+# level by it from dimensions they already agree on; measured on the
+# chip at 8, 16 and 32 MiB (PERF.md section 6, PR 31).  At 16 MiB and
+# under, every fetch and receive buffer also stays below the 32 MiB from
+# which glibc maps new pages for each request (protocol/wire.py).
+CHUNK_FRAME_BYTES: int = 16 << 20
+
+
+def level_chunks(B: int, S: int, W: int, path: str) -> list:
+    """``[(t0, n)]``: the test ranges a ``B``-test level of string width
+    ``S`` and payload width ``W`` crosses in, on equality path ``path``.
+    One range is the whole level."""
+    from ..ops import gc_pallas
+    from ..parallel.kernel_shard import n_msg_planes
+
+    block = gc_pallas.R_BLK * gc_pallas.GROUP
+    # bytes a test adds to the receiver's u-matrix (S rows of 128 bits)
+    # and to the sender's planar message
+    per_test = max(16 * S, 4 * n_msg_planes(path, S, W))
+    n = max(1, CHUNK_FRAME_BYTES // (per_test * block)) * block
+    return [(t0, min(n, B - t0)) for t0 in range(0, B, n)]
+
+
+@partial(jax.jit, static_argnames=("n",))
+def _test_rows(flat, t0, n: int):
+    return jax.lax.dynamic_slice_in_dim(flat, t0, n)
+
+
+def chunk_rows(flat, t0: int, n: int):
+    """Tests ``[t0, t0 + n)`` of the level's flat strings, on the device
+    (``t0`` traced: one program a chunk size)."""
+    return flat if n == flat.shape[0] else _test_rows(flat, t0, n)
+
+
+def gb_chunk_msg(s_block, q_rows, x_flat, w0, w1, gc_seed, W: int,
+                 path: str, idx0: int, B: int, t0: int):
+    """The sender's planar message for tests ``[t0, t0 + n)`` of a
+    ``B``-test level, from those tests' extension rows, strings and
+    payload pair: the 1-of-2^S table or the packed garbled batch."""
+    n, S = x_flat.shape
+    q_rows = q_rows.reshape(n, S, 4)
+    # result 1 (strings equal) -> receiver learns r0 (collect.rs:439-456)
+    if path == "ot2s":
+        return ot2s_encrypt_packed(
+            q_rows, jnp.asarray(s_block), x_flat, w1, w0, W, idx0 + t0
+        )
+    return gc.garble_equality_payload_packed_rows(
+        jnp.asarray(s_block), q_rows, gc_seed, x_flat, w1, w0, W,
+        idx0 + t0, B, t0,
+    )
+
+
+def ev_chunk_words(t_rows, y_flat, msg, W: int, path: str, idx0: int,
+                   t0: int):
+    """The receiver's payload words uint32[n, W] for tests
+    ``[t0, t0 + n)``: opens that range's planar message with its T
+    rows."""
+    n, S = y_flat.shape
+    t_rows = jnp.asarray(t_rows).reshape(n, S, 4)
+    if path == "ot2s":
+        return ot2s_decrypt_packed(t_rows, y_flat, msg, W, idx0 + t0)
+    return gc.eval_equality_payload_packed(msg, t_rows, W, idx0 + t0)[1]
+
+
+# ---------------------------------------------------------------------------
 # Warmup: compile the per-shape kernel chain without touching live state
 # ---------------------------------------------------------------------------
 
@@ -749,11 +836,12 @@ def _warm_pair():
 def warm_level_kernels(packed, d: int, field, path: str = "auto",
                        share_sums=None, radix: int = 1) -> None:
     """Run the WHOLE per-level 2PC kernel chain — string extraction,
-    Δ-OT extension, the b2a share pair (both garbling signs), the
-    whole-level equality message (1-of-2^S table or packed garbled
-    batch, whichever :func:`ot_path` picks for this shape under the
-    config's ``path`` knob — the fused otext/gc programs included),
-    payload open, alive-gated share sums — on a THROWAWAY in-process OT
+    then for each of the level's chunks (:func:`level_chunks`) its rows
+    of the Δ-OT extension, its b2a share pair (both garbling signs), its
+    equality message (1-of-2^S table or packed garbled batch, whichever
+    :func:`ot_path` picks for this shape under the config's ``path``
+    knob — the fused otext/gc programs included) and payload open, then
+    the alive-gated share sums — on a THROWAWAY in-process OT
     session, so every jit program a real level of this shape will
     dispatch is compiled (and lands in the persistent compile cache,
     utils/compile_cache) before measured crawl time starts.  The live OT
@@ -786,15 +874,33 @@ def warm_level_kernels(packed, d: int, field, path: str = "auto",
     snd, rcv = _warm_pair()
     zero = np.zeros(4, np.uint32)
     gseed, bseed = derive_seed(zero, 1, 0), derive_seed(zero, 2, 0)
-    u, t_rows, idx0 = ev_step1_fused(rcv, flat)
-    u = np.asarray(u)  # wire round trip (see docstring)
-    # the real crawl alternates the garbler per level, so each server
-    # runs BOTH payload-pair signs (r0 + 1 and r0 - 1) at this shape
-    for g in (0, 1):
-        b2a_payload_pair(field, bseed, B, g)
-    msg, _ = gb_step_level(snd, u, flat, gseed, bseed, field, 0, path=path)
-    msg = np.asarray(msg)  # wire round trip (see docstring)
-    vals = ev_open_level(t_rows, flat, msg, B, S, field, idx0, path=path)
+    W, p = payload_words(field), ot_path(S, path)
+    # the live level's chunks, each through the live level's calls
+    # (protocol/rpc.py ``_crawl_counts_secure``): one set of programs a
+    # chunk size, the same for every bucket that cuts into whole chunks
+    idx0, off_r, off_s = rcv.consumed, rcv.stream_offset, snd.stream_offset
+    rcv.advance(B * S)
+    snd.advance(B * S)
+    vals = []
+    for t0, n in level_chunks(B, S, W, p):
+        y = chunk_rows(flat, t0, n)
+        u, t_rows = rcv.extend_rows(y.reshape(n * S), off_r, t0 * S)
+        # fhh-lint: disable=chunked-device-readback,host-sync-in-hot-loop (warmup only: the live level's per-chunk wire round trip, see docstring)
+        u = np.asarray(u)
+        q = snd.extend_rows(n * S, u, off_s, t0 * S)
+        # the real crawl alternates the garbler per level, so each
+        # server runs BOTH payload-pair signs (r0 + 1 and r0 - 1)
+        b2a_payload_pair(field, bseed, n, 1, t0)
+        _, w0, w1 = b2a_payload_pair(field, bseed, n, 0, t0)
+        msg = gb_chunk_msg(
+            snd.s_block, q, y, w0, w1, gseed, W, p, idx0, B, t0
+        )
+        # fhh-lint: disable=chunked-device-readback,host-sync-in-hot-loop (as above)
+        msg = np.asarray(msg)
+        vals.append(words_to_field(
+            field, ev_chunk_words(t_rows, y, msg, W, p, idx0, t0)
+        ))
+    vals = vals[0] if len(vals) == 1 else jnp.concatenate(vals)
     w = jnp.ones((F_, C, N), bool)
     reduce_fn = node_share_sums if share_sums is None else share_sums
     jax.block_until_ready(

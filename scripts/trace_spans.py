@@ -10,8 +10,13 @@ object:
 - ``span_ms``: per ``<comp>:<name>`` the count, median, mean and largest
   duration, in milliseconds;
 - ``gc_ot_cover``: per server, over its ``gc_ot`` spans, the share of the
-  span that its leaf spans inside it cover (median and smallest), and the
-  median milliseconds of each leaf inside one ``gc_ot``;
+  span that the UNION of its leaf spans' intervals covers (median and
+  smallest: a secure level that crosses in chunks runs its stages as tasks,
+  so a server's leaves overlap and their sum passes the span), the leaves' sum over
+  the span (``busy_share_median``: how many leaves ran at once), the median
+  milliseconds of each leaf inside one ``gc_ot`` (summed over its chunks),
+  and for the levels that crossed in chunks (spans labelled ``chunk``) the
+  median count of chunks and the median milliseconds of one chunk's leaf;
 - ``wire_oob``: per component, the frames that carried raw array buffers
   (``wire_oob`` instants, one a frame: protocol/rpc.py ``_send``), their
   framed bytes, the bytes out of band (the counter ``wire_oob_bytes``) and
@@ -61,29 +66,55 @@ def span_ms(spans: list) -> dict:
     }
 
 
+def _union(intervals: list) -> float:
+    """Seconds covered by at least one of ``(start, end)``."""
+    total, reach = 0.0, float("-inf")
+    for a, b in sorted(intervals):
+        if b > reach:
+            total += b - max(a, reach)
+            reach = b
+    return total
+
+
 def gc_ot_cover(spans: list) -> dict:
     out = {}
     for comp in sorted({e["comp"] for e in spans if e["name"] == "gc_ot"}):
         mine = [e for e in spans if e["comp"] == comp]
-        shares, by_leaf = [], {}
+        shares, busy, by_leaf, n_chunks, by_chunk_leaf = [], [], {}, [], {}
         for g in (e for e in mine if e["name"] == "gc_ot" and e["dur"] > 0):
             lo, hi = g["ts"], g["ts"] + g["dur"]
             inside = [e for e in mine if e["name"] in LEAVES
                       and lo <= e["ts"] and e["ts"] + e["dur"] <= hi + 1e-5]
-            shares.append(sum(e["dur"] for e in inside) / g["dur"])
+            shares.append(
+                _union([(e["ts"], e["ts"] + e["dur"]) for e in inside]) / g["dur"])
+            busy.append(sum(e["dur"] for e in inside) / g["dur"])
             per = {}
             for e in inside:
                 per[e["name"]] = per.get(e["name"], 0.0) + 1e3 * e["dur"]
+                if "chunk" in e:
+                    by_chunk_leaf.setdefault(e["name"], []).append(1e3 * e["dur"])
             for name in LEAVES:
                 by_leaf.setdefault(name, []).append(per.get(name, 0.0))
+            chunks = {e["chunk"] for e in inside if "chunk" in e}
+            if chunks:
+                n_chunks.append(len(chunks))
         if shares:
             out[comp] = {
                 "gc_ot_spans": len(shares),
                 "share_median": statistics.median(shares),
                 "share_min": min(shares),
+                "busy_share_median": statistics.median(busy),
                 "leaf_ms_median": {k: statistics.median(v)
                                    for k, v in by_leaf.items() if any(v)},
             }
+            if n_chunks:
+                out[comp].update(
+                    chunked_spans=len(n_chunks),
+                    chunks_median=statistics.median(n_chunks),
+                    chunk_leaf_ms_median={
+                        k: statistics.median(v)
+                        for k, v in sorted(by_chunk_leaf.items())},
+                )
     return out
 
 

@@ -199,6 +199,67 @@ def test_iknp_extension(one_chip):
              off, m=m)
 
 
+@pytest.mark.parametrize("words", [4, 8], ids=["FE62", "F255"])
+def test_secure_level_chunk_programs(one_chip, words):
+    """What one chunk of a secure level dispatches on a server
+    (``rpc._ev_chunks`` / ``_gb_chunks``), at the chunk
+    ``secure.level_chunks`` cuts from the benchmark's steady level
+    (bucket 32 at N=16,384; the leaf level's F255 table is twice as
+    wide, so its chunk is half the tests): the cut of the flat strings,
+    both roles' rows of the extension, the planar table and its open.
+    Offsets and pad indices are traced scalars, so these are every
+    chunk's programs from bucket 16 up."""
+    b = 32 * 2 * N_SECURE
+    chunks = secure.level_chunks(b, S, words, "ot2s")
+    assert len(chunks) == (4 if words == 4 else 8)
+    n = chunks[0][1]
+    assert all(c[1] == n for c in chunks) and n % kernel_shard.BLOCK == 0
+    sds = _sds(one_chip)
+    at = sds((), jnp.int64)  # a Python int of the level's plan
+    _compile(secure._test_rows, sds((b, S), jnp.bool_), at, n=n)
+    m = n * S
+    seeds = sds((128, 4), jnp.uint32)
+    _compile(otext._receiver_extend, seeds, seeds, sds((m,), jnp.bool_),
+             at, m=m)
+    _compile(otext._sender_extend, seeds, sds((128,), jnp.bool_),
+             sds((128, m // 32), jnp.uint32), at, m=m)
+    text = _compile(
+        otext_pallas._enc_planar,
+        sds((n, S, 4), jnp.uint32), sds((4,), jnp.uint32),
+        sds((n, S), jnp.bool_), sds((n, words), jnp.uint32),
+        sds((n, words), jnp.uint32), at,
+        S=S, W=words, domain=secure._OT2S_DOMAIN, interpret=False,
+    )
+    assert "tpu_custom_call" in text
+    text = _compile(
+        otext_pallas._dec_planar,
+        sds((n, S, 4), jnp.uint32), sds((n, S), jnp.bool_),
+        sds(((1 << S) * words * n,), jnp.uint32), at,
+        S=S, W=words, domain=secure._OT2S_DOMAIN, interpret=False,
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_gc_chunk_garble(one_chip):
+    """The garbled-circuit path's chunk (no cell runs it): tests
+    ``[t0, t0 + n)`` of a level's packed garble, the labels carved from
+    the level's one draw at a traced offset."""
+    from fuzzyheavyhitters_tpu.ops import gc
+
+    b = 32 * 2 * N_SECURE
+    n = secure.level_chunks(b, S, W, "gc")[0][1]
+    sds = _sds(one_chip)
+    at = sds((), jnp.int64)
+    text = _compile(
+        gc._garble_rows_packed,
+        sds((4,), jnp.uint32), sds((n, S, 4), jnp.uint32),
+        sds((4,), jnp.uint32), sds((n, S), jnp.bool_),
+        sds((n, W), jnp.uint32), sds((n, W), jnp.uint32),
+        idx_offset=at, t0=at, n_words=W, B=b, pallas=True,
+    )
+    assert "tpu_custom_call" in text
+
+
 @pytest.mark.parametrize(
     "path,field", [("ot2s", "FE62"), ("ot2s", "F255"), ("gc", "FE62")]
 )

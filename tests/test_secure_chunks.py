@@ -1,0 +1,372 @@
+"""The secure level as a stream of row chunks (protocol/rpc.py
+``_ev_chunks`` / ``_gb_chunks``, ``secure.level_chunks``): a CPU pair
+over real sockets, ``secure.CHUNK_FRAME_BYTES`` patched small so that a
+level of a few planar blocks crosses in K > 1 chunks.
+
+What is held: each server's shares and both OT cursors after a chunked
+level are those of the level gone whole (K = 1), bit for bit, on both
+equality paths and with either server garbling; the chunks' frames put
+side by side are the whole level's two messages; ``secure_chunks``
+counts K; a plane cut mid-level fails the verb, leaves no task behind
+and the level run again gives the exact counts; a peer that cut the
+level differently gets ``ConnectionError`` and nobody hangs.
+"""
+
+import asyncio
+
+import numpy as np
+import pytest
+
+from fuzzyheavyhitters_tpu.ops import gc_pallas, ibdcf
+from fuzzyheavyhitters_tpu.ops.fields import F255, FE62
+from fuzzyheavyhitters_tpu.protocol import rpc, secure
+from fuzzyheavyhitters_tpu.protocol.leader_rpc import RpcLeader
+from fuzzyheavyhitters_tpu.utils import bits as bitutils
+from fuzzyheavyhitters_tpu.utils.config import Config
+
+BASE_PORT = 30731  # a range of its own, under the ephemeral ports
+BLOCK = gc_pallas.R_BLK * gc_pallas.GROUP  # tests of one planar block
+L = 3
+WHOLE = 1 << 40  # a frame budget no level of these tests reaches
+
+
+@pytest.fixture(autouse=True)
+def _module_cpu(cpu_default):
+    yield
+
+
+def _cfg(port, **kw):
+    return Config(
+        data_len=L, n_dims=1, ball_size=1, addkey_batch_size=1024,
+        num_sites=4, threshold=0.2, zipf_exponent=1.03,
+        server0=f"127.0.0.1:{port}", server1=f"127.0.0.1:{port + 10}",
+        distribution="zipf", f_max=64, secure_exchange=True, **kw,
+    )
+
+
+def _keys(n, seed=7):
+    rng = np.random.default_rng(seed)
+    pts = rng.integers(0, 1 << L, size=n)
+    bits = np.array([[bitutils.int_to_bits(L, int(v))] for v in pts])
+    return pts, ibdcf.gen_l_inf_ball(bits, 1, rng, engine="np")
+
+
+def _root_counts(pts):
+    """Clients whose ball [x-1, x+1] meets the left / the right half."""
+    half = 1 << (L - 1)
+    return np.array([np.sum(pts - 1 < half), np.sum(pts + 1 >= half)])
+
+
+class _Pair:
+    """Both servers, their clients and a leader in this process."""
+
+    def __init__(self, port, n, **cfg):
+        self.port, self.n = port, n
+        self.cfg = _cfg(port, **cfg)
+        self.pts, (self.k0, self.k1) = _keys(n)
+
+    async def __aenter__(self):
+        p = self.port
+        self.s0 = rpc.CollectorServer(0, self.cfg)
+        self.s1 = rpc.CollectorServer(1, self.cfg)
+        t1 = asyncio.create_task(
+            self.s1.start("127.0.0.1", p + 10, "127.0.0.1", p + 11)
+        )
+        await asyncio.sleep(0.05)
+        t0 = asyncio.create_task(
+            self.s0.start("127.0.0.1", p, "127.0.0.1", p + 11)
+        )
+        await asyncio.gather(t0, t1)
+        self.c0 = await rpc.CollectorClient.connect("127.0.0.1", p)
+        self.c1 = await rpc.CollectorClient.connect("127.0.0.1", p + 10)
+        lead = RpcLeader(self.cfg, self.c0, self.c1)
+        await lead._both("reset")
+        await lead.upload_keys(self.k0, self.k1)
+        return self
+
+    async def __aexit__(self, *exc):
+        for c in (self.c0, self.c1):
+            await c.aclose()
+        for s in (self.s0, self.s1):
+            await s.aclose()
+
+    @property
+    def sessions(self):
+        return self.s0._default(), self.s1._default()
+
+    async def both(self, verb, req=None):
+        return await asyncio.gather(
+            self.c0.call(verb, req), self.c1.call(verb, req)
+        )
+
+    def ot_state(self):
+        """Every cursor of both directions' OT sessions, and what seeds
+        a level's randomness."""
+        out = []
+        for cs in self.sessions:
+            for ep in (cs._ot_snd, cs._ot_rcv):
+                out.append((ep.consumed, ep.stream_offset))
+            out.append(cs._crawl_ctr)
+        return out
+
+    def set_ot_state(self, state):
+        it = iter(state)
+        for cs in self.sessions:
+            for ep, ctr in ((cs._ot_snd, "_sent"), (cs._ot_rcv, "_recv")):
+                sent, off = next(it)
+                setattr(ep, ctr, sent)
+                ep._off = off
+            cs._crawl_ctr = next(it)
+
+    async def level(self, garbler, last=False, path="auto"):
+        verb = "tree_crawl_last" if last else "tree_crawl"
+        req = {"level": L - 1 if last else 0, "garbler": garbler,
+               "ot_path": path}
+        return [np.asarray(v) for v in await self.both(verb, req)]
+
+
+def _run(coro):
+    return asyncio.run(coro)
+
+
+# B = F * 2 * N tests at the root level of a one-dimensional crawl
+_SHAPES = {
+    # one chunk exactly: the level goes whole
+    "one_chunk": dict(f=4, n=1024, blocks=1, K=1),
+    # one chunk and one planar block more
+    "chunk_plus_block": dict(f=4, n=3072, blocks=2, K=2),
+    # eight whole chunks
+    "K8": dict(f=8, n=4096, blocks=1, K=8),
+    # the last chunk ends inside a block, as the level does
+    "ragged_tail": dict(f=4, n=2500, blocks=1, K=3),
+}
+
+
+@pytest.mark.parametrize("garbler", [0, 1])
+@pytest.mark.parametrize("path", ["ot2s", "gc"])
+@pytest.mark.parametrize("shape", list(_SHAPES))
+def test_chunked_level_is_the_whole_level(monkeypatch, shape, path, garbler):
+    """Shares, cursors and frames of a level in K chunks against the
+    same level gone whole from the same OT state."""
+    sh = _SHAPES[shape]
+    port = BASE_PORT + 20 * (
+        list(_SHAPES).index(shape) * 4 + (path == "gc") * 2 + garbler
+    )
+    sent = []
+    real_send = rpc._send
+
+    async def spy(writer, obj, **kw):
+        if kw.get("counter") == "data_bytes_sent":
+            sent.append(obj[1])
+        await real_send(writer, obj, **kw)
+
+    monkeypatch.setattr(rpc, "_send", spy)
+    # a chunk of ``blocks`` planar blocks, by the larger frame's bytes
+    S, W = 2, secure.payload_words(FE62)
+    from fuzzyheavyhitters_tpu.parallel.kernel_shard import n_msg_planes
+
+    per_test = max(16 * S, 4 * n_msg_planes(path, S, W))
+    small = sh["blocks"] * BLOCK * per_test
+
+    async def run():
+        async with _Pair(port, sh["n"]) as pair:
+            await pair.both("tree_init", {"root_bucket": sh["f"]})
+            del sent[:]  # the session's handshake
+            before = pair.ot_state()
+            monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", WHOLE)
+            whole = await pair.level(garbler, path=path)
+            after_whole, frames_whole = pair.ot_state(), list(sent)
+            chunks0 = [
+                cs.obs.counter_value("secure_chunks", level=0)
+                for cs in pair.sessions
+            ]
+            pair.set_ot_state(before)
+            del sent[:]
+            monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", small)
+            cut = await pair.level(garbler, path=path)
+            chunks1 = [
+                cs.obs.counter_value("secure_chunks", level=0)
+                for cs in pair.sessions
+            ]
+            return (whole, after_whole, frames_whole, cut,
+                    pair.ot_state(), list(sent), chunks0, chunks1, pair.pts)
+
+    (whole, after_whole, frames_whole, cut, after_cut, frames_cut,
+     chunks0, chunks1, pts) = _run(run())
+    K = sh["K"]
+    # the same shares and the same cursors on each server
+    for a, b in zip(whole, cut):
+        assert a.dtype == b.dtype and np.array_equal(a, b)
+    assert after_cut == after_whole
+    assert chunks0 == [1, 1] and chunks1 == [1 + K, 1 + K]
+    # and they are shares of the exact counts
+    got = np.asarray(FE62.canon(FE62.sub(cut[0], cut[1])))
+    assert np.array_equal(got[0], _root_counts(pts)) and not got[1:].any()
+    # the frames: u then the table when whole, K of each in chunks
+    assert len(frames_whole) == 2 and len(frames_cut) == 2 * K
+    u_whole, msg_whole = sorted(frames_whole, key=lambda a: a.nbytes)
+    if K == 1:
+        u_cut, msg_cut = sorted(frames_cut, key=lambda a: a.nbytes)
+        assert np.array_equal(u_cut, u_whole)
+        assert np.array_equal(msg_cut, msg_whole)
+        return
+    assert all(isinstance(f, tuple) and f[1] == K for f in frames_cut)
+    us = [f[2] for f in frames_cut if f[2].ndim == 2]
+    msgs = [f[2] for f in frames_cut if f[2].ndim == 1]
+    assert [f[0] for f in frames_cut if f[2].ndim == 2] == list(range(K))
+    assert [f[0] for f in frames_cut if f[2].ndim == 1] == list(range(K))
+    # u: the level's column words in order
+    assert np.array_equal(np.concatenate(us, axis=1), u_whole)
+    # the message: the level's planar blocks in order, plane by plane
+    planes = n_msg_planes(path, S, W)
+    assert np.array_equal(
+        np.concatenate([m.reshape(planes, -1) for m in msgs], axis=1),
+        msg_whole.reshape(planes, -1),
+    )
+
+
+def test_leaf_level_in_chunks(monkeypatch):
+    """The leaf level (F255 shares, a table twice as wide a test) cut
+    by the same byte budget: the whole level's shares."""
+    port = BASE_PORT + 340
+    small = BLOCK * 4 * (1 << 2) * secure.payload_words(F255)
+
+    async def run():
+        async with _Pair(port, 3072) as pair:
+            await pair.both("tree_init", {"root_bucket": 4})
+            before = pair.ot_state()
+            monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", WHOLE)
+            whole = await pair.level(1, last=True)
+            pair.set_ot_state(before)
+            monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", small)
+            cut = await pair.level(1, last=True)
+            ks = [
+                cs.obs.counter_value("secure_chunks", level=L - 1)
+                for cs in pair.sessions
+            ]
+            return whole, cut, ks
+
+    whole, cut, ks = _run(run())
+    assert ks == [1 + 3, 1 + 3]
+    for a, b in zip(whole, cut):
+        assert a.shape[-1] == 8 and np.array_equal(a, b)
+
+
+def test_plane_cut_mid_level_fails_the_verb_and_the_retry_is_exact(monkeypatch):
+    """The plane closed under chunk 1's frame: both verbs fail, no chunk
+    task is left, and after a plane reset the same level gives the exact
+    counts."""
+    port = BASE_PORT + 360
+    monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", BLOCK * 64)
+    real = rpc.CollectorServer._dp_send
+    cut = {"armed": False}
+
+    async def cutting(self, cs, obj):
+        if cut["armed"] and isinstance(obj, tuple) and obj[0] == 1:
+            cut["armed"] = False
+            self._peer_writer.close()
+        await real(self, cs, obj)
+
+    monkeypatch.setattr(rpc.CollectorServer, "_dp_send", cutting)
+
+    async def run():
+        async with _Pair(port, 4096) as pair:
+            await pair.both("tree_init", {"root_bucket": 4})
+            cut["armed"] = True
+            tasks0 = asyncio.all_tasks()
+            res = await asyncio.wait_for(
+                asyncio.gather(
+                    pair.c0.call("tree_crawl", {"level": 0, "garbler": 0}),
+                    pair.c1.call("tree_crawl", {"level": 0, "garbler": 0}),
+                    return_exceptions=True,
+                ),
+                60,
+            )
+            await asyncio.sleep(0.05)
+            # a new connection's pump and handler may come, a chunk task
+            # may not stay
+            left = [
+                t for t in asyncio.all_tasks() - tasks0
+                if "_chunks" in repr(t.get_coro())
+            ]
+            await pair.both("plane_reset")
+            again = await pair.level(0)
+            ks = [
+                cs.obs.counter_value("secure_chunks", level=0)
+                for cs in pair.sessions
+            ]
+            return res, left, again, ks, pair.pts
+
+    res, left, again, ks, pts = _run(run())
+    assert all(isinstance(r, Exception) for r in res), res
+    assert not cut["armed"] and not left
+    assert ks == [8, 8]  # four chunks each time
+    got = np.asarray(FE62.canon(FE62.sub(again[0], again[1])))
+    assert np.array_equal(got[0], _root_counts(pts)) and not got[1:].any()
+
+
+def test_peer_with_another_cut_gets_connection_error(monkeypatch):
+    """One server cuts the level in four, its peer in two: the plane
+    fails, both verbs return an error, and nothing waits for ever."""
+    port = BASE_PORT + 380
+    real = secure.level_chunks
+    calls = []
+
+    def uneven(B, S, W, path):
+        calls.append(B)
+        monkeypatch.setattr(
+            secure, "CHUNK_FRAME_BYTES", BLOCK * 64 * (1 + len(calls) % 2)
+        )
+        return real(B, S, W, path)
+
+    monkeypatch.setattr(secure, "level_chunks", uneven)
+
+    async def run():
+        async with _Pair(port, 4096) as pair:
+            await pair.both("tree_init", {"root_bucket": 4})
+            res = await asyncio.wait_for(
+                asyncio.gather(
+                    pair.c0.call("tree_crawl", {"level": 0, "garbler": 0}),
+                    pair.c1.call("tree_crawl", {"level": 0, "garbler": 0}),
+                    return_exceptions=True,
+                ),
+                60,
+            )
+            return res, [
+                cs.obs.counter_value("secure_chunks", level=0)
+                for cs in pair.sessions
+            ]
+
+    res, ks = _run(run())
+    assert sorted(ks) == [2, 4]
+    assert all(isinstance(r, Exception) for r in res), res
+    assert any("ConnectionError" in str(r) and "chunk" in str(r) for r in res), res
+
+
+@pytest.mark.parametrize(
+    "B,S,W,path,want",
+    [
+        # the flagship's steady bucket: 32 nodes x 2 patterns x 16,384
+        (32 * 2 * 16384, 2, 4, "ot2s", [262144] * 4),
+        (16 * 2 * 16384, 2, 4, "ot2s", [262144] * 2),
+        (64 * 2 * 16384, 2, 4, "ot2s", [262144] * 8),
+        # buckets 2-8 go whole
+        (8 * 2 * 16384, 2, 4, "ot2s", [262144]),
+        (2 * 2 * 16384, 2, 4, "ot2s", [65536]),
+        # the leaf level's table is twice as wide a test
+        (32 * 2 * 16384, 2, 8, "ot2s", [131072] * 8),
+        # the garbled batch of S = 8: 97 words a test
+        (1 << 20, 8, 4, "gc", [40960] * 25 + [24576]),
+        # a tail that is no whole block
+        (262144 + 5, 2, 4, "ot2s", [262144, 5]),
+    ],
+)
+def test_level_chunks_from_the_level_dimensions(B, S, W, path, want):
+    """K and the boundaries at the shipped 16 MiB: whole planar blocks,
+    the larger frame at most the budget, every test once."""
+    assert secure.CHUNK_FRAME_BYTES == 16 << 20
+    chunks = secure.level_chunks(B, S, W, path)
+    assert [n for _, n in chunks] == want
+    assert [t0 for t0, _ in chunks] == list(np.cumsum([0] + want[:-1]))
+    assert all(t0 % BLOCK == 0 for t0, _ in chunks)
+    assert all(t0 * S % 512 == 0 and t0 * W % 16 == 0 for t0, _ in chunks)

@@ -862,6 +862,93 @@ def test_wire_and_transfer_spans_every_level(rng, trace_dir, secure):
             assert total <= g["dur"] + _EPS * len(inside), (comp, g["level"])
 
 
+def test_chunk_spans_carry_the_level_and_lie_inside_gc_ot(rng, trace_dir, monkeypatch):
+    """A secure level that crosses in K = 4 chunks (the frame budget
+    patched to one planar block): every span of a chunk says which, says
+    the level, and lies inside the level's gc_ot on its server; the
+    leaves of different chunks may overlap, so their sum is no longer
+    held under gc_ot; a level that goes whole labels nothing."""
+    from fuzzyheavyhitters_tpu.ops import gc_pallas
+    from fuzzyheavyhitters_tpu.protocol import secure
+
+    L, n, K = 5, 4096, 4
+    port = BASE_PORT + 440
+    k0, k1 = _client_keys(rng, L, n)
+    cfg = _cfg(port, secure_exchange=True, addkey_batch_size=1024)
+    block = gc_pallas.R_BLK * gc_pallas.GROUP
+    monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", block * 64)
+
+    async def run():
+        lead, c0, c1, live = await _bring_up(cfg, port)
+        await lead.upload_keys(k0, k1)
+        with tracemod.root("crawl"):
+            await lead._both("tree_init", {"root_bucket": 4})  # B = 4 blocks
+            await lead._both("tree_crawl", {"level": 0, "garbler": 0})
+            monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", 1 << 40)
+            await lead._both("tree_crawl", {"level": 1, "garbler": 1})
+        ks = {
+            name: s._default().obs.report()["counters"]["secure_chunks"]
+            for name, s in live.items()
+        }
+        rep = obsreport.run_report([s._default().obs for s in live.values()])
+        await _teardown((c0, c1), live)
+        return ks, rep
+
+    ks, rep = asyncio.run(run())
+    for srv in ("s0", "s1"):
+        assert ks[srv]["by_level"] == {"0": K, "1": 1}
+    assert rep["secure_kernels"]["chunks_by_level"] == {"0": K, "1": 1}
+    evs = _events(trace_dir)
+    assert tracemod.validate(evs)["ok"]
+    spans = [e for e in evs if e["ph"] == "X"]
+    by_id = {e["span"]: e for e in spans}
+    assert not [e for e in spans if "chunk" in e and e["level"] != 0]
+    for comp in ("server0", "server1"):
+        mine = [e for e in spans if e["comp"] == comp and e.get("level") == 0]
+        (g,) = [e for e in mine if e["name"] == "gc_ot"]
+        lo, hi = g["ts"] - _EPS, g["ts"] + g["dur"] + _EPS
+        chunked = [e for e in mine if "chunk" in e]
+        # every chunk, in each span a role records once a chunk
+        for name in ("otext", "b2a", "d2h", "h2d", "wire_write", "wire_wait",
+                     "peer_wait", "wire_read"):
+            got = sorted(e["chunk"] for e in chunked if e["name"] == name)
+            assert got == list(range(K)), (comp, name, got)
+        for e in chunked:
+            assert e["name"] in _LEAVES + ("wire_wait",), e
+            # (a frame's read is stamped by the pump, which may take it
+            # off the socket before this server's gc_ot began)
+            if e["name"] not in ("peer_wait", "wire_read", "wire_unpickle"):
+                assert lo <= e["ts"] and e["ts"] + e["dur"] <= hi, (comp, e)
+            # under the gc_ot: directly, or through its wire_wait
+            up = by_id[e["parent"]]
+            if up["name"] == "wire_wait":
+                assert up["chunk"] == e["chunk"]
+                up = by_id[up["parent"]]
+            assert up is g, (comp, e["name"])
+        # nothing of the level's exchange went unlabelled
+        assert not [
+            e for e in mine if e["name"] in ("otext", "b2a", "wire_wait")
+            and "chunk" not in e
+        ]
+    # scripts/trace_spans.py: what the leaves cover is the union of
+    # their intervals (their sum may pass the span), and the chunks count
+    import importlib.util
+    import os
+
+    spec = importlib.util.spec_from_file_location(
+        "trace_spans", os.path.join(
+            os.path.dirname(os.path.dirname(__file__)), "scripts", "trace_spans.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    assert mod._union([(0, 2), (1, 3), (5, 6), (5.5, 5.75)]) == 4
+    for row in mod.gc_ot_cover(spans).values():
+        assert row["gc_ot_spans"] == 2 and row["chunked_spans"] == 1
+        assert row["chunks_median"] == K
+        assert 0 < row["share_min"] <= row["share_median"] <= 1 + 1e-3
+        assert row["busy_share_median"] >= row["share_median"] - 1e-9
+        assert {"otext", "b2a", "d2h"} <= set(row["chunk_leaf_ms_median"])
+
+
 def test_wire_spans_of_a_frame_with_two_out_of_band_buffers(trace_dir, monkeypatch):
     """One data-plane frame of two raw buffers over a loopback socket,
     through the real pump: the sender's ``wire_pickle`` and
