@@ -84,7 +84,7 @@ _DEFAULT_GUARDS = {
 # the key material; the analyzer reports the key as the source label.
 _DEFAULT_TAINT = {
     # per-session secrets (protocol/sessions.py; read through the
-    # rpc/mesh delegation properties under the same attr names)
+    # rpc delegation properties under the same attr names)
     "CollectionSession._sec_seed": "per-session GC/b2a PRG root seed",
     "CollectionSession._sketch_seed": "sketch challenge coin (server-server secret)",
     "CollectionSession._ratchet_digest": "crawl transcript ratchet digest",
@@ -286,7 +286,6 @@ class LintConfig:
         "instant",
         "span",
         "call_event",
-        "fire",
         "_fire",
         "count",
         "gauge",
@@ -305,11 +304,7 @@ class LintConfig:
     taint_declassifiers: tuple = (
         "ot2s_encrypt",
         "ot2s_encrypt_packed",
-        "ot4_encrypt",
-        "b2a_encrypt",
-        "ev_open_ot4",
         "ev_open_level",
-        "ev_open_fused",
         "window_root",
         "np_add",
     )

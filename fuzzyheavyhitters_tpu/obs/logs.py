@@ -16,8 +16,8 @@ coerced to plain Python numbers so the lines round-trip through
 ``json.loads``).
 
 The stream defaults to stderr so stdout stays a clean program-output
-channel (bench.py's contract is "the last stdout line is the JSON
-result"); ``FHH_LOG_STREAM`` accepts ``stdout`` / ``stderr`` / a file
+channel (benchmark/run.py's contract is "the last stdout line is the
+JSON result"); ``FHH_LOG_STREAM`` accepts ``stdout`` / ``stderr`` / a file
 path.  Severity gating (``FHH_LOG_LEVEL``, default ``info``) is what
 lets the per-level phase breakdown ride at ``debug`` without spamming a
 512-level crawl's console.

@@ -1,7 +1,7 @@
 """End-of-run machine-readable report.
 
 One JSON document aggregating every registry's snapshot — the artifact
-bench.py and postmortems consume instead of scraping stdout.  Schema
+postmortems consume instead of scraping stdout.  Schema
 (``fhh-run-report/1``)::
 
     {
@@ -680,7 +680,7 @@ def maybe_write_run_report(registries=None) -> str | None:
 
 def per_process_report_path(path: str, tag: str) -> str:
     """``/tmp/r.json`` + ``s0`` -> ``/tmp/r.s0.json``.  Multi-process
-    deployments (socket servers, 2-process mesh) inherit ONE
+    deployments (the two socket servers and their leader) inherit ONE
     ``FHH_RUN_REPORT`` path from the shared environment, and each process
     writes the whole document atomically at exit — without a per-process
     suffix the last exiter silently clobbers the other parties' reports."""

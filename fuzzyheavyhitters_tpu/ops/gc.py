@@ -244,7 +244,7 @@ def _carve_label_words_shard(seed, B: int, S: int, t0, bloc: int):
 def _garble_core(R, X0, Y0, mask, x_bits):
     """Shared garbling core: labels + offset in, (batch, output zero-labels)
     out — ``out0`` is what payload delivery hashes (see
-    :func:`garble_equality_payload`)."""
+    :func:`_garble_packed_planes_xla`)."""
     B = x_bits.shape[0]
     Z0 = X0 ^ Y0 ^ R  # XNOR relabel (free): Z0_i = X0_i ^ Y0_i ^ R
     out0, tables = _and_tree_garble(Z0, jnp.broadcast_to(R, (B, 4)))
@@ -308,69 +308,6 @@ def eval_equality(batch: GarbledEqBatch, ev_labels: jax.Array) -> jax.Array:
     return _lsb(out) ^ batch.decode
 
 
-def garble_equality_payload(R, Y0, seed, x_bits, m_v0, m_v1,
-                            n_words: int, idx_offset):
-    """Engine dispatcher — the fused Pallas kernel on a real chip (module
-    flag ``GC_PALLAS``), the XLA program otherwise; outputs are bit-exact
-    either way.  See :func:`_garble_equality_payload_xla` for semantics."""
-    if jnp.asarray(x_bits).shape[1] >= 2 and _pallas_engine():
-        from . import gc_pallas
-
-        return gc_pallas.garble_equality_payload(
-            R, Y0, seed, x_bits, m_v0, m_v1, n_words, idx_offset
-        )
-    return _garble_equality_payload_xla(
-        R, Y0, seed, x_bits, m_v0, m_v1, n_words, idx_offset
-    )
-
-
-def eval_equality_payload(batch: GarbledEqBatch, ev_labels, cts,
-                          n_words: int, idx_offset):
-    """Engine dispatcher twin of :func:`garble_equality_payload`."""
-    if batch.gb_labels.shape[1] >= 2 and _pallas_engine():
-        from . import gc_pallas
-
-        return gc_pallas.eval_equality_payload(
-            batch, ev_labels, cts, n_words, idx_offset
-        )
-    return _eval_equality_payload_xla(batch, ev_labels, cts, n_words, idx_offset)
-
-
-@partial(jax.jit, static_argnames=("n_words",))
-def _garble_equality_payload_xla(R, Y0, seed, x_bits, m_v0, m_v1,
-                                 n_words: int, idx_offset):
-    """:func:`garble_equality_delta` + payload delivery riding the OUTPUT
-    wire labels: the evaluator's garbled output label IS its 1-of-2 OT
-    choice, so the separate b2a OT round (and with it a full protocol
-    round trip) disappears.
-
-    m_v0/m_v1: uint32[B, n_words] — the payload the evaluator must learn
-    when the output wire carries semantic value 0 / 1 (value 1 = strings
-    equal).  Ciphertexts are indexed by the label's select (lsb) bit and
-    encrypted under ``H(out_label, idx)`` with the OT-domain hash — the
-    same circular-correlation-robustness assumption the Δ-OT pads already
-    rest on (labels differ by R = s).  ``idx_offset`` must be unique per
-    (session, batch) like any OT pad index; the caller uses the extension
-    session's consumed counter.
-
-    Returns (batch, cts uint32[2, B, n_words], mask bool[B]).
-    """
-    from .otext import ot_hash
-
-    x_bits = jnp.asarray(x_bits, bool)
-    B, S = x_bits.shape
-    _, (X0,), mask = _carve_label_words(seed, B, S, 1, with_r=False)
-    R = jnp.asarray(R, jnp.uint32)
-    batch, out0 = _garble_core(R, X0, jnp.asarray(Y0, jnp.uint32), mask, x_bits)
-    h0 = ot_hash(out0, n_words, idx_offset)  # pad for the v=0 label
-    h1 = ot_hash(out0 ^ R, n_words, idx_offset)
-    c_v0 = jnp.asarray(m_v0, jnp.uint32) ^ h0
-    c_v1 = jnp.asarray(m_v1, jnp.uint32) ^ h1
-    p = _lsb(out0)[:, None]  # select bit of the v=0 label
-    cts = jnp.stack([jnp.where(p, c_v1, c_v0), jnp.where(p, c_v0, c_v1)])
-    return batch, cts, mask
-
-
 @partial(jax.jit, static_argnames=("n_words",))
 def _eval_equality_payload_xla(batch: GarbledEqBatch, ev_labels, cts,
                                n_words: int, idx_offset):
@@ -421,7 +358,17 @@ def _garble_packed_planes_xla(R, Y0, X0, mask, x_bits, m_v0, m_v1,
                               n_words: int, idx_offset):
     """The packed-garble math AFTER label carving: every input already at
     the full planar extent (``x_bits.shape[0]`` a multiple of the planar
-    block, pad slots zero).  Shared by the single-device twin below
+    block, pad slots zero).  Payload delivery rides the OUTPUT wire
+    labels: the evaluator's garbled output label IS its 1-of-2 OT choice,
+    so no separate b2a OT round is needed.  m_v0/m_v1 uint32[bp,
+    n_words] are what the evaluator must learn when the output wire
+    carries semantic value 0 / 1 (value 1 = strings equal); the two
+    ciphertexts are indexed by the label's select (lsb) bit and encrypted
+    under ``H(out_label, idx)`` with the OT-domain hash — the same
+    circular-correlation-robustness assumption the Δ-OT pads already
+    rest on (labels differ by R = s).  ``idx_offset`` must be unique per
+    (session, batch) like any OT pad index; the caller uses the extension
+    session's consumed counter.  Shared by the single-device twin below
     (which carves then pads) and the row-sharded kernel stage
     (parallel/kernel_shard.py — each shard feeds its
     :func:`_carve_label_words_shard` slice and a TRACED ``idx_offset``),

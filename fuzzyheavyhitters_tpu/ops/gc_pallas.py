@@ -1,12 +1,12 @@
 """Fused Pallas garbling/evaluation — the secure level's dominant chip op.
 
-``gc.garble_equality_payload`` / ``gc.eval_equality_payload`` (the
-output-label-b2a flow every secure deployment path ships) are
+``gc._garble_equality_payload_packed_xla`` / ``gc._eval_..._packed_xla``
+(the output-label-b2a flow every secure deployment path ships) are
 glue-bound as XLA programs, exactly like the round-4 expand engine was:
 the hash math is a handful of ChaCha permutations per test, but every
 stacked ``_hash_many`` call, ``_maskw`` select, table stack, and pad XOR
 materializes another ``[B, 4]`` tensor in HBM.  Measured on-chip
-(bench.bench_hash_margin, BENCH_r04): garbling cost is nearly flat in
+(round 4 of the plug-in era): garbling cost is nearly flat in
 the ChaCha round count — i.e. it is bandwidth, not cipher arithmetic.
 
 This module runs the WHOLE garble (resp. eval) batch as one kernel in
@@ -22,8 +22,9 @@ vreg, the AND-tree unrolled over wire planes in-kernel:
 Randomness stays OUTSIDE the kernel: the garbler's own labels + mask
 bits come from the same ``gc._carve_label_words`` stream draw as the XLA
 engine, so both engines are BIT-EXACT for identical inputs — the parity
-test compares entire ``GarbledEqBatch``es (tests/test_gc_pallas.py), and
-a mid-crawl engine switch is sound (the wire format does not change).
+tests compare the whole planar message (tests/test_secure_kernels.py in
+interpret mode, tests/test_gc_pallas.py compiled), and a mid-crawl
+engine switch is sound (the wire format does not change).
 
 Ref seam: src/equalitytest.rs:25-191 (the per-core swanky garbler this
 batched kernel replaces) driven from src/collect.rs:419-482.
@@ -40,7 +41,7 @@ import numpy as np
 from . import gc, otext
 from .keygen_pallas import LANES, SUB, _chacha16
 
-R_BLK = 8  # row-groups per grid step (sweep note: bench.bench_secure_device)
+R_BLK = 8  # row-groups per grid step
 GROUP = SUB * LANES  # tests per row
 
 
@@ -248,8 +249,7 @@ def _garble_call(R, Y0, X0, mask, x_bits, m_v0, m_v1, idx_offset,
                  S: int, W: int, interpret: bool):
     """Shared pallas_call builder: planarize inputs, run the garble
     kernel, return the RAW planar outputs [tables, gb_labels, decode,
-    cts] — the packed wire path ravels them as-is; the compat path
-    unplanarizes back to test-major tensors."""
+    cts] — the packed wire path ravels them as-is."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -295,20 +295,6 @@ def _garble_call(R, Y0, X0, mask, x_bits, m_v0, m_v1, idx_offset,
 
 
 @partial(jax.jit, static_argnames=("S", "W", "interpret"))
-def _garble_planar(R, Y0, X0, mask, x_bits, m_v0, m_v1, idx_offset,
-                   S: int, W: int, interpret: bool):
-    B = x_bits.shape[0]
-    outs = _garble_call(R, Y0, X0, mask, x_bits, m_v0, m_v1, idx_offset,
-                        S, W, interpret)
-    tables = _unplanarize(outs[0], B).reshape(B, S - 1, 2, 4)
-    gb_labels = _unplanarize(outs[1], B).reshape(B, S, 4)
-    decode = _unplanarize(outs[2], B).reshape(B) != 0
-    cts = _unplanarize(outs[3], B).reshape(B, 2, W).transpose(1, 0, 2)
-    return gc.GarbledEqBatch(tables=tables, gb_labels=gb_labels,
-                             decode=decode), cts
-
-
-@partial(jax.jit, static_argnames=("S", "W", "interpret"))
 def _garble_packed(R, Y0, X0, mask, x_bits, m_v0, m_v1, idx_offset,
                    S: int, W: int, interpret: bool):
     """Whole-level fused garble→pack: the kernel's planar outputs ravel
@@ -348,27 +334,6 @@ def _eval_call(sc, gbl, evl, tab, dec, cts, S: int, W: int,
     )(sc, gbl, evl, tab, dec, cts)
 
 
-@partial(jax.jit, static_argnames=("S", "W", "interpret"))
-def _eval_planar(tables, gb_labels, decode, ev_labels, cts, idx_offset,
-                 S: int, W: int, interpret: bool):
-    B = gb_labels.shape[0]
-    bp = padded_tests(B)
-    sc = jnp.asarray(idx_offset, jnp.uint32).reshape(1)
-    outs = _eval_call(
-        sc,
-        _planarize(gb_labels, B, bp),
-        _planarize(ev_labels, B, bp),
-        _planarize(tables, B, bp),
-        _planarize(jnp.asarray(decode, jnp.uint32), B, bp),
-        _planarize(jnp.transpose(jnp.asarray(cts, jnp.uint32), (1, 0, 2)),
-                   B, bp),
-        S, W, interpret,
-    )
-    e = _unplanarize(outs[0], B).reshape(B) != 0
-    pay = _unplanarize(outs[1], B).reshape(B, W)
-    return e, pay
-
-
 def _split_packed(msg, B: int, S: int, W: int):
     """Packed wire buffer -> the four planar plane stacks (pure reshapes
     of contiguous slices — no transposes)."""
@@ -400,43 +365,6 @@ def _eval_packed(msg, ev_labels, idx_offset, S: int, W: int,
     e = _unplanarize(outs[0], B).reshape(B) != 0
     pay = _unplanarize(outs[1], B).reshape(B, W)
     return e, pay
-
-
-def garble_equality_payload(R, Y0, seed, x_bits, m_v0, m_v1,
-                            n_words: int, idx_offset, interpret: bool = False):
-    """Drop-in for :func:`gc.garble_equality_payload` — bit-exact.
-
-    The garbler's own labels + mask come from the SAME PRG stream draw
-    (gc._carve_label_words), so the emitted batch, ciphertexts, and mask
-    are word-for-word identical to the XLA engine's."""
-    x_bits = jnp.asarray(x_bits, bool)
-    B, S = x_bits.shape
-    if S < 2:  # S=1 has no AND gates; the XLA form covers it (gc.py's
-        # dispatcher never routes it here)
-        raise ValueError("gc_pallas requires S >= 2 wire strings")
-    _, (X0,), mask = gc._carve_label_words(seed, B, S, 1, with_r=False)
-    batch, cts = _garble_planar(
-        jnp.asarray(R, jnp.uint32), jnp.asarray(Y0, jnp.uint32), X0, mask,
-        x_bits, jnp.asarray(m_v0, jnp.uint32), jnp.asarray(m_v1, jnp.uint32),
-        idx_offset, S, n_words, interpret,
-    )
-    return batch, cts, mask
-
-
-def eval_equality_payload(batch: gc.GarbledEqBatch, ev_labels, cts,
-                          n_words: int, idx_offset, interpret: bool = False):
-    """Drop-in for :func:`gc.eval_equality_payload` — bit-exact."""
-    B, S = batch.gb_labels.shape[:2]
-    if S < 2:
-        raise ValueError("gc_pallas requires S >= 2 wire strings")
-    return _eval_planar(
-        jnp.asarray(batch.tables, jnp.uint32),
-        jnp.asarray(batch.gb_labels, jnp.uint32),
-        jnp.asarray(batch.decode),
-        jnp.asarray(ev_labels, jnp.uint32),
-        jnp.asarray(cts, jnp.uint32),
-        idx_offset, S, n_words, interpret,
-    )
 
 
 def garble_equality_payload_packed(R, Y0, seed, x_bits, m_v0, m_v1,

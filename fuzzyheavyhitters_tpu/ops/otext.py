@@ -173,32 +173,6 @@ def receiver_extend_rows(seeds0, seeds1, choices, base_off, row0, m: int):
     )
 
 
-# Fused extension+hash: the column PRG, the u-XOR, the packed butterfly
-# transpose, and the chosen-payload pad hash of one batch as a SINGLE
-# jitted program per role — one device dispatch, no [m, 4] row tensor
-# round-tripping HBM between a separately-dispatched extend and its
-# ot_hash (the three-dispatch shape the per-level b2a flow used to run).
-# The stream offset and pad index base enter as TRACED scalars, so batch
-# N+1 of a session reuses the compiled program — per-batch bookkeeping
-# never recompiles and never syncs the host.
-
-
-@partial(jax.jit, static_argnames=("m", "n_words", "domain"))
-def _receiver_extend_pads(seeds0, seeds1, choices, offset, idx0, m,
-                          n_words, domain):
-    u, t = _receiver_extend_core(seeds0, seeds1, choices, offset, m)
-    return u, t, ot_hash(t, n_words, idx0, domain=domain)
-
-
-@partial(jax.jit, static_argnames=("m", "n_words", "domain"))
-def _sender_extend_pads(seeds, s_bits, s_block, u, offset, idx0, m,
-                        n_words, domain):
-    q = _sender_extend_core(seeds, s_bits, u, offset, m)
-    p0 = ot_hash(q, n_words, idx0, domain=domain)
-    p1 = ot_hash(q ^ s_block[None, :], n_words, idx0, domain=domain)
-    return q, p0, p1
-
-
 @partial(jax.jit, static_argnames=("n_words", "domain"))
 def ot_hash(rows: jax.Array, n_words: int, idx_offset=0,
             domain: int = 0) -> jax.Array:
@@ -370,20 +344,6 @@ class OtExtSender:
         p1 = ot_hash(q_rows ^ jnp.asarray(self.s_block), n_words, idx_offset)
         return p0, p1
 
-    def extend_pads(self, m: int, u_msg, n_words: int, domain: int = 0):
-        """:meth:`extend` + :meth:`pads` as ONE jitted program: returns
-        (Q rows uint32[m, 4], pad0, pad1 uint32[m, n_words]).  The pad
-        index base is this batch's pre-extension ``consumed`` counter —
-        the same convention every chosen-payload flow uses — folded in
-        on device, so extension and hash share one dispatch and the
-        rows never surface between them."""
-        q, p0, p1 = _sender_extend_pads(
-            self._seeds, self._s_dev, jnp.asarray(self.s_block),
-            jnp.asarray(u_msg), self._off, self._sent, m, n_words, domain,
-        )
-        self.advance(m)
-        return q, p0, p1
-
 
 class OtExtReceiver:
     """Extension receiver: holds both base-seed columns (it played base-OT
@@ -444,20 +404,6 @@ class OtExtReceiver:
         """uint32[m, n_words] — the receiver's chosen pad H(j, T_j)."""
         return ot_hash(t_rows, n_words, idx_offset)
 
-    def extend_pads(self, choices, n_words: int, domain: int = 0):
-        """:meth:`extend` + :meth:`pads` as ONE jitted program: returns
-        (u message, T rows uint32[m, 4], pad uint32[m, n_words]) with the
-        pad index base = this batch's pre-extension ``consumed`` counter
-        (the sender's :meth:`OtExtSender.extend_pads` twin)."""
-        choices = jnp.asarray(choices, bool)
-        m = choices.shape[0]
-        u, t, pad = _receiver_extend_pads(
-            self._seeds0, self._seeds1, choices, self._off, self._recv,
-            m, n_words, domain,
-        )
-        self.advance(m)
-        return u, t, pad
-
 
 def fresh_s_bits(rng: secrets.SystemRandom | None = None) -> np.ndarray:
     """Random sender choice vector with lsb forced to 1 (free-XOR ready)."""
@@ -468,7 +414,7 @@ def fresh_s_bits(rng: secrets.SystemRandom | None = None) -> np.ndarray:
 
 
 def inprocess_pair() -> tuple[OtExtSender, OtExtReceiver]:
-    """Run the base-OT setup in-process (tests / colocated mesh parties)."""
+    """Run the base-OT setup in-process (tests, the secure warm-up)."""
     s_bits = fresh_s_bits()
     seeds0, seeds1, chosen = baseot.exchange(s_bits)
     return OtExtSender(s_bits, chosen), OtExtReceiver(seeds0, seeds1)

@@ -4,8 +4,7 @@ Where the per-level OT cost actually lives after the whole-level
 restructure (protocol/secure.py): not in the IKNP matrix itself — the
 column PRG, u-XOR, and packed butterfly transpose already run as ONE
 jitted XLA program per extension (``otext._receiver_extend`` /
-``_sender_extend``, with ``extend_pads`` fusing the pad hash into the
-same dispatch) — but in the chosen-payload stage that multiplies per
+``_sender_extend``) — but in the chosen-payload stage that multiplies per
 test: the 1-of-2^S equality OT hashes 2^S pads per test and builds the
 ciphertext table, which as glue-bound XLA ops materializes a fresh
 ``[2^S, B, ...]`` tensor per step (comb, offsets broadcast, pads,

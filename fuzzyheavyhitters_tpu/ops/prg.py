@@ -32,7 +32,7 @@ the reference — a correlation-robust hash for FSS (Guo et al. 2020 model).
 fixed-key AES (a reduced-round fixed-key cipher as CR hash): the best
 public distinguisher on ChaCha is on 7 rounds, so 8 keeps a one-round
 margin in a model where the adversary does not even control the key.
-Measured cost of more margin (bench.bench_hash_margin, v5e, BENCH_r04):
+Measured cost of more margin (v5e, round 4 of the plug-in era):
 in the GC/OT hash role garbling is bandwidth-bound, so 12/20 rounds cost
 only +3% / +6% (18.7 -> 19.3 / 19.8 ms per 262144-wire garble) — an
 operator wanting standard-cipher margins can raise ``N_ROUNDS`` to 20
@@ -55,7 +55,7 @@ SEED_WORDS = 4  # 128-bit seeds as uint32[..., 4], little-endian word order
 N_ROUNDS = 8  # ChaCha double-round count = N_ROUNDS // 2
 
 # Round-loop form, read at TRACE time (set before the first jit call in the
-# process; bin/server.py and bench.py set it for the TPU backend):
+# process; bin/server.py sets it for the TPU backend):
 #   False -> lax.scan over double-rounds: ~4x smaller HLO per call site, the
 #            right default on compile-bound hosts (XLA:CPU on small cores);
 #   True  -> unrolled rounds: ~6% faster keygen on the TPU chip.

@@ -1,10 +1,7 @@
 """Per-server multi-chip execution: the client axis sharded over a
 LOCAL 1-D device mesh.
 
-parallel/mesh.py is the WHOLE-PROTOCOL mesh (2-D ``{'servers': 2,
-'data': k}``: both parties on one runtime, the inter-party exchange as
-``ppermute`` collectives) — a single trust domain.  This module is the
-production complement for the two-administrative-domain deployment
+The multi-chip shape of the two-administrative-domain deployment
 (protocol/rpc.py sockets): each :class:`CollectorServer` keeps its OWN
 pjit mesh over its OWN chips and shards only the client axis across
 them.  The paper's crawl cost is linear in clients per level (every

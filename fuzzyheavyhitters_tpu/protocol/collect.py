@@ -111,9 +111,8 @@ def check_radix(d: int, radix: int) -> None:
 # the flag handling INTO the kernel (packed u32 emitted in-kernel, cw
 # broadcast over nodes via a modular BlockSpec index map) — the round-4
 # prototype the round-4 VERDICT asked to land.  NB the shared chip's
-# throughput swings ~4x by hour; only back-to-back A/Bs are meaningful —
-# bench.py's crawl section measures both engines back to back.  The
-# engine — and with it the frontier state LAYOUT — is read at tree_init /
+# throughput swings ~4x by hour; only back-to-back A/Bs are meaningful.
+# The engine — and with it the frontier state LAYOUT — is read at tree_init /
 # expand / advance time and must not flip mid-crawl.
 EXPAND_PALLAS: bool = True
 

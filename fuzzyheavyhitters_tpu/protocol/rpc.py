@@ -20,8 +20,7 @@ communication backend"):
   share-bit tensors per level (server1 listens on ``port+1``, server0 dials
   with retries — the reference's GC-mesh bootstrap order, server.rs:197-262,
   collapsed from ``num_cpus`` sockets to one because the exchange is a single
-  batched tensor, not per-thread GC traffic).  On a shared TPU pod the same
-  exchange rides ICI via parallel/mesh.py instead.
+  batched tensor, not per-thread GC traffic).
 
 Counts come back as **field-element shares**: both servers derive a common
 pseudorandom mask stream from a shared seed — the reference hardcodes the
@@ -1477,9 +1476,9 @@ class CollectorServer:
 
     async def _mesh_guard(self, cs, level, thunk):
         """Device-loss containment for the multi-chip server: fire any
-        scheduled mesh chaos at the crawl boundary (the same consumed-
-        once :class:`resilience.chaos.MeshChaos` schedule the 2-D mesh
-        path uses), and on a mesh fault recover IN PLACE — a lost device
+        scheduled mesh chaos at the crawl boundary (the consumed-once
+        :class:`resilience.chaos.MeshChaos` schedule), and on a mesh
+        fault recover IN PLACE — a lost device
         is NOT a lost server.  ``state_lost`` (kill) re-shards the
         frontier from the newest on-disk checkpoint and rebuilds the
         keys from the host-side upload chunks; a suspect collective

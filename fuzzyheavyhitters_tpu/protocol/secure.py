@@ -23,16 +23,23 @@ TPU-native shape: everything is batched device tensors —
 - per-node share sums are alive-gated field reductions on device
   (collect.rs:487-501's ``add_lazy`` loop as one ``field.sum``).
 
-The step functions here are sans-IO.  protocol/rpc.py strings the
-WHOLE-LEVEL flow over the data-plane socket (ev u-matrix → gb planar
-message — the 1-of-2^S payload table for S ≤ ``OT2S_MAX_S``, else the
-packed garbled batch with the b2a payloads riding the output labels —
-ONE round trip and ONE fused device program per side per level, see
-``gb_step_level``/``ev_open_level`` below; the older flat-wire
-``gb_step_fused``/``gb_step_ot4`` forms remain as parity oracles);
-parallel/mesh.py runs the explicit two-round math (ev u → gb batch →
-ev b2a u → gb ciphertexts) with ``ppermute`` transfers on the 2-chip
-axis, where an extra round costs microseconds, not a host round trip.
+The step functions here are sans-IO, and the level has TWO forms, both
+emitting the PLANAR wire (the 1-of-2^S payload table for S ≤
+``OT2S_MAX_S``, else the packed garbled batch with the b2a payloads
+riding the output labels):
+
+- the CHUNK functions (``level_chunks``, ``chunk_rows``,
+  ``gb_chunk_msg``, ``ev_chunk_words``) are what protocol/rpc.py
+  dispatches: a level crosses the data-plane socket as K frames a
+  direction, each a run of whole planar blocks (K = 1 is one round
+  trip of one message a side);
+- the WHOLE-LEVEL trio (``ev_step1_fused``, ``gb_step_level`` /
+  ``ev_open_level``) is the same level as ONE message from one call a
+  side.  Nothing in the package calls it: it is the oracle the chunk
+  tests (tests/test_secure_chunks.py), the engine-parity tests
+  (tests/test_secure_kernels.py) and the row-shard tests
+  (tests/test_kernel_shard.py) hold the served functions to, bit for
+  bit.
 
 Wire-share semantics: the garbler's per-test share is ``r1 = r0 ± 1``
 (+1 when server 0 garbles, −1 when server 1 does — the garbler flips per
@@ -155,45 +162,9 @@ def derive_seed(base: np.ndarray, purpose: int, level: int, ctr: int = 0) -> np.
 
 
 # ---------------------------------------------------------------------------
-# Protocol steps (sans-IO).  Roles: garbler = server 0 (gc_sender=true,
-# ref: leader.rs:204-205 pins the role per request), evaluator = server 1.
+# The b2a share pair.  Roles: the garbler/sender flips per level (ref:
+# leader.rs:204-205 pins the role per request), the peer evaluates/receives.
 # ---------------------------------------------------------------------------
-
-
-def ev_step1(rcv: otext.OtExtReceiver, y_flat):
-    """Evaluator: request input labels.  y_flat bool[B, S] -> (u message,
-    T rows uint32[B*S, 4] — the Δ-OT labels-to-be).  ``y_flat`` may stay
-    a DEVICE array — fetching it first is a blocking device->host fetch
-    and the extension consumes it on device anyway."""
-    B, S = y_flat.shape
-    u, t = rcv.extend(jnp.reshape(jnp.asarray(y_flat), (B * S,)))
-    return u, t
-
-
-def gb_step1(snd: otext.OtExtSender, u_msg, x_flat, gc_seed):
-    """Garbler: derive evaluator zero-labels from the extension and garble.
-
-    Returns (batch to send, mask bool[B] — the garbler's XOR shares)."""
-    B, S = x_flat.shape
-    q = snd.extend(B * S, u_msg)
-    y0 = q.reshape(B, S, 4)
-    return gc.garble_equality_delta(
-        jnp.asarray(snd.s_block), y0, jnp.asarray(gc_seed), x_flat
-    )
-
-
-def ev_step2(batch: gc.GarbledEqBatch, t_rows, B: int, S: int) -> jax.Array:
-    """Evaluator: labels are the Δ-OT T rows; evaluate -> XOR shares bool[B]."""
-    return gc.eval_equality(batch, jnp.asarray(t_rows).reshape(B, S, 4))
-
-
-def ev_step3(rcv: otext.OtExtReceiver, e_bits):
-    """Evaluator: open the b2a OT with its GC output shares as choices.
-    Returns (u message, T2 rows, idx0 — the pad tweak base).  ``e_bits``
-    may stay a device array (see ev_step1)."""
-    idx0 = rcv.consumed
-    u2, t2 = rcv.extend(jnp.asarray(e_bits))
-    return u2, t2, idx0
 
 
 def b2a_payload_pair(field, b2a_seed, B: int, garbler: int, t0: int = 0):
@@ -216,60 +187,6 @@ def b2a_payload_pair(field, b2a_seed, B: int, garbler: int, t0: int = 0):
     one = field.from_int(1)
     r1 = field.sub(r0, one) if garbler else field.add(r0, one)
     return r1, field_to_words(field, r0), field_to_words(field, r1)
-
-
-def b2a_encrypt(field, q2_rows, s_block, mask, b2a_seed, idx0, garbler: int = 0):
-    """Stateless b2a sender core: sample (r0, r1 = r0 ± 1), order payloads
-    by ``mask`` (collect.rs:439-456), encrypt under the OT pads derived
-    from the Q rows.  Returns (c0, c1 ciphertext words [B, W], r1 — the
-    sender's additive shares).  Shared by the socket path (gb_step2) and
-    the mesh kernel (parallel/mesh.py) so the trick lives in exactly one
-    place."""
-    mask = jnp.asarray(mask, bool)
-    B = mask.shape[0]
-    W = payload_words(field)
-    q2_rows = jnp.asarray(q2_rows)
-    pad0 = otext.ot_hash(q2_rows, W, idx0)
-    pad1 = otext.ot_hash(q2_rows ^ jnp.asarray(s_block), W, idx0)
-    r1, w0, w1 = b2a_payload_pair(field, b2a_seed, B, garbler)
-    m0 = jnp.where(mask[:, None], w0, w1)
-    m1 = jnp.where(mask[:, None], w1, w0)
-    return m0 ^ pad0, m1 ^ pad1, r1
-
-
-def b2a_decrypt(field, t2_rows, idx0, c0, c1, e_bits):
-    """Stateless b2a receiver core: decrypt the choice-side ciphertext with
-    the T-row pad -> field values (r0 where equal, r1 where not)."""
-    W = payload_words(field)
-    pad = otext.ot_hash(jnp.asarray(t2_rows), W, idx0)
-    e = jnp.asarray(e_bits, bool)
-    ct = jnp.where(e[:, None], jnp.asarray(c1), jnp.asarray(c0))
-    return words_to_field(field, ct ^ pad)
-
-
-def gb_step2(snd: otext.OtExtSender, u2_msg, mask, b2a_seed, field, garbler: int = 0):
-    """Garbler: extend the b2a OT — extension and pad hash as ONE jitted
-    program (:meth:`otext.OtExtSender.extend_pads`) — and encrypt the
-    ordered payload pair under the pads.  Bit-identical to the
-    :func:`b2a_encrypt` form (same hash, same index base), which the
-    mesh keeps for its in-jit collective flow.
-
-    Returns (c0, c1 ciphertext words [B, W], field values [B] — the
-    garbler's additive shares, always r1 = r0 ± 1 by ``garbler`` side)."""
-    mask = jnp.asarray(mask, bool)
-    B = mask.shape[0]
-    W = payload_words(field)
-    _, pad0, pad1 = snd.extend_pads(B, u2_msg, W)
-    r1, w0, w1 = b2a_payload_pair(field, b2a_seed, B, garbler)
-    m0 = jnp.where(mask[:, None], w0, w1)
-    m1 = jnp.where(mask[:, None], w1, w0)
-    return m0 ^ pad0, m1 ^ pad1, r1
-
-
-def ev_step4(rcv: otext.OtExtReceiver, t2_rows, idx0, c0, c1, e_bits, field):
-    """Evaluator: decrypt its chosen payload -> field values [B] (its
-    additive shares: r0 where equal, r1 where not)."""
-    return b2a_decrypt(field, t2_rows, idx0, c0, c1, e_bits)
 
 
 # ---------------------------------------------------------------------------
@@ -302,11 +219,8 @@ def ev_step4(rcv: otext.OtExtReceiver, t2_rows, idx0, c0, c1, e_bits, field):
 # ~1/3 the hash count and no tree — ``OT2S_MAX_S`` caps the auto path at
 # the point where the 2^S table stops paying (beyond it the GC path,
 # whose wire is linear in S, takes over).  The GC path also remains the
-# arbitrary-S fallback and the reference-parity oracle; ``EQ_OT4``
-# (historical name, kept because tests and deployments toggle it) turns
-# the fast path off entirely.
-
-EQ_OT4: bool = True
+# arbitrary-S fallback and the reference-parity oracle; ``Config.ot_path
+# = "gc"`` turns the fast path off entirely.
 
 # auto-path ceiling for the 1-of-2^S table (S = 2·n_dims; 6 covers the
 # 3-dim roadmap workloads).  Protocol-legal up to 128; the 2^S·W wire
@@ -320,7 +234,6 @@ OT2S_MAX_S: int = 6
 OT2S_PALLAS: bool = True
 
 _OT2S_DOMAIN = 0x0F4E4F54  # ot_hash tweak-domain of the per-test pads
-_OT4_DOMAIN = _OT2S_DOMAIN  # historical alias
 
 
 def _ot2s_pallas_engine() -> bool:
@@ -333,8 +246,8 @@ def ot_path(S: int, override: str = "auto") -> str:
     """Which equality-test engine a level of string width ``S`` runs:
     ``"ot2s"`` (1-of-2^S chosen-payload OT) or ``"gc"`` (garbled
     circuit).  ``override`` is the config knob (utils/config.Config
-    ``ot_path``): "auto" picks ot2s for S <= OT2S_MAX_S (unless EQ_OT4
-    is off), "ot2s"/"gc" force a path — forcing ot2s past the ceiling is
+    ``ot_path``): "auto" picks ot2s for 2 <= S <= OT2S_MAX_S,
+    "ot2s"/"gc" force a path — forcing ot2s past the ceiling is
     a loud error rather than a silent 2^S blowup.  Both servers derive
     the path from the same (cfg, S), so the wire format always agrees."""
     if override == "gc":
@@ -349,12 +262,7 @@ def ot_path(S: int, override: str = "auto") -> str:
         return "ot2s"
     if override != "auto":
         raise ValueError(f"unknown ot_path {override!r}")
-    return "ot2s" if (EQ_OT4 and 2 <= S <= OT2S_MAX_S) else "gc"
-
-
-def _ot4_use(S: int) -> bool:
-    """Historical predicate (bench/tests): does the auto path skip GC?"""
-    return ot_path(S) == "ot2s"
+    return "ot2s" if 2 <= S <= OT2S_MAX_S else "gc"
 
 
 @partial(jax.jit, static_argnames=("n_words",))
@@ -410,58 +318,23 @@ def ot2s_decrypt(t_rows, y_flat, cts, n_words: int, idx_offset):
     return ct ^ pad
 
 
-def ot4_encrypt(q_rows, s_block, x_flat, m_v0, m_v1, n_words: int, idx_offset):
-    """The S = 2 specialization, kept under its historical name — now a
-    view of :func:`ot2s_encrypt` (identical bits: gf128_comb/offsets at
-    S = 2 reproduce the original {0, s, 2s, s^2s} table)."""
-    return ot2s_encrypt(q_rows, s_block, x_flat, m_v0, m_v1, n_words,
-                        idx_offset)
-
-
-def ot4_decrypt(t_rows, y_flat, cts, n_words: int, idx_offset):
-    """S = 2 view of :func:`ot2s_decrypt` (historical name)."""
-    return ot2s_decrypt(t_rows, y_flat, cts, n_words, idx_offset)
-
-
-def gb_step_ot4(snd: otext.OtExtSender, u_msg, x_flat, b2a_seed, field,
-                garbler: int = 0):
-    """Garbler/sender level step on the S = 2 fast path: extend the Δ-OT,
-    derive (r0, r1 = r0 ± 1), and encrypt the 1-of-4 payload table — the
-    whole level in one message (cts ravel), like :func:`gb_step_fused`.
-
-    Returns (cts uint32[4, B, W], vals — the sender's additive shares)."""
-    x_flat = jnp.asarray(x_flat, bool)
-    B, S = x_flat.shape
-    assert S == 2, "ot4 path is the S == 2 specialization"
-    idx0 = snd.consumed
-    q = snd.extend(B * S, u_msg)
-    W = payload_words(field)
-    r1, w0, w1 = b2a_payload_pair(field, b2a_seed, B, garbler)
-    # result 1 (strings equal) -> receiver learns r0 (collect.rs:439-456)
-    cts = ot4_encrypt(
-        q.reshape(B, S, 4), jnp.asarray(snd.s_block), x_flat, w1, w0, W, idx0
-    )
-    return cts, r1
-
-
-def ev_open_ot4(rcv: otext.OtExtReceiver, t_rows, y_flat, msg, B: int,
-                field, idx0: int):
-    """Receiver twin of :func:`gb_step_ot4`: open the 1-of-4 table with the
-    combined T rows -> field values [B] (r0 where equal, else r1)."""
-    W = payload_words(field)
-    cts = jnp.asarray(msg).reshape(4, B, W)
-    w = ot4_decrypt(jnp.asarray(t_rows).reshape(B, 2, 4), y_flat, cts, W, idx0)
-    return words_to_field(field, w)
-
-
 # ---------------------------------------------------------------------------
 # WHOLE-LEVEL packed flow: one device program per side, planar wire
 # ---------------------------------------------------------------------------
 #
-# The deployment flow (protocol/rpc.py since round 6): every (node,
-# pattern, client) test of a level rides ONE message built by ONE fused
-# device program per side — the 1-of-2^S table for S <= OT2S_MAX_S, the
-# packed garbled batch (b2a payloads under the output labels) beyond it.
+# The level as ONE message (the oracle of the chunked flow below, see the
+# module docstring): every (node, pattern, client) test of a level rides
+# one message built by one fused device program per side — the 1-of-2^S
+# table for S <= OT2S_MAX_S, the packed garbled batch beyond it.  In the
+# garbled batch the b2a payloads ride the GC OUTPUT LABELS: the
+# evaluator's b2a choice bit is exactly its GC output share, and its
+# garbled output label already encodes that choice 1-of-2 (labels differ
+# by R with the select bit in the lsb), so encrypting the two payloads
+# under the two possible output labels delivers the b2a OT inside the
+# batch — one protocol round trip a level where the reference's
+# GC-then-OT structure (collect.rs:419-482) takes two.  Security rests on
+# the same circular-correlation-robust hash assumption as the Δ-OT pads
+# (labels differ by R = s).
 # The wire format is the PLANAR plane layout of ops/gc_pallas.py /
 # ops/otext_pallas.py, padded to ``padded_tests(B)`` tests: on a real
 # chip the buffer is the fused kernel's output raveled in place (no
@@ -550,6 +423,19 @@ def ot2s_decrypt_packed(t_rows, y_flat, msg, n_words: int, idx_offset):
     )
 
 
+def ev_step1_fused(rcv: otext.OtExtReceiver, y_flat):
+    """Evaluator round 1: request input labels.  y_flat bool[B, S] ->
+    (u message, T rows uint32[B*S, 4] — the Δ-OT labels-to-be, idx0 —
+    the pre-extension consumed counter, the payload-pad index base the
+    garbler captures too).  ``y_flat`` may stay a DEVICE array —
+    fetching it first is a blocking device->host fetch and the extension
+    consumes it on device anyway."""
+    B, S = y_flat.shape
+    idx0 = rcv.consumed
+    u, t = rcv.extend(jnp.reshape(jnp.asarray(y_flat), (B * S,)))
+    return u, t, idx0
+
+
 def gb_step_level(snd: otext.OtExtSender, u_msg, x_flat, gc_seed, b2a_seed,
                   field, garbler: int = 0, path: str = "auto"):
     """Garbler/sender whole-level step: extend the Δ-OT, derive the b2a
@@ -593,113 +479,6 @@ def ev_open_level(t_rows, y_flat, msg, B: int, S: int, field, idx0: int,
             msg, jnp.asarray(t_rows).reshape(B, S, 4), W, idx0
         )
     return words_to_field(field, w)
-
-
-# ---------------------------------------------------------------------------
-# FUSED socket flow: the b2a payloads ride the GC output labels
-# ---------------------------------------------------------------------------
-#
-# The two-round flow above (ev u -> gb batch -> ev u2 -> gb ciphertexts)
-# follows the reference's GC-then-OT structure (collect.rs:419-482).  But
-# the evaluator's b2a choice bit is exactly its GC output share — and its
-# garbled OUTPUT LABEL already encodes that choice 1-of-2 (labels differ
-# by R with the select bit in the lsb).  Encrypting the two payloads under
-# the two possible output labels (ops/gc.garble_equality_payload) delivers
-# the b2a OT for free inside the garbled batch: ONE protocol round trip
-# per level (ev u -> gb batch+cts), one blocking device->host fetch fewer
-# on each side.  Security
-# rests on the same circular-correlation-robust hash assumption as the
-# Δ-OT pads (labels differ by R = s); the mesh path keeps the explicit
-# two-round form (device-resident, RTT-free, and its collectives are
-# already minimal).
-
-
-def ev_step1_fused(rcv: otext.OtExtReceiver, y_flat):
-    """Evaluator round 1: like :func:`ev_step1` but also captures the
-    pre-extension consumed counter — the payload-pad index base both
-    sides must agree on (the garbler captures the same value)."""
-    idx0 = rcv.consumed
-    u, t = ev_step1(rcv, y_flat)
-    return u, t, idx0
-
-
-def gb_step_fused(snd: otext.OtExtSender, u_msg, x_flat, gc_seed, b2a_seed,
-                  field, garbler: int = 0):
-    """Garbler: extend the input-label Δ-OT, garble, and attach the b2a
-    payloads under the output labels — the whole level in one message.
-
-    Returns (packed message, vals — the garbler's additive shares
-    ``r1 = r0 ± 1`` by garbling side, as in :func:`b2a_encrypt`)."""
-    x_flat = jnp.asarray(x_flat, bool)
-    B, S = x_flat.shape
-    idx0 = snd.consumed
-    q = snd.extend(B * S, u_msg)
-    W = payload_words(field)
-    r1, w0, w1 = b2a_payload_pair(field, b2a_seed, B, garbler)
-    # v = 1 (strings equal) -> evaluator learns r0, else r1: the ordering
-    # of collect.rs:439-456 with the choice implicit in the output label
-    batch, cts, _ = gc.garble_equality_payload(
-        jnp.asarray(snd.s_block), q.reshape(B, S, 4), jnp.asarray(gc_seed),
-        x_flat, w1, w0, W, idx0,
-    )
-    return pack_gc_payload_batch(batch, cts), r1
-
-
-def ev_open_fused(rcv: otext.OtExtReceiver, t_rows, msg, B: int, S: int,
-                  field, idx0: int):
-    """Evaluator round 2: evaluate the batch and open the payload under
-    the output label -> field values [B] (r0 where equal, else r1)."""
-    W = payload_words(field)
-    batch, cts = unpack_gc_payload_batch(jnp.asarray(msg), B, S, W)
-    _, w = gc.eval_equality_payload(
-        batch, jnp.asarray(t_rows).reshape(B, S, 4), cts, W, idx0
-    )
-    return words_to_field(field, w)
-
-
-# ---------------------------------------------------------------------------
-# Wire packing: one buffer per message
-# ---------------------------------------------------------------------------
-#
-# Every device->host fetch is a synchronous round trip with a fixed cost
-# regardless of size, so a message that fetches three arrays pays it
-# three times.  Packing the garbled batch (and the b2a
-# ciphertext pair) into ONE u32 vector on device makes each data-plane
-# message one fetch + one pickle; the peer re-uploads once and slices on
-# device.
-
-
-@jax.jit
-def pack_gc_batch(batch: gc.GarbledEqBatch) -> jax.Array:
-    return jnp.concatenate([
-        jnp.ravel(batch.tables),
-        jnp.ravel(batch.gb_labels),
-        jnp.ravel(batch.decode).astype(jnp.uint32),
-    ])
-
-
-@partial(jax.jit, static_argnames=("B", "S"))
-def unpack_gc_batch(buf: jax.Array, B: int, S: int) -> gc.GarbledEqBatch:
-    buf = jnp.asarray(buf)
-    nt = B * (S - 1) * 2 * 4
-    nl = B * S * 4
-    return gc.GarbledEqBatch(
-        tables=buf[:nt].reshape(B, S - 1, 2, 4),
-        gb_labels=buf[nt : nt + nl].reshape(B, S, 4),
-        decode=buf[nt + nl :] != 0,
-    )
-
-
-@jax.jit
-def pack_gc_payload_batch(batch: gc.GarbledEqBatch, cts: jax.Array) -> jax.Array:
-    return jnp.concatenate([pack_gc_batch(batch), jnp.ravel(cts)])
-
-
-@partial(jax.jit, static_argnames=("B", "S", "W"))
-def unpack_gc_payload_batch(buf: jax.Array, B: int, S: int, W: int):
-    buf = jnp.asarray(buf)
-    base = B * (S - 1) * 2 * 4 + B * S * 4 + B
-    return unpack_gc_batch(buf[:base], B, S), buf[base:].reshape(2, B, W)
 
 
 # ---------------------------------------------------------------------------

@@ -298,13 +298,14 @@ class _ConnState:
 
 
 # ---------------------------------------------------------------------------
-# Mesh (ICI) chaos: in-process fault injection for parallel/mesh.py
+# Mesh (ICI) chaos: in-process fault injection for a multi-chip server
+# (parallel/server_mesh.py)
 #
-# The mesh path has no sockets to proxy — the whole two-party exchange is
-# XLA collectives (ppermute/psum) inside compiled programs, so faults are
-# injected at the LEVEL boundaries the host-side driver crosses anyway
-# (MeshLeader.run_supervised consults the injector before each level's
-# collective dispatch).  Three surrogates for the real ICI failure modes:
+# A server's own mesh has no sockets to proxy — its reductions are XLA
+# collectives (psum) inside compiled programs, so faults are injected at
+# the LEVEL boundaries the crawl verbs cross anyway
+# (``CollectorServer._mesh_guard`` consults the injector before each
+# level's dispatch).  Three surrogates for the real ICI failure modes:
 #
 # - ``drop``  — a dropped data-parallel shard: the level's collective
 #   result cannot be trusted; device state (the frontier) is intact, so
@@ -384,10 +385,11 @@ def parse_mesh_faults(spec: str) -> list:
 
 class MeshChaos:
     """Consumed-once mesh fault schedule.  ``before_level(runner, level)``
-    is the hook :class:`parallel.mesh.MeshLeader` calls at each level
-    entry; a clause whose ``at_level`` has been reached fires exactly
-    once (re-run levels do not re-trigger it — the recovery must be able
-    to make progress, exactly like the proxy's fired severs)."""
+    is the hook ``CollectorServer._mesh_guard`` calls at each level
+    entry, ``runner`` being the collection session; a clause whose
+    ``at_level`` has been reached fires exactly once (re-run levels do
+    not re-trigger it — the recovery must be able to make progress,
+    exactly like the proxy's fired severs)."""
 
     def __init__(self, faults: list | None = None):
         self._armed: list[MeshFaultSpec] = list(faults or [])

@@ -18,7 +18,7 @@ otherwise skip small/fast programs, and returns the directory in use —
 or ``None`` (with a ``compile_cache.unavailable`` warning) when the
 fallback directory cannot be created; the crawl then recompiles cold.
 ``chip_smoke.py`` treats ``None`` as a failure.  The binaries
-(bin/leader, bin/server, bin/mesh), bench.py, chip_smoke.py and
+(bin/leader, bin/server), benchmark/run.py, chip_smoke.py and
 tests/conftest.py all call it at startup.
 
 :func:`backend_compiles` counts fresh XLA backend compiles process-wide

@@ -106,7 +106,7 @@ class Config:
     # 0 = auto: every visible local device on an accelerator host, ONE on
     # a CPU host (the virtual host-platform devices exist for tests —
     # production CPU servers gain nothing from sharding a host backend;
-    # tests/bench pass explicit counts under
+    # tests pass explicit counts under
     # XLA_FLAGS=--xla_force_host_platform_device_count=8).  1 pins the
     # single-device path; N > 1 requests exactly N (capped at the visible
     # device count, then at the largest divisor of the client batch).
@@ -133,7 +133,7 @@ class Config:
     # of the client batch that fits, so a non-dividing batch degrades to
     # fewer shards instead of failing.  The challenge stream, both wire
     # messages, and the verdict vector are bit-identical at every
-    # setting (asserted in tier-1 and gated in bench_sketch).
+    # setting (asserted in tier-1).
     sketch_shards: int = 0
     # per-level secure-kernel phase split (phase_otext/garble/eval/b2a
     # spans in the run report): True syncs the device at each phase

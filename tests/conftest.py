@@ -1,6 +1,6 @@
 """Test harness: everything runs on XLA:CPU with 8 virtual devices.
 
-Multi-chip sharding (the 2-server mesh axis plus client data-parallel axis)
+Multi-chip sharding (each server's client axis over its own devices)
 is exercised on virtual CPU devices, per the reference's in-process
 integration-test shape (two servers' state machines in one process,
 ref: tests/collect_test.rs).  The suite never touches an accelerator: the

@@ -862,6 +862,56 @@ def test_pyproject_and_dataclass_defaults_do_not_drift():
         assert getattr(operative, key) == getattr(defaults, key), key
 
 
+def test_config_tables_name_code_that_exists():
+    """The tables name functions and classes BY NAME, so a deletion or a
+    rename leaves them pointing at nothing, in silence: every function
+    name in the taint tables (sources by return, sinks, wire calls,
+    declassifiers) and in ``hot_roots`` is defined somewhere in the
+    package, and every ``Class.attr`` of the race guard map is a class
+    of the package that assigns both the attribute and its lock."""
+    import ast
+    import builtins
+
+    funcs, classes = set(), {}
+    pkg = os.path.join(REPO, "fuzzyheavyhitters_tpu")
+    for root, _, files in os.walk(pkg):
+        for name in files:
+            if not name.endswith(".py"):
+                continue
+            with open(os.path.join(root, name), encoding="utf-8") as f:
+                tree = ast.parse(f.read())
+            for node in ast.walk(tree):
+                if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
+                    funcs.add(node.name)
+                elif isinstance(node, ast.ClassDef):
+                    attrs = classes.setdefault(node.name, set())
+                    for sub in ast.walk(node):
+                        if (isinstance(sub, ast.Attribute)
+                                and isinstance(sub.ctx, ast.Store)
+                                and isinstance(sub.value, ast.Name)
+                                and sub.value.id == "self"):
+                            attrs.add(sub.attr)
+    cfg = load_config(REPO)
+    tables = {
+        "taint (return sources)": [k for k in cfg.taint if "." not in k],
+        "taint_sinks": [n for n in cfg.taint_sinks
+                        if not hasattr(builtins, n)],
+        "taint_wire_calls": cfg.taint_wire_calls,
+        "taint_declassifiers": cfg.taint_declassifiers,
+        "hot_roots": cfg.hot_roots,
+    }
+    gone = {t: [n for n in names if n not in funcs]
+            for t, names in tables.items()}
+    assert not any(gone.values()), gone
+    for key in cfg.taint:
+        if "." in key:
+            assert key.split(".")[0] in classes, key
+    for key, lock in cfg.guards.items():
+        cls, attr = key.split(".")
+        assert cls in classes, key
+        assert {attr, lock} <= classes[cls], (key, lock)
+
+
 # ---------------------------------------------------------------------------
 # self-lint: the repo is clean under the checked-in baseline
 # ---------------------------------------------------------------------------

@@ -25,7 +25,10 @@ from fuzzyheavyhitters_tpu.workloads import rides
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 N_REQS = 32
-PORT = 30531  # a range of its own: 21701 lay inside test_resilience's and test_secure_kernels' offsets
+# a range of its own (21701 lay inside test_resilience's and
+# test_secure_kernels' offsets): lane i of three at PORT + 40 * i, +10 and
+# +11 inside it
+PORT = 30531
 CFG = {
     "data_len": 16,
     "n_dims": 2,
@@ -68,13 +71,20 @@ def _expected_csv(tmp_path):
     return out.read_text()
 
 
-def test_binaries_end_to_end(tmp_path):
+def _run_binaries(tmp_path, cfg, **leader_env):
+    """``bin/server`` x2 + ``bin/leader`` as OS processes on ``cfg``:
+    the CSV the leader wrote, with the run reports checked on the way."""
     cfg_path = tmp_path / "config.json"
-    cfg_path.write_text(json.dumps(CFG))
+    cfg_path.write_text(json.dumps(cfg))
     env = dict(os.environ)
+    env.update(leader_env)
     env["PYTHONPATH"] = _REPO + os.pathsep + env.get("PYTHONPATH", "")
+    # eight virtual devices a process: a sharded server takes its own
+    # ``server_data_devices`` of them (server 1 the second run of them)
     env["XLA_FLAGS"] = (
-        env.get("XLA_FLAGS", "") + " --xla_backend_optimization_level=1"
+        env.get("XLA_FLAGS", "")
+        + " --xla_force_host_platform_device_count=8"
+        + " --xla_backend_optimization_level=1"
     ).strip()
 
     def spawn(mod, *args, log=None):
@@ -114,6 +124,7 @@ def test_binaries_end_to_end(tmp_path):
             p.terminate()
         for p in (s0, s1):
             p.communicate(timeout=60)
+        sreps = []
         for sid in (0, 1):
             srep = json.loads(
                 (tmp_path / f"leader_report.s{sid}.json").read_text()
@@ -121,41 +132,51 @@ def test_binaries_end_to_end(tmp_path):
             assert f"server{sid}" in srep["registries"], sorted(
                 srep["registries"]
             )
+            sreps.append(srep)
         assert json.loads(report_path.read_text()) == rep  # not clobbered
     finally:
         for p in (s0, s1, lead):
             if p is not None and p.poll() is None:
                 p.kill()
-    want = _expected_csv(tmp_path)
-    assert got == want
+    return got, sreps
 
 
-def test_mesh_binary_rides_matches_socket_csv(tmp_path):
-    """The pod entry point on the flagship rides workload writes the SAME
-    heavy-hitter CSV as the socket deployment on identical client points
-    (both sample seed-42 synthetic coords via the shared workloads
-    sampler)."""
-    cfg = dict(CFG)
-    del cfg["backend"]  # mesh binary pins its platform via --platform
-    cfg_path = tmp_path / "rides_mesh.json"
-    cfg_path.write_text(json.dumps(cfg))
-    env = dict(os.environ)
-    env["PYTHONPATH"] = _REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env["XLA_FLAGS"] = (
-        env.get("XLA_FLAGS", "")
-        + " --xla_force_host_platform_device_count=8"
-        + " --xla_backend_optimization_level=1"
-    ).strip()
-    out = subprocess.run(
-        [sys.executable, "-m", "fuzzyheavyhitters_tpu.bin.mesh",
-         "--config", str(cfg_path), "-n", str(N_REQS), "--platform", "cpu",
-         "--devices", "4"],
-        cwd=tmp_path, env=env, capture_output=True, text=True, timeout=540,
-    )
-    assert out.returncode == 0, out.stdout[-3000:] + out.stderr[-2000:]
-    csv_path = tmp_path / "data" / "ride_heavy_hitters.csv"
-    assert csv_path.exists(), out.stdout[-2000:]
-    assert csv_path.read_text() == _expected_csv(tmp_path)
+def _lane_cfg(lane: int, **kw):
+    port = PORT + 40 * lane
+    return dict(CFG, server0=f"127.0.0.1:{port}",
+                server1=f"127.0.0.1:{port + 10}", **kw)
+
+
+@pytest.mark.parametrize("data_devices", [1, 2])
+def test_binaries_end_to_end(tmp_path, data_devices):
+    """One device a server, and the co-resident multi-chip shape: each
+    server process shards its clients over two of its devices
+    (``server_data_devices`` 2) and says so in its own report; the CSV
+    is the driver oracle's either way."""
+    cfg = _lane_cfg(data_devices - 1, server_data_devices=data_devices)
+    got, sreps = _run_binaries(tmp_path, cfg)
+    for srep in sreps:
+        assert srep.get("mesh", {}).get("data_shards") == (
+            None if data_devices == 1 else data_devices
+        )
+    assert got == _expected_csv(tmp_path)
+
+
+def test_binaries_malicious_lane_on_sharded_servers(tmp_path):
+    """The malicious lane (sketch verification of every client's keys)
+    through the sharded pair: honest clients all pass, so the CSV is
+    still the oracle's, and each server's report shows the sharded
+    verify ran."""
+    cfg = _lane_cfg(2, server_data_devices=2, malicious=True)
+    # no warm-up ladder: on XLA:CPU it RUNS the sketch chain at every
+    # bucket up to f_max (100 s of this case's 117), and what it warms is
+    # tests/test_multichip.py::test_warmed_malicious_crawl_zero_fresh_compiles'
+    got, sreps = _run_binaries(tmp_path, cfg, FHH_WARMUP="0")
+    for srep in sreps:
+        assert srep["mesh"]["data_shards"] == 2
+        assert srep["sketch"]["sketch_shards"] == 2
+        assert srep["sketch"]["verify_seconds"] > 0
+    assert got == _expected_csv(tmp_path)
 
 
 @pytest.mark.parametrize(
@@ -163,9 +184,8 @@ def test_mesh_binary_rides_matches_socket_csv(tmp_path):
     [
         ["fuzzyheavyhitters_tpu.bin.server", "--server_id", "0"],
         ["fuzzyheavyhitters_tpu.bin.leader", "-n", "4"],
-        ["fuzzyheavyhitters_tpu.bin.mesh", "-n", "4"],
     ],
-    ids=["server", "leader", "mesh"],
+    ids=["server", "leader"],
 )
 def test_binaries_refuse_a_tpu_config_without_an_accelerator(tmp_path, argv):
     """No hidden XLA:CPU fallback: with ``backend: "tpu"`` (the config
@@ -182,59 +202,3 @@ def test_binaries_refuse_a_tpu_config_without_an_accelerator(tmp_path, argv):
     )
     assert out.returncode != 0
     assert '"backend": "cpu"' in out.stderr, out.stderr[-2000:]
-
-
-def test_mesh_binary_refuses_malicious(tmp_path):
-    """malicious mode on the mesh is a DOCUMENTED refusal (one trust
-    domain — sketch verification adds nothing there; the socket binaries
-    carry the real path)."""
-    cfg = dict(CFG, malicious=True)
-    cfg_path = tmp_path / "mal.json"
-    cfg_path.write_text(json.dumps(cfg))
-    env = dict(os.environ)
-    env["PYTHONPATH"] = _REPO + os.pathsep + env.get("PYTHONPATH", "")
-    out = subprocess.run(
-        [sys.executable, "-m", "fuzzyheavyhitters_tpu.bin.mesh",
-         "--config", str(cfg_path), "-n", "4", "--platform", "cpu"],
-        cwd=tmp_path, env=env, capture_output=True, text=True, timeout=120,
-    )
-    assert out.returncode != 0
-    assert "malicious mode refused" in out.stderr
-
-
-def test_mesh_binary_smoke(tmp_path):
-    """The pod-deployment entry point (bin/mesh.py) runs a zipf collection
-    on the virtual 2x4 CPU mesh and prints heavy hitters."""
-    cfg = {
-        "data_len": 8,
-        "n_dims": 1,
-        "ball_size": 1,
-        "addkey_batch_size": 16,
-        "num_sites": 4,
-        "threshold": 0.1,
-        "zipf_exponent": 1.03,
-        "server0": "127.0.0.1:1",
-        "server1": "127.0.0.1:2",
-        "distribution": "zipf",
-        "f_max": 64,
-    }
-    cfg_path = tmp_path / "mesh.json"
-    cfg_path.write_text(json.dumps(cfg))
-    env = dict(os.environ)
-    env["PYTHONPATH"] = _REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env["XLA_FLAGS"] = (
-        env.get("XLA_FLAGS", "")
-        + " --xla_force_host_platform_device_count=8"
-        + " --xla_backend_optimization_level=1"
-    ).strip()
-    out = subprocess.run(
-        [sys.executable, "-m", "fuzzyheavyhitters_tpu.bin.mesh",
-         "--config", str(cfg_path), "-n", "32", "--platform", "cpu"],
-        cwd=tmp_path, env=env, capture_output=True, text=True, timeout=540,
-    )
-    assert out.returncode == 0, out.stdout[-3000:] + out.stderr[-2000:]
-    assert "crawl.done" in out.stdout + out.stderr  # obs telemetry line
-    # NB no hitter-count assertion: the zipf workload appends 8 random
-    # augmentation bits per request (leader.rs:331 parity), so leaf-level
-    # hitters are luck at smoke scale; hitter correctness is pinned by the
-    # driver-oracle tests, this test pins that the BINARY runs end to end

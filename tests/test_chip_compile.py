@@ -148,39 +148,29 @@ def test_ot2s_encrypt_decrypt(one_chip):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("wire", ["planar", "packed"])
-def test_gc_garble_eval(one_chip, wire):
-    """``planar`` is the unit form, ``packed`` the whole-level wire the
-    servers exchange (gc.garble/eval_equality_payload_packed)."""
+@pytest.mark.parametrize("words", [W, 8], ids=["FE62", "F255"])
+def test_gc_garble_eval(one_chip, words):
+    """The whole-level wire the servers exchange on the GC path
+    (gc.garble/eval_equality_payload_packed), at both payload widths:
+    every level's FE62 message and the leaf level's F255 one."""
     b = B_SECURE
     sds = _sds(one_chip)
     idx = sds((), jnp.uint32)
-    garble = (gc_pallas._garble_planar if wire == "planar"
-              else gc_pallas._garble_packed)
     text = _compile(
-        garble,
+        gc_pallas._garble_packed,
         sds((4,), jnp.uint32), sds((b, S, 4), jnp.uint32),
         sds((b, S, 4), jnp.uint32), sds((b,), jnp.uint32),
-        sds((b, S), jnp.bool_), sds((b, W), jnp.uint32),
-        sds((b, W), jnp.uint32), idx,
-        S=S, W=W, interpret=False,
+        sds((b, S), jnp.bool_), sds((b, words), jnp.uint32),
+        sds((b, words), jnp.uint32), idx,
+        S=S, W=words, interpret=False,
     )
     assert "tpu_custom_call" in text
-    if wire == "planar":
-        text = _compile(
-            gc_pallas._eval_planar,
-            sds((b, S - 1, 2, 4), jnp.uint32), sds((b, S, 4), jnp.uint32),
-            sds((b,), jnp.bool_), sds((b, S, 4), jnp.uint32),
-            sds((2, b, W), jnp.uint32), idx,
-            S=S, W=W, interpret=False,
-        )
-    else:
-        text = _compile(
-            gc_pallas._eval_packed,
-            sds((gc_pallas.packed_msg_words(b, S, W),), jnp.uint32),
-            sds((b, S, 4), jnp.uint32), idx,
-            S=S, W=W, interpret=False,
-        )
+    text = _compile(
+        gc_pallas._eval_packed,
+        sds((gc_pallas.packed_msg_words(b, S, words),), jnp.uint32),
+        sds((b, S, 4), jnp.uint32), idx,
+        S=S, W=words, interpret=False,
+    )
     assert "tpu_custom_call" in text
 
 

@@ -34,6 +34,11 @@ from fuzzyheavyhitters_tpu.utils.config import Config
 # +8200 top offset: a leader-side client's ephemeral socket must never
 # land on a later test's hard-coded listener port (EADDRINUSE flakes)
 BASE_PORT = 23810
+# the fault cases further down (drop, delay, an unsharded kill, an
+# ingest-only checkpoint) listen in a range of their own, which no other
+# file's offsets reach: a crawl at FAULT_PORT + 40 * i, +10 and +11
+# inside it (19131 .. 19462)
+FAULT_PORT = 19131
 
 L, N_CLIENTS, D = 5, 12, 1
 
@@ -84,7 +89,7 @@ def sketch_keys(client_keys):
 
 async def _crawl(cfg, port, k0, k1, sk0=None, sk1=None, *, warmup=False,
                  chaos=None, ckpt_dir=None, supervised=False,
-                 n_clients=N_CLIENTS):
+                 n_clients=N_CLIENTS, before_run=None):
     s0 = rpc.CollectorServer(0, cfg, ckpt_dir=ckpt_dir, _mesh_chaos=chaos)
     s1 = rpc.CollectorServer(1, cfg, ckpt_dir=ckpt_dir)
     t1 = asyncio.create_task(
@@ -109,6 +114,8 @@ async def _crawl(cfg, port, k0, k1, sk0=None, sk1=None, *, warmup=False,
             await lead.upload_keys(k0, k1, sk0, sk1)
             if warmup:
                 await lead.warmup()
+            if before_run is not None:
+                await before_run(c0, c1)
             res = await lead.run(n_clients)
         status0 = await c0.call("status")
         report = obsreport.run_report([s0.obs, s1.obs, lead.obs])
@@ -122,6 +129,56 @@ async def _crawl(cfg, port, k0, k1, sk0=None, sk1=None, *, warmup=False,
 
 def _run(cfg, port, k0, k1, **kw):
     return asyncio.run(_crawl(cfg, port, k0, k1, **kw))
+
+
+def _over_data_axis(fn, x, out_spec):
+    """``fn`` on each of the eight shards of ``x``'s leading axis, as one
+    program (eagerly, F255's carry chains dispatch op by op: 27 s)."""
+    import jax
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    mesh = Mesh(np.asarray(jax.devices()[:8]), ("data",))
+    # fhh-lint: disable=recompile-churn (one program a test case)
+    return jax.jit(jax.shard_map(
+        fn, mesh=mesh, in_specs=P("data"), out_specs=out_spec,
+        check_vma=False,
+    ))(x)
+
+
+@pytest.mark.parametrize("field", [FE62, F255], ids=["FE62", "F255"])
+def test_field_psum_is_exact_where_a_raw_psum_overflows(field):
+    """``parallel/mesh.field_psum`` — the one reduction every sharded
+    stage ends in: eight shards each holding p - 1 (FE62: 8 (p - 1) is
+    past 2^64; F255: every limb sum past 2^32 with 2^256 wraps to fold)
+    sum to 8 (p - 1) mod p, like Python's integers."""
+    from jax.sharding import PartitionSpec as P
+
+    from fuzzyheavyhitters_tpu.parallel.mesh import field_psum
+
+    vals = [field.P - 1 - i for i in range(8)]
+    x = np.stack([np.asarray(field.from_int(v)) for v in vals])
+    got = _over_data_axis(
+        lambda v: field_psum(field, v[0], "data"), x, P()
+    )
+    want = np.asarray(field.from_int(sum(vals) % field.P))
+    np.testing.assert_array_equal(np.asarray(field.canon(got)), want)
+
+
+@pytest.mark.parametrize("dtype", [np.uint32, np.uint64])
+def test_psum_exact_keeps_every_bit_through_32_bit_collectives(dtype):
+    """``_psum_exact``: 16-bit limbs through u32 all-reduces (the TPU
+    lowers no 64-bit one) give the integer sum, to the last bit under
+    2^64."""
+    from jax.sharding import PartitionSpec as P
+
+    from fuzzyheavyhitters_tpu.parallel.mesh import _psum_exact
+
+    top = int(np.iinfo(dtype).max) if dtype is np.uint32 else (1 << 61) - 1
+    vals = [top - 977 * i for i in range(8)]
+    x = np.asarray(vals, dtype).reshape(8, 1)
+    got = _over_data_axis(lambda v: _psum_exact(v[0], "data"), x, P())
+    assert np.asarray(got).dtype == np.uint64
+    assert int(np.asarray(got)[0]) == sum(vals)
 
 
 def test_largest_divisor_shard_binding():
@@ -198,8 +255,8 @@ def test_sharded_vs_single_device_bit_identical(mode, client_keys,
 
 
 def test_device_loss_reshards_not_restarts(client_keys):
-    """Kill one simulated data device mid-level (the 2-D mesh path's
-    ``mesh:kill`` chaos clause reused): the server re-shards its
+    """Kill one simulated data device mid-level (the ``mesh:kill``
+    chaos clause): the server re-shards its
     frontier from the host-side checkpoint IN PLACE and re-runs the
     level's crawl inside the same verb — results bit-identical, the
     recovery section counts a shard re-run and ZERO level re-runs (a
@@ -242,6 +299,101 @@ def test_device_loss_without_checkpoint_escalates(client_keys):
     cfg = _cfg(port, server_data_devices=2)
     with pytest.raises(RuntimeError, match="no level-1 checkpoint"):
         _run(cfg, port, k0, k1, chaos=chaos)
+
+
+_FAULT_MODES = {
+    "trusted": dict(),
+    "secure_ot2s": dict(secure_exchange=True, ot_path="ot2s"),
+    "secure_gc": dict(secure_exchange=True, ot_path="gc"),
+}
+
+
+@pytest.mark.parametrize("mode", list(_FAULT_MODES))
+def test_suspect_collective_reruns_in_place(mode, client_keys):
+    """``mesh:drop`` — a collective whose result cannot be trusted, the
+    device state intact: the sharded server runs the level's crawl again
+    inside the same verb and restores NOTHING (no checkpoint directory
+    exists to restore from): one fault, one shard re-run, no re-shard,
+    no level re-run, and the result is the single-device crawl's."""
+    _, (k0, k1) = client_keys
+    kw = _FAULT_MODES[mode]
+    port = FAULT_PORT + 80 * list(_FAULT_MODES).index(mode)
+    base, _, _ = _run(_cfg(port, server_data_devices=1, **kw), port, k0, k1)
+    chaos = MeshChaos(parse_mesh_faults("mesh:drop@level=2"))
+    res, status0, report = _run(
+        _cfg(port + 40, server_data_devices=2, **kw), port + 40, k0, k1,
+        chaos=chaos,
+    )
+    assert chaos.fired == [("drop", 2)]
+    np.testing.assert_array_equal(base.paths, res.paths)
+    np.testing.assert_array_equal(base.counts, res.counts)
+    rec = report["recovery"]
+    assert rec["shards_rerun"] == 1
+    assert rec["levels_rerun"] == 0 and rec["count"] == 0
+    assert report["mesh"]["faults"] == 1
+    assert report["mesh"]["reshards"] == 0
+    assert status0["mesh"]["reshards"] == 0
+    assert report["registries"]["server0"]["counters"].get(
+        "checkpoint_restores", 0) == 0
+
+
+def test_slow_participant_is_not_a_fault(client_keys):
+    """``mesh:delay`` — a participant that is late, not lost: the level
+    takes the delay and NOTHING recovers (no fault counted, no shard
+    re-run), result bit-identical."""
+    _, (k0, k1) = client_keys
+    port = FAULT_PORT + 240
+    base, _, _ = _run(_cfg(port, server_data_devices=1), port, k0, k1)
+    chaos = MeshChaos(parse_mesh_faults("mesh:delay@level=1,ms=400"))
+    res, _, report = _run(
+        _cfg(port + 40, server_data_devices=2), port + 40, k0, k1,
+        chaos=chaos,
+    )
+    assert chaos.fired == [("delay", 1)]
+    np.testing.assert_array_equal(base.paths, res.paths)
+    np.testing.assert_array_equal(base.counts, res.counts)
+    assert report["recovery"]["shards_rerun"] == 0
+    assert report["mesh"]["faults"] == 0 and report["mesh"]["reshards"] == 0
+    # the stall is where it was put: level 1, as the leader timed it
+    level_s = report["registries"]["leader"]["phases"]["level"]["by_level"]
+    assert level_s["1"] >= 0.4
+
+
+def test_device_loss_on_an_unsharded_server_reaches_the_leader(client_keys):
+    """In-place recovery belongs to the mesh: a server with ONE device
+    has no shard to re-place, so the fault leaves ``tree_crawl`` as the
+    verb's error and the leader sees it — it is not swallowed and the
+    crawl does not go on over the clobbered frontier."""
+    _, (k0, k1) = client_keys
+    port = FAULT_PORT + 320
+    chaos = MeshChaos(parse_mesh_faults("mesh:kill@level=2"))
+    with pytest.raises(RuntimeError, match="tree_crawl.*killed mid-collective"):
+        _run(_cfg(port, server_data_devices=1), port, k0, k1, chaos=chaos)
+    assert chaos.fired == [("kill", 2)]
+
+
+def test_device_loss_with_an_ingest_only_checkpoint_escalates(client_keys):
+    """The newest checkpoint carries the front door's pools and no crawl
+    state (a windowed server between windows): restoring it gives the
+    lost device's shard nothing to re-place, and the server says so to
+    the leader rather than crawl on from ``None``."""
+    _, (k0, k1) = client_keys
+    port = FAULT_PORT + 360
+
+    async def pools_then_checkpoint(c0, c1):
+        for c, keys in ((c0, k0), (c1, k1)):
+            await c.call("submit_keys", {
+                "window": 0, "sub_id": "w0-a", "client_id": "site0",
+                "keys": tuple(np.asarray(a)[:2] for a in keys),
+            })
+            await c.call("tree_checkpoint", {"level": 1, "ingest_only": True})
+
+    chaos = MeshChaos(parse_mesh_faults("mesh:kill@level=2"))
+    with tempfile.TemporaryDirectory() as td:
+        with pytest.raises(RuntimeError, match="level-1 checkpoint is ingest-only"):
+            _run(_cfg(port, server_data_devices=2), port, k0, k1,
+                 chaos=chaos, ckpt_dir=td, before_run=pools_then_checkpoint)
+    assert chaos.fired == [("kill", 2)]
 
 
 L_K, N_K = 4, 1024  # kernel-sharded e2e shape: the last level's

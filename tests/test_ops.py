@@ -1,19 +1,16 @@
 """fhh-ops suite: the live /metrics exporter, device-memory/compile
-telemetry, the alert engine, the ``ops top`` CLI, and the crash-proof
-resumable bench.
+telemetry, the alert engine and the ``ops top`` CLI.
 
 Three layers, cheapest first:
 
 - pure units (render families, bucket round-trip, alert fire-once,
-  devmem sampling, bench resume bookkeeping) — no sockets beyond an
-  ephemeral loopback exporter;
+  devmem sampling) — no sockets beyond an ephemeral loopback exporter;
 - an in-process supervised bring-up proving the ``status`` verb and the
   trace ring carry a fired alert;
 - process-level acceptance: the README run shape with the exporter live
   on leader + both servers (scrapes match the servers' own run-report
   registries, an injected tenant stall fires exactly once across every
-  surface), a disabled-exporter server binding no telemetry socket, and
-  a bench SIGTERMed mid-run resuming from its partial artifact.
+  surface) and a disabled-exporter server binding no telemetry socket.
 
 The histogram round-trip pins the tentpole invariant: a Prometheus
 scrape carries EXACTLY the information the run report computes its SLO
@@ -25,7 +22,6 @@ import glob
 import json
 import os
 import re
-import signal
 import socket
 import subprocess
 import sys
@@ -438,66 +434,6 @@ def test_status_and_trace_carry_alert(cpu_default, monkeypatch, tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# bench: crash-proof resumable artifact bookkeeping (units)
-# ---------------------------------------------------------------------------
-
-
-def _import_bench():
-    if _REPO not in sys.path:
-        sys.path.insert(0, _REPO)
-    import bench
-    return bench
-
-
-def test_bench_partial_artifact_roundtrip(tmp_path):
-    bench = _import_bench()
-    saved_out, saved_partial = bench._OUT, dict(bench._PARTIAL)
-    try:
-        bench._OUT = str(tmp_path / "art.json")
-        bench._PARTIAL.clear()
-        bench._PARTIAL["keygen_sweep"] = {16: {"keys_per_s": 1.5}}
-        bench._PARTIAL["keygen_headline"] = 123.4
-        bench._PARTIAL["secure"] = {"xput": 9.0}
-        bench._write_leg_artifact()
-        doc = json.loads((tmp_path / "art.json").read_text())
-        assert doc["partial"] is True and doc["reason"] == "in-progress"
-        res = bench._load_resume(bench._OUT)
-        # JSON stringifies the sweep's data_len keys; resume restores them
-        assert res["keygen_sweep"] == {16: {"keys_per_s": 1.5}}
-        assert res["keygen_headline"] == 123.4
-        assert res["secure"] == {"xput": 9.0}
-    finally:
-        bench._OUT = saved_out
-        bench._PARTIAL.clear()
-        bench._PARTIAL.update(saved_partial)
-
-
-def test_bench_load_resume_closed_manifest(tmp_path):
-    bench = _import_bench()
-    path = tmp_path / "bench_full.json"
-    path.write_text(json.dumps({
-        "value": 99.5,
-        "extra": {
-            "keygen_sweep": {"16": {"keys_per_s": 2.0}},
-            "secure_crawl": {"xput": 7.0},
-            "reference_key_bytes": 555,
-            "crawl": {"wall_s": 1.0},
-        },
-    }))
-    res = bench._load_resume(str(path))
-    assert res["secure"] == {"xput": 7.0}  # final key mapped back to leg name
-    assert "secure_crawl" not in res
-    assert "reference_key_bytes" not in res  # derived, not a leg
-    assert res["keygen_headline"] == 99.5
-    assert res["keygen_sweep"] == {16: {"keys_per_s": 2.0}}
-    assert res["crawl"] == {"wall_s": 1.0}
-    assert bench._load_resume(str(tmp_path / "missing.json")) == {}
-    bad = tmp_path / "bad.json"
-    bad.write_text("{not json")
-    assert bench._load_resume(str(bad)) == {}
-
-
-# ---------------------------------------------------------------------------
 # process-level acceptance
 # ---------------------------------------------------------------------------
 
@@ -796,65 +732,3 @@ def test_ops_e2e_taint_sweep_secure_crawl(tmp_path):
         for p in (s0, s1, lead):
             if p is not None and p.poll() is None:
                 p.kill()
-
-
-@pytest.mark.slow  # ~3 min: two real bench invocations (smoke legs)
-def test_bench_sigterm_partial_then_resume(tmp_path):
-    """The crash-proof bench: SIGTERM mid-run leaves a valid artifact
-    with every completed leg and ``"partial": true``; ``--resume`` skips
-    the completed legs, runs the rest, and closes the manifest."""
-    art = tmp_path / "art.json"
-    env = dict(os.environ)
-    env["PYTHONPATH"] = _REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env["XLA_FLAGS"] = (
-        env.get("XLA_FLAGS", "")
-        + " --xla_force_host_platform_device_count=8"
-        + " --xla_backend_optimization_level=1"
-    ).strip()
-    env["FHH_BENCH_SMOKE"] = "1"
-    env.pop("FHH_RUN_REPORT", None)
-    cmd = [
-        sys.executable, os.path.join(_REPO, "bench.py"),
-        "--out", str(art), "--sections", "secure",
-    ]
-    p = subprocess.Popen(
-        cmd, cwd=tmp_path, env=env, stdout=subprocess.PIPE,
-        stderr=subprocess.STDOUT, text=True, start_new_session=True,
-    )
-    try:
-        deadline = time.monotonic() + 540
-        seen_keygen = False
-        while time.monotonic() < deadline and p.poll() is None:
-            if art.exists():
-                try:
-                    doc = json.loads(art.read_text())
-                except ValueError:
-                    doc = {}
-                if "keygen_sweep" in doc.get("results", {}):
-                    seen_keygen = True
-                    break
-            time.sleep(0.25)
-        assert seen_keygen, "bench never wrote its first completed leg"
-        os.killpg(p.pid, signal.SIGTERM)  # the whole group: children too
-        out, _ = p.communicate(timeout=120)
-    finally:
-        if p.poll() is None:
-            os.killpg(p.pid, signal.SIGKILL)
-            p.communicate(timeout=60)
-    doc = json.loads(art.read_text())  # valid JSON after the kill
-    assert doc["partial"] is True
-    assert "keygen_sweep" in doc["results"]
-    # resume: completed legs skip, the remaining section runs, and the
-    # manifest closes
-    res = subprocess.run(
-        cmd + ["--resume"], cwd=tmp_path, env=env, capture_output=True,
-        text=True, timeout=540,
-    )
-    tail = res.stdout[-4000:] + res.stderr[-4000:]
-    assert res.returncode == 0, tail
-    log = res.stdout + res.stderr
-    assert "resume-skip" in log, tail
-    final = json.loads(art.read_text())
-    assert "partial" not in final
-    assert "secure_crawl" in final["extra"]
-    assert "keygen_sweep" in final["extra"]

@@ -1,5 +1,5 @@
 """Pipelined secure crawl: parity, depth sweep, quiesce chaos, warmup,
-report schema, and the bench budget helpers.
+report schema, and where the compile cache lands.
 
 The pipeline (protocol/leader_rpc.py `_crawl_level_pipelined` + the
 server-side expand/open stage split in protocol/rpc.py) is a pure
@@ -344,87 +344,3 @@ def test_compile_cache_enable(tmp_path, monkeypatch):
     finally:
         for knob, val in restore.items():
             jax.config.update(knob, val)
-
-
-def test_bench_budget_and_compact_line(monkeypatch):
-    """bench.py's budget + compact-final-line helpers: the compact extra
-    keeps each section's acceptance scalars (and error/skip markers) and
-    drops the bulk, and the budget clock counts down from module start."""
-    import bench
-
-    extra = {
-        "keygen_sweep": {"512": {"keys_per_sec": 1.0}},
-        "reference_key_bytes": {"512": 10265},
-        "secure_crawl": {
-            "secure_clients_per_sec": 112.5,
-            "ms_per_level_e2e": 750.0,
-            "secure_kernel": {
-                "ot_path": "ot2s",
-                "phase_otext_seconds": 0.4,
-                "phase_garble_seconds": 0.0,
-                "phase_eval_seconds": 0.0,
-                "phase_b2a_seconds": 0.9,
-            },
-            "whole_level_speedup_vs_pipelined": 3.2,
-            "sequential_clients_per_sec": 56.0,
-            "pipeline_speedup": 2.01,
-            "pipeline": {"depth": 4, "overlap_seconds": 9.1, "stalls": 0},
-            "hitters": 40,
-            "data_plane_mbytes_sent": 12.0,
-        },
-        "crawl_hbm_max": {"skipped": "budget"},
-        "covid": {"error": "timeout after 540s", "partial_thing": 1},
-        "upload": {"upload_keys_per_sec": 3e5, "n_keys": 10**6},
-        "ingest": {
-            "ingest_keys_per_sec": 150000.0,
-            "concurrent_keys_per_sec": 90000.0,
-            "windows": 2,
-            "shed": 0,
-            "rejected": 3,
-            "bit_identical_vs_batch": True,
-            "report_ingest": {"admitted": 65536, "keys_per_sec": 150000.0},
-            "window_crawl_seconds": 4.2,
-            "n_keys": 65536,
-        },
-        "sketch": {
-            "malicious_overhead_vs_semi_honest": 1.31,
-            "sketch_clients_per_sec": 85.9,
-            "semi_honest_clients_per_sec": 112.5,
-            "bit_identical": True,
-            "sketch_shards": 8,
-            "verify_seconds": 0.412,
-            "clients_per_sec_by_shards": {"1": 60.1, "8": 85.9},
-            "skipped_shards": {},
-            "n_clients": 1024,
-        },
-    }
-    compact = bench._compact_extra(extra)
-    assert "keygen_sweep" not in compact
-    assert compact["secure_crawl"]["secure_clients_per_sec"] == 112.5
-    assert compact["secure_crawl"]["secure_kernel"]["ot_path"] == "ot2s"
-    assert compact["secure_crawl"]["whole_level_speedup_vs_pipelined"] == 3.2
-    # the bulky blocks stay out of the compact line
-    assert "pipeline" not in compact["secure_crawl"]
-    assert "hitters" not in compact["secure_crawl"]
-    assert compact["crawl_hbm_max"] == {"skipped": "budget"}
-    assert compact["covid"] == {"error": "timeout after 540s"}
-    assert compact["upload"] == {"upload_keys_per_sec": 3e5}
-    # the streaming front-door section rides the line, scalars only
-    assert compact["ingest"]["ingest_keys_per_sec"] == 150000.0
-    assert compact["ingest"]["bit_identical_vs_batch"] is True
-    assert "report_ingest" not in compact["ingest"]
-    # the malicious-sketch section: overhead headline + rate + the
-    # bit-identity gate ride the line; the per-shard sweep stays out
-    assert compact["sketch"]["malicious_overhead_vs_semi_honest"] == 1.31
-    assert compact["sketch"]["sketch_clients_per_sec"] == 85.9
-    assert compact["sketch"]["bit_identical"] is True
-    assert compact["sketch"]["sketch_shards"] == 8
-    assert "clients_per_sec_by_shards" not in compact["sketch"]
-    # the compact line stays far under the harness's stdout tail capture
-    import json
-
-    assert len(json.dumps(compact)) < 1800
-
-    monkeypatch.setattr(bench, "BENCH_BUDGET_S", 100.0)
-    monkeypatch.setattr(bench, "_BENCH_T0", bench.time.monotonic() - 30.0)
-    assert 69.0 < bench._budget_left() < 71.0

@@ -23,7 +23,7 @@ def test_jax_matches_numpy_block(rng):
 
 
 def test_unrolled_rounds_bit_exact(rng):
-    """CHACHA_UNROLL (the TPU hot-path form, bin/server.py + bench.py) and
+    """CHACHA_UNROLL (the TPU hot-path form, bin/server.py) and
     the default scan form compute identical blocks."""
     import jax
 

@@ -19,11 +19,18 @@ from fuzzyheavyhitters_tpu.ops import ibdcf
 from fuzzyheavyhitters_tpu.protocol import driver, rpc
 from fuzzyheavyhitters_tpu.protocol.leader_rpc import RpcLeader
 from fuzzyheavyhitters_tpu.resilience import policy as respolicy
-from fuzzyheavyhitters_tpu.resilience.chaos import ChaosProxy, parse_faults
+from fuzzyheavyhitters_tpu.resilience.chaos import (
+    ChaosProxy,
+    MeshChaos,
+    MeshFaultError,
+    MeshFaultSpec,
+    parse_faults,
+    parse_mesh_faults,
+)
 from fuzzyheavyhitters_tpu.utils import bits as bitutils
 from fuzzyheavyhitters_tpu.utils.config import Config
 
-BASE_PORT = 31631  # a range of its own: 21631 + offsets ran into test_obs, test_ops and test_mesh_multiprocess under xdist
+BASE_PORT = 31631  # a range of its own: 21631 + offsets ran into test_obs and test_ops under xdist
 
 
 @pytest.fixture(autouse=True)
@@ -281,6 +288,55 @@ def test_chaos_proxy_delay_defers_the_frame():
         await srv.wait_closed()
 
     asyncio.run(run())
+
+
+# ---------------------------------------------------------------------------
+# the device-loss schedule (FHH_MESH_FAULTS): grammar and fire-once.  What
+# a sharded server does with a fired clause is tests/test_multichip.py's.
+# ---------------------------------------------------------------------------
+
+
+def test_parse_mesh_faults_grammar():
+    faults = parse_mesh_faults(
+        "mesh:drop@level=3;mesh:kill@level=5;mesh:delay@level=1,ms=50"
+    )
+    assert [f.action for f in faults] == ["drop", "kill", "delay"]
+    assert faults[0].at_level == 3
+    assert faults[2].ms == 50
+    assert parse_mesh_faults("") == [] and parse_mesh_faults(None) == []
+
+
+@pytest.mark.parametrize(
+    "bad",
+    [
+        "mesh:drop",  # no trigger
+        "mesh:drop@ms=5",  # missing level=
+        "mesh:explode@level=1",  # unknown action
+        "plane:drop@level=1",  # wrong link
+        "mesh:drop@level=-1",  # negative level
+        "garbage",
+    ],
+)
+def test_parse_mesh_faults_rejects_malformed(bad):
+    with pytest.raises(ValueError):
+        parse_mesh_faults(bad)
+
+
+def test_mesh_chaos_clauses_fire_once():
+    """A fired clause must not re-trigger on the recovery re-run of the
+    same level (the injector's twin of the proxy's consumed severs)."""
+
+    class Session:  # minimal stand-in for what a kill clobbers
+        frontier = object()
+        _children = None
+
+    chaos = MeshChaos([MeshFaultSpec("drop", 2)])
+    chaos.before_level(Session(), 0)  # below the trigger: nothing
+    with pytest.raises(MeshFaultError) as ei:
+        chaos.before_level(Session(), 2)
+    assert not ei.value.state_lost
+    chaos.before_level(Session(), 2)  # the re-run proceeds
+    assert chaos.fired == [("drop", 2)]
 
 
 # ---------------------------------------------------------------------------

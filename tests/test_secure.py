@@ -1,4 +1,7 @@
-"""Secure data-plane tests: the GC+OT 2PC pipeline sans-IO, the string
+"""Secure data-plane tests: the GC+OT 2PC level sans-IO (the whole-level
+oracle trio, at widths and batches tests/test_secure_kernels.py does not
+take: S = 33, the two sides of ``OT2S_MAX_S``, a partly filled planar
+block), which engine a width gets (``secure.ot_path``), the string
 extraction's equivalence with the trusted compare, and a full two-server
 socket run in secure mode that must (a) match trusted-mode heavy hitters
 bit-for-bit and (b) never send a packed share-bit tensor to the peer."""
@@ -9,7 +12,7 @@ import secrets as pysecrets
 import numpy as np
 import pytest
 
-from fuzzyheavyhitters_tpu.ops import gc, ibdcf, otext
+from fuzzyheavyhitters_tpu.ops import gc_pallas, ibdcf, otext
 from fuzzyheavyhitters_tpu.ops.fields import F255, FE62
 from fuzzyheavyhitters_tpu.protocol import collect, driver, rpc, secure
 from fuzzyheavyhitters_tpu.protocol.leader_rpc import RpcLeader
@@ -30,28 +33,35 @@ def ot_pair():
     return otext.inprocess_pair()
 
 
-@pytest.mark.parametrize("field", [FE62, F255], ids=["FE62", "F255"])
-def test_pipeline_sans_io(ot_pair, rng, field):
-    """garble -> Δ-OT labels -> eval -> b2a: v0 - v1 == [x == y] per test
-    (the r1-r0=1 trick, ref: collect.rs:439-471; F255 payloads ride two
-    blocks — the BlockPair double OT of collect.rs:775-916)."""
-    snd, rcv = ot_pair
-    B, S = 16, 33  # matches test_gc's delta shape -> shared compiles
+def _strings(rng, B, S):
     x = rng.integers(0, 2, size=(B, S)).astype(bool)
     y = x.copy()
     flip = rng.integers(0, 2, size=B).astype(bool)
     y[flip, rng.integers(0, S, size=B)[flip]] ^= True
-    eq = np.all(x == y, axis=1)
+    return x, y, np.all(x == y, axis=1)
 
+
+def _level(ot_pair, x, y, field, garbler, path):
+    """One whole level through the oracle trio (secure.ev_step1_fused +
+    gb_step_level / ev_open_level), the wire crossing as host arrays:
+    (v0, v1) in server order, and the sender's message."""
+    snd, rcv = ot_pair
+    B, S = x.shape
     gc_seed = np.frombuffer(pysecrets.token_bytes(16), "<u4")
     b2a_seed = np.frombuffer(pysecrets.token_bytes(16), "<u4")
-    u, t_rows = secure.ev_step1(rcv, y)
-    batch, mask = secure.gb_step1(snd, np.asarray(u), x, gc_seed)
-    e = secure.ev_step2(batch, t_rows, B, S)
-    np.testing.assert_array_equal(np.asarray(mask) ^ np.asarray(e), eq)
-    u2, t2, idx0 = secure.ev_step3(rcv, np.asarray(e))
-    c0, c1, v0 = secure.gb_step2(snd, np.asarray(u2), mask, b2a_seed, field)
-    v1 = secure.ev_step4(rcv, t2, idx0, np.asarray(c0), np.asarray(c1), e, field)
+    u, t_rows, idx0 = secure.ev_step1_fused(rcv, y)
+    msg, v_gb = secure.gb_step_level(
+        snd, np.asarray(u), x, gc_seed, b2a_seed, field, garbler, path=path
+    )
+    msg = np.asarray(msg)
+    v_ev = secure.ev_open_level(t_rows, y, msg, B, S, field, idx0, path=path)
+    return ((v_gb, v_ev) if garbler == 0 else (v_ev, v_gb)), msg
+
+
+def _assert_shares_open_to(field, v0, v1, eq):
+    """v0 - v1 == [x == y] per test (the r1 - r0 = 1 trick, ref:
+    collect.rs:439-471; F255 payloads ride two blocks — the BlockPair
+    double OT of collect.rs:775-916)."""
     diff = np.asarray(field.canon(field.sub(v0, v1)))
     if field is F255:
         np.testing.assert_array_equal(diff[:, 0], eq.astype(np.uint32))
@@ -61,35 +71,30 @@ def test_pipeline_sans_io(ot_pair, rng, field):
 
 
 @pytest.mark.parametrize("field", [FE62, F255], ids=["FE62", "F255"])
+def test_pipeline_sans_io(ot_pair, rng, field):
+    """garble -> Δ-OT labels -> eval -> b2a on ``auto`` JUST PAST the
+    table's ceiling (S = OT2S_MAX_S + 1: the first width the garbled
+    circuit takes, which the size of the message shows): v0 - v1 ==
+    [x == y] per test."""
+    B, S = 16, secure.OT2S_MAX_S + 1
+    x, y, eq = _strings(rng, B, S)
+    (v0, v1), msg = _level(ot_pair, x, y, field, 0, "auto")
+    W = secure.payload_words(field)
+    assert msg.size == gc_pallas.packed_msg_words(B, S, W)
+    _assert_shares_open_to(field, v0, v1, eq)
+
+
+@pytest.mark.parametrize("field", [FE62, F255], ids=["FE62", "F255"])
 @pytest.mark.parametrize("garbler", [0, 1])
 def test_pipeline_fused_sans_io(ot_pair, rng, field, garbler):
-    """The FUSED flow (b2a payloads under the GC output labels — one
-    protocol round trip, secure.gb_step_fused/ev_open_fused): v0 - v1 ==
+    """The garbled-circuit level (b2a payloads under the GC output labels
+    — one protocol round trip) on an odd AND-tree (S = 33): v0 - v1 ==
     [x == y] per test REGARDLESS of which side garbles (the r1 = r0 ± 1
-    sign trick), exactly like the two-round flow it replaces."""
-    snd, rcv = ot_pair
+    sign trick)."""
     B, S = 16, 33
-    x = rng.integers(0, 2, size=(B, S)).astype(bool)
-    y = x.copy()
-    flip = rng.integers(0, 2, size=B).astype(bool)
-    y[flip, rng.integers(0, S, size=B)[flip]] ^= True
-    eq = np.all(x == y, axis=1)
-
-    gc_seed = np.frombuffer(pysecrets.token_bytes(16), "<u4")
-    b2a_seed = np.frombuffer(pysecrets.token_bytes(16), "<u4")
-    u, t_rows, idx0 = secure.ev_step1_fused(rcv, y)
-    msg, v_gb = secure.gb_step_fused(
-        snd, np.asarray(u), x, gc_seed, b2a_seed, field, garbler
-    )
-    v_ev = secure.ev_open_fused(rcv, t_rows, np.asarray(msg), B, S, field, idx0)
-    v0, v1 = (v_gb, v_ev) if garbler == 0 else (v_ev, v_gb)
-    diff = np.asarray(field.canon(field.sub(v0, v1)))
-    want = eq.astype(np.uint64)
-    if field is F255:
-        np.testing.assert_array_equal(diff[:, 0], want.astype(np.uint32))
-        assert not diff[:, 1:].any()
-    else:
-        np.testing.assert_array_equal(diff, want)
+    x, y, eq = _strings(rng, B, S)
+    (v0, v1), _ = _level(ot_pair, x, y, field, garbler, "gc")
+    _assert_shares_open_to(field, v0, v1, eq)
 
 
 def test_gf128_double_linearity_and_carry():
@@ -121,51 +126,36 @@ def test_gf128_double_linearity_and_carry():
 @pytest.mark.parametrize("field", [FE62, F255], ids=["FE62", "F255"])
 @pytest.mark.parametrize("garbler", [0, 1])
 def test_pipeline_ot4_sans_io(ot_pair, rng, field, garbler):
-    """The S = 2 fast path (1-of-4 chosen-payload OT, secure.gb_step_ot4 /
-    ev_open_ot4): v0 - v1 == [x == y] per test on both garbling sides —
-    the same contract as the GC fused flow it replaces for 1-dim crawls."""
-    snd, rcv = ot_pair
-    B, S = 64, 2
-    x = rng.integers(0, 2, size=(B, S)).astype(bool)
-    y = x.copy()
-    flip = rng.integers(0, 2, size=B).astype(bool)
-    y[flip, rng.integers(0, S, size=B)[flip]] ^= True
-    eq = np.all(x == y, axis=1)
-
-    b2a_seed = np.frombuffer(pysecrets.token_bytes(16), "<u4")
-    u, t_rows, idx0 = secure.ev_step1_fused(rcv, y)
-    msg, v_snd = secure.gb_step_ot4(
-        snd, np.asarray(u), x, b2a_seed, field, garbler
-    )
-    v_rcv = secure.ev_open_ot4(
-        rcv, t_rows, y, np.asarray(msg), B, field, idx0
-    )
-    v0, v1 = (v_snd, v_rcv) if garbler == 0 else (v_rcv, v_snd)
-    diff = np.asarray(field.canon(field.sub(v0, v1)))
-    want = eq.astype(np.uint64)
-    if field is F255:
-        np.testing.assert_array_equal(diff[:, 0], want.astype(np.uint32))
-        assert not diff[:, 1:].any()
-    else:
-        np.testing.assert_array_equal(diff, want)
+    """The S = 2 fast path (1-of-4 chosen-payload OT — what ``auto`` picks
+    for every 1-dim crawl) over a batch that is NOT a whole number of
+    planar blocks (one block and 40 tests: the second block is mostly
+    padding): v0 - v1 == [x == y] per test on both garbling sides."""
+    B, S = gc_pallas.R_BLK * gc_pallas.GROUP + 40, 2
+    x, y, eq = _strings(rng, B, S)
+    (v0, v1), msg = _level(ot_pair, x, y, field, garbler, "auto")
+    W = secure.payload_words(field)
+    assert msg.size == (1 << S) * W * gc_pallas.padded_tests(B)
+    _assert_shares_open_to(field, v0, v1, eq)
 
 
 def test_ot4_receiver_learns_exactly_one_payload(ot_pair, rng):
-    """1-of-4 privacy shape: decrypting with a WRONG choice (a string the
-    receiver does not hold rows for) yields pad-garbage, not a payload —
-    i.e. the table holds exactly one opening per receiver."""
+    """1-of-2^S privacy shape, at the widest table ``auto`` builds (S =
+    OT2S_MAX_S): opening with a WRONG choice (a string the receiver
+    does not hold rows for) yields pad-garbage, not a payload — i.e. the
+    table holds exactly one opening per receiver."""
     snd, rcv = ot_pair
-    B = 32
-    x = rng.integers(0, 2, size=(B, 2)).astype(bool)
-    y = rng.integers(0, 2, size=(B, 2)).astype(bool)
-    b2a_seed = np.frombuffer(pysecrets.token_bytes(16), "<u4")
+    B, S = 32, secure.OT2S_MAX_S
+    x = rng.integers(0, 2, size=(B, S)).astype(bool)
+    y = rng.integers(0, 2, size=(B, S)).astype(bool)
+    seed = np.frombuffer(pysecrets.token_bytes(16), "<u4")
     u, t_rows, idx0 = secure.ev_step1_fused(rcv, y)
-    msg, _ = secure.gb_step_ot4(snd, np.asarray(u), x, b2a_seed, FE62, 0)
+    msg, _ = secure.gb_step_level(snd, np.asarray(u), x, seed, seed, FE62, 0)
+    msg = np.asarray(msg)
     good = np.asarray(FE62.canon(
-        secure.ev_open_ot4(rcv, t_rows, y, np.asarray(msg), B, FE62, idx0)
+        secure.ev_open_level(t_rows, y, msg, B, S, FE62, idx0)
     ))
     bad = np.asarray(FE62.canon(
-        secure.ev_open_ot4(rcv, t_rows, ~y, np.asarray(msg), B, FE62, idx0)
+        secure.ev_open_level(t_rows, ~y, msg, B, S, FE62, idx0)
     ))
     # wrong-choice openings decrypt the wrong row with the wrong pad:
     # they must not reproduce the correct payloads (w.h.p.)
@@ -173,18 +163,36 @@ def test_ot4_receiver_learns_exactly_one_payload(ot_pair, rng):
 
 
 def test_evaluator_share_is_masked(ot_pair, rng):
-    """The evaluator's GC output alone must not reveal equality: its share
-    differs from the plaintext wherever the garbler's mask bit is set."""
-    snd, rcv = ot_pair
-    B, S = 16, 33  # same shape as the pipeline test (one garble program)
+    """The evaluator's share alone must not reveal equality: with x == y
+    in EVERY test it is still not a constant (each test's share is its
+    own draw of the garbler's stream), and only the difference of the
+    two servers' shares says 1."""
+    B, S = 16, 33  # same shape as the garbled-circuit pipeline test
     x = rng.integers(0, 2, size=(B, S)).astype(bool)
-    u, t_rows = secure.ev_step1(rcv, x)  # y == x: all equal
-    gc_seed = np.frombuffer(pysecrets.token_bytes(16), "<u4")
-    batch, mask = secure.gb_step1(snd, np.asarray(u), x, gc_seed)
-    e = np.asarray(secure.ev_step2(batch, t_rows, B, S))
-    m = np.asarray(mask)
-    assert m.any() and not m.all()
-    np.testing.assert_array_equal(e, ~m)  # eq=1 everywhere -> e = 1 ^ mask
+    (v0, v1), _ = _level(ot_pair, x, x, FE62, 0, "gc")  # y == x: all equal
+    _assert_shares_open_to(FE62, v0, v1, np.ones(B, bool))
+    assert len(set(np.asarray(FE62.canon(v1)).tolist())) == B
+
+
+@pytest.mark.parametrize(
+    "S,override,want",
+    [
+        (1, "auto", "gc"),  # no table under two bits
+        (secure.OT2S_MAX_S, "auto", "ot2s"),
+        (secure.OT2S_MAX_S + 1, "auto", "gc"),
+        (2, "gc", "gc"),  # the configuration turns the table off
+        (secure.OT2S_MAX_S + 1, "ot2s", ValueError),  # loud, not 2^S wide
+        (2, "ot4", ValueError),  # not a path
+    ],
+)
+def test_ot_path_is_a_function_of_width_and_override(S, override, want):
+    """``secure.ot_path``: both servers derive the level's engine from
+    (S, ``Config.ot_path``) alone, so the wire format always agrees."""
+    if want is ValueError:
+        with pytest.raises(ValueError):
+            secure.ot_path(S, override)
+    else:
+        assert secure.ot_path(S, override) == want
 
 
 def test_child_strings_match_pattern_masks(rng):
@@ -279,13 +287,13 @@ def _client_keys(rng, L, n):
     return ibdcf.gen_l_inf_ball(pts_bits, 1, rng, engine="np")
 
 
-@pytest.mark.parametrize("eq_ot4", [True, False], ids=["ot4", "gc"])
-def test_secure_socket_run_matches_trusted(rng, monkeypatch, eq_ot4):
-    """n_dims = 1 -> S = 2: runs the 1-of-4 fast path (the production
-    default) AND the GC parity path through the full socket flow."""
-    monkeypatch.setattr(secure, "EQ_OT4", eq_ot4)
+@pytest.mark.parametrize("ot_path", ["auto", "gc"])
+def test_secure_socket_run_matches_trusted(rng, monkeypatch, ot_path):
+    """n_dims = 1 -> S = 2: ``Config.ot_path`` "auto" runs the 1-of-4 fast
+    path (the production default), "gc" the garbled-circuit parity path,
+    both through the full socket flow."""
     L, n = 5, 12
-    port_base = BASE_PORT + (0 if eq_ot4 else 40)  # distinct ports per run
+    port_base = BASE_PORT + (0 if ot_path == "auto" else 40)  # distinct ports per run
     k0, k1 = _client_keys(rng, L, n)
 
     # record every data/control-plane payload and every packed tensor
@@ -305,7 +313,7 @@ def test_secure_socket_run_matches_trusted(rng, monkeypatch, eq_ot4):
     monkeypatch.setattr(rpc, "_send", spy_send)
     monkeypatch.setattr(collect, "expand_share_bits", spy_expand)
 
-    cfg = _cfg(port_base=port_base, secure_exchange=True)
+    cfg = _cfg(port_base=port_base, secure_exchange=True, ot_path=ot_path)
     res = asyncio.run(_run_protocol(cfg, k0, k1, n))
     got = {
         tuple(int(v) for v in r): int(c)

@@ -130,40 +130,6 @@ def test_ot_hash_index_separation_and_offset(rng):
 
 
 # ---------------------------------------------------------------------------
-# fused extension: extend+pads as one program
-# ---------------------------------------------------------------------------
-
-
-def test_extend_pads_matches_split_form(rng):
-    """The one-dispatch extend_pads is bit-identical to extend followed
-    by pads, on both roles, and advances the counters in lockstep."""
-    snd, rcv = otext.inprocess_pair()
-    m = 96
-    r = rng.integers(0, 2, size=m).astype(bool)
-    u, t, pad_r = rcv.extend_pads(r, 4)
-    q, p0, p1 = snd.extend_pads(m, np.asarray(u), 4)
-    np.testing.assert_array_equal(
-        np.asarray(t),
-        np.where(r[:, None], np.asarray(q) ^ snd.s_block, np.asarray(q)),
-    )
-    np.testing.assert_array_equal(
-        np.asarray(pad_r), np.asarray(otext.ot_hash(t, 4, 0))
-    )
-    np.testing.assert_array_equal(
-        np.asarray(pad_r),
-        np.where(r[:, None], np.asarray(p1), np.asarray(p0)),
-    )
-    assert snd.consumed == rcv.consumed == m
-    # second batch: the pad index base moved with the counters
-    u2, t2, pad_r2 = rcv.extend_pads(r, 4)
-    q2, p0b, p1b = snd.extend_pads(m, np.asarray(u2), 4)
-    np.testing.assert_array_equal(
-        np.asarray(pad_r2), np.asarray(otext.ot_hash(t2, 4, m))
-    )
-    assert snd.consumed == rcv.consumed == 2 * m
-
-
-# ---------------------------------------------------------------------------
 # 1-of-2^S: engine parity + cross-parity against the GC path
 # ---------------------------------------------------------------------------
 
