@@ -39,6 +39,7 @@ _DEFAULT_GUARDS = {
     "CollectionSession.frontier": "_verb_lock",
     "CollectionSession.keys": "_verb_lock",
     "CollectionSession.keys_parts": "_verb_lock",
+    "CollectionSession.key_planes": "_verb_lock",
     "CollectionSession.alive_keys": "_verb_lock",
     "CollectionSession._children": "_verb_lock",
     "CollectionSession._last_shares": "_verb_lock",

@@ -15,8 +15,9 @@ ladder.  Both become live, named numbers here:
   (``hbm_watermark_bytes:<phase>`` — the colon becomes a ``key`` label
   at export).
 - :func:`tree_nbytes` sizes a pytree of arrays; the session layer uses
-  it to publish ``key_plane_bytes`` per collection when the key plane
-  concatenates (sessions.concat_keys).
+  it to publish ``key_plane_bytes`` per collection where the key planes
+  are allocated (sessions.add_key_batch; sessions.concat_keys for batches
+  that arrive with no total).
 - :func:`install_compile_listener` hooks JAX's monitoring event
   ``/jax/core/compile/backend_compile_duration`` (fires once per FRESH
   backend compile — persistent-cache hits do not fire it).  The event

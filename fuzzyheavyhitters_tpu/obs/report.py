@@ -44,6 +44,16 @@ Well-known metric names (what populates them):
   ``checkpoint_writes`` / ``checkpoint_restores``.
 - gauges ``ot_batch_size`` (per level), ``survivors`` /
   ``frontier_nodes`` (per level).
+- counters ``keys_placed_bytes`` (bytes of a bulk upload's batches
+  written to their rows of the resident key planes as they arrived,
+  protocol/keyplanes.py: the key-plane bytes once an upload) and
+  ``key_planes_reused`` (``tree_init`` / ``warmup`` / ``tree_restore``
+  calls that found the planes resident and placed nothing); gauges
+  ``key_plane_bytes`` (set where the planes are allocated) and
+  ``key_host_bytes_held`` (host batches the session still references
+  when a crawl opens: 0 unless it keeps them to re-place after a lost
+  chip, i.e. has a checkpoint directory); phase ``key_place`` (the
+  wait for the last write, once an upload).
 - counters ``recoveries`` / ``levels_rerun`` / ``shards_rerun``
   (supervising leaders, socket and mesh) and ``dedup_hits`` /
   ``verb_requests`` (servers' idempotent-replay accounting) — rolled up

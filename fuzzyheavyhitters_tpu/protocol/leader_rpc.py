@@ -135,7 +135,10 @@ class RpcLeader:
             async with sem:
                 await client.call(
                     "add_keys",
-                    {"keys": _key_chunk(keys, sl), "sketch": sk_chunk(sketch, sl)},
+                    {"keys": _key_chunk(keys, sl), "sketch": sk_chunk(sketch, sl),
+                     # where the batch goes: the server writes it to rows
+                     # [lo, lo + B) of n on its chip as it arrives
+                     "n": n, "lo": sl.start},
                 )
 
         with self.obs.span("upload_keys"):

@@ -516,6 +516,7 @@ class CollectorServer:
 
     keys = _mk_state_prop("keys")
     keys_parts = _mk_state_prop("keys_parts")
+    key_planes = _mk_state_prop("key_planes")
     alive_keys = _mk_state_prop("alive_keys")
     frontier = _mk_state_prop("frontier")
     _children = _mk_state_prop("_children")
@@ -565,9 +566,14 @@ class CollectorServer:
         """req: pytree-of-arrays key batch chunk [B, d, 2] (the tensor form
         of AddKeysRequest, ref: rpc.rs:13-15).  An optional ``sketch`` entry
         carries the clients' malicious-security material (MAC'd payload
-        DPFs + triples, protocol/sketch.py)."""
+        DPFs + triples, protocol/sketch.py).  A bulk upload says where the
+        batch goes — ``n``, the collection's client count, and ``lo``,
+        the batch's first row — and the batch is written to its place on
+        the chip now (``CollectionSession.add_key_batch``: dispatch
+        only); a batch with no total waits on the host for
+        ``concat_keys``."""
         cs = cs if cs is not None else self._default()
-        cs.keys_parts.append(IbDcfKeyBatch(*req["keys"]))
+        cs.add_key_batch(IbDcfKeyBatch(*req["keys"]), req.get("n"), req.get("lo"))
         if req.get("sketch") is not None:
             cs._sketch_parts.append(
                 jax.tree.unflatten(
@@ -578,16 +584,16 @@ class CollectorServer:
 
     async def tree_init(self, req, cs: CollectionSession | None = None) -> bool:  # fhh-race: holds=_verb_lock (dispatched only by _dispatch, which holds the session's verb lock; sanitizer-validated)
         cs = cs if cs is not None else self._default()
-        if not cs.keys_parts:
+        if cs.key_planes is None and not cs.keys_parts:
             raise RuntimeError("tree_init before add_keys")
         # the session's data-plane channel must be keyed (coin flip +
         # base-OT) before the ratchet root commits or any level crawls
         await self._ensure_session_plane(cs)
         root_bucket = int((req or {}).get("root_bucket", 1))
-        # the two halves of what opens every crawl in no level
-        # (dispatch only where nothing forces the device)
-        with cs.obs.span("concat_keys"):
-            cs.concat_keys()
+        # what opens every crawl in no level: the keys, which a bulk
+        # upload left resident, and the root frontier (dispatch only
+        # where nothing forces the device)
+        cs.ready_keys("tree_init", again=True)
         n = cs.keys.cw_seed.shape[0]
         cs.alive_keys = np.ones(n, bool)
         with cs.obs.span("frontier_init"):
@@ -1934,6 +1940,7 @@ class CollectorServer:
                 "pre-sketch server?)"
             )
         cs.clear_crawl_state()  # per-window sketch state clears with it
+        cs.key_planes = None  # the window's pool is the key set now
         cs.keys_parts = [IbDcfKeyBatch(*e[:nk]) for e in pool.entries]
         if has_sketch:
             # the sketch leaves ride each entry tuple (submit_keys
@@ -2006,8 +2013,7 @@ class CollectorServer:
             dropped = len(cs._ingest_pools)
             cs._ingest_pools.clear()
             cs.clear_crawl_state()
-            cs.keys = None
-            cs.keys_parts.clear()
+            cs.drop_keys()
             cs.alive_keys = None
             if cs.ckpt_dir is not None and os.path.exists(self._export_path(cs)):
                 os.remove(self._export_path(cs))
@@ -2228,7 +2234,11 @@ class CollectorServer:
             # (obs.trace: NTP-style midpoint against the caller's
             # send/recv instants; piggybacked here and on __hello__)
             "clock": round(time.time(), 6),
-            "has_keys": cs.keys is not None or bool(cs.keys_parts),
+            "has_keys": (
+                cs.keys is not None
+                or bool(cs.keys_parts)
+                or cs.key_planes is not None
+            ),
             "has_frontier": cs.frontier is not None,
             "dedup_hits": int(self.obs.counter_value("dedup_hits")),
             "plane_resets": int(self.obs.counter_value("plane_resets")),
@@ -2530,10 +2540,7 @@ class CollectorServer:
                 ingest_only=True,
             )
             return {"level": want_level}
-        if cs.keys is None:
-            if not cs.keys_parts:
-                raise RuntimeError("tree_restore before add_keys")
-            cs.concat_keys()
+        cs.ready_keys("tree_restore")
         required = {"seed", "bit", "y_bit", "alive", "alive_keys", "level",
                     "planar", "keys_fp"}
         missing = required - set(z)
@@ -2727,10 +2734,7 @@ class CollectorServer:
         warm executions.  Returns the number of (bucket, span) shapes
         warmed plus the ladder hits."""
         cs = cs if cs is not None else self._default()
-        if cs.keys is None:
-            if not cs.keys_parts:
-                raise RuntimeError("warmup before add_keys")
-            cs.concat_keys()
+        cs.ready_keys("warmup")
         buckets = sorted(
             {int(b) for b in (req or {}).get("f_buckets", []) if int(b) > 0}
         )

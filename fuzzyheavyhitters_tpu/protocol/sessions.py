@@ -57,7 +57,7 @@ from ..parallel import server_mesh as smesh
 from ..resilience import admission as resadmission
 from ..utils import guards, taint_guard
 from ..utils.config import Config
-from . import collect, mpc, sketch as sketchmod
+from . import collect, keyplanes, mpc, sketch as sketchmod
 
 DEFAULT_COLLECTION = "default"
 # collection keys become checkpoint filename components and wire channel
@@ -272,6 +272,7 @@ _SESSION_GUARDS = {
     "frontier": "_verb_lock",
     "keys": "_verb_lock",
     "keys_parts": "_verb_lock",
+    "key_planes": "_verb_lock",
     "alive_keys": "_verb_lock",
     "_children": "_verb_lock",
     "_last_shares": "_verb_lock",
@@ -340,7 +341,13 @@ class CollectionSession:
         # different radix refuses (validate-before-mutate)
         collect.check_radix(cfg.n_dims, cfg.crawl_radix_bits)
         self._radix: int = int(cfg.crawl_radix_bits)
+        # the uploaded keys on their way to ``keys``: a bulk upload that
+        # says where its batches go is placed on arrival (``key_planes``,
+        # protocol/keyplanes.py); batches that arrive with no total (a
+        # streaming window's pool, a leader that sends no ``n``) wait on
+        # the host in ``keys_parts`` for ``concat_keys``
         self.keys_parts: list = []
+        self.key_planes: keyplanes.KeyPlanes | None = None
         self.keys: IbDcfKeyBatch | None = None
         self.alive_keys: np.ndarray | None = None
         self.frontier: collect.Frontier | None = None
@@ -425,8 +432,7 @@ class CollectionSession:
         (single-tenant reports depend on that), so when other tenants
         are live its reset must not zero their shared-plane accounting
         (scheduler fills, dedup hits, control bytes)."""
-        self.keys_parts.clear()
-        self.keys = None
+        self.drop_keys()
         self.alive_keys = None
         self.frontier = None
         self._children = None
@@ -490,6 +496,7 @@ class CollectionSession:
             self.bound == 0
             and self.keys is None
             and not self.keys_parts
+            and self.key_planes is None
             and self.frontier is None
             and not self._ingest_pools
             and not self._verb_lock.locked()
@@ -546,15 +553,105 @@ class CollectionSession:
         L = self.keys.cw_seed.shape[-2]
         return min(self._radix, L - int(level))
 
-    def concat_keys(self) -> None:  # fhh-race: holds=_verb_lock (reached only from tree_init/tree_restore/warmup under this session's verb lock; sanitizer-validated)
-        """Materialize ``self.keys`` from the uploaded chunks (shared by
-        ``tree_init`` and ``tree_restore``).  Under the multi-chip mesh
+    def drop_keys(self) -> None:  # fhh-race: holds=_verb_lock (reached only from the reset and session_export verbs under this session's verb lock; sanitizer-validated)
+        """Let go of the collection's keys wherever they are: resident,
+        on their way in (``key_planes``) or waiting on the host."""
+        self.keys_parts.clear()
+        self.key_planes = None
+        self.keys = None
+        self.obs.gauge("key_plane_bytes", 0)
+
+    def add_key_batch(self, batch: IbDcfKeyBatch, n: int | None = None, lo: int | None = None) -> None:  # fhh-race: atomic (the body of the unlocked add_keys fast path: dispatch only, never suspends)
+        """One batch of uploaded keys.  Of a bulk upload of ``n``
+        clients, rows ``[lo, lo + B)``: written to its place on this
+        session's chip(s) now (protocol/keyplanes.py).  The first batch
+        of an upload allocates the planes, and with them lets go of a
+        finished upload's: an ``add_keys`` after a crawl opens a new key
+        set.  With no total the batch waits on the host for
+        ``concat_keys``; the two do not mix in one collection."""
+        if n is None:
+            if self.key_planes is not None:
+                raise RuntimeError(
+                    "add_keys without a total into an upload that has one: "
+                    "reset the collection first"
+                )
+            self.keys_parts.append(batch)
+            return
+        kp = self.key_planes
+        if kp is None or kp.sealed:
+            if self.keys_parts:
+                raise RuntimeError(
+                    "add_keys with a total after batches without one: "
+                    "reset the collection first"
+                )
+            self.keys = None  # the planes of the upload before, if any
+            devices = None
+            if self._mesh is not None:
+                devices = self._mesh.bind(int(n))._active_devices()
+            # a lost chip is re-placed from the host (_mesh_recover ->
+            # tree_restore), and only where there is a checkpoint to
+            # stand on: such a session alone keeps its host batches
+            kp = self.key_planes = keyplanes.KeyPlanes(
+                n, batch, devices, keep_host=self.ckpt_dir is not None
+            )
+            # key-plane residency (obs.devmem): the flagship's "1.51
+            # chips of key storage" risk as a live per-collection gauge,
+            # set where the planes are allocated
+            self.obs.gauge("key_plane_bytes", kp.nbytes)
+        elif int(n) != kp.n:
+            raise RuntimeError(
+                f"add_keys for {int(n)} clients into an upload of {kp.n}"
+            )
+        self.obs.count("keys_placed_bytes", kp.add(lo, batch))
+
+    def ready_keys(self, verb: str, again: bool = False) -> None:  # fhh-race: holds=_verb_lock (reached only from tree_init/tree_restore/warmup under this session's verb lock; sanitizer-validated)
+        """``self.keys`` resident for ``verb``, from wherever the upload
+        left them.  A bulk upload is on the chip already: the first call
+        checks that its rows are whole and waits for the last write
+        (span ``key_place``: until RESIDENT, not until queued; the sync
+        costs nothing, level 0's expand cannot start before the keys
+        are there), every later one finds the planes (``key_planes_
+        reused``).  Batches that came with no total are concatenated
+        and placed (``concat_keys``), where the planes are missing or,
+        ``again``, by every ``tree_init``."""
+        kp = self.key_planes
+        if kp is not None:
+            held = kp.host_nbytes()
+            if self.keys is not None:
+                self.obs.count("key_planes_reused")
+            else:
+                with self.obs.span("key_place") as sp:
+                    self.keys = kp.finish(
+                        None if self._mesh is None
+                        else self._mesh.bind(kp.n).mesh
+                    )
+                obsmod.emit(
+                    "ingest.keys_resident",
+                    server=self.server_id,
+                    collection=self.key,
+                    clients=kp.n,
+                    batches=len(kp.written),
+                    plane_bytes=kp.nbytes,
+                    host_bytes_held=held,
+                    wait_s=round(sp.seconds, 4),
+                )
+        elif not self.keys_parts:
+            raise RuntimeError(f"{verb} before add_keys")
+        else:
+            held = sum(devmem.tree_nbytes(tuple(p)) for p in self.keys_parts)
+            if self.keys is None or again:
+                with self.obs.span("concat_keys"):
+                    self.concat_keys()
+        self.obs.gauge("key_host_bytes_held", held)
+
+    def concat_keys(self) -> None:  # fhh-race: holds=_verb_lock (reached only from ready_keys under this session's verb lock; sanitizer-validated)
+        """Materialize ``self.keys`` from batches that arrived with no
+        total (``keys_parts``: a streaming window's pool, a leader that
+        sends no ``n``), in order of arrival.  Under the multi-chip mesh
         the batch binds the active shard count and the key planes land
         client-axis-sharded across the server's own devices.  The
         ``key_place`` span is the second half of it — the copies, after
-        the host concatenate — and ends when the planes are RESIDENT,
-        not when the copies are queued (the sync costs nothing: level
-        0's expand cannot start before the keys are there)."""
+        the host concatenate — and ends when the planes are RESIDENT."""
         self.keys = IbDcfKeyBatch(
             *[
                 # fhh-lint: disable=chunked-device-readback,host-sync-in-hot-loop (wire input: the uploaded chunks are host numpy already — np.asarray is a no-copy view; runs once per collection/restore, never per level)
@@ -572,9 +669,6 @@ class CollectionSession:
                 self.keys = jax.device_put(self.keys)
             # fhh-lint: disable=host-sync-in-hot-loop (once per collection/restore, never per level)
             jax.block_until_ready(self.keys)
-        # key-plane residency (obs.devmem): the flagship's "1.51 chips
-        # of key storage" risk as a live per-collection gauge — set at
-        # the one place the materialized plane changes size
         self.obs.gauge(
             "key_plane_bytes", devmem.tree_nbytes(tuple(self.keys))
         )

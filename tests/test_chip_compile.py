@@ -13,7 +13,9 @@ handed this file does), and the persistent compile cache is off around the
 compiles (an entry written here cannot be read back without a chip).
 """
 
+import math
 import os
+import re
 
 import pytest
 
@@ -25,7 +27,7 @@ from fuzzyheavyhitters_tpu.ops import gc_pallas, keygen_pallas, otext, otext_pal
 from fuzzyheavyhitters_tpu.ops.ibdcf import EvalState, IbDcfKeyBatch
 from fuzzyheavyhitters_tpu.parallel import kernel_shard, server_mesh
 from fuzzyheavyhitters_tpu.parallel.server_mesh import DATA
-from fuzzyheavyhitters_tpu.protocol import collect, secure
+from fuzzyheavyhitters_tpu.protocol import collect, keyplanes, secure
 
 # chip_smoke.py's table
 L = 512
@@ -431,3 +433,47 @@ def test_sharded_secure_level_hands_planar_bits_to_the_kernel_stage(topo):
         sds((ks.bp, S), jnp.bool_, DATA, None), seed4, seed4, scalar,
     )
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize(
+    "chip,rows",
+    [(0, N_TRUSTED), (2, N_4CHIP // 2), (3, N_4CHIP // 2)],
+    ids=["one_chip_server", "two_chip_server_chip2", "two_chip_server_chip3"],
+)
+def test_key_rows_are_written_in_place(topo, chip, rows):
+    """The row write of an arriving key batch (``keyplanes._write_rows``)
+    at the cells' sizes: the whole plane of ``flagship-trusted`` on one
+    chip, and a chip's half of ``flagship-trusted-4chip`` on each chip of
+    server 1 (per-chip buffers, one-chip programs).  Every plane is aliased
+    to its output and the program holds no second plane: its temporaries
+    are smaller than one batch."""
+    sds = _sds(SingleDeviceSharding(topo.devices[chip]))
+    d, batch = 1, 100  # addkey_batch_size of the three configurations
+
+    def key_batch(n, flat=False):
+        def leaf(shape, dtype):  # a batch crosses flat, [B, -1] a leaf
+            return sds((n, math.prod(shape)) if flat else (n, *shape), dtype)
+
+        return IbDcfKeyBatch(
+            key_idx=leaf((d, 2), jnp.bool_),
+            root_seed=leaf((d, 2, 4), jnp.uint32),
+            cw_seed=leaf((d, 2, L, 4), jnp.uint32),
+            cw_bits=leaf((d, 2, L, 2), jnp.bool_),
+            cw_y_bits=leaf((d, 2, L, 2), jnp.bool_),
+        )
+
+    compiled = keyplanes._write_rows.lower(
+        key_batch(rows), key_batch(batch, flat=True), sds((), jnp.int32)
+    ).compile()
+    mem = compiled.memory_analysis()
+    plane_bytes = rows * d * 2 * (1 + 16 + L * 16 + L * 2 + L * 2)
+    batch_bytes = batch * d * 2 * (1 + 16 + L * 16 + L * 2 + L * 2)
+    # the planes as the device lays them out: no smaller than their values,
+    # and what one chip of the cell is sized for (PERF.md section 4)
+    assert plane_bytes <= mem.output_size_in_bytes < 1.05 * plane_bytes
+    # (the output's own bytes beyond the aliased planes: its tuple table)
+    assert 0 <= mem.output_size_in_bytes - mem.alias_size_in_bytes < 4096
+    assert mem.temp_size_in_bytes < batch_bytes
+    text = compiled.as_text()
+    assert len(re.findall(r"(?:may|must)-alias", text)) >= 5  # one a plane
+    assert "dynamic-update-slice" in text

@@ -403,12 +403,17 @@ def test_session_replay_answers_from_cache():
         assert (await rpc._recv(r))[1] is True
         k0, _ = _client_keys(np.random.default_rng(7), 5, 6)
         chunk = tuple(np.asarray(x) for x in k0)
-        frame = (3, "add_keys", {"keys": chunk})
+        frame = (3, "add_keys", {"keys": chunk, "n": 6, "lo": 0})
         await rpc._send(w, frame)
         assert (await rpc._recv(r))[1] is True
         await rpc._send(w, frame)  # replay: same req_id, same session
         assert (await rpc._recv(r))[1] is True
-        assert len(s0.keys_parts) == 1  # applied ONCE
+        # applied ONCE: rows [0, 6) written once (a second write would
+        # refuse the crawl as an overlap), and their bytes placed once
+        assert s0.key_planes.written == [(0, 6)]
+        assert s0.obs.counter_value("keys_placed_bytes") == sum(
+            x.nbytes for x in chunk
+        )
         await rpc._send(w, (4, "status", {}))
         st = (await rpc._recv(r))[1]
         assert st["dedup_hits"] == 1

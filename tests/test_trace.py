@@ -820,8 +820,10 @@ def test_wire_and_transfer_spans_every_level(rng, trace_dir, secure):
         for name in _WIRE_SPANS:
             assert name in ph, (srv, name)
             assert set(ph[name]["by_level"]) >= levels, (srv, name)
-        # what opens the crawl in no level
-        assert ph["concat_keys"]["count"] == ph["frontier_init"]["count"] == 1
+        # what opens the crawl in no level: the wait for the upload's last
+        # placement and the root frontier; a bulk upload concatenates nothing
+        assert ph["key_place"]["count"] == ph["frontier_init"]["count"] == 1
+        assert "concat_keys" not in ph
     # the leader's own work of a level, and its side of the wire
     for name in ("reconstruct", "threshold", "prune", "paths",
                  "wire_pickle", "wire_write", "wire_read", "wire_unpickle"):
