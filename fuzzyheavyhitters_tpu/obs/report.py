@@ -332,6 +332,8 @@ def _secure_kernel_summary(registries: dict) -> dict | None:
     by_level: dict = {}
     paths = {"ot2s": 0, "gc": 0}
     chunks: dict = {}
+    held: dict = {}
+    index_high = 0
     kshards = None
     kgather = 0.0
     seen = False
@@ -361,6 +363,12 @@ def _secure_kernel_summary(registries: dict) -> dict | None:
             kshards = g.get("last") if kshards is None else max(
                 kshards, g.get("last")
             )
+        g = snap.get("gauges", {}).get("ot_index_high")
+        if g is not None:
+            index_high = max(index_high, g.get("last"))
+        g = snap.get("gauges", {}).get("secure_t_rows_held_bytes")
+        for lvl, b in (g or {}).get("by_level", {}).items():
+            held[lvl] = max(held.get(lvl, 0), b)
         t = phases.get("kernel_gather")
         if t is not None:
             kgather += t.get("seconds", 0.0)
@@ -381,6 +389,15 @@ def _secure_kernel_summary(registries: dict) -> dict | None:
         "chunks_by_level": dict(
             sorted(chunks.items(), key=lambda kv: int(kv[0]))
         ),
+        # device bytes of the chunks the evaluator had sent u for and not
+        # yet opened, at the fullest of each level (gauge
+        # ``secure_t_rows_held_bytes``)
+        "t_rows_held_bytes_by_level": dict(
+            sorted(held.items(), key=lambda kv: int(kv[0]))
+        ),
+        # the high word of the OT sessions' 64-bit pad index (gauge
+        # ``ot_index_high``): above 0, a session has extended 2^32 OTs
+        "ot_index_high": index_high,
         # kernel-stage layout (multi-chip servers only; None/0.0 on a
         # single-device crawl — see the mesh section for the per-level
         # breakdown): the phase seconds above are the SHARDED kernels'

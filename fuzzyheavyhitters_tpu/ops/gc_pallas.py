@@ -81,13 +81,28 @@ def _gate_hash(label, gid: int, half: int):
     return _chacha16(blk)[:4]
 
 
+def _test_idx(sc_ref, pos: int, sh2):
+    """Per-test OT pad index, (low, high) word vregs: the test's place in
+    the batch plus the batch's 64-bit base (SMEM words ``pos`` and
+    ``pos + 1``, otext.index_base) — the planar twin of
+    ``otext.index_words(base, arange(B))``."""
+    from jax.experimental import pallas as pl
+
+    off = (
+        jnp.uint32(pl.program_id(0) * R_BLK * SUB * LANES)
+        + jax.lax.broadcasted_iota(jnp.uint32, sh2, 0) * jnp.uint32(LANES)
+        + jax.lax.broadcasted_iota(jnp.uint32, sh2, 1)
+    )
+    return otext.index_words((sc_ref[pos], sc_ref[pos + 1]), off)
+
+
 def _ot_pad(rows, idx, n_words: int):
     """In-kernel twin of otext.ot_hash: rows = 4 word-vregs, idx = the
-    per-test OT index vreg (already offset)."""
+    per-test OT index as (low, high) word vregs (:func:`_test_idx`)."""
     blk = [
-        rows[0] ^ idx,
+        rows[0] ^ idx[0],
         rows[1] ^ jnp.uint32(otext._OT_TWEAK1),
-        rows[2] ^ jnp.uint32(otext._OT_TWEAK2),
+        rows[2] ^ jnp.uint32(otext._OT_TWEAK2) ^ idx[1],
         rows[3] ^ jnp.uint32(otext._OT_TWEAK3),
     ]
     return _chacha16(blk)[:n_words]
@@ -107,11 +122,9 @@ def _garble_kernel(S: int, W: int, sc_ref,
     planes; mask ``u32`` 0/1; mv0/mv1 ``u32[W]``; tables
     ``u32[(S-1)*2*4]`` at ``(gate*2 + t)*4 + w`` (tree order, exactly
     _and_tree_garble's concatenation); gbl ``u32[4*S]``; dec ``u32`` 0/1;
-    cts ``u32[2*W]`` at ``c*W + w``.  sc_ref (SMEM u32[5]): R words 0..3,
-    idx_offset at 4.
+    cts ``u32[2*W]`` at ``c*W + w``.  sc_ref (SMEM u32[6]): R words 0..3,
+    idx_offset's low and high words at 4 and 5.
     """
-    from jax.experimental import pallas as pl
-
     sh2 = (R_BLK * SUB, LANES)
     sh3 = (R_BLK, SUB, LANES)
     R = [sc_ref[w] for w in range(4)]
@@ -161,12 +174,7 @@ def _garble_kernel(S: int, W: int, sc_ref,
 
     # b2a payload ciphertexts under the two output labels (gc.garble_
     # equality_payload): pad_v = H_ot(out0 [^ R], idx); ct slot = select bit
-    idx = (
-        jnp.uint32(pl.program_id(0) * R_BLK * SUB * LANES)
-        + jax.lax.broadcasted_iota(jnp.uint32, sh2, 0) * jnp.uint32(LANES)
-        + jax.lax.broadcasted_iota(jnp.uint32, sh2, 1)
-        + sc_ref[4]
-    )
+    idx = _test_idx(sc_ref, 4, sh2)
     pad0 = _ot_pad(out0, idx, W)
     pad1 = _ot_pad([o ^ r for o, r in zip(out0, R)], idx, W)
     p = _lsb01(out0[0])
@@ -181,8 +189,6 @@ def _eval_kernel(S: int, W: int, sc_ref,
                  gbl_ref, evl_ref, tab_ref, dec_ref, cts_ref,
                  e_ref, pay_ref):
     """Evaluator twin: active labels in, XOR share + opened payload out."""
-    from jax.experimental import pallas as pl
-
     sh2 = (R_BLK * SUB, LANES)
     sh3 = (R_BLK, SUB, LANES)
     wires = [
@@ -214,12 +220,7 @@ def _eval_kernel(S: int, W: int, sc_ref,
 
     s_bit = _lsb01(out[0])
     e_ref[0] = (s_bit ^ dec_ref[0].reshape(sh2)).reshape(sh3)
-    idx = (
-        jnp.uint32(pl.program_id(0) * R_BLK * SUB * LANES)
-        + jax.lax.broadcasted_iota(jnp.uint32, sh2, 0) * jnp.uint32(LANES)
-        + jax.lax.broadcasted_iota(jnp.uint32, sh2, 1)
-        + sc_ref[0]
-    )
+    idx = _test_idx(sc_ref, 0, sh2)
     pad = _ot_pad(out, idx, W)
     for w in range(W):
         ct = _sel(s_bit, cts_ref[1 * W + w].reshape(sh2),
@@ -258,8 +259,7 @@ def _garble_call(R, Y0, X0, mask, x_bits, m_v0, m_v1, idx_offset,
     rows = bp // GROUP
 
     sc = jnp.concatenate([
-        jnp.asarray(R, jnp.uint32),
-        jnp.asarray(idx_offset, jnp.uint32).reshape(1),
+        jnp.asarray(R, jnp.uint32), otext.index_base(idx_offset),
     ])
     ops = [
         _planarize(X0, B, bp),
@@ -275,7 +275,7 @@ def _garble_call(R, Y0, X0, mask, x_bits, m_v0, m_v1, idx_offset,
     n_tab = (S - 1) * 2 * 4
     # explicit i32 index map: the package enables x64, and Mosaic rejects
     # the i64 indices an auto-generated trivial map would return
-    sc_spec = pl.BlockSpec((5,), lambda j: (z,), memory_space=pltpu.SMEM)
+    sc_spec = pl.BlockSpec((6,), lambda j: (z,), memory_space=pltpu.SMEM)
     outs = pl.pallas_call(
         partial(_garble_kernel, S, W),
         grid=(rows // R_BLK,),
@@ -318,7 +318,7 @@ def _eval_call(sc, gbl, evl, tab, dec, cts, S: int, W: int,
     z = np.int32(0)
     spec = lambda k: pl.BlockSpec((k, R_BLK, SUB, LANES),
                                   lambda j: (z, j, z, z))
-    sc_spec = pl.BlockSpec((1,), lambda j: (z,), memory_space=pltpu.SMEM)
+    sc_spec = pl.BlockSpec((2,), lambda j: (z,), memory_space=pltpu.SMEM)
     return pl.pallas_call(
         partial(_eval_kernel, S, W),
         grid=(rows // R_BLK,),
@@ -357,7 +357,7 @@ def _eval_packed(msg, ev_labels, idx_offset, S: int, W: int,
     B = ev_labels.shape[0]
     bp = padded_tests(B)
     tab, gbl, dec, cts = _split_packed(jnp.asarray(msg, jnp.uint32), B, S, W)
-    sc = jnp.asarray(idx_offset, jnp.uint32).reshape(1)
+    sc = otext.index_base(idx_offset)
     outs = _eval_call(
         sc, gbl, _planarize(ev_labels, B, bp), tab, dec, cts,
         S, W, interpret,

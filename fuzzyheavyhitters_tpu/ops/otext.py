@@ -46,8 +46,10 @@ from . import baseot, prg
 
 KAPPA = 128  # security parameter: base-OT count == row width in bits
 
-# OT-hash tweak constants (words 1..3); word 0 carries the OT index.
-# Distinct from the GC gate-hash tweak (ops/gc.py) by construction.
+# OT-hash tweak constants (words 1..3); word 0 carries the OT index's low
+# word, and its high word is XORed into word 2 (:func:`index_words`), so
+# an index under 2^32 hashes exactly as it did when the index was 32 bits
+# wide.  Distinct from the GC gate-hash tweak (ops/gc.py) by construction.
 _OT_TWEAK1 = 0x4F545F31
 _OT_TWEAK2 = 0xB7E15162
 _OT_TWEAK3 = 0x8AED2A6B
@@ -173,26 +175,52 @@ def receiver_extend_rows(seeds0, seeds1, choices, base_off, row0, m: int):
     )
 
 
+def index_base(idx_offset) -> jax.Array:
+    """uint32[2], the (low, high) words of a pad index base.
+
+    An extension session's index counts every OT it has extended and
+    never resets (``OtExtSender.consumed``): at the flagship's 131,072
+    clients a crawl extends 16.8M rows a level, 4.3e9 by its leaf level,
+    so the index is 64 bits wide.  ``idx_offset`` is a Python int or a
+    traced integer scalar (the package enables x64: a Python int reaches
+    a jitted function as int64)."""
+    i = jnp.asarray(idx_offset).astype(jnp.uint64)
+    return jnp.stack([i.astype(jnp.uint32), (i >> 32).astype(jnp.uint32)])
+
+
+def index_words(base, off):
+    """(low, high) uint32 words of ``base + off``: ``base`` the uint32[2]
+    of :func:`index_base` (or any two words), ``off`` uint32 offsets of
+    the batch's OTs.  The carry out of the low word is the one place a
+    batch can straddle 2^32; in 32-bit lanes, so the Pallas kernels share
+    it (ops/gc_pallas.py ``_test_idx``)."""
+    lo = base[0] + off
+    return lo, base[1] + (lo < base[0]).astype(jnp.uint32)
+
+
 @partial(jax.jit, static_argnames=("n_words", "domain"))
 def ot_hash(rows: jax.Array, n_words: int, idx_offset=0,
             domain: int = 0) -> jax.Array:
     """Correlation-robust hash of 128-bit rows -> uint32[..., n_words] pads.
 
-    The per-row OT index is folded into the tweak so identical rows at
-    different positions hash independently (the `H(j, ·)` of IKNP).
+    The per-row OT index (64 bits: :func:`index_base`) is folded into
+    the tweak so identical rows at different positions hash
+    independently (the `H(j, ·)` of IKNP).
     ``domain`` separates distinct protocol uses that might share an index
     range (e.g. the 1-of-4 per-TEST pads vs per-ROW Δ-OT pads of the same
     extension batch); it XORs into tweak word 1.
     """
     rows = jnp.asarray(rows, jnp.uint32)
     m = rows.shape[-2]
-    idx = jnp.arange(m, dtype=jnp.uint32) + jnp.asarray(idx_offset, jnp.uint32)
+    lo, hi = index_words(
+        index_base(idx_offset), jnp.arange(m, dtype=jnp.uint32)
+    )
     shape = rows.shape[:-1]
     tweak = jnp.stack(
         [
-            jnp.broadcast_to(idx, shape),
+            jnp.broadcast_to(lo, shape),
             jnp.full(shape, _OT_TWEAK1 ^ domain, jnp.uint32),
-            jnp.full(shape, _OT_TWEAK2, jnp.uint32),
+            jnp.broadcast_to(jnp.uint32(_OT_TWEAK2) ^ hi, shape),
             jnp.full(shape, _OT_TWEAK3, jnp.uint32),
         ],
         axis=-1,

@@ -48,7 +48,8 @@ import numpy as np
 
 from . import otext
 from .gc_pallas import (
-    GROUP, R_BLK, _ot_pad, _planarize, _unplanarize, padded_tests,
+    GROUP, R_BLK, _ot_pad, _planarize, _test_idx, _unplanarize,
+    padded_tests,
 )
 from .keygen_pallas import LANES, SUB
 
@@ -71,19 +72,6 @@ def _comb(rows):
     return acc
 
 
-def _test_idx(sc_ref, pos, sh2):
-    """Per-test OT pad index vreg: global test index + the batch base
-    (SMEM word ``pos``) — the planar twin of ``idx0 + arange(B)``."""
-    from jax.experimental import pallas as pl
-
-    return (
-        jnp.uint32(pl.program_id(0) * R_BLK * SUB * LANES)
-        + jax.lax.broadcasted_iota(jnp.uint32, sh2, 0) * jnp.uint32(LANES)
-        + jax.lax.broadcasted_iota(jnp.uint32, sh2, 1)
-        + sc_ref[pos]
-    )
-
-
 def _ot2s_enc_kernel(S: int, W: int, sc_ref,
                      q_ref, x_ref, mv0_ref, mv1_ref, cts_ref):
     """Grid step (row block j, choice c): comb the S Q-rows, hash choice
@@ -98,8 +86,9 @@ def _ot2s_enc_kernel(S: int, W: int, sc_ref,
     Planar blocks: q ``u32[4*S]`` planes at ``s*4 + w``; x ``u32[S]`` 0/1
     planes; mv0/mv1 ``u32[W]``; out block = choice c's ``u32[W]`` planes
     of the ``u32[2^S * W]``-plane ciphertext stack (plane ``c*W + w``).
-    sc_ref (SMEM u32[4*2^S + 1]): the offset table ``o_c`` words at
-    ``4*c + w`` (otext.gf128_offsets order), idx_offset last."""
+    sc_ref (SMEM u32[4*2^S + 2]): the offset table ``o_c`` words at
+    ``4*c + w`` (otext.gf128_offsets order), then idx_offset's low and
+    high words."""
     from jax.experimental import pallas as pl
 
     sh2 = (R_BLK * SUB, LANES)
@@ -126,7 +115,8 @@ def _ot2s_enc_kernel(S: int, W: int, sc_ref,
 def _ot2s_dec_kernel(S: int, W: int, sc_ref,
                      t_ref, y_ref, cts_ref, pay_ref):
     """Receiver twin: comb the T-rows (= Q-comb ^ o_y), one pad, one-hot
-    XOR-select of ciphertext slot y, open.  sc_ref (SMEM u32[1]): idx0.
+    XOR-select of ciphertext slot y, open.  sc_ref (SMEM u32[2]): idx0's
+    low and high words.
 
     Like the encrypt kernel, the 2^S choice axis rides the GRID: the cts
     input block is ONE choice's W planes per step (at S=6/W=8 the full
@@ -188,10 +178,7 @@ def _enc_planar(q_rows, s_block, x_bits, m_v0, m_v1, idx_offset,
     # reproduces ot_hash(comb ^ o_c, domain=domain) bit-exactly.
     offs = otext.gf128_offsets(s_block, S)
     offs = offs.at[:, 1].set(offs[:, 1] ^ jnp.uint32(domain))
-    sc = jnp.concatenate([
-        jnp.ravel(offs),
-        jnp.asarray(idx_offset, jnp.uint32).reshape(1),
-    ])
+    sc = jnp.concatenate([jnp.ravel(offs), otext.index_base(idx_offset)])
     ops = [
         _planarize(q_rows, B, bp),
         _planarize(jnp.asarray(x_bits, jnp.uint32), B, bp),
@@ -202,7 +189,7 @@ def _enc_planar(q_rows, s_block, x_bits, m_v0, m_v1, idx_offset,
     spec = lambda k: pl.BlockSpec((k, R_BLK, SUB, LANES),
                                   lambda j, c: (z, j, z, z))
     sc_spec = pl.BlockSpec(
-        (4 * (1 << S) + 1,), lambda j, c: (z,), memory_space=pltpu.SMEM
+        (4 * (1 << S) + 2,), lambda j, c: (z,), memory_space=pltpu.SMEM
     )
     n_cts = (1 << S) * W
     # choice axis on the grid (innermost): the out block's plane index
@@ -233,7 +220,7 @@ def _dec_planar(t_rows, y_bits, msg, idx_offset,
     bp = padded_tests(B)
     rows = bp // GROUP
     n_cts = (1 << S) * W
-    sc = jnp.asarray(idx_offset, jnp.uint32).reshape(1)
+    sc = otext.index_base(idx_offset)
     # receiver-side domain fold: the kernel hashes comb(t) under the
     # fixed tweak; comb is linear with coefficient x^0 = 1 on row 0, so
     # XORing the domain into row 0's word 1 lands it on comb's word 1 —
@@ -248,7 +235,7 @@ def _dec_planar(t_rows, y_bits, msg, idx_offset,
     z = np.int32(0)
     spec = lambda k: pl.BlockSpec((k, R_BLK, SUB, LANES),
                                   lambda j, c: (z, j, z, z))
-    sc_spec = pl.BlockSpec((1,), lambda j, c: (z,),
+    sc_spec = pl.BlockSpec((2,), lambda j, c: (z,),
                            memory_space=pltpu.SMEM)
     # choice axis on the grid: the cts block follows c (one choice's W
     # planes in VMEM at a time), the payload output block does not (it

@@ -22,8 +22,8 @@ wire**:
 - every per-test stream draw (b2a payload pair, GC labels + masks)
   seeks to its shard's slice of the SAME per-level stream
   (``gc._carve_label_words_shard``, the b2a block seek below), and every
-  per-test pad index enters as ``idx0 + t0`` — so shard outputs are the
-  exact planar-row slices of the single-device buffers;
+  per-test pad index enters as ``idx0 + t0`` (64 bits wide) — so shard
+  outputs are the exact planar-row slices of the single-device buffers;
 - rows at or past the real batch (the planar pad region, which the
   uniform per-shard shapes cover) are ZERO-masked before anything
   wire-visible, reproducing the single-device ``_pad_tests`` padding
@@ -280,7 +280,7 @@ def _gb_kernel_fn(devices: tuple, field_name: str, B: int, S: int, W: int,
     def body(q_loc, s_block, flat_loc, gc_seed, b2a_seed, idx0):
         t0 = jax.lax.axis_index(DATA).astype(jnp.int64) * bloc
         q_rows = q_loc.reshape(bloc, S, 4)
-        idx = idx0 + t0.astype(jnp.uint32)
+        idx = idx0 + t0.astype(jnp.uint64)
         r1, w0, w1 = _b2a_pair_shard(
             field, b2a_seed, B, bloc, t0, W, garbler
         )
@@ -346,7 +346,7 @@ def _ev_open_fn(devices: tuple, field_name: str, B: int, S: int, W: int,
 
     def body(msg_loc, t_loc, flat_loc, idx0):
         t0 = jax.lax.axis_index(DATA).astype(jnp.int64) * bloc
-        idx = idx0 + t0.astype(jnp.uint32)
+        idx = idx0 + t0.astype(jnp.uint64)
         t_rows = t_loc.reshape(bloc, S, 4)
         msg = jnp.ravel(msg_loc)
         if path == "ot2s":
@@ -437,6 +437,11 @@ def _u32(x) -> jax.Array:
     return jnp.asarray(np.uint32(x & 0xFFFFFFFF))
 
 
+def _u64(x) -> jax.Array:
+    """A pad index base: 64 bits wide (otext.index_base)."""
+    return jnp.asarray(np.uint64(x))
+
+
 def put_u(ks: KernelShard, u_np) -> jax.Array:
     """The peer's wire u-matrix padded to the planar word extent and
     placed column-sharded: the host->device half of :func:`snd_extend`,
@@ -489,7 +494,7 @@ def gb_kernel(ks: KernelShard, s_block, q, flat, gc_seed, b2a_seed, field,
     return fn(
         q, jnp.asarray(s_block, jnp.uint32), flat,
         jnp.asarray(gc_seed, jnp.uint32), jnp.asarray(b2a_seed, jnp.uint32),
-        _u32(idx0),
+        _u64(idx0),
     )
 
 
@@ -519,7 +524,7 @@ def ev_open(ks: KernelShard, t_rows, flat, msg_dev, field, path: str,
         ks.devices, field.__name__, ks.B, ks.S, W, path,
         engine or _engine(path),
     )
-    return fn(msg_dev, t_rows, flat, _u32(idx0))
+    return fn(msg_dev, t_rows, flat, _u64(idx0))
 
 
 def share_sums(ks: KernelShard, field, vals, weight, F: int, C: int, N: int):

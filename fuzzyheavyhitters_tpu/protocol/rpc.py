@@ -194,7 +194,7 @@ async def _recv(reader: wire.FrameReader, reg=None, counter=None):
         reg.count(counter, n + _HDR.size)
     with span("wire_read"):
         # fhh-lint: disable=unbounded-await (see docstring)
-        meta, bufs = await wire.read_body(reader, n)
+        meta, bufs = await wire.read_body(reader, n, reg)
     with span("wire_unpickle"):
         return pickle.loads(meta, buffers=bufs)
 
@@ -1083,6 +1083,11 @@ class CollectorServer:
     # reader in order.  One chunk (K = 1) is the whole level: the same
     # calls, one after the other as they always were.
 
+    # how many chunks the evaluator's u may run ahead of the tables it
+    # has opened (``_ev_chunks``): every level of eight chunks or fewer
+    # runs as far ahead as it has chunks
+    CHUNKS_AHEAD = 8
+
     @staticmethod
     def _chunk_frame(k: int, K: int, arr: np.ndarray):
         """What chunk ``k`` of ``K`` crosses as: the array alone where
@@ -1135,13 +1140,13 @@ class CollectorServer:
         return [t.result() for t in tasks]
 
     def _chunk_senders(self, cs, level: int, K: int, made: asyncio.Queue,
-                       sent: asyncio.Queue | None = None):
+                       on_sent=None):
         """The two tasks that take a level's K chunk arrays from the
         device to the peer, in order: one fetches, one sends, two
         chunks at most between them, so chunk k+1's fetch runs while
         chunk k is on the socket.  ``made`` yields ``(array, token)``;
-        the token goes on to ``sent`` once that chunk's frame is with
-        the kernel."""
+        ``on_sent`` is awaited with the token once that chunk's frame
+        is with the kernel."""
         fetched: asyncio.Queue = asyncio.Queue(maxsize=2)
 
         async def fetch():
@@ -1160,8 +1165,8 @@ class CollectorServer:
                 arr, token = await fetched.get()
                 with self._chunk_label(k, K):
                     await self._dp_send(cs, self._chunk_frame(k, K, arr))
-                if sent is not None:
-                    sent.put_nowait(token)
+                if on_sent is not None:
+                    await on_sent(token)
 
         return fetch(), send()
 
@@ -1180,8 +1185,23 @@ class CollectorServer:
         idx0, off = rcv.consumed, rcv.stream_offset
         rcv.advance(B * S)
         made: asyncio.Queue = asyncio.Queue(maxsize=2)
-        # chunks whose u is on the wire, for the task that opens them
-        sent: asyncio.Queue = asyncio.Queue(maxsize=K)
+        # chunks whose u is on the wire, for the task that opens them;
+        # their (y, T rows) wait on the device meanwhile, and the gauge
+        # ``secure_t_rows_held_bytes`` says how many bytes at the fullest.
+        # At most CHUNKS_AHEAD of them: the peer always has a u to work
+        # on, and a level of 64 chunks holds neither all its T rows on
+        # the device nor all its u frames in the peer's receive slabs
+        sent: asyncio.Queue = asyncio.Queue(maxsize=min(K, self.CHUNKS_AHEAD))
+        held = [0, 0]  # device bytes of the tokens in ``sent``: now, peak
+
+        def nbytes(token) -> int:
+            return sum(int(a.nbytes) for a in token)
+
+        async def on_sent(token):
+            held[0] += nbytes(token)
+            held[1] = max(held)
+            # fhh-lint: disable=unbounded-await (drained by a sibling task, which _chunk_tasks cancels with this one)
+            await sent.put(token)
 
         async def extend():
             for k, (t0, n) in enumerate(chunks):
@@ -1202,6 +1222,7 @@ class CollectorServer:
             for k, (t0, _) in enumerate(chunks):
                 # fhh-lint: disable=unbounded-await (fed by the sibling task, which _chunk_tasks cancels with this one)
                 y, t_rows = await sent.get()
+                held[0] -= nbytes((y, t_rows))
                 with self._chunk_label(k, K):
                     bmsg = self._h2d(
                         cs, level, await self._chunk_recv(cs, k, K)
@@ -1223,9 +1244,10 @@ class CollectorServer:
             return vals
 
         *_, vals = await self._chunk_tasks(
-            extend(), *self._chunk_senders(cs, level, K, made, sent),
+            extend(), *self._chunk_senders(cs, level, K, made, on_sent),
             consume(),
         )
+        cs.obs.gauge("secure_t_rows_held_bytes", held[1], level=level)
         self._zero_phases(
             cs, level, "garble", *(("eval",) if path == "ot2s" else ())
         )
@@ -1452,6 +1474,24 @@ class CollectorServer:
                     vals = await self._ev_chunks(
                         cs, level, flat, chunks, B, W, path, count_field
                     )
+            # the pad index of this direction's extension session never
+            # resets and is 64 bits wide (otext.index_base): a session
+            # that has passed 2^32 OTs says so
+            evaluates = self.server_id != garbler
+            ot = cs._ot_rcv if evaluates else cs._ot_snd
+            index_high = ot.consumed >> 32
+            cs.obs.gauge("ot_index_high", index_high, level=level)
+            # the span log carries no gauges: under fhh-trace the level's
+            # K, what its evaluator held (the gauge ``_ev_chunks`` has
+            # just set) and the index's high word are an instant
+            # (scripts/trace_spans.py ``secure_levels``)
+            obstrace.instant(
+                "secure_level", comp=cs.obs.name, level=int(level),
+                chunks=len(chunks), index_high=index_high,
+                t_rows_held=cs.obs.gauge_value(
+                    "secure_t_rows_held_bytes", level=level
+                ) if evaluates and ks is None else 0,
+            )
         with cs.obs.span("field", level=level) as sp_field:
             if ks is not None:
                 # test-sharded b2a shares: scatter into the (F, C, N)
@@ -3394,7 +3434,7 @@ class CollectorServer:
         (n,) = _HDR.unpack(hdr)
         with obstrace.annotate(self.obs.name, "wire_read") or _NO_CTX:
             # fhh-lint: disable=unbounded-await (as above)
-            meta, bufs = await wire.read_body(reader, n)
+            meta, bufs = await wire.read_body(reader, n, self.obs)
         t_body = time.time()
         with obstrace.annotate(self.obs.name, "wire_unpickle") or _NO_CTX:
             frame = pickle.loads(meta, buffers=bufs)

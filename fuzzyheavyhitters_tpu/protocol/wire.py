@@ -100,10 +100,13 @@ class _Lease:
         _free.appendleft(self._slab)
 
 
-def _recv_buffer(size: int) -> np.ndarray:
+def _recv_buffer(size: int, reg=None) -> np.ndarray:
     """``size`` bytes for ONE frame's out-of-band buffer: memory that
     nothing else can read.  Either new (``np.empty``), or a released
-    slab of exactly that size (see :class:`_Lease`)."""
+    slab of exactly that size (see :class:`_Lease`).  ``reg`` (an
+    ``obs.metrics.Registry``) counts a slab's bytes under
+    ``wire_slab_new_bytes`` or ``wire_slab_reused_bytes``: new ones are
+    what the receive then pays a first touch for."""
     if size < _SLAB_MIN or not _LEASES:
         return np.empty(size, dtype=np.uint8)
     slab, kept, others = None, 0, []
@@ -115,6 +118,11 @@ def _recv_buffer(size: int) -> np.ndarray:
             kept += cand.nbytes
             others.append(cand)  # beyond the cap the oldest are dropped
     _free_slabs.extend(others)
+    if reg is not None:
+        reg.count(
+            "wire_slab_new_bytes" if slab is None else "wire_slab_reused_bytes",
+            size,
+        )
     if slab is None:
         slab = np.empty(size, dtype=np.uint8)
     return np.frombuffer(_Lease(slab), dtype=np.uint8)
@@ -152,9 +160,11 @@ def encode(obj) -> tuple[list, int, int]:
     return [head, meta, *bufs], HDR.size + n, oob
 
 
-async def read_body(reader: "FrameReader", n: int) -> tuple[bytearray, list]:
+async def read_body(reader: "FrameReader", n: int,
+                    reg=None) -> tuple[bytearray, list]:
     """The body of a frame whose prefix said ``n``: ``(meta, buffers)``
-    for ``pickle.loads(meta, buffers=buffers)``.  Each buffer is
+    for ``pickle.loads(meta, buffers=buffers)``; ``reg`` counts the
+    slabs (:func:`_recv_buffer`).  Each buffer is
     obtained HERE, once, at its stated length, filled from the socket
     and handed to exactly one frame: the arrays ``pickle.loads`` builds
     are views of it, and nothing writes to it again while any of them,
@@ -180,7 +190,7 @@ async def read_body(reader: "FrameReader", n: int) -> tuple[bytearray, list]:
     meta = await reader.readexactly(lens[0])
     bufs = []
     for size in lens[1:]:
-        buf = _recv_buffer(size)
+        buf = _recv_buffer(size, reg)
         # fhh-lint: disable=unbounded-await (as above)
         await reader.readinto(buf)
         bufs.append(buf)

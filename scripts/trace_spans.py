@@ -21,6 +21,11 @@ object:
   (``wire_oob`` instants, one a frame: protocol/rpc.py ``_send``), their
   framed bytes, the bytes out of band (the counter ``wire_oob_bytes``) and
   the share;
+- ``secure_levels``: per component, over its ``secure_level`` instants
+  (one a secure level), the most chunks a level crossed in, the most
+  device bytes its evaluator held in unopened chunks (the gauge
+  ``secure_t_rows_held_bytes``) and the high word of the OT pad index
+  (the gauge ``ot_index_high``);
 - ``clock`` (with ``--capture``): the program's spans are also profiler
   annotations (``<comp>:<name>``) on the profiler's own clock.  The
   benchmark lays the JSONL lines over a capture by one sync mark
@@ -132,6 +137,27 @@ def wire_oob(events: list) -> dict:
     return dict(sorted(out.items()))
 
 
+def secure_levels(events: list) -> dict:
+    """Per component, over its ``secure_level`` instants (one a secure
+    level: protocol/rpc.py ``_crawl_counts_secure``): the levels, the
+    most chunks a level crossed in, the most device bytes its evaluator
+    held in unopened chunks (gauge ``secure_t_rows_held_bytes``) and the
+    high word of the 64-bit OT pad index (gauge ``ot_index_high``)."""
+    out: dict = {}
+    for e in events:
+        if e.get("ph") == "i" and e.get("name") == "secure_level":
+            a = e["args"]
+            row = out.setdefault(e["comp"], {
+                "levels": 0, "chunks_max": 0, "t_rows_held_bytes_max": 0,
+                "ot_index_high": 0})
+            row["levels"] += 1
+            row["chunks_max"] = max(row["chunks_max"], a["chunks"])
+            row["t_rows_held_bytes_max"] = max(
+                row["t_rows_held_bytes_max"], a["t_rows_held"])
+            row["ot_index_high"] = max(row["ot_index_high"], a["index_high"])
+    return dict(sorted(out.items()))
+
+
 def clock_check(spans: list, capture: str, wall_ns_at_sync: int,
                 sync_event: str) -> dict:
     from jax.profiler import ProfileData
@@ -214,7 +240,8 @@ def main(argv=None) -> int:
         print(f"no span under {args.trace_dir}", file=sys.stderr)
         return 1
     out = {"span_ms": span_ms(spans), "gc_ot_cover": gc_ot_cover(spans),
-           "wire_oob": wire_oob(events)}
+           "wire_oob": wire_oob(events),
+           "secure_levels": secure_levels(events)}
     if args.capture:
         if args.wall_ns_at_sync is None:
             p.error("--capture needs --wall-ns-at-sync")

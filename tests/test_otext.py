@@ -1,6 +1,7 @@
 """IKNP OT-extension tests: Δ-OT invariant, chosen-payload delivery,
 stream-counter lockstep, and receiver privacy basics."""
 
+import jax
 import numpy as np
 import pytest
 
@@ -107,3 +108,112 @@ def test_fresh_s_bits_lsb_forced():
     s = otext.fresh_s_bits()
     assert s.shape == (128,) and s[0]
     assert otext.s_to_block(s)[0] & 1 == 1
+
+
+# -- the pad index is 64 bits wide -------------------------------------------
+
+EDGE = 1 << 32
+
+
+_index_base_jit = jax.jit(otext.index_base)
+
+
+def test_index_base_splits_python_ints_and_traced_scalars():
+    import jax.numpy as jnp
+
+    for v in (0, 7, EDGE - 1, EDGE, EDGE + 5, (3 << 32) + 9):
+        assert [int(w) for w in otext.index_base(v)] == [v % EDGE, v >> 32]
+        assert [int(w) for w in _index_base_jit(v)] == [v % EDGE, v >> 32]
+    assert [int(w) for w in otext.index_base(jnp.uint32(EDGE - 1))] == [EDGE - 1, 0]
+    lo, hi = otext.index_words(
+        otext.index_base(EDGE - 2), jnp.arange(4, dtype=jnp.uint32)
+    )
+    assert [int(w) for w in lo] == [EDGE - 2, EDGE - 1, 0, 1]
+    assert [int(w) for w in hi] == [0, 0, 1, 1]
+
+
+def test_ot_hash_index_does_not_wrap_at_2_32(rng):
+    """A batch that straddles 2^32 hashes each row under its own 64-bit
+    index: the rows under the boundary as a 32-bit index hashed them,
+    the rows past it unlike the wrapped index, and a batch that starts
+    past it like the tail of the one that straddles."""
+    rows = rng.integers(0, 2**32, size=(8, 4), dtype=np.uint32)
+    across = np.asarray(otext.ot_hash(rows, 4, EDGE - 3))
+    low = np.asarray(otext.ot_hash(rows[:3], 4, np.uint32(EDGE - 3)))
+    np.testing.assert_array_equal(across[:3], low)
+    past = np.asarray(otext.ot_hash(rows[3:], 4, EDGE))
+    np.testing.assert_array_equal(across[3:], past)
+    wrapped = np.asarray(otext.ot_hash(rows[3:], 4, 0))
+    assert not (past == wrapped).all(axis=1).any()
+    # the high word counts on: 2^33 is neither 2^32 nor 0
+    far = np.asarray(otext.ot_hash(rows[3:], 4, 2 * EDGE))
+    assert not (far == past).all(axis=1).any()
+    assert not (far == wrapped).all(axis=1).any()
+
+
+@pytest.mark.parametrize("idx0", [EDGE - 5000, EDGE + 17, (5 << 32) - 9000],
+                         ids=["straddles", "past", "straddles_5"])
+def test_planar_kernels_match_their_twins_across_2_32(rng, idx0):
+    """Both Pallas kernel pairs (interpret mode) against their XLA twins
+    with a pad index base at, past and across a multiple of 2^32, the
+    carry falling inside a planar block: byte-identical messages, and
+    they open to the payloads."""
+    import jax.numpy as jnp
+
+    from fuzzyheavyhitters_tpu.ops import gc, gc_pallas, otext_pallas
+    from fuzzyheavyhitters_tpu.ops.fields import FE62
+    from fuzzyheavyhitters_tpu.protocol import secure
+
+    B, S = 9000, 2  # two planar blocks, the second mostly padding
+    W = secure.payload_words(FE62)
+    s = np.asarray(otext.s_to_block(otext.fresh_s_bits()))
+    qr = rng.integers(0, 2**32, size=(B, S, 4), dtype=np.uint32)
+    x = rng.integers(0, 2, size=(B, S)).astype(bool)
+    y = x.copy()
+    y[::3] = ~y[::3]
+    m0 = rng.integers(0, 2**32, size=(B, W), dtype=np.uint32)
+    m1 = rng.integers(0, 2**32, size=(B, W), dtype=np.uint32)
+    eq = np.all(x == y, axis=1)
+    # the 1-of-2^S table
+    msg_x = np.asarray(secure._ot2s_encrypt_packed_xla(
+        jnp.asarray(qr), jnp.asarray(s), jnp.asarray(x), jnp.asarray(m0),
+        jnp.asarray(m1), W, idx0,
+    ))
+    msg_p = np.asarray(otext_pallas.ot2s_encrypt(
+        qr, s, x, m0, m1, W, idx0, domain=secure._OT2S_DOMAIN, interpret=True
+    ))
+    np.testing.assert_array_equal(msg_x, msg_p)
+    tr = np.where(y[..., None], qr ^ s, qr)
+    pay_p = np.asarray(otext_pallas.ot2s_decrypt(
+        tr, y, msg_p, W, idx0, domain=secure._OT2S_DOMAIN, interpret=True
+    ))
+    pay_x = np.asarray(secure._ot2s_decrypt_packed_xla(
+        jnp.asarray(tr), jnp.asarray(y), jnp.asarray(msg_x), S, W, idx0
+    ))
+    np.testing.assert_array_equal(pay_x, pay_p)
+    np.testing.assert_array_equal(pay_p, np.where(eq[:, None], m1, m0))
+    # an index 2^32 lower is another table
+    low = np.asarray(otext_pallas.ot2s_encrypt(
+        qr, s, x, m0, m1, W, idx0 - EDGE, domain=secure._OT2S_DOMAIN,
+        interpret=True,
+    )) if idx0 >= EDGE else None
+    assert low is None or not np.array_equal(low, msg_p)
+    # the packed garbled batch
+    seed = rng.integers(0, 2**32, size=4, dtype=np.uint32)
+    gmsg_x, _ = gc._garble_equality_payload_packed_xla(
+        jnp.asarray(s), jnp.asarray(qr), jnp.asarray(seed), jnp.asarray(x),
+        jnp.asarray(m0), jnp.asarray(m1), W, idx0,
+    )
+    gmsg_p, _ = gc_pallas.garble_equality_payload_packed(
+        s, qr, seed, x, m0, m1, W, idx0, interpret=True
+    )
+    np.testing.assert_array_equal(np.asarray(gmsg_x), np.asarray(gmsg_p))
+    ev = qr ^ np.where(x[..., None], s, np.zeros(4, np.uint32))
+    _, gpay_p = gc_pallas.eval_equality_payload_packed(
+        np.asarray(gmsg_p), ev, W, idx0, interpret=True
+    )
+    _, gpay_x = gc._eval_equality_payload_packed_xla(
+        gmsg_x, jnp.asarray(ev), S, W, idx0
+    )
+    np.testing.assert_array_equal(np.asarray(gpay_x), np.asarray(gpay_p))
+    np.testing.assert_array_equal(np.asarray(gpay_p), m1)  # y == x here

@@ -250,7 +250,10 @@ def _cfg(port_base=BASE_PORT, **kw):
     return Config(**defaults)
 
 
-async def _run_protocol(cfg, keys0, keys1, nreqs):
+async def _run_protocol(cfg, keys0, keys1, nreqs, make_leader=RpcLeader,
+                        probe=None):
+    """The crawl's result; with ``probe``, what ``probe(leader, s0, s1)``
+    read before the pair went down."""
     s0 = rpc.CollectorServer(0, cfg)
     s1 = rpc.CollectorServer(1, cfg)
     host0, port0 = cfg.server0.rsplit(":", 1)
@@ -263,11 +266,12 @@ async def _run_protocol(cfg, keys0, keys1, nreqs):
     c0 = await rpc.CollectorClient.connect(host0, port0)
     c1 = await rpc.CollectorClient.connect(host1, port1)
     await asyncio.gather(t0, t1)
-    lead = RpcLeader(cfg, c0, c1)
+    lead = make_leader(cfg, c0, c1)
     try:
         await asyncio.gather(c0.call("reset"), c1.call("reset"))
         await lead.upload_keys(keys0, keys1)
-        return await lead.run(nreqs)
+        res = await lead.run(nreqs)
+        return res if probe is None else probe(lead, s0, s1)
     finally:
         # a leaked listener (held alive by reference cycles until a gc
         # pass) keeps its port bound into LATER tests — close everything
@@ -350,3 +354,82 @@ def test_secure_socket_run_matches_trusted(rng, monkeypatch, ot_path):
                     leaf.shape == p.shape and leaf.dtype == p.dtype
                     and np.array_equal(leaf, p)
                 ), "packed share-bit tensor crossed the wire in secure mode"
+
+
+# ---------------------------------------------------------------------------
+# The secure lane against the benchmark's plain reference, level by level,
+# with every level in eight chunks
+# ---------------------------------------------------------------------------
+
+
+def _plain_reference():
+    import importlib.util
+    import os
+
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmark", "references", "linf_ball_1d.py",
+    )
+    spec = importlib.util.spec_from_file_location("linf_ball_1d", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+class _TapLeader(RpcLeader):
+    """The leader with a tap on its level loop: what it holds after each
+    level (benchmark/lane.py does the same for the harness)."""
+
+    def __init__(self, *a, **kw):
+        super().__init__(*a, **kw)
+        self.held = []
+
+    async def _run_one_level(self, level, nreqs, thresh):
+        counts, alive = await super()._run_one_level(level, nreqs, thresh)
+        if counts is not None:
+            self.held.append((int(level), self.paths.copy(), counts.copy()))
+        return counts, alive
+
+
+@pytest.mark.parametrize("seed", [20260930, 3735928559])
+def test_secure_lane_in_eight_chunks_matches_the_plain_reference(monkeypatch, seed):
+    """``CollectorServer`` x2 + ``RpcLeader`` over sockets, secure lane,
+    4,096 clients and a frontier bucket pinned at 8, so that every level
+    (the F255 leaf level too) crosses in K = 8 chunks of one planar
+    block: the frontier and the counts held after every level are those
+    of ``benchmark/references/linf_ball_1d.py`` on the same seeded
+    points."""
+    from fuzzyheavyhitters_tpu.ops import gc_pallas
+
+    L, n, ball = 5, 4096, 1
+    rng = np.random.default_rng(seed)
+    hot = rng.integers(0, 1 << L, size=3)
+    pts = np.concatenate([
+        rng.choice(hot, size=n - n // 4, p=[0.5, 0.3, 0.2]),
+        rng.integers(0, 1 << L, size=n // 4),
+    ])
+    bits = np.array([[bitutils.int_to_bits(L, int(v))] for v in pts])
+    k0, k1 = ibdcf.gen_l_inf_ball(bits, ball, rng, engine="np")
+    block = gc_pallas.R_BLK * gc_pallas.GROUP
+    monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", block * 64)
+    cfg = _cfg(port_base=BASE_PORT + 80 + 40 * (seed % 2), data_len=L,
+               ball_size=ball, threshold=0.1, addkey_batch_size=1024, f_max=32,
+               secure_exchange=True)
+
+    def probe(lead, s0, s1):
+        return lead.held, [
+            [s._default().obs.counter_value("secure_chunks", level=lv)
+             for lv in range(L)] for s in (s0, s1)
+        ]
+
+    held, chunks = asyncio.run(_run_protocol(
+        cfg, k0, k1, n,
+        make_leader=lambda *a: _TapLeader(*a, min_bucket=8), probe=probe,
+    ))
+    ref = _plain_reference()
+    want = ref.frontiers(bits, ball, max(1, int(cfg.threshold * n)), L)
+    assert [lv for lv, _, _ in held] == list(range(L))
+    for lv, paths, counts in held:
+        assert ref.crawl_frontier(paths, counts) == want[lv + 1], lv
+    assert want[L]  # hitters came out
+    assert chunks == [[8] * L, [8] * L]

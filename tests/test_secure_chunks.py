@@ -129,6 +129,20 @@ def _run(coro):
     return asyncio.run(coro)
 
 
+def _spy_frames(monkeypatch):
+    """Every payload the servers put on the data plane, in order."""
+    sent = []
+    real_send = rpc._send
+
+    async def spy(writer, obj, **kw):
+        if kw.get("counter") == "data_bytes_sent":
+            sent.append(obj[1])
+        await real_send(writer, obj, **kw)
+
+    monkeypatch.setattr(rpc, "_send", spy)
+    return sent
+
+
 # B = F * 2 * N tests at the root level of a one-dimensional crawl
 _SHAPES = {
     # one chunk exactly: the level goes whole
@@ -152,15 +166,7 @@ def test_chunked_level_is_the_whole_level(monkeypatch, shape, path, garbler):
     port = BASE_PORT + 20 * (
         list(_SHAPES).index(shape) * 4 + (path == "gc") * 2 + garbler
     )
-    sent = []
-    real_send = rpc._send
-
-    async def spy(writer, obj, **kw):
-        if kw.get("counter") == "data_bytes_sent":
-            sent.append(obj[1])
-        await real_send(writer, obj, **kw)
-
-    monkeypatch.setattr(rpc, "_send", spy)
+    sent = _spy_frames(monkeypatch)
     # a chunk of ``blocks`` planar blocks, by the larger frame's bytes
     S, W = 2, secure.payload_words(FE62)
     from fuzzyheavyhitters_tpu.parallel.kernel_shard import n_msg_planes
@@ -370,3 +376,255 @@ def test_level_chunks_from_the_level_dimensions(B, S, W, path, want):
     assert [t0 for t0, _ in chunks] == list(np.cumsum([0] + want[:-1]))
     assert all(t0 % BLOCK == 0 for t0, _ in chunks)
     assert all(t0 * S % 512 == 0 and t0 * W % 16 == 0 for t0, _ in chunks)
+
+
+# -- the 64-bit OT index, and levels of many chunks --------------------------
+
+EDGE = 1 << 32  # where a 32-bit pad index would wrap
+
+
+def _pin_sessions(pair, seed=11):
+    """Both directions' OT sessions and both servers' level seeds made
+    from ``seed`` instead of the system's randomness, so that a level's
+    frames and shares are the same bytes run after run."""
+    from fuzzyheavyhitters_tpu.ops import otext
+
+    rng = np.random.default_rng(seed)
+    cs = pair.sessions
+    for g in (0, 1):
+        s_bits = rng.integers(0, 2, 128).astype(bool)
+        s_bits[0] = True
+        seeds0, seeds1 = (
+            rng.integers(0, 1 << 32, (128, 4), dtype=np.uint32) for _ in "01"
+        )
+        cs[g]._ot_snd = otext.OtExtSender(
+            s_bits, np.where(s_bits[:, None], seeds1, seeds0)
+        )
+        cs[1 - g]._ot_rcv = otext.OtExtReceiver(seeds0, seeds1)
+    for i, c in enumerate(cs):
+        c._ot = (c._ot_snd, c._ot_rcv)
+        c._sec_seed = np.arange(4, dtype=np.uint32) + np.uint32(100 * i + seed)
+        c._crawl_ctr = 0
+
+
+def _set_cursors(pair, consumed):
+    """Every OT endpoint's pad index set to ``consumed``; the column
+    streams stay where they are."""
+    for cs in pair.sessions:
+        cs._ot_snd._sent = cs._ot_rcv._recv = consumed
+
+
+def _interpret_engines(monkeypatch):
+    """The chip's engine for the 1-of-2^S table on the CPU: the Pallas
+    kernels in interpret mode, through the dispatchers the servers
+    call."""
+    import functools
+
+    from fuzzyheavyhitters_tpu.ops import otext_pallas
+
+    monkeypatch.setattr(secure, "_ot2s_pallas_engine", lambda: True)
+    for name in ("ot2s_encrypt", "ot2s_decrypt"):
+        monkeypatch.setattr(
+            otext_pallas, name,
+            functools.partial(getattr(otext_pallas, name), interpret=True),
+        )
+
+
+def _digest(frames, shares):
+    import hashlib
+
+    h = hashlib.sha256()
+    for f in frames:
+        h.update(np.ascontiguousarray(f[2] if isinstance(f, tuple) else f))
+    for v in shares:
+        h.update(np.ascontiguousarray(v))
+    return h.hexdigest()
+
+
+async def _three_levels(pair, monkeypatch, sent, start, frame_bytes):
+    """Levels garbled by server 0, 1, 0 from pinned sessions whose pad
+    indices stand at ``start``: (frames, shares) of each, then every
+    cursor."""
+    await pair.both("tree_init", {"root_bucket": 4})
+    _pin_sessions(pair)
+    _set_cursors(pair, start)
+    monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", frame_bytes)
+    out = []
+    for g in (0, 1, 0):
+        del sent[:]
+        shares = await pair.level(g, path="ot2s")
+        out.append((list(sent), shares))
+    return out, pair.ot_state()
+
+
+_INDEX_N = 3072              # B = 4 * 2 * 3072 tests = 3 planar blocks
+_INDEX_ROWS = 4 * 2 * _INDEX_N * 2
+_TWO_BLOCKS = 2 * BLOCK * 64  # the ot2s table: 64 bytes a test
+# frames and shares of the three levels below, recorded on the parent of
+# the 64-bit index (commit ff34183: the index a uint32) with the pad
+# indices starting 4 levels under 2^32, so that none passes it
+_RECORDED = {
+    "whole": "bd49df05774aac0c4db11fd5b791c79e173d52014fb7b0a6b988d2bb7be9e495",
+    "K2": "2eb7b63ef4c7796191d8ab5c4ca455a54c9d3304316c0c45603fcc77c8485914",
+}
+
+
+@pytest.mark.parametrize("engine", ["xla", "pallas_interpret"])
+@pytest.mark.parametrize("flow", ["whole", "K2"])
+def test_levels_under_2_32_are_the_recorded_bytes(monkeypatch, flow, engine):
+    """An index under 2^32 hashes as it did when the index was 32 bits
+    wide: frames and shares byte for byte, on either engine."""
+    if engine == "pallas_interpret":
+        _interpret_engines(monkeypatch)
+    sent = _spy_frames(monkeypatch)
+    port = BASE_PORT + 400 + 20 * (2 * (flow == "K2") + (engine != "xla"))
+
+    async def run():
+        async with _Pair(port, _INDEX_N) as pair:
+            return await _three_levels(
+                pair, monkeypatch, sent, EDGE - 4 * _INDEX_ROWS,
+                WHOLE if flow == "whole" else _TWO_BLOCKS,
+            )
+
+    levels, state = _run(run())
+    assert all(c < EDGE for c, _ in state[0:2] + state[3:5])
+    got = _digest([f for fr, _ in levels for f in fr],
+                  [v for _, sh in levels for v in sh])
+    assert got == _RECORDED[flow]
+
+
+@pytest.mark.parametrize("engine", ["xla", "pallas_interpret"])
+@pytest.mark.parametrize("flow", ["whole", "K2"])
+def test_levels_across_2_32_keep_exact_shares_and_lockstep(monkeypatch, flow, engine):
+    """Pad indices that start half a level under 2^32: the first level
+    of each direction crosses inside its first chunk (in the middle of a
+    kernel's planar block) and every later base is itself past 2^32.
+    Shares reconstruct to the exact counts, both endpoints of each
+    session stay in lockstep and ``ot_index_high`` says 1 (that a
+    crossed index is not the wrapped one: tests/test_otext.py)."""
+    if engine == "pallas_interpret":
+        _interpret_engines(monkeypatch)
+    sent = _spy_frames(monkeypatch)
+    port = BASE_PORT + 500 + 20 * (2 * (flow == "K2") + (engine != "xla"))
+    start = EDGE - _INDEX_ROWS // 8 * 3  # 1.5 planar blocks of tests under
+
+    async def run():
+        async with _Pair(port, _INDEX_N) as pair:
+            levels, state = await _three_levels(
+                pair, monkeypatch, sent, start,
+                WHOLE if flow == "whole" else _TWO_BLOCKS,
+            )
+            high = [
+                (cs.obs.gauge_value("ot_index_high"),
+                 cs.obs.gauge_max("ot_index_high"))
+                for cs in pair.sessions
+            ]
+            return levels, state, high, pair.pts
+
+    levels, state, high, pts = _run(run())
+    for frames, shares in levels:
+        got = np.asarray(FE62.canon(FE62.sub(shares[0], shares[1])))
+        assert np.array_equal(got[0], _root_counts(pts)) and not got[1:].any()
+        assert len(frames) == (2 if flow == "whole" else 4)
+    # server 0's sender with server 1's receiver, and the reverse
+    (s0_snd, s0_rcv, _, s1_snd, s1_rcv, _) = state
+    assert s0_snd == s1_rcv and s1_snd == s0_rcv
+    assert s0_snd[0] == start + 2 * _INDEX_ROWS > EDGE
+    assert s1_snd[0] == start + _INDEX_ROWS > EDGE
+    assert high == [(1, 1), (1, 1)]
+
+
+def test_chunked_and_whole_agree_across_2_32_on_both_engines(monkeypatch):
+    """One level across the boundary four ways (whole or in two chunks,
+    XLA twin or the Pallas kernels in interpret mode) from the same
+    pinned sessions: the same shares, and the chunks' frames side by
+    side are the whole level's."""
+    sent = _spy_frames(monkeypatch)
+    start = EDGE - _INDEX_ROWS // 8 * 3
+
+    async def run():
+        out = {}
+        async with _Pair(BASE_PORT + 600, _INDEX_N) as pair:
+            for engine in ("xla", "pallas_interpret"):
+                if engine == "pallas_interpret":
+                    _interpret_engines(monkeypatch)
+                for flow, budget in (("whole", WHOLE), ("K2", _TWO_BLOCKS)):
+                    levels, _ = await _three_levels(
+                        pair, monkeypatch, sent, start, budget
+                    )
+                    out[engine, flow] = levels[0]
+        return out
+
+    out = _run(run())
+    want_frames, want_shares = out["xla", "whole"]
+    u_whole, msg_whole = sorted(want_frames, key=lambda a: a.nbytes)
+    for (engine, flow), (frames, shares) in out.items():
+        for a, b in zip(want_shares, shares):
+            assert np.array_equal(a, b), (engine, flow)
+        if flow == "whole":
+            u, msg = sorted(frames, key=lambda a: a.nbytes)
+        else:
+            u = np.concatenate([f[2] for f in frames if f[2].ndim == 2], axis=1)
+            msg = np.concatenate(
+                [f[2].reshape(16, -1) for f in frames if f[2].ndim == 1], axis=1
+            ).reshape(-1)
+        assert np.array_equal(u, u_whole), (engine, flow)
+        assert np.array_equal(msg, msg_whole), (engine, flow)
+
+
+@pytest.mark.parametrize("K,f", [(32, 32), (64, 64)])
+def test_level_of_many_chunks_is_the_whole_level(monkeypatch, K, f):
+    """The flagship's K at N = 131,072 (32 chunks at bucket 32, 64 at
+    bucket 64), here chunks of one planar block: the whole level's two
+    messages and shares bit for bit, and the evaluator never holds more
+    T rows on the device than its queue of unopened chunks allows."""
+    from fuzzyheavyhitters_tpu.parallel.kernel_shard import n_msg_planes
+
+    sent = _spy_frames(monkeypatch)
+    n, S, W = 4096, 2, secure.payload_words(FE62)
+    per_test = max(16 * S, 4 * n_msg_planes("ot2s", S, W))
+
+    async def run():
+        async with _Pair(BASE_PORT + 620 + 20 * (K == 64), n) as pair:
+            await pair.both("tree_init", {"root_bucket": f})
+            del sent[:]
+            before = pair.ot_state()
+            monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", WHOLE)
+            whole = await pair.level(0, path="ot2s")
+            after_whole, frames_whole = pair.ot_state(), list(sent)
+            pair.set_ot_state(before)
+            del sent[:]
+            monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", BLOCK * per_test)
+            cut = await pair.level(0, path="ot2s")
+            held = [cs.obs.gauge_value("secure_t_rows_held_bytes", level=0)
+                    for cs in pair.sessions]
+            ks = [cs.obs.counter_value("secure_chunks", level=0)
+                  for cs in pair.sessions]
+            return (whole, after_whole, frames_whole, cut, pair.ot_state(),
+                    list(sent), held, ks)
+
+    (whole, after_whole, frames_whole, cut, after_cut, frames_cut, held,
+     ks) = _run(run())
+    assert f * 2 * n == K * BLOCK and ks == [1 + K, 1 + K]
+    for a, b in zip(whole, cut):
+        assert a.dtype == b.dtype and np.array_equal(a, b)
+    assert after_cut == after_whole
+    u_whole, msg_whole = sorted(frames_whole, key=lambda a: a.nbytes)
+    assert len(frames_cut) == 2 * K
+    us = [fr for fr in frames_cut if fr[2].ndim == 2]
+    msgs = [fr for fr in frames_cut if fr[2].ndim == 1]
+    assert [fr[:2] for fr in us] == [fr[:2] for fr in msgs] == [
+        (k, K) for k in range(K)
+    ]
+    assert np.array_equal(np.concatenate([fr[2] for fr in us], axis=1), u_whole)
+    planes = n_msg_planes("ot2s", S, W)
+    assert np.array_equal(
+        np.concatenate([fr[2].reshape(planes, -1) for fr in msgs], axis=1),
+        msg_whole.reshape(planes, -1),
+    )
+    # the evaluator (server 1) held some chunks' (strings, T rows), never
+    # more than the CHUNKS_AHEAD its queue takes and the one that waits
+    # to get in; the garbler holds none
+    token = BLOCK * S * (1 + 16)
+    assert held[0] is None
+    assert token <= held[1] <= (rpc.CollectorServer.CHUNKS_AHEAD + 1) * token

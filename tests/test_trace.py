@@ -900,6 +900,10 @@ def test_chunk_spans_carry_the_level_and_lie_inside_gc_ot(rng, trace_dir, monkey
     for srv in ("s0", "s1"):
         assert ks[srv]["by_level"] == {"0": K, "1": 1}
     assert rep["secure_kernels"]["chunks_by_level"] == {"0": K, "1": 1}
+    # the evaluator's gauge of what it held, and the index's high word
+    held = rep["secure_kernels"]["t_rows_held_bytes_by_level"]
+    assert set(held) == {"0", "1"} and held["0"] > 0 and held["1"] > 0
+    assert rep["secure_kernels"]["ot_index_high"] == 0
     evs = _events(trace_dir)
     assert tracemod.validate(evs)["ok"]
     spans = [e for e in evs if e["ph"] == "X"]
@@ -949,6 +953,15 @@ def test_chunk_spans_carry_the_level_and_lie_inside_gc_ot(rng, trace_dir, monkey
         assert 0 < row["share_min"] <= row["share_median"] <= 1 + 1e-3
         assert row["busy_share_median"] >= row["share_median"] - 1e-9
         assert {"otext", "b2a", "d2h"} <= set(row["chunk_leaf_ms_median"])
+    # one ``secure_level`` instant a level and server: K, the held bytes
+    # and the index's high word, for the span log that carries no gauges
+    rows = mod.secure_levels(evs)
+    assert set(rows) == {"server0", "server1"}
+    for row in rows.values():
+        assert row["levels"] == 2 and row["chunks_max"] == K
+        assert row["ot_index_high"] == 0
+    assert max(r["t_rows_held_bytes_max"] for r in rows.values()) == max(
+        held.values())
 
 
 def test_wire_spans_of_a_frame_with_two_out_of_band_buffers(trace_dir, monkeypatch):

@@ -393,6 +393,55 @@ def test_slab_returns_only_when_its_last_reader_is_gone(monkeypatch, cpu_default
     wire._free_slabs.clear()
 
 
+@pytest.mark.skipif(not wire._LEASES, reason="slabs need PEP 688 (Python 3.12)")
+@pytest.mark.parametrize("in_flight,keep", [(2, None), (8, 4)],
+                         ids=["two-in-flight", "more-than-the-list-keeps"])
+def test_slab_counters_say_how_many_receive_buffers_were_new(
+        monkeypatch, in_flight, keep):
+    """64 frames of one size through a mux whose consumer holds
+    ``in_flight`` of them at a time: ``wire_slab_new_bytes`` +
+    ``wire_slab_reused_bytes`` count every frame once; with two in flight
+    three slabs at most are ever new, and with more in flight than the
+    free list keeps (``_SLAB_KEEP`` lowered to ``keep`` slabs) every
+    round past the first pays for the ones that were dropped."""
+    monkeypatch.setattr(wire, "_SLAB_MIN", OOB)
+    size, frames = 8 * OOB, 64
+    if keep is not None:
+        monkeypatch.setattr(wire, "_SLAB_KEEP", keep * size)
+    wire._free_slabs.clear()
+    srv_obj = rpc.CollectorServer(0, _cfg())
+
+    async def run():
+        cr, cw, sr, sw, srv = await _pair(BASE_PORT + 53 + in_flight)
+        mux = sessions.PlaneMux()
+        mux.attach(sr, srv_obj._recv_plane_frame)
+        payload = _big(size, seed=60)
+        held = []
+        for i in range(frames):
+            await rpc._send(cw, ("chan", payload))
+            held.append(await asyncio.wait_for(mux.recv("chan"), 10))
+            assert np.array_equal(held[-1], payload)
+            if len(held) == in_flight:
+                held.clear()  # the consumer is done with these
+        mux.close()
+        await _close(srv, cw, sw)
+
+    asyncio.run(asyncio.wait_for(run(), 60))
+    wire._free_slabs.clear()
+    new = srv_obj.obs.counter_value("wire_slab_new_bytes")
+    reused = srv_obj.obs.counter_value("wire_slab_reused_bytes")
+    assert new + reused == frames * size
+    if keep is None:
+        assert size <= new <= 3 * size
+    else:
+        # a round's first receive takes one of the 8 released and keeps
+        # 4 more: the round pays for the 3 that were dropped
+        rounds = frames // in_flight
+        dropped = in_flight - keep - 1
+        assert new >= (in_flight + (rounds - 1) * dropped) * size
+        assert reused >= (rounds - 1) * keep * size
+
+
 def test_read_cancelled_midway_aborts_the_connection():
     """A frame read cancelled after some of its bytes were taken cannot
     resume: the connection is aborted, not left out of step."""
