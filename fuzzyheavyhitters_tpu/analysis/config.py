@@ -172,6 +172,7 @@ class LintConfig:
         "fuzzyheavyhitters_tpu/obs",
         "fuzzyheavyhitters_tpu/native",
         "fuzzyheavyhitters_tpu/protocol/rpc.py",
+        "fuzzyheavyhitters_tpu/protocol/wire.py",
     )
     # unbounded-await rule: transport modules where every await on a
     # network read / event wait / dial must carry a timeout or deadline
@@ -297,7 +298,7 @@ class LintConfig:
     # Every byte still leaves through these two: rpc._send hands the
     # frame built by wire.encode (pickled metadata + the arrays' own
     # buffers) to the transport, and nothing else calls the writer
-    taint_wire_calls: tuple = ("_send", "_dp_send")
+    taint_wire_calls: tuple = ("_send", "_dp_send", "_encode")
     # declared declassifiers: masking/opening operations whose output
     # is public by protocol argument — pad-XOR encryptions, share
     # openings, one-way commitments.  `declassified(reason)` contracts

@@ -37,7 +37,12 @@ Well-known metric names (what populates them):
   ``control_bytes_*`` — leader↔server control plane;
   ``wire_oob_bytes`` — the part of a registry's sent bytes (either
   plane) that crossed as raw array buffers, not through pickle
-  (protocol/wire.py);
+  (protocol/wire.py); ``plane_stream_frames`` — data-plane frames sent
+  through their stream's writer thread (``wire.PlaneStreams``: all of
+  ``data_msgs_sent``), with the gauge ``plane_send_queue_high`` (the
+  most frames that thread held at a hand-over of the level, that frame
+  included: 1 = the stream was free) — rolled up per server into a
+  top-level ``plane`` section whenever a data plane carried a frame;
   ``device_fetches`` — device->host transfers (each a synchronous
   round trip: the COUNT is a latency term beside the byte count, so
   both are measured); ``gc_tests`` — secure-mode equality tests;
@@ -147,6 +152,9 @@ def run_report(registries=None) -> dict:
     sk = _secure_kernel_summary(out)
     if sk is not None:
         doc["secure_kernels"] = sk
+    plane = _plane_summary(out)
+    if plane is not None:
+        doc["plane"] = plane
     sketch = _sketch_summary(out)
     if sketch is not None:
         doc["sketch"] = sketch
@@ -410,6 +418,31 @@ def _secure_kernel_summary(registries: dict) -> dict | None:
             for lvl, v in sorted(by_level.items(), key=lambda kv: int(kv[0]))
         },
     }
+
+
+def _plane_summary(registries: dict) -> dict | None:
+    """The data plane's streams, per registry that sent on one: frames
+    sent, how many of them went through the stream's writer thread (a
+    frame that took any other way shows as the difference), and the
+    most frames that thread held at once; None when no plane carried
+    a frame."""
+    out = {}
+    for name, snap in registries.items():
+        counters = snap.get("counters", {})
+        sent = counters.get("data_msgs_sent")
+        if sent is None:
+            continue
+        g = snap.get("gauges", {}).get("plane_send_queue_high") or {}
+        out[name] = {
+            "msgs_sent": sent.get("total", 0),
+            "stream_frames": counters.get(
+                "plane_stream_frames", {}
+            ).get("total", 0),
+            "send_queue_high": max(
+                [g.get("last", 0), *g.get("by_level", {}).values()]
+            ),
+        }
+    return out or None
 
 
 def _sketch_summary(registries: dict) -> dict | None:

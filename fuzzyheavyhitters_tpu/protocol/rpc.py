@@ -16,11 +16,16 @@ communication backend"):
   layout and the endpoints).  A peer that still speaks the bare-pickle
   body is refused by the first frame (its lengths cannot sum: a
   ``FrameError`` here, an ``UnpicklingError`` there).
-- **Data plane** — one server↔server TCP connection carrying the packed
-  share-bit tensors per level (server1 listens on ``port+1``, server0 dials
-  with retries — the reference's GC-mesh bootstrap order, server.rs:197-262,
-  collapsed from ``num_cpus`` sockets to one because the exchange is a single
-  batched tensor, not per-thread GC traffic).
+- **Data plane** — the server↔server exchange of a level's tensors
+  (server1 listens on ``port+1``, server0 dials with retries — the
+  reference's GC-mesh bootstrap order, server.rs:197-262, collapsed from
+  ``num_cpus`` sockets because the exchange is batched tensors, not
+  per-thread GC traffic): two one-way TCP streams, one a direction, each
+  written by a thread at its sender and read by a thread at its receiver
+  (:class:`~.wire.PlaneStreams`), so that the loop thread, which
+  dispatches every kernel, copies none of the plane's bytes.  Server 0
+  dials the port twice; the first frame on each connection (the hello)
+  says which direction it carries.
 
 Counts come back as **field-element shares**: both servers derive a common
 pseudorandom mask stream from a shared seed — the reference hardcodes the
@@ -76,7 +81,7 @@ each with its own frontier, sketch ratchet, expand cache, ingest gate
 namespace, OT endpoints, and verb lock.  The server↔server data plane
 demultiplexes into per-collection channels (:class:`~.sessions.PlaneMux`
 — frames are ``(collection, payload)``), so two tenants' 2PC exchanges
-interleave on one socket without desynchronizing; per-session base-OT /
+interleave on one plane without desynchronizing; per-session base-OT /
 coin-flip handshakes run lazily over each session's channel.  Device
 work interleaves across sessions through the
 :class:`~.tenancy.TenantScheduler`: while tenant A's span waits on the
@@ -131,6 +136,13 @@ from .sessions import (  # noqa: F401  (re-exports: wire-format helpers kept imp
 )
 
 _HDR = wire.HDR
+# the first frame of each data-plane connection, in words: hello,
+# direction, dial token (``wire.hello_frame``).  The dialer (server 0)
+# opens both streams and names them by the direction their bulk flows;
+# the listener answers hello, "ok", token.  The digit is the plane
+# protocol's generation: a peer that speaks another is refused
+_PLANE_HELLO = b"fhh-plane/2"
+_PLANE_DIRECTIONS = (b"0to1", b"1to0")
 
 
 _NO_CTX = contextlib.nullcontext()
@@ -159,8 +171,18 @@ async def _send(writer: wire.FrameWriter, obj, reg=None, counter=None,
     frames can coalesce into ONE drain on its final frame instead of
     one await per frame — but some frame in every burst MUST flush, or
     a dead peer lets the queue grow without bound."""
-    span = _no_span if reg is None else reg.span
-    with span("wire_pickle"):
+    pieces = _encode(obj, reg, counter)
+    with (_no_span if reg is None else reg.span)("wire_write"):
+        writer.writelines(pieces)
+        if flush:
+            await writer.drain()
+
+
+def _encode(obj, reg=None, counter=None) -> list:
+    """``obj`` as the pieces of one frame (``wire.encode``), timed and
+    counted on ``reg`` as :func:`_send` says: the head that both planes'
+    sends share."""
+    with (_no_span if reg is None else reg.span)("wire_pickle"):
         pieces, nbytes, oob = wire.encode(obj)
     if counter is not None:
         reg.count(counter, nbytes)
@@ -169,10 +191,7 @@ async def _send(writer: wire.FrameWriter, obj, reg=None, counter=None,
             # the span log carries no counters: under fhh-trace the
             # engagement is an instant beside the frame's wire_write
             obstrace.instant("wire_oob", comp=reg.name, oob=oob, framed=nbytes)
-    with span("wire_write"):
-        writer.writelines(pieces)
-        if flush:
-            await writer.drain()
+    return pieces
 
 
 async def _recv(reader: wire.FrameReader, reg=None, counter=None):
@@ -426,14 +445,17 @@ class CollectorServer:
         self._table = SessionTable(server_id, cfg, self.obs, ckpt_dir)
         # device-work interleaving across sessions + stall-fill telemetry
         self._sched = tenancy.TenantScheduler(self.obs)
-        # peer data plane: one socket, demuxed per collection; sends are
-        # (collection, payload) frames, the pump routes receives
-        self._peer_reader: wire.FrameReader | None = None
-        self._peer_writer: wire.FrameWriter | None = None
+        # peer data plane: two one-way streams with a thread each
+        # (wire.PlaneStreams), demuxed per collection; sends are
+        # (collection, payload) frames, the mux routes receives
+        self._peer: wire.PlaneStreams | None = None
         self._plane = sessions.PlaneMux(
             route_count=self._plane_count, tag=f"server{server_id}"
         )
         self._peer_addr: tuple | None = None
+        # the listener's half-open plane: (token, {direction: socket})
+        # of the dial whose second connection has not said hello yet
+        self._plane_half: tuple | None = None
         # resilience state: boot id (reconnect vs restart), per-leader-
         # session replay dedup, control writers for aclose
         self._boot_id: str = _secrets.token_hex(8)
@@ -813,8 +835,8 @@ class CollectorServer:
     # data-plane framing with byte/message accounting; levels attribute
     # via the active span (obs.metrics.Registry.count).  Every frame is
     # (collection, payload): sends interleave freely across sessions
-    # (one atomic write per frame), receives demux through the PlaneMux
-    # so each session reads only its own FIFO channel.
+    # (the writer thread takes each frame whole), receives demux through
+    # the PlaneMux so each session reads only its own FIFO channel.
     def _plane_count(self, chan: str, nbytes: int) -> None:
         """PlaneMux byte-accounting hook: received bytes land on the
         owning session's registry (whose active span attributes them to
@@ -824,15 +846,46 @@ class CollectorServer:
         reg.count("data_bytes_recv", nbytes)
 
     async def _dp_send(self, cs: CollectionSession, obj):
-        cs.obs.count("data_msgs_sent")
+        """One frame to the peer, through the plane's writer thread:
+        pickled and counted here on the loop (the pieces are views,
+        never joined), written there, and "sent" when the kernel has
+        every byte.  The thread's clock becomes this registry's spans
+        when the send returns, as ``PlaneMux.recv`` does for a read:
+        ``wire_queue`` (frame handed over -> its send begins; the thread
+        hop alone where the stream was free) and ``wire_write`` (the
+        thread's send of this frame).  ``plane_stream_frames`` counts
+        the frames that went this way (all of ``data_msgs_sent``), the
+        gauge ``plane_send_queue_high`` the most frames the writer held
+        at a hand-over of the level, this one included (1: free;
+        ``wire.PlaneStreams.SEND_DEPTH``: at its bound)."""
+        reg = cs.obs
+        reg.count("data_msgs_sent")
         # under fhh-trace the frame's session header carries this verb's
         # (trace_id, span_id), so the peer's arrival instant parents
         # under the sender's span in the merged timeline
-        hdr = obstrace.wire_tag() if obstrace.enabled() else None
+        tracing = obstrace.enabled()
+        hdr = obstrace.wire_tag() if tracing else None
         frame = (cs.key, obj) if hdr is None else (cs.key, obj, hdr)
-        await _send(
-            self._peer_writer, frame, reg=cs.obs, counter="data_bytes_sent"
-        )
+        pieces = _encode(frame, reg, "data_bytes_sent")
+        sp = reg.current_span()
+        level = None if sp is None else sp.level
+        peer = self._peer
+        if peer is None:
+            raise ConnectionResetError("no peer data plane")
+        t_put, t_begin, t_end, held = await peer.send(pieces)
+        reg.count("plane_stream_frames", level=level)
+        if held > (reg.gauge_value("plane_send_queue_high", level) or 0):
+            reg.gauge("plane_send_queue_high", held, level=level)
+        for name, a, b in (
+            ("wire_queue", t_put, t_begin), ("wire_write", t_begin, t_end),
+        ):
+            reg.timer_add(name, b - a, level)
+            if tracing:
+                obstrace.span_at(name, reg.name, a, b - a, level)
+        if tracing:
+            # the span log carries no gauges (scripts/trace_spans.py
+            # ``plane_streams``)
+            obstrace.instant("plane_send", comp=reg.name, held=held)
 
     async def _dp_recv(self, cs: CollectionSession):
         # the whole data-plane receive; its children peer_wait,
@@ -846,15 +899,15 @@ class CollectorServer:
                 return await self._plane.recv(cs.key, cs.obs)
 
     async def _swap(self, cs: CollectionSession, obj):
-        """Role-ordered data-plane exchange on this session's channel:
-        server 0 writes first, server 1 reads first — symmetric
-        send-then-recv deadlocks once payloads exceed the combined
-        socket buffers (both drains stall)."""
-        if self.server_id == 0:
-            await self._dp_send(cs, obj)
-            return await self._dp_recv(cs)
-        peer = await self._dp_recv(cs)
-        await self._dp_send(cs, obj)
+        """Full-duplex data-plane exchange on this session's channel:
+        both servers send and receive at once.  Each direction has a
+        stream and a reader thread of its own that always drains into
+        receive buffers, so two sends past the socket buffers cannot
+        wait on each other (what the old order, server 0 writes first
+        and server 1 reads first, existed to avoid on one loop)."""
+        _, peer = await self._chunk_tasks(
+            self._dp_send(cs, obj), self._dp_recv(cs)
+        )
         return peer
 
     # -- expand stage (device) vs open stage (plane I/O) -----------------
@@ -1075,13 +1128,12 @@ class CollectorServer:
     # between them — kernel, fetch and send of what it makes
     # (``_chunk_senders``), receive and kernel of what it is sent — so
     # chunk k+1's kernel and chunk k's fetch run while chunk k-1 is on
-    # the socket and the peer works on chunk k-2.  Every server has a
-    # task that only receives, so someone always reads: the ``_swap``
-    # deadlock of two sides writing past the socket buffers cannot form.
-    # Fetches stay on threads (``_fetch``), a send is a whole frame on
-    # the one writer, and ``PlaneMux`` holds frames ahead of their
-    # reader in order.  One chunk (K = 1) is the whole level: the same
-    # calls, one after the other as they always were.
+    # the socket and the peer works on chunk k-2.  Fetches stay on
+    # threads (``_fetch``), a send is a whole frame on the plane's
+    # writer thread, its reader thread always reads, and ``PlaneMux``
+    # holds frames ahead of their receiver in order.  One chunk (K = 1)
+    # is the whole level: the same calls, one after the other as they
+    # always were.
 
     # how many chunks the evaluator's u may run ahead of the tables it
     # has opened (``_ev_chunks``): every level of eight chunks or fewer
@@ -2731,8 +2783,8 @@ class CollectorServer:
         level, exactly the per-tenant recovery story."""
         if self.server_id != 0:
             return True  # listener: re-accept + re-key is automatic
-        if self._peer_writer is not None and not self._peer_writer.is_closing():
-            self._peer_writer.close()
+        if self._peer is not None:
+            self._peer.close()
         await self._dial_peer()
         self.obs.count("plane_resets")
         obs.emit("resilience.plane_reset", server=self.server_id)
@@ -2746,12 +2798,12 @@ class CollectorServer:
         reached only one server, so the peer's matching frame never
         comes); this verb dispatches OUTSIDE the verb locks (see
         ``_dispatch``) precisely so it can break that wedge: the close
-        kills the mux pump, every channel's blocked recv raises, the
-        wedged verbs error out and release their locks, and the leader's
-        subsequent ``plane_reset`` re-keys the plane cleanly."""
-        w = self._peer_writer
-        if w is not None and not w.is_closing():
-            w.close()
+        ends both streams and fails the mux, every channel's blocked
+        recv and every queued send raises, the wedged verbs error out
+        and release their locks, and the leader's subsequent
+        ``plane_reset`` re-keys the plane cleanly."""
+        if self._peer is not None:
+            self._peer.close()
         self.obs.count("plane_breaks")
         obs.emit("resilience.plane_break", server=self.server_id)
         return True
@@ -3356,8 +3408,8 @@ class CollectorServer:
                 if done:  # progress: push the backstop out again
                     deadline = time.monotonic() + 1800
                 if pending and (
-                    self._peer_writer is None
-                    or self._peer_writer.is_closing()
+                    self._peer is None
+                    or self._peer.is_closing()
                     or time.monotonic() > deadline
                     # the backstop covers what keepalive cannot: a verb
                     # blocked while the peer data plane stays OPEN (e.g. a
@@ -3393,23 +3445,21 @@ class CollectorServer:
             if not w.is_closing():
                 w.close()
         self._ctl_writers.clear()
-        if self._peer_writer is not None and not self._peer_writer.is_closing():
-            self._peer_writer.close()
+        if self._peer is not None:
+            self._peer.close()
         self._plane.close()
         for srv in srvs:
             await srv.wait_closed()
 
     @staticmethod
-    def _keepalive(writer: wire.FrameWriter) -> None:
-        """Aggressive-ish TCP keepalive on the persistent data plane so a
-        SILENTLY dead peer (partition, power loss — no FIN/RST) surfaces as
-        a connection error within ~2 minutes instead of hanging a blocked
-        ``_swap`` recv forever (kernels default to ~2 hours)."""
+    def _keepalive(sock) -> None:
+        """Aggressive-ish TCP keepalive on a stream of the persistent
+        data plane so a SILENTLY dead peer (partition, power loss — no
+        FIN/RST) surfaces as a connection error within ~2 minutes
+        instead of hanging a blocked send or receive forever (kernels
+        default to ~2 hours).  It is what bounds the streams' threads."""
         import socket
 
-        sock = writer.get_extra_info("socket")
-        if sock is None:
-            return
         sock.setsockopt(socket.SOL_SOCKET, socket.SO_KEEPALIVE, 1)
         for opt, val in (
             ("TCP_KEEPIDLE", 60), ("TCP_KEEPINTVL", 20), ("TCP_KEEPCNT", 3)
@@ -3417,57 +3467,75 @@ class CollectorServer:
             if hasattr(socket, opt):
                 sock.setsockopt(socket.IPPROTO_TCP, getattr(socket, opt), val)
 
-    async def _recv_plane_frame(self, reader):
-        """One framed data-plane read for the PlaneMux pump: returns
-        (framed byte size, frame, stamps).  Byte accounting happens in
-        the mux's route hook (the channel is only known after
-        unpickling), and so does the timing: ``stamps`` are the wall
-        clock with the header read, the body read (metadata and every
-        out-of-band buffer held) and the frame unpickled, which the
-        receiving verb turns into its ``peer_wait``, ``wire_read`` and
-        ``wire_unpickle``
-        (:meth:`~.sessions.PlaneMux.recv`).  Under fhh-trace the read
-        and the unpickle are profiler annotations while they run."""
-        # fhh-lint: disable=unbounded-await (serve-loop read: the pump waits indefinitely for the next frame by design; liveness comes from the socket's TCP keepalive)
-        hdr = await reader.readexactly(_HDR.size)
-        t_hdr = time.time()
-        (n,) = _HDR.unpack(hdr)
-        with obstrace.annotate(self.obs.name, "wire_read") or _NO_CTX:
-            # fhh-lint: disable=unbounded-await (as above)
-            meta, bufs = await wire.read_body(reader, n, self.obs)
-        t_body = time.time()
-        with obstrace.annotate(self.obs.name, "wire_unpickle") or _NO_CTX:
-            frame = pickle.loads(meta, buffers=bufs)
-        return n + _HDR.size, frame, (t_hdr, t_body, time.time())
+    def _plane_annotation(self, name: str):
+        """The streams' threads' profiler annotations under fhh-trace
+        (``<server>:wire_write`` / ``wire_read`` / ``wire_unpickle``
+        while they run, on the thread that runs them)."""
+        return obstrace.annotate(self.obs.name, name) or _NO_CTX
 
-    def _attach_plane(self, reader, writer) -> None:
-        """Bind a fresh peer transport: keepalive on, mux pump attached
-        (failing every session's blocked recv from the OLD transport).
-        Sessions re-key their channels lazily (``_ensure_session_plane``
-        compares ``cs.plane_epoch`` to the mux epoch)."""
-        old = self._peer_writer
-        if old is not None and old is not writer and not old.is_closing():
-            old.close()  # the replaced transport: nobody else will
-        self._peer_reader, self._peer_writer = reader, writer
-        self._keepalive(writer)
-        self._plane.attach(reader, self._recv_plane_frame)
+    def _attach_plane(self, send_sock, recv_sock) -> None:
+        """Bind a fresh pair of peer streams as THE plane: the old one
+        closed (failing every session's blocked recv and queued send),
+        keepalive on both sockets, a new mux epoch, the threads started
+        (``wire.PlaneStreams``: received frames reach the mux on the
+        loop, stamped; byte accounting happens in the mux's route hook,
+        the channel being known only after unpickling).  Sessions re-key
+        their channels lazily (``_ensure_session_plane`` compares
+        ``cs.plane_epoch`` to the mux epoch)."""
+        if self._peer is not None:
+            self._peer.close()  # the replaced plane: nobody else will
+        for sock in (send_sock, recv_sock):
+            self._keepalive(sock)
+        epoch = self._plane.attach()
+        self._peer = wire.PlaneStreams(
+            send_sock, recv_sock,
+            on_frame=functools.partial(self._plane.route, epoch),
+            on_lost=functools.partial(self._plane.lost, epoch),
+            reg=self.obs, annotate=self._plane_annotation,
+            name=self.obs.name,
+        )
 
     async def _dial_peer(self) -> None:
         """Dial the peer data plane under the shared backoff policy (the
         reference's connect_with_retries_tcp, server.rs:235, upgraded from
-        fixed sleeps to exponential backoff + full jitter).  Per-session
-        channel handshakes (coin flip + base-OT) run lazily over the
-        fresh transport — see ``_ensure_session_plane``."""
+        fixed sleeps to exponential backoff + full jitter): the peer's
+        data port twice, a hello on each that says which direction it
+        carries and which dial it belongs to, and the listener's answer
+        once it holds both.  Per-session channel handshakes (coin flip +
+        base-OT) run lazily over the fresh plane — see
+        ``_ensure_session_plane``."""
         peer_host, peer_port = self._peer_addr
 
         async def dial():
-            return await asyncio.wait_for(
-                wire.open_connection(peer_host, peer_port),
-                respolicy.DIAL_TIMEOUT_S,
-            )
+            loop = asyncio.get_running_loop()
+            token = _secrets.token_hex(8).encode()
+            socks = []
+            try:
+                for direction in _PLANE_DIRECTIONS:
+                    sock = await wire.open_plane_socket(
+                        peer_host, peer_port, respolicy.DIAL_TIMEOUT_S
+                    )
+                    socks.append(sock)
+                    hello = b" ".join((_PLANE_HELLO, direction, token))
+                    await asyncio.wait_for(
+                        loop.sock_sendall(sock, wire.hello_frame(hello)),
+                        respolicy.DIAL_TIMEOUT_S,
+                    )
+                ok = await asyncio.wait_for(
+                    wire.sock_recv_hello(socks[0]), respolicy.DIAL_TIMEOUT_S
+                )
+                if ok != b" ".join((_PLANE_HELLO, b"ok", token)):
+                    raise ConnectionError(
+                        f"peer answered the data-plane hello with {ok[:64]!r}"
+                    )
+            except BaseException:
+                for sock in socks:
+                    sock.close()
+                raise
+            return socks
 
         try:
-            r, w = await respolicy.retry_async(
+            send_sock, recv_sock = await respolicy.retry_async(
                 dial,
                 respolicy.DIAL_POLICY,
                 what=f"peer data plane {peer_host}:{peer_port}",
@@ -3476,7 +3544,7 @@ class CollectorServer:
             raise ConnectionError(
                 f"peer data-plane unreachable at {peer_host}:{peer_port}: {e!r}"
             ) from e
-        self._attach_plane(r, w)
+        self._attach_plane(send_sock, recv_sock)
 
     async def start(self, host: str, port: int, peer_host: str, peer_port: int):
         """Bring up the data plane FIRST (like the reference: GC mesh before
@@ -3487,7 +3555,9 @@ class CollectorServer:
         obs.emit("server.engines", server=self.server_id, **self.engine_tags())
         with self.obs.span("setup"):
             if self.server_id == 1:
-                srv = await wire.start_server(self._on_peer, host, peer_port)
+                srv = await wire.start_socket_server(
+                    self._on_peer, host, peer_port
+                )
                 self._peer_ready = asyncio.Event()
                 self._peer_srv = srv
                 # fhh-lint: disable=unbounded-await (startup barrier: a
@@ -3501,8 +3571,54 @@ class CollectorServer:
             )
         return self._rpc_srv
 
-    async def _on_peer(self, reader, writer):
-        self._attach_plane(reader, writer)
+    async def _on_peer(self, conn) -> None:
+        """One accepted connection of the data plane: its first frame
+        must be the hello of a stream, ``hello direction token``.
+        The second stream of a dial completes the plane, which is then
+        attached and answered on the stream this server reads.  Anything
+        else (a peer that opens the plane on one connection and starts
+        with a data frame, a torn or late hello) is refused loudly and
+        the connection closed: a plane is never met halfway."""
+        try:
+            hello = (await asyncio.wait_for(
+                wire.sock_recv_hello(conn), respolicy.DIAL_TIMEOUT_S
+            )).split(b" ")
+            if not (
+                len(hello) == 3 and hello[0] == _PLANE_HELLO
+                and hello[1] in _PLANE_DIRECTIONS
+            ):
+                raise ConnectionError(
+                    "the first frame of a data-plane connection is not a "
+                    "stream's hello (a peer of the one-connection plane?)"
+                )
+        except BaseException as e:
+            conn.close()
+            if not isinstance(e, Exception):
+                raise
+            self.obs.count("plane_hellos_refused")
+            obs.emit(
+                "plane.hello_refused", severity="error",
+                server=self.server_id, error=repr(e),
+            )
+            return
+        _, direction, token = hello
+        half = self._plane_half
+        if half is None or half[0] != token:
+            # a newer dial: what an abandoned one left half-open goes
+            for stale in (half[1].values() if half is not None else ()):
+                stale.close()
+            half = self._plane_half = (token, {})
+        half[1][direction] = conn
+        if len(half[1]) < len(_PLANE_DIRECTIONS):
+            return
+        self._plane_half = None
+        recv_sock, send_sock = (half[1][d] for d in _PLANE_DIRECTIONS)
+        self._attach_plane(send_sock, recv_sock)
+        # the answer: a few dozen bytes into an empty socket buffer
+        # (the reader thread has this socket's other direction)
+        recv_sock.sendall(
+            wire.hello_frame(b" ".join((_PLANE_HELLO, b"ok", token)))
+        )
         self._peer_ready.set()
 
     async def _ensure_session_plane(self, cs: CollectionSession) -> None:

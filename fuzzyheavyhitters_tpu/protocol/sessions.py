@@ -19,10 +19,11 @@ global.  This module holds that keying:
   ``__hello__`` handshake (protocol/rpc.py).  A connection that never
   says hello (or says it without a collection) works on the DEFAULT
   session, so every single-tenant flow is unchanged.
-- :class:`PlaneMux` — the server↔server data-plane socket demultiplexed
+- :class:`PlaneMux` — the server↔server data plane demultiplexed
   into per-collection FIFO channels: every plane frame is
-  ``(channel, payload)``, a single pump task routes frames into
-  per-channel queues, and each session's exchanges ride its own channel
+  ``(channel, payload)``, the plane's reader thread hands frames to the
+  loop, which routes them into per-channel queues, and each session's
+  exchanges ride its own channel
   — two collections' 2PC transcripts interleave on the wire without
   ever desynchronizing, because each receiver demuxes by key instead
   of assuming global FIFO order.
@@ -1236,16 +1237,19 @@ class _PlaneFailure:
 
 
 class PlaneMux:
-    """Per-collection demux of the single server↔server socket.
+    """Per-collection demux of the server↔server data plane.
 
-    Every data-plane frame is ``(channel, payload)``; one pump task per
-    live transport routes payloads into per-channel FIFO queues.  Sends
-    interleave freely (each frame is one atomic ``writer.write``), and
-    each receiver reads only its own channel — so two collections' 2PC
-    exchanges share the socket without any cross-tenant ordering
-    assumptions.  ``epoch`` counts transports: a session whose channel
-    handshake ran against an older epoch must re-key before trusting
-    the plane again (protocol/rpc.py ``_ensure_session_plane``)."""
+    Every data-plane frame is ``(channel, payload)``; the plane's
+    reader thread (:class:`~.wire.PlaneStreams`) hands each frame to
+    :meth:`route` on the loop, which puts the payload into its
+    channel's FIFO queue.  Sends interleave freely (each frame goes to
+    the writer thread whole), and each receiver reads only its own
+    channel — so two collections' 2PC exchanges share the plane without
+    any cross-tenant ordering assumptions.  ``epoch`` counts planes: a
+    session whose channel handshake ran against an older epoch must
+    re-key before trusting the plane again (protocol/rpc.py
+    ``_ensure_session_plane``), and a frame or a loss that a replaced
+    plane still reports is dropped by its epoch."""
 
     # per-channel depth bound: the positional protocol keeps at most a
     # handful of frames in flight per collection (one exchange at a
@@ -1257,7 +1261,6 @@ class PlaneMux:
         self.epoch = 0
         self._queues: dict[str, asyncio.Queue] = {}
         self._err: BaseException | None = None
-        self._pump_task: asyncio.Task | None = None
         # (chan, nbytes) byte-accounting hook, resolved by the server to
         # the owning session's registry
         self._route_count = route_count
@@ -1266,27 +1269,26 @@ class PlaneMux:
         # the peer's span -> this server's wire arrival
         self.tag = tag
 
-    def attach(self, reader, read_frame) -> int:
-        """Bind the mux to a fresh transport: fail every waiter of the
-        old one (their frames can never arrive), reset channels, and
-        start the new pump.  Returns the new epoch."""
+    def attach(self) -> int:
+        """Bind the mux to a fresh plane: fail every waiter of the old
+        one (their frames can never arrive) and reset the channels.
+        Returns the new epoch, which the plane's frames and its loss
+        are to be reported under (:meth:`route`, :meth:`lost`)."""
         self.epoch += 1
         old, self._queues = self._queues, {}
         err = ConnectionError("data plane replaced by a new connection")
         for q in old.values():
             self._deliver_failure(q, err)
         self._err = None
-        if self._pump_task is not None and not self._pump_task.done():
-            self._pump_task.cancel()
-        self._pump_task = asyncio.ensure_future(
-            self._pump(reader, read_frame, self.epoch)
-        )
         return self.epoch
 
     def close(self) -> None:
-        if self._pump_task is not None and not self._pump_task.done():
-            self._pump_task.cancel()
         self.fail(ConnectionError("data plane closed"))
+
+    def lost(self, epoch: int, err: BaseException) -> None:
+        """The plane of ``epoch`` was closed or lost."""
+        if epoch == self.epoch and self._err is None:
+            self.fail(err)
 
     def fail(self, err: BaseException) -> None:
         """Fail every current and future recv with ``err`` (until the
@@ -1311,7 +1313,7 @@ class PlaneMux:
     def _queue(self, chan: str) -> asyncio.Queue:
         q = self._queues.get(chan)
         if q is None:
-            # fhh-lint: disable=unbounded-queue (bounded: MAX_DEPTH is a positive maxsize; overflow fails the plane loudly in _pump)
+            # fhh-lint: disable=unbounded-queue (bounded: MAX_DEPTH is a positive maxsize; overflow fails the plane loudly in route)
             q = self._queues[chan] = asyncio.Queue(maxsize=self.MAX_DEPTH)
         return q
 
@@ -1322,19 +1324,23 @@ class PlaneMux:
         (plane_reset, shard retry, supervisor rollback) works
         unchanged.
 
-        The pump reads the frame outside the receiving verb's context
-        and stamps the wall clock on it; ``reg``, the receiver's
-        registry, turns the stamps into its spans ``peer_wait`` (this
-        call -> the frame's header read; 0 where the frame was already
-        there: time the peer's compute, pickle and write own),
-        ``wire_read`` (header -> body held) and ``wire_unpickle``, as
-        the pump took them: a frame read before this call lies before
-        it in the trace too."""
-        if self._err is not None:
+        The plane's reader thread reads the frame outside the
+        receiving verb's context and stamps the wall clock on it;
+        ``reg``, the receiver's registry, turns the stamps into its
+        spans HERE, on the loop thread, where the registry's span stack
+        lives: ``peer_wait`` (this call -> the frame's header read; 0
+        where the frame was already there: time the peer's compute,
+        pickle and write own), ``wire_read`` (header -> body held) and
+        ``wire_unpickle``, as the thread took them: a frame read before
+        this call lies before it in the trace too."""
+        q = self._queue(chan)
+        if self._err is not None and q.empty():
+            # a channel born on a dead plane; one that lived through the
+            # death holds the frames that arrived whole before it, and
+            # then the failure (fail)
             raise ConnectionError(
                 f"data plane down: {self._err!r}"
             ) from self._err
-        q = self._queue(chan)
         t0 = time.time()
         # fhh-lint: disable=unbounded-await (deliberately unbounded like the serve-loop reads: response waits are bounded at the caller — per-verb deadlines on the control plane, TCP keepalive on the data plane)
         item = await q.get()
@@ -1368,34 +1374,23 @@ class PlaneMux:
             )
         return payload
 
-    async def _pump(self, reader, read_frame, epoch: int) -> None:
-        """Route frames until the transport dies.  A pump outliving its
-        epoch (superseded by attach) exits quietly — its queues were
-        already failed and replaced."""
+    def route(self, epoch: int, nbytes: int, frame, stamps=None) -> None:
+        """One received frame into its channel's queue, on the loop
+        thread.  Frames are (collection, payload) — or, under fhh-trace,
+        (collection, payload, (trace_id, span_id)): the session header
+        grows the sender's trace context.  ``stamps`` are the reader
+        thread's (:meth:`recv`).  A frame that cannot be routed — a
+        channel past ``MAX_DEPTH`` (the servers' streams diverged),
+        something that is no frame — is a plane death for every blocked
+        receiver."""
+        if epoch != self.epoch or self._err is not None:
+            return
         try:
-            while True:
-                # fhh-lint: disable=unbounded-await (serve-loop read: waits indefinitely for the next frame by design; liveness comes from TCP keepalive on the peer socket)
-                nbytes, frame, *stamps = await read_frame(reader)
-                if epoch != self.epoch:
-                    return
-                # frames are (collection, payload) — or, under fhh-trace,
-                # (collection, payload, (trace_id, span_id)): the session
-                # header grows the sender's trace context
-                chan, payload = frame[0], frame[1]
-                hdr = frame[2] if len(frame) > 2 else None
-                if self._route_count is not None:
-                    self._route_count(chan, nbytes)
-                self._queue(chan).put_nowait(
-                    (payload, hdr, stamps[0] if stamps else None)
-                )
-                # the queue owns the frame now: a pump that kept it in
-                # its locals until the NEXT frame arrives would pin the
-                # receive buffer (a slab of up to a level's message,
-                # protocol/wire.py) long after its consumer let go
-                frame = payload = None
-        except asyncio.CancelledError:
-            raise
-        # fhh-lint: disable=broad-except (transport boundary: EVERY pump failure — EOF, reset, a QueueFull divergence, a corrupt frame — must surface to the blocked receivers as a plane death)
+            chan, payload = frame[0], frame[1]
+            hdr = frame[2] if len(frame) > 2 else None
+            if self._route_count is not None:
+                self._route_count(chan, nbytes)
+            self._queue(chan).put_nowait((payload, hdr, stamps))
+        # fhh-lint: disable=broad-except (transport boundary: EVERY routing failure — a QueueFull divergence, a malformed frame — must surface to the blocked receivers as a plane death)
         except Exception as e:
-            if epoch == self.epoch:
-                self.fail(e)
+            self.fail(e)

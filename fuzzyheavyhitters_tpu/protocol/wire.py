@@ -32,15 +32,30 @@ buffer the consumer awaits, so each readiness event is one
 ``recv_into`` of all the kernel holds, with no pause and no second
 copy.  :class:`FrameWriter` is the ``StreamWriter`` subset the package
 uses, on the same transport.
+
+The server↔server data plane does not use them.  Its bytes are most of a
+secure level's, and a loop thread that copies them dispatches nothing
+meanwhile: :class:`PlaneStreams` carries the plane on two one-way TCP
+streams over blocking sockets, each written by a thread at its sender
+and read by a thread at its receiver (:func:`open_plane_socket`,
+:func:`start_socket_server`, :func:`hello_frame` and
+:func:`sock_recv_hello` bring the sockets up and exchange the hellos
+that say which direction each carries).  The frame is the same.
 """
 
 from __future__ import annotations
 
 import asyncio
 import collections
+import contextlib
 import pickle
+import queue
+import socket
 import struct
 import sys
+import threading
+import time
+import weakref
 
 import numpy as np
 
@@ -72,7 +87,13 @@ _SLAB_KEEP = 512 << 20  # bytes of released slabs kept, newest first
 _LEASES = sys.version_info >= (3, 12)
 # released slabs (np.uint8 arrays), newest first; a release onto a full
 # list drops the oldest
-_free_slabs: collections.deque = collections.deque(maxlen=16)
+_free_slabs: collections.deque = collections.deque(maxlen=16)  # fhh-guard: _free_slabs=_slab_lock
+# One taker at a time: the data plane's reader threads (one a server,
+# two in a process that holds both) and the loop's control-plane reads
+# all take from the list, and a taker empties it while it scans.  A
+# second taker that came meanwhile would see no slab and pay first
+# touches for a new one.  Releases need no lock (_Lease.__del__).
+_slab_lock = threading.Lock()
 
 
 class _Lease:
@@ -94,9 +115,12 @@ class _Lease:
     def __buffer__(self, flags) -> memoryview:
         return memoryview(self._slab)
 
+    # any thread may drop the last reference, at interpreter exit too,
+    # and inside _recv_buffer's locked scan (a collection run by one of
+    # its allocations): one atomic append and never the lock, the
+    # trimming is _recv_buffer's
+    # fhh-lint: disable=guarded-state-unlocked (a release is one atomic deque.appendleft; taking _slab_lock in a finalizer could deadlock its own thread inside _recv_buffer)
     def __del__(self, _free=_free_slabs):
-        # any thread may drop the last reference, at interpreter exit
-        # too: one atomic append, the trimming is _recv_buffer's
         _free.appendleft(self._slab)
 
 
@@ -110,14 +134,15 @@ def _recv_buffer(size: int, reg=None) -> np.ndarray:
     if size < _SLAB_MIN or not _LEASES:
         return np.empty(size, dtype=np.uint8)
     slab, kept, others = None, 0, []
-    while _free_slabs:  # newest first; popleft is atomic, so a slab has one taker
-        cand = _free_slabs.popleft()
-        if slab is None and cand.nbytes == size:
-            slab = cand
-        elif kept + cand.nbytes <= _SLAB_KEEP:
-            kept += cand.nbytes
-            others.append(cand)  # beyond the cap the oldest are dropped
-    _free_slabs.extend(others)
+    with _slab_lock:
+        while _free_slabs:  # newest first
+            cand = _free_slabs.popleft()
+            if slab is None and cand.nbytes == size:
+                slab = cand
+            elif kept + cand.nbytes <= _SLAB_KEEP:
+                kept += cand.nbytes
+                others.append(cand)  # beyond the cap the oldest are dropped
+        _free_slabs.extend(others)
     if reg is not None:
         reg.count(
             "wire_slab_new_bytes" if slab is None else "wire_slab_reused_bytes",
@@ -160,11 +185,16 @@ def encode(obj) -> tuple[list, int, int]:
     return [head, meta, *bufs], HDR.size + n, oob
 
 
-async def read_body(reader: "FrameReader", n: int,
-                    reg=None) -> tuple[bytearray, list]:
-    """The body of a frame whose prefix said ``n``: ``(meta, buffers)``
-    for ``pickle.loads(meta, buffers=buffers)``; ``reg`` counts the
-    slabs (:func:`_recv_buffer`).  Each buffer is
+def _body_reads(n: int, reg=None):
+    """The reads that take in the body of a frame whose prefix said
+    ``n``, for whoever owns the socket: a generator that yields a
+    count twice (that many bytes of their own, to be sent back in: the
+    buffer count, then the lengths) and then ONE list of buffers to be
+    filled exactly, in order (the pickled metadata and every
+    out-of-band buffer: a blocking socket fills them with one scatter
+    read), and returns ``(meta, buffers)`` for
+    ``pickle.loads(meta, buffers=buffers)``; ``reg`` counts the slabs
+    (:func:`_recv_buffer`).  Each out-of-band buffer is
     obtained HERE, once, at its stated length, filled from the socket
     and handed to exactly one frame: the arrays ``pickle.loads`` builds
     are views of it, and nothing writes to it again while any of them,
@@ -176,25 +206,41 @@ async def read_body(reader: "FrameReader", n: int,
     reference's death (:func:`_recv_buffer`)."""
     if n < _NBUF.size:
         raise FrameError(f"frame of {n} bytes holds no buffer count")
-    # fhh-lint: disable=unbounded-await (part of a frame whose header has arrived; the callers' frame reads are unbounded by design, see rpc._recv)
-    (k,) = _NBUF.unpack(await reader.readexactly(_NBUF.size))
+    (k,) = _NBUF.unpack((yield _NBUF.size))
     if _NBUF.size + 8 * (k + 1) > n:
         raise FrameError(f"frame of {n} bytes cannot hold {k} buffer lengths")
-    # fhh-lint: disable=unbounded-await (as above)
-    lens = struct.unpack(f"<{k + 1}Q", await reader.readexactly(8 * (k + 1)))
+    lens = struct.unpack(f"<{k + 1}Q", (yield 8 * (k + 1)))
     if _NBUF.size + 8 * (k + 1) + sum(lens) != n:
         raise FrameError(
             f"frame lengths {lens} do not sum to its prefix {n}"
         )
-    # fhh-lint: disable=unbounded-await (as above)
-    meta = await reader.readexactly(lens[0])
-    bufs = []
-    for size in lens[1:]:
-        buf = _recv_buffer(size, reg)
-        # fhh-lint: disable=unbounded-await (as above)
-        await reader.readinto(buf)
-        bufs.append(buf)
+    meta = bytearray(lens[0])
+    bufs = [_recv_buffer(size, reg) for size in lens[1:]]
+    yield [meta, *bufs]
     return meta, bufs
+
+
+async def read_body(reader: "FrameReader", n: int,
+                    reg=None) -> tuple[bytearray, list]:
+    """The body of a frame whose prefix said ``n``, off a
+    :class:`FrameReader`: see :func:`_body_reads`."""
+    reads, got = _body_reads(n, reg), None
+    try:
+        while True:
+            want, got = reads.send(got), None
+            if isinstance(want, int):
+                # fhh-lint: disable=unbounded-await (part of a frame whose header has arrived; the callers' frame reads are unbounded by design, see rpc._recv)
+                got = await reader.readexactly(want)
+                continue
+            # the metadata by readexactly, which a plain StreamReader
+            # has too (it reads frames that carry no raw buffer)
+            # fhh-lint: disable=unbounded-await (as above)
+            want[0][:] = await reader.readexactly(len(want[0]))
+            for buf in want[1:]:
+                # fhh-lint: disable=unbounded-await (as above)
+                await reader.readinto(buf)
+    except StopIteration as done:
+        return done.value
 
 
 class FrameReader(asyncio.BufferedProtocol):
@@ -436,3 +482,361 @@ async def start_server(on_connect, host: str, port: int) -> asyncio.Server:
     return await loop.create_server(
         lambda: FrameReader(on_connect), host, port
     )
+
+
+# -- the data plane's endpoints: blocking sockets, a thread a stream ----------
+
+# a hello is a few words: a first frame that claims more is not one,
+# and is refused before its body is read
+_HELLO_MAX = 256
+_IOV_MAX = 1024
+
+
+async def open_plane_socket(host: str, port: int, timeout: float) -> socket.socket:
+    """A connected TCP socket (non-blocking, no transport on it) for one
+    stream of the data plane; each address ``host`` resolves to is given
+    ``timeout`` seconds."""
+    loop = asyncio.get_running_loop()
+    infos = await loop.getaddrinfo(host, port, type=socket.SOCK_STREAM)
+    err: BaseException = OSError(f"{host}:{port} resolves to no address")
+    for family, kind, proto, _, addr in infos:
+        sock = socket.socket(family, kind, proto)
+        sock.setblocking(False)
+        try:
+            await asyncio.wait_for(loop.sock_connect(sock, addr), timeout)
+            return sock
+        except (OSError, asyncio.TimeoutError) as e:
+            sock.close()
+            err = e
+        except BaseException:
+            sock.close()
+            raise
+    raise err
+
+
+def hello_frame(words: bytes) -> bytes:
+    """The frame that opens a stream of the data plane, and its answer:
+    the outer prefix (all a forwarder reads) over a few plain words,
+    not a pickle: nothing of a connection is unpickled before it has
+    said what it is."""
+    return HDR.pack(len(words)) + words
+
+
+async def sock_recv_hello(sock: socket.socket) -> bytes:
+    """The words of the frame that opens a stream, off a non-blocking
+    socket, from the loop (the streams' threads are not running yet),
+    and not a byte past it: what follows is the reader thread's.  The
+    caller bounds the wait."""
+    loop = asyncio.get_running_loop()
+
+    async def read(n: int) -> bytes:
+        buf = memoryview(bytearray(n))
+        got = 0
+        while got < n:
+            # fhh-lint: disable=unbounded-await (the caller's wait_for bounds the whole frame)
+            k = await loop.sock_recv_into(sock, buf[got:])
+            if not k:
+                raise ConnectionResetError("stream closed inside its hello")
+            got += k
+        return bytes(buf)
+
+    (n,) = HDR.unpack(await read(HDR.size))
+    if n > _HELLO_MAX:
+        raise FrameError(f"a first frame of {n} bytes is no data-plane hello")
+    return await read(n)
+
+
+class SocketServer:
+    """A listening socket whose accepted connections go to
+    ``on_connect(sock)`` as they are, non-blocking and with no
+    transport: the data plane's listener.  ``close`` /
+    ``wait_closed`` as ``asyncio.Server`` has them; handlers still
+    running at ``close`` are cancelled."""
+
+    def __init__(self, sock: socket.socket, on_connect):
+        self._sock = sock
+        self._on_connect = on_connect
+        self._handlers: set[asyncio.Task] = set()
+        self._task = asyncio.get_running_loop().create_task(self._accept())
+
+    async def _accept(self) -> None:
+        loop = asyncio.get_running_loop()
+        while True:
+            # fhh-lint: disable=unbounded-await (a listener waits for its next connection by design; close() cancels it)
+            conn, _ = await loop.sock_accept(self._sock)
+            t = loop.create_task(self._on_connect(conn))
+            self._handlers.add(t)
+            t.add_done_callback(self._handlers.discard)
+
+    def close(self) -> None:
+        for t in (self._task, *self._handlers):
+            t.cancel()
+        self._sock.close()
+
+    async def wait_closed(self) -> None:
+        await asyncio.gather(
+            self._task, *self._handlers, return_exceptions=True
+        )
+
+
+async def start_socket_server(on_connect, host: str, port: int) -> SocketServer:
+    sock = socket.create_server((host, port))
+    sock.setblocking(False)
+    return SocketServer(sock, on_connect)
+
+
+def _advance(views: list, n: int) -> None:
+    """Drop the first ``n`` bytes of ``views`` (what one ``sendmsg`` or
+    ``recvmsg_into`` moved)."""
+    while views and n >= len(views[0]):
+        n -= len(views.pop(0))
+    if n:
+        views[0] = views[0][n:]
+
+
+def _recv_exact(sock: socket.socket, *bufs) -> None:
+    """Fill ``bufs``, in order, from a blocking socket: one scatter
+    read for all of them where the kernel allows (``MSG_WAITALL``),
+    the GIL released throughout.  A thread that has to take the GIL
+    back from a busy loop thread after every call waits up to the
+    interpreter's switch interval each time: few calls a frame."""
+    views = [memoryview(b).cast("B") for b in bufs if len(b)]
+    while views:
+        got = sock.recvmsg_into(views[:_IOV_MAX], 0, socket.MSG_WAITALL)[0]
+        if not got:
+            raise ConnectionResetError("data-plane stream closed by the peer")
+        _advance(views, got)
+
+
+def _sock_read_body(sock: socket.socket, n: int, count, reg=None):
+    """:func:`read_body` off a blocking socket; the body's first bytes,
+    the buffer count, came with the prefix (``count``)."""
+    reads = _body_reads(n, reg)
+    next(reads)  # asks for the count
+    got = count
+    try:
+        while True:
+            want, got = reads.send(got), None
+            if isinstance(want, int):
+                want = [got := bytearray(want)]
+            _recv_exact(sock, *want)
+    except StopIteration as done:
+        return done.value
+
+
+def _send_all(sock: socket.socket, pieces) -> None:
+    """Every byte of ``pieces`` into the kernel, from a blocking
+    socket: ``sendmsg`` over the views, never joined, the GIL released
+    for each call."""
+    views = [memoryview(p).cast("B") for p in pieces if len(p)]
+    while views:
+        _advance(views, sock.sendmsg(views[:_IOV_MAX]))
+
+
+class PlaneStreams:
+    """One server's end of the server↔server data plane: two one-way
+    TCP streams on blocking sockets, the one this server writes with a
+    writer thread on it, the one it reads with a reader thread on it.
+    The loop thread hands frames over (:meth:`send`) and takes frames
+    back (``on_frame``) and never copies a byte of either; a socket is
+    never shared by a writer and a reader of bulk (two directions on
+    one socket fight for its lock, PERF.md section 6, PR 36).
+
+    The pair is ONE plane: the loss of either stream, seen by its
+    thread, closes both, and :meth:`close` (which ``shutdown``s the
+    sockets: what wakes a thread blocked in ``sendmsg`` or
+    ``recv_into``) fails every frame still queued.  Whoever closes or
+    loses the plane, ``on_lost(err)`` is called once, on the loop.
+
+    ``on_frame(nbytes, frame, stamps)`` is called on the loop for each
+    frame received, in order; ``stamps`` are the reader thread's wall
+    clock with the header read, the body held and the frame
+    unpickled.  No span is opened on either thread: the loop turns
+    stamps into spans, where the registries' span stacks live.
+    ``annotate(name)`` gives the threads' profiler annotations
+    (``wire_write`` / ``wire_read`` / ``wire_unpickle``)."""
+
+    # frames with the writer thread at once, the one being written
+    # included: a dead peer stalls the producers here instead of
+    # growing the queue (the threads' own bound is TCP keepalive)
+    SEND_DEPTH = 8
+
+    def __init__(self, send_sock: socket.socket, recv_sock: socket.socket,
+                 on_frame, on_lost, reg=None, annotate=None, name="plane"):
+        self._loop = asyncio.get_running_loop()
+        self._socks = (send_sock, recv_sock)
+        for s in self._socks:
+            s.setblocking(True)
+            s.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
+        self._on_frame, self._on_lost = on_frame, on_lost
+        self._reg = reg
+        self._annotate = annotate or (lambda _name: contextlib.nullcontext())
+        self._closed = False
+        # bounded by _slots: a frame enters with a slot and gives it
+        # back when the thread is done with it
+        # fhh-lint: disable=unbounded-queue (SEND_DEPTH slots bound it; queue.Queue(maxsize) would block the loop thread instead of suspending the producer)
+        self._sendq: queue.SimpleQueue = queue.SimpleQueue()
+        self._slots = asyncio.Semaphore(self.SEND_DEPTH)
+        self.waiting = 0  # frames handed over and not yet sent (loop thread's)
+        # A thread blocked on its socket or its queue holds this object
+        # weakly: a plane that was dropped unclosed (with its server,
+        # its loop long gone) is collected, __del__ wakes the threads
+        # and they end, instead of pinning server and sockets for ever.
+        me = weakref.ref(self)
+        self.threads = [
+            threading.Thread(
+                target=fn, args=(me, *args), name=f"{name}-plane-{what}",
+                daemon=True,
+            )
+            for fn, args, what in (
+                (self._write_loop, (send_sock, self._sendq), "write"),
+                (self._read_loop, (recv_sock,), "read"),
+            )
+        ]
+        for t in self.threads:
+            t.start()
+
+    # -- the loop thread's side ----------------------------------------------
+
+    async def send(self, pieces) -> tuple[float, float, float, int]:
+        """Hand one frame's pieces (:func:`encode`) to the writer
+        thread and wait until the kernel has every byte: no view of the
+        caller's arrays is left behind.  Frames go out in the order of
+        their hand-over.  Returns the wall clock at the hand-over, at
+        the start and at the end of the thread's send, and how many
+        frames the thread then held, this one included (1: the stream
+        was free).  Raises ``ConnectionError`` on a plane that is, or
+        while it waits becomes, closed or lost."""
+        # fhh-lint: disable=unbounded-await (a slot comes back when the thread is done with a frame, sent or failed: bounded by the socket's TCP keepalive and by close(), like the send itself)
+        await self._slots.acquire()
+        if self._closed:
+            self._slots.release()
+            raise ConnectionResetError("data plane closed")
+        self.waiting += 1
+        held = self.waiting
+        done = self._loop.create_future()
+        t_put = time.time()
+        self._sendq.put((pieces, done))
+        pieces = None
+        # fhh-lint: disable=unbounded-await (resolved by the writer thread for every frame it was handed, sent or failed; see above)
+        t_begin, t_end = await done
+        return t_put, t_begin, t_end, held
+
+    def _sent(self, done: asyncio.Future, t_begin: float, t_end: float,
+              err: BaseException | None) -> None:
+        self.waiting -= 1
+        self._slots.release()
+        if done.done():  # its sender was cancelled: the frame went whole all the same
+            return
+        if err is None:
+            done.set_result((t_begin, t_end))
+        else:
+            done.set_exception(ConnectionResetError(f"data plane lost: {err!r}"))
+
+    def _wake(self) -> None:
+        """``shutdown`` is what ends a ``sendmsg`` or a ``recv_into``
+        another thread is blocked in (``close`` alone does not), and
+        the ``None`` what ends the writer's wait for a frame; the
+        threads close the sockets as they end."""
+        for s in self._socks:
+            try:
+                s.shutdown(socket.SHUT_RDWR)
+            except OSError:
+                pass  # already closed by its thread
+        self._sendq.put(None)
+
+    def close(self, err: BaseException | None = None) -> None:
+        """Close both streams (idempotent).  Every thread wakes, every
+        queued frame fails with ``ConnectionError``, and ``on_lost``
+        hears of it."""
+        if self._closed:
+            return
+        self._closed = True
+        self._wake()
+        self._on_lost(err or ConnectionResetError("data plane closed"))
+
+    def is_closing(self) -> bool:
+        return self._closed
+
+    def __del__(self):
+        if not self._closed:
+            self._wake()
+
+    # -- the threads ------------------------------------------------------------
+
+    def _post(self, fn, *args) -> bool:
+        try:
+            self._loop.call_soon_threadsafe(fn, *args)
+        except RuntimeError:  # the loop is closed: nobody is left to tell
+            return False
+        return True
+
+    @staticmethod
+    def _write_loop(me, sock: socket.socket, sendq) -> None:
+        err = None
+        while True:
+            # blocks without a bound of its own: _wake() ends the wait
+            # with None (close(), or the plane collected unclosed)
+            item = sendq.get()
+            plane = me()
+            if item is None or plane is None:
+                break
+            pieces, done = item
+            t_begin = time.time()
+            if err is None:
+                try:
+                    # blocks while the peer does not read: bounded by
+                    # the socket's TCP keepalive (the server sets it,
+                    # ~2 min for a silent peer) and ended by _wake()'s
+                    # shutdown, which fails the call
+                    with plane._annotate("wire_write"):
+                        _send_all(sock, pieces)
+                except OSError as e:
+                    err = e
+                    plane._post(plane.close, e)
+            item = pieces = None  # the views of the sender's arrays end here
+            if not plane._post(plane._sent, done, t_begin, time.time(), err):
+                break
+            plane = None
+        sock.close()
+
+    @staticmethod
+    def _read_loop(me, sock: socket.socket) -> None:
+        # the prefix and the buffer count, which every frame has, in
+        # one read
+        hdr = bytearray(HDR.size + _NBUF.size)
+        plane = None
+        try:
+            while True:
+                # blocks until the peer sends: a reader waits for its
+                # next frame by design; bounded by TCP keepalive and
+                # ended by _wake()'s shutdown, like the send
+                _recv_exact(sock, hdr)
+                t_hdr = time.time()
+                plane = me()
+                if plane is None:
+                    return
+                (n,) = HDR.unpack_from(hdr)
+                with plane._annotate("wire_read"):
+                    meta, bufs = _sock_read_body(
+                        sock, n, hdr[HDR.size:], plane._reg
+                    )
+                t_body = time.time()
+                with plane._annotate("wire_unpickle"):
+                    frame = pickle.loads(meta, buffers=bufs)
+                if not plane._post(
+                    plane._on_frame, n + HDR.size, frame,
+                    (t_hdr, t_body, time.time()),
+                ):
+                    return
+                # the loop owns the frame now: a thread that kept it in
+                # its locals until the NEXT frame arrives would pin the
+                # receive buffers long after their consumer let go
+                frame = meta = bufs = plane = None
+        # fhh-lint: disable=broad-except (transport boundary: EOF, a reset, a corrupt frame or an unpicklable one all end the stream, and the plane with it)
+        except Exception as e:
+            plane = plane or me()
+            if plane is not None:
+                plane._post(plane.close, e)
+        finally:
+            sock.close()

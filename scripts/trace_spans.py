@@ -26,6 +26,11 @@ object:
   device bytes its evaluator held in unopened chunks (the gauge
   ``secure_t_rows_held_bytes``) and the high word of the OT pad index
   (the gauge ``ot_index_high``);
+- ``plane_streams``: per component, over its ``plane_send`` instants
+  (one a data-plane frame sent through its stream's writer thread:
+  protocol/rpc.py ``_dp_send``), the frames (the counter
+  ``plane_stream_frames``) and the most frames the writer held at a
+  hand-over (the gauge ``plane_send_queue_high``; 1: the stream was free);
 - ``clock`` (with ``--capture``): the program's spans are also profiler
   annotations (``<comp>:<name>``) on the profiler's own clock.  The
   benchmark lays the JSONL lines over a capture by one sync mark
@@ -158,6 +163,22 @@ def secure_levels(events: list) -> dict:
     return dict(sorted(out.items()))
 
 
+def plane_streams(events: list) -> dict:
+    """Per component, over its ``plane_send`` instants (one a frame that
+    went through the data plane's writer thread: protocol/rpc.py
+    ``_dp_send``): the frames (counter ``plane_stream_frames``) and the
+    most the writer held at a hand-over, that frame included (gauge
+    ``plane_send_queue_high``)."""
+    out: dict = {}
+    for e in events:
+        if e.get("ph") == "i" and e.get("name") == "plane_send":
+            row = out.setdefault(e["comp"], {"frames": 0, "send_queue_high": 0})
+            row["frames"] += 1
+            row["send_queue_high"] = max(
+                row["send_queue_high"], e["args"]["held"])
+    return dict(sorted(out.items()))
+
+
 def clock_check(spans: list, capture: str, wall_ns_at_sync: int,
                 sync_event: str) -> dict:
     from jax.profiler import ProfileData
@@ -241,7 +262,8 @@ def main(argv=None) -> int:
         return 1
     out = {"span_ms": span_ms(spans), "gc_ot_cover": gc_ot_cover(spans),
            "wire_oob": wire_oob(events),
-           "secure_levels": secure_levels(events)}
+           "secure_levels": secure_levels(events),
+           "plane_streams": plane_streams(events)}
     if args.capture:
         if args.wall_ns_at_sync is None:
             p.error("--capture needs --wall-ns-at-sync")

@@ -30,7 +30,7 @@ def test_the_two_secure_configurations_differ_by_size_alone():
 
 @pytest.mark.parametrize("metric", [
     "secure_chunks_per_level", "b2a_ms_per_level",
-    "wire_slab_new_bytes_per_level",
+    "wire_slab_new_bytes_per_level", "wire_queue_ms_per_level",
 ])
 def test_new_metric_files_agree_with_their_entries(metric):
     """A per-layer entry of BENCHMARK.json and its file say the same of

@@ -398,7 +398,7 @@ def test_socket_run_report_two_servers_consistent(secure_exchange):
     byte counts are populated on BOTH sides, and one side's bytes sent
     equal the other's bytes received (same framed stream)."""
     L, n = 2, 12
-    port = 31151 if secure_exchange else 31131  # a range of its own (21871 is test_ops' E2E_PORT)
+    port = 32151 if secure_exchange else 32131  # a range of its own (21871 is test_ops' E2E_PORT; 31131 and 31151 are test_secure_chunks')
     k0, k1 = _keys(L, n)
     cfg = Config(
         data_len=L, n_dims=1, ball_size=1, addkey_batch_size=8,

@@ -1123,8 +1123,8 @@ def test_e2e_chaos_storm_multiple_faults(rng, tmp_path):
             # cut the data plane out from under the live crawl first
             while lead.obs.counter_value("crawl_checkpoints") < 1:
                 await asyncio.sleep(0)
-            if live["s0"]._peer_writer is not None:
-                live["s0"]._peer_writer.close()
+            if live["s0"]._peer is not None:
+                live["s0"]._peer.close()
             await base(live, lead)
 
         res, lead, clients, live = await _crawl_with_chaos(

@@ -258,8 +258,8 @@ def test_keepalive_sets_socket_options():
 
         srv = await asyncio.start_server(server, "127.0.0.1", port)
         _, w = await asyncio.open_connection("127.0.0.1", port)
-        rpc.CollectorServer._keepalive(w)
         sock = w.get_extra_info("socket")
+        rpc.CollectorServer._keepalive(sock)
         assert sock.getsockopt(socket.SOL_SOCKET, socket.SO_KEEPALIVE) == 1
         for opt, want in (
             ("TCP_KEEPIDLE", 60), ("TCP_KEEPINTVL", 20), ("TCP_KEEPCNT", 3)
@@ -273,3 +273,197 @@ def test_keepalive_sets_socket_options():
         await srv.wait_closed()
 
     asyncio.run(flow())
+
+
+# ---------------------------------------------------------------------------
+# the data plane between two started servers: two streams, a thread each
+# ---------------------------------------------------------------------------
+
+
+def _plane_threads(settle=()):
+    """The live I/O threads of data planes (``wire.PlaneStreams``);
+    those of ``settle`` (planes that were closed) are given a moment
+    to end first."""
+    import threading
+
+    for t in settle:
+        t.join(10)
+    return {t for t in threading.enumerate() if "-plane-" in t.name}
+
+
+async def _started_pair(cfg, port):
+    s0, s1 = rpc.CollectorServer(0, cfg), rpc.CollectorServer(1, cfg)
+    t1 = asyncio.create_task(s1.start("127.0.0.1", port + 10, "127.0.0.1", port + 11))
+    await asyncio.sleep(0.05)
+    await asyncio.gather(s0.start("127.0.0.1", port, "127.0.0.1", port + 11), t1)
+    return s0, s1
+
+
+def test_both_servers_swap_frames_larger_than_the_socket_buffers():
+    """The duplex ``_swap``: both servers send 48 MiB at the same moment
+    and both complete (each direction has a stream and a reader thread
+    of its own; on one loop thread the two sends waited on each other
+    for ever, which the old role order existed to avoid), three times
+    over on two sessions' channels at once, each channel in its order.
+    The plane came up by the dial: two connections, a hello on each."""
+    port = 32231  # (these three: a range of their own, beside test_obs's)
+
+    async def flow():
+        s0, s1 = await _started_pair(_cfg(), port)
+        assert s0._plane.epoch == s1._plane.epoch == 1
+        assert len(_plane_threads()) == 4
+
+        def payload(server, chan, i):
+            return np.full(48 << 20, 16 * server + 4 * chan + i, np.uint8)
+
+        async def talk(s, chan):
+            cs = s._table.get(f"tenant{chan}")
+            for i in range(3):
+                got = await s._swap(cs, payload(s.server_id, chan, i))
+                assert np.array_equal(got, payload(1 - s.server_id, chan, i))
+
+        await asyncio.wait_for(asyncio.gather(
+            *(talk(s, chan) for s in (s0, s1) for chan in (0, 1))), 120)
+        out = []
+        for s in (s0, s1):
+            regs = [s._table.get(f"tenant{c}").obs for c in (0, 1)]
+            out.append([
+                sum(r.counter_value(n) for r in regs)
+                for n in ("data_msgs_sent", "plane_stream_frames")
+            ] + [max(r.gauge_value("plane_send_queue_high") for r in regs)])
+            assert all(r.timer_seconds("wire_write") > 0 for r in regs)
+            assert all(r.timer_seconds("wire_queue") > 0 for r in regs)
+        mine = _plane_threads()
+        for s in (s0, s1):
+            await s.aclose()
+        return out, mine
+
+    before = _plane_threads()
+    out, mine = asyncio.run(flow())
+    # every frame went through its stream's thread; two sessions sending
+    # at once: the writer held two frames at most
+    assert all(o[:2] == [6, 6] and o[2] in (1, 2) for o in out), out
+    assert _plane_threads(settle=mine) <= before
+
+
+def test_plane_cut_under_blocked_verbs_then_reset_carries_a_level(rng, monkeypatch):
+    """Server 0 blocked in a send (its peer's reader thread is held
+    inside a frame, so the socket fills) and server 1 blocked in a
+    receive: ``plane_break`` fails both with ConnectionError and the
+    plane's four I/O threads end; ``plane_reset`` brings up a new plane
+    (new threads, the next epoch) that carries a crawl level bit for
+    bit."""
+    import threading
+
+    from fuzzyheavyhitters_tpu.protocol import wire
+
+    port, L, n = 32261, 6, 40
+    cfg = _cfg(data_len=L)
+    pts = np.concatenate([np.full(32, 20), rng.integers(0, 1 << L, size=8)])[:, None]
+    pts_bits = np.array([[bitutils.int_to_bits(L, int(v)) for v in row] for row in pts])
+    k0, k1 = ibdcf.gen_l_inf_ball(pts_bits, cfg.ball_size, rng)
+    hold, real = threading.Event(), wire._recv_buffer
+    hold.set()
+
+    def held_buffer(size, reg=None):  # on a reader thread
+        hold.wait(30)
+        return real(size, reg)
+
+    monkeypatch.setattr(wire, "_recv_buffer", held_buffer)
+
+    async def flow():
+        s0, s1 = await _started_pair(cfg, port)
+        c0 = await rpc.CollectorClient.connect("127.0.0.1", port)
+        c1 = await rpc.CollectorClient.connect("127.0.0.1", port + 10)
+        lead = RpcLeader(cfg, c0, c1)
+        await lead._both("reset")
+        await lead.upload_keys(k0, k1)
+        await lead._both("tree_init")
+        first = await lead._both("tree_crawl", {"level": 0})
+        old = _plane_threads()
+        assert len(old) == 4
+
+        hold.clear()
+        cs0, cs1 = s0._default(), s1._default()
+        send = asyncio.ensure_future(s0._dp_send(cs0, np.zeros(64 << 20, np.uint8)))
+        queued = asyncio.ensure_future(s0._dp_send(cs0, b"behind it"))
+        recv = asyncio.ensure_future(s0._dp_recv(cs0))
+        await asyncio.sleep(0.5)
+        assert not (send.done() or queued.done() or recv.done())
+        await c0.call("plane_break")
+        hold.set()
+        for f in (send, queued, recv):
+            with pytest.raises(ConnectionError):
+                await asyncio.wait_for(f, 10)
+        with pytest.raises(ConnectionError):  # server 1 saw the plane go too
+            await asyncio.wait_for(s1._dp_recv(cs1), 10)
+        for t in old:
+            await asyncio.to_thread(t.join, 10)
+        assert not _plane_threads() & old and not _plane_threads()
+
+        await lead._both("plane_reset")
+        again = await lead._both("tree_crawl", {"level": 0})
+        new = _plane_threads()
+        epochs = (s0._plane.epoch, s1._plane.epoch)
+        sent = [s.obs.counter_value(n) for s in (s0, s1)
+                for n in ("data_msgs_sent", "plane_stream_frames")]
+        for c in (c0, c1):
+            await c.aclose()
+        for s in (s0, s1):
+            await s.aclose()
+        return first, again, new, epochs, sent
+
+    before = _plane_threads()
+    first, again, new, epochs, sent = asyncio.run(asyncio.wait_for(flow(), 120))
+    n_new = len(new)
+    assert n_new == 4 and epochs == (2, 2)
+    for a, b in zip(first, again):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.dtype == b.dtype and np.array_equal(a, b)
+    # the frames that failed were counted as sent and not as through a
+    # stream: the difference is what took any other way, here nowhere
+    assert sent[0] - sent[1] == 2 and sent[2] == sent[3]
+    assert _plane_threads(settle=new) <= before
+
+
+def test_a_peer_that_opens_the_plane_the_old_way_is_refused():
+    """One connection whose first frame is a data frame (the
+    one-connection plane) is refused at the hello: logged, counted,
+    closed; so is a hello of another generation; the plane the servers
+    hold is untouched and a real dial still completes."""
+    import socket
+
+    from fuzzyheavyhitters_tpu.protocol import wire
+
+    port = 32291
+
+    async def flow():
+        s0, s1 = await _started_pair(_cfg(), port)
+        loop = asyncio.get_running_loop()
+        old_way = b"".join(bytes(p) for p in wire.encode(("default", b"\x00" * 16))[0])
+        for first in (old_way,
+                      wire.hello_frame(b"fhh-plane/1 0to1 abcd"),
+                      wire.hello_frame(rpc._PLANE_HELLO + b" sideways abcd"),
+                      wire.HDR.pack(1 << 20) + b"a frame too long for a hello"):
+            sock = socket.create_connection(("127.0.0.1", port + 11))
+            sock.setblocking(False)
+            await loop.sock_sendall(sock, first)
+            try:  # closed on us, nothing said: an EOF, or a reset
+                assert await asyncio.wait_for(loop.sock_recv(sock, 1), 10) == b""
+            except ConnectionResetError:
+                pass  # (what it had not read of ours was thrown away)
+            sock.close()
+        refused = s1.obs.counter_value("plane_hellos_refused")
+        epoch = s1._plane.epoch
+        theirs = await asyncio.wait_for(asyncio.gather(
+            s0._swap(s0._default(), b"still"), s1._swap(s1._default(), b"there")), 10)
+        await s0.plane_reset({})
+        again = await asyncio.wait_for(asyncio.gather(
+            s0._swap(s0._default(), b"and"), s1._swap(s1._default(), b"again")), 10)
+        for s in (s0, s1):
+            await s.aclose()
+        return refused, epoch, theirs, again, s1._plane.epoch
+
+    refused, epoch, theirs, again, epoch2 = asyncio.run(flow())
+    assert refused == 4 and (epoch, epoch2) == (1, 2)
+    assert theirs == [b"there", b"still"] and again == [b"again", b"and"]

@@ -13,6 +13,7 @@ level differently gets ``ConnectionError`` and nobody hangs.
 """
 
 import asyncio
+import threading
 
 import numpy as np
 import pytest
@@ -129,17 +130,22 @@ def _run(coro):
     return asyncio.run(coro)
 
 
+def _plane_threads():
+    """The live I/O threads of data planes (wire.PlaneStreams)."""
+    return {t for t in threading.enumerate() if "-plane-" in t.name}
+
+
 def _spy_frames(monkeypatch):
     """Every payload the servers put on the data plane, in order."""
     sent = []
-    real_send = rpc._send
+    real = rpc._encode
 
-    async def spy(writer, obj, **kw):
-        if kw.get("counter") == "data_bytes_sent":
+    def spy(obj, reg=None, counter=None):
+        if counter == "data_bytes_sent":
             sent.append(obj[1])
-        await real_send(writer, obj, **kw)
+        return real(obj, reg, counter)
 
-    monkeypatch.setattr(rpc, "_send", spy)
+    monkeypatch.setattr(rpc, "_encode", spy)
     return sent
 
 
@@ -260,8 +266,8 @@ def test_leaf_level_in_chunks(monkeypatch):
 
 def test_plane_cut_mid_level_fails_the_verb_and_the_retry_is_exact(monkeypatch):
     """The plane closed under chunk 1's frame: both verbs fail, no chunk
-    task is left, and after a plane reset the same level gives the exact
-    counts."""
+    task and no I/O thread of that plane is left, and after a plane
+    reset (four new threads) the same level gives the exact counts."""
     port = BASE_PORT + 360
     monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", BLOCK * 64)
     real = rpc.CollectorServer._dp_send
@@ -270,7 +276,7 @@ def test_plane_cut_mid_level_fails_the_verb_and_the_retry_is_exact(monkeypatch):
     async def cutting(self, cs, obj):
         if cut["armed"] and isinstance(obj, tuple) and obj[0] == 1:
             cut["armed"] = False
-            self._peer_writer.close()
+            self._peer.close()
         await real(self, cs, obj)
 
     monkeypatch.setattr(rpc.CollectorServer, "_dp_send", cutting)
@@ -280,6 +286,7 @@ def test_plane_cut_mid_level_fails_the_verb_and_the_retry_is_exact(monkeypatch):
             await pair.both("tree_init", {"root_bucket": 4})
             cut["armed"] = True
             tasks0 = asyncio.all_tasks()
+            threads0 = _plane_threads()
             res = await asyncio.wait_for(
                 asyncio.gather(
                     pair.c0.call("tree_crawl", {"level": 0, "garbler": 0}),
@@ -295,17 +302,22 @@ def test_plane_cut_mid_level_fails_the_verb_and_the_retry_is_exact(monkeypatch):
                 t for t in asyncio.all_tasks() - tasks0
                 if "_chunks" in repr(t.get_coro())
             ]
+            for t in threads0:
+                await asyncio.to_thread(t.join, 10)
+            threads = [len(threads0), len(_plane_threads())]
             await pair.both("plane_reset")
             again = await pair.level(0)
+            threads.append(len(_plane_threads() - threads0))
             ks = [
                 cs.obs.counter_value("secure_chunks", level=0)
                 for cs in pair.sessions
             ]
-            return res, left, again, ks, pair.pts
+            return res, left, again, ks, pair.pts, threads
 
-    res, left, again, ks, pts = _run(run())
+    res, left, again, ks, pts, threads = _run(run())
     assert all(isinstance(r, Exception) for r in res), res
     assert not cut["armed"] and not left
+    assert threads == [4, 0, 4]
     assert ks == [8, 8]  # four chunks each time
     got = np.asarray(FE62.canon(FE62.sub(again[0], again[1])))
     assert np.array_equal(got[0], _root_counts(pts)) and not got[1:].any()

@@ -175,38 +175,37 @@ def test_warm_ladder_marks_and_skips():
 
 def test_plane_mux_demux_fifo_and_failure():
     """Frames interleaved across channels demux into per-channel FIFO
-    order; a transport death surfaces to every blocked recv as
-    ConnectionError; attach() supersedes the old pump."""
+    order; a plane's death surfaces to every blocked recv as
+    ConnectionError; attach() supersedes the old plane, whose late
+    frames and late loss are dropped by their epoch."""
 
     async def run():
         mux = sessions.PlaneMux()
-        reader = asyncio.StreamReader()
-
-        async def read_frame(r):
-            line = await r.readexactly(4)
-            # fake framing: b"Axy1" -> channel "A"+"xy", payload int
-            return 4, (line[:1].decode() + line[1:3].decode(), line[3])
-
-        mux.attach(reader, read_frame)
-        reader.feed_data(b"Axy1Bzz9Axy2")
-        assert await mux.recv("Axy") == ord("1")
-        assert await mux.recv("Bzz") == ord("9")
-        assert await mux.recv("Axy") == ord("2")
-        # a blocked recv learns of the transport death
+        epoch = mux.attach()
+        for chan, payload in (("Axy", 1), ("Bzz", 9), ("Axy", 2)):
+            mux.route(epoch, 4, (chan, payload))
+        assert await mux.recv("Axy") == 1
+        assert await mux.recv("Bzz") == 9
+        assert await mux.recv("Axy") == 2
+        # a blocked recv learns of the plane's death
         waiter = asyncio.ensure_future(mux.recv("Axy"))
         await asyncio.sleep(0)
-        reader.feed_eof()
+        mux.lost(epoch, EOFError())
         with pytest.raises(ConnectionError):
             await waiter
         # and later recvs on ANY channel fail too, until re-attach
         with pytest.raises(ConnectionError):
             await mux.recv("Bzz")
-        r2 = asyncio.StreamReader()
-        epoch = mux.attach(r2, read_frame)
-        assert epoch == 2
-        r2.feed_data(b"Axy7")
-        assert await mux.recv("Axy") == ord("7")
+        mux.route(epoch, 4, ("Bzz", 3))  # a dead plane routes nothing
+        epoch2 = mux.attach()
+        assert epoch2 == 2
+        mux.route(epoch, 4, ("Axy", 5))  # the replaced plane's stragglers
+        mux.lost(epoch, EOFError())
+        mux.route(epoch2, 4, ("Axy", 7))
+        assert await mux.recv("Axy") == 7
         mux.close()
+        with pytest.raises(ConnectionError):
+            await mux.recv("Axy")
 
     asyncio.run(run())
 

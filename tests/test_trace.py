@@ -762,16 +762,22 @@ def test_profile_capture_survives_profiler_failure(tmp_path, monkeypatch):
 # the host work inside a level: transfer, codec and socket spans
 # ---------------------------------------------------------------------------
 
-# what _send/_recv/_fetch/PlaneMux.recv and the crawl paths record on the
-# side doing the work; wire_wait is the parent of the three receive spans
+# what _send/_dp_send/_recv/_fetch/PlaneMux.recv and the crawl paths
+# record on the side doing the work; wire_wait is the parent of the three
+# receive spans
 _WIRE_SPANS = (
-    "d2h", "wire_pickle", "wire_write", "wire_wait", "peer_wait",
-    "wire_read", "wire_unpickle", "h2d",
+    "d2h", "wire_pickle", "wire_queue", "wire_write", "wire_wait",
+    "peer_wait", "wire_read", "wire_unpickle", "h2d",
 )
-# spans with no span of their own inside: within one gc_ot they are disjoint
-_LEAVES = tuple(n for n in _WIRE_SPANS if n != "wire_wait") + (
-    "otext", "b2a", "garble", "eval",
-)
+# spans with no span of their own inside.  The data plane's two
+# directions have a stream and a thread each, so what the reader thread
+# stamped may overlap anything else (the duplex swap sends and receives at
+# once); everything else within one gc_ot is disjoint, and so are the
+# reader's three among themselves
+_RECV_LEAVES = ("peer_wait", "wire_read", "wire_unpickle")
+_LEAVES = tuple(
+    n for n in _WIRE_SPANS if n != "wire_wait" and n not in _RECV_LEAVES
+) + ("otext", "b2a", "garble", "eval")
 _EPS = 5e-6  # ts and dur are rounded to the microsecond
 
 
@@ -850,18 +856,22 @@ def test_wire_and_transfer_spans_every_level(rng, trace_dir, secure):
         assert len(outer) == L
         for g in outer:
             lo, hi = g["ts"] - _EPS, g["ts"] + g["dur"] + _EPS
-            inside = sorted(
-                (e["ts"], e["ts"] + e["dur"], e["name"]) for e in mine
-                if e["name"] in _LEAVES
-                and lo <= e["ts"] and e["ts"] + e["dur"] <= hi
-            )
-            assert {"wire_pickle", "wire_write", "peer_wait"} <= {
-                nm for _, _, nm in inside
-            }
-            for (_, end, a), (start, _, b) in zip(inside, inside[1:]):
-                assert start >= end - _EPS, (comp, g["level"], a, b)
-            total = sum(end - start for start, end, _ in inside)
-            assert total <= g["dur"] + _EPS * len(inside), (comp, g["level"])
+            for leaves, needed in (
+                (_LEAVES, {"wire_pickle", "wire_queue", "wire_write"}),
+                # (a read the thread took before this gc_ot began lies
+                # before it: only the wait is always inside)
+                (_RECV_LEAVES, {"peer_wait"}),
+            ):
+                inside = sorted(
+                    (e["ts"], e["ts"] + e["dur"], e["name"]) for e in mine
+                    if e["name"] in leaves
+                    and lo <= e["ts"] and e["ts"] + e["dur"] <= hi
+                )
+                assert needed <= {nm for _, _, nm in inside}
+                for (_, end, a), (start, _, b) in zip(inside, inside[1:]):
+                    assert start >= end - _EPS, (comp, g["level"], a, b)
+                total = sum(end - start for start, end, _ in inside)
+                assert total <= g["dur"] + _EPS * len(inside), (comp, g["level"])
 
 
 def test_chunk_spans_carry_the_level_and_lie_inside_gc_ot(rng, trace_dir, monkeypatch):
@@ -915,15 +925,15 @@ def test_chunk_spans_carry_the_level_and_lie_inside_gc_ot(rng, trace_dir, monkey
         lo, hi = g["ts"] - _EPS, g["ts"] + g["dur"] + _EPS
         chunked = [e for e in mine if "chunk" in e]
         # every chunk, in each span a role records once a chunk
-        for name in ("otext", "b2a", "d2h", "h2d", "wire_write", "wire_wait",
-                     "peer_wait", "wire_read"):
+        for name in ("otext", "b2a", "d2h", "h2d", "wire_queue", "wire_write",
+                     "wire_wait", "peer_wait", "wire_read"):
             got = sorted(e["chunk"] for e in chunked if e["name"] == name)
             assert got == list(range(K)), (comp, name, got)
         for e in chunked:
-            assert e["name"] in _LEAVES + ("wire_wait",), e
-            # (a frame's read is stamped by the pump, which may take it
-            # off the socket before this server's gc_ot began)
-            if e["name"] not in ("peer_wait", "wire_read", "wire_unpickle"):
+            assert e["name"] in _LEAVES + _RECV_LEAVES + ("wire_wait",), e
+            # (a frame's read is stamped by the reader thread, which may
+            # take it off the socket before this server's gc_ot began)
+            if e["name"] not in _RECV_LEAVES:
                 assert lo <= e["ts"] and e["ts"] + e["dur"] <= hi, (comp, e)
             # under the gc_ot: directly, or through its wire_wait
             up = by_id[e["parent"]]
@@ -965,62 +975,56 @@ def test_chunk_spans_carry_the_level_and_lie_inside_gc_ot(rng, trace_dir, monkey
 
 
 def test_wire_spans_of_a_frame_with_two_out_of_band_buffers(trace_dir, monkeypatch):
-    """One data-plane frame of two raw buffers over a loopback socket,
-    through the real pump: the sender's ``wire_pickle`` and
-    ``wire_write``, the receiver's ``peer_wait`` -> ``wire_read`` ->
-    ``wire_unpickle`` end to end under its ``wire_wait``, ``wire_read``
-    ending once the LAST buffer is held, and the ``wire_oob`` instant
-    that ``scripts/trace_spans.py`` sums."""
+    """One data-plane frame of two raw buffers over loopback, through
+    the real plane (two streams, a thread on each): the sender's
+    ``wire_pickle``, ``wire_queue`` and ``wire_write`` (the writer
+    thread's clock), the receiver's ``peer_wait`` -> ``wire_read`` ->
+    ``wire_unpickle`` (the reader thread's) end to end under its
+    ``wire_wait``, ``wire_read`` ending once the LAST buffer is held,
+    and the ``wire_oob`` and ``plane_send`` instants that
+    ``scripts/trace_spans.py`` sums."""
     import importlib.util
     import os
+    import socket
 
-    from fuzzyheavyhitters_tpu.protocol import sessions, wire
+    from fuzzyheavyhitters_tpu.protocol import wire
 
     held = []
-    real = wire.FrameReader.readinto
+    real = wire._recv_exact
 
-    async def spy(self, buf):
-        await real(self, buf)
-        if isinstance(buf, np.ndarray):
-            held.append(time.time())
+    def spy(sock, *bufs):  # on the reader threads
+        real(sock, *bufs)
+        held.extend(time.time() for b in bufs if isinstance(b, np.ndarray))
 
-    monkeypatch.setattr(wire.FrameReader, "readinto", spy)
+    monkeypatch.setattr(wire, "_recv_exact", spy)
     port = BASE_PORT + 400
-    srv_obj = rpc.CollectorServer(1, _cfg(port))
+    s0, s1 = (rpc.CollectorServer(i, _cfg(port)) for i in (0, 1))
+    tx, rx = s0.obs, s1.obs
     a = np.arange(1 << 18, dtype=np.uint32)
     b = np.arange(1 << 17, dtype=np.uint64)
-    tx, rx = obsmetrics.Registry("server0"), obsmetrics.Registry("server1")
 
     async def run():
-        accepted = asyncio.get_running_loop().create_future()
-
-        async def on(r, w):
-            accepted.set_result((r, w))
-
-        srv = await wire.start_server(on, "127.0.0.1", port)
-        _, cw = await wire.open_connection("127.0.0.1", port)
-        sr, sw = await asyncio.wait_for(accepted, 5)
-        mux = sessions.PlaneMux(tag="server1")
-        mux.attach(sr, srv_obj._recv_plane_frame)
+        lsock = socket.create_server(("127.0.0.1", port))
+        conns = []
+        for _ in range(2):
+            dialed = socket.create_connection(("127.0.0.1", port))
+            conns.append((dialed, lsock.accept()[0]))
+        lsock.close()
+        (a_send, b_recv), (a_recv, b_send) = conns
+        s0._attach_plane(a_send, a_recv)
+        s1._attach_plane(b_send, b_recv)
         with tracemod.root("crawl"):
             async def receive():
                 with rx.span("gc_ot", level=3):
-                    with rx.span("wire_wait"):
-                        return await mux.recv("chan", rx)
+                    return await s1._dp_recv(s1._default())
 
             pending = asyncio.ensure_future(receive())
             await asyncio.sleep(0.05)  # the receiver waits: peer_wait > 0
             with tx.span("gc_ot", level=3):
-                await rpc._send(
-                    cw, ("chan", (a, b), tracemod.wire_tag()),
-                    reg=tx, counter="data_bytes_sent",
-                )
+                await s0._dp_send(s0._default(), (a, b))
             got = await asyncio.wait_for(pending, 10)
-        mux.close()
-        for w in (cw, sw):
-            w.close()
-        srv.close()
-        await asyncio.wait_for(srv.wait_closed(), 5)
+        await s0.aclose()
+        await s1.aclose()
         return got
 
     got = asyncio.run(run())
@@ -1032,7 +1036,8 @@ def test_wire_spans_of_a_frame_with_two_out_of_band_buffers(trace_dir, monkeypat
     evs = _events(trace_dir)
     assert tracemod.validate(evs)["ok"]
     by = {(e["comp"], e["name"]): e for e in evs if e["ph"] == "X"}
-    for key in (("server0", "wire_pickle"), ("server0", "wire_write"),
+    for key in (("server0", "wire_pickle"), ("server0", "wire_queue"),
+                ("server0", "wire_write"),
                 ("server1", "peer_wait"), ("server1", "wire_read"),
                 ("server1", "wire_unpickle")):
         assert by[key]["level"] == 3, key
@@ -1045,9 +1050,19 @@ def test_wire_spans_of_a_frame_with_two_out_of_band_buffers(trace_dir, monkeypat
     # wire_read: header read -> BOTH buffers held, and no longer
     assert rd["ts"] - _EPS <= held[0] <= held[1] <= end(rd) + _EPS
     assert end(up) <= end(wait) + _EPS
-    # the sender pickled no array: its wire_pickle precedes its write
-    pk, wr = by["server0", "wire_pickle"], by["server0", "wire_write"]
-    assert end(pk) <= wr["ts"] + _EPS and wr["ts"] <= end(rd)
+    # the sender pickled no array: its wire_pickle precedes the hand-over
+    # to the writer thread, whose send begins where the frame's wait in
+    # the stream's queue ends (a free stream: the thread hop alone)
+    pk, qu, wr = (by["server0", n] for n in ("wire_pickle", "wire_queue", "wire_write"))
+    assert end(pk) <= qu["ts"] + _EPS and abs(end(qu) - wr["ts"]) <= _EPS
+    assert qu["dur"] < 0.05 and wr["ts"] <= end(rd)
+    assert pk["parent"] == qu["parent"] == wr["parent"]
+    assert tx.timer_seconds("wire_write", level=3) == pytest.approx(wr["dur"], abs=1e-5)
+    assert tx.timer_seconds("wire_queue", level=3) == pytest.approx(qu["dur"], abs=1e-5)
+    # every frame went through the stream's thread, one at a time
+    assert tx.counter_value("plane_stream_frames", level=3) == 1
+    assert tx.counter_value("data_msgs_sent") == 1
+    assert tx.gauge_value("plane_send_queue_high", level=3) == 1
     inst = [e for e in evs if e["ph"] == "i" and e["name"] == "wire_oob"]
     assert [e["args"]["oob"] for e in inst] == [oob]
     spec = importlib.util.spec_from_file_location(
@@ -1055,6 +1070,8 @@ def test_wire_spans_of_a_frame_with_two_out_of_band_buffers(trace_dir, monkeypat
             os.path.dirname(os.path.dirname(__file__)), "scripts", "trace_spans.py"))
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
+    assert mod.plane_streams(evs) == {
+        "server0": {"frames": 1, "send_queue_high": 1}}
     row = mod.wire_oob(evs)["server0"]
     assert row["frames"] == 1 and row["wire_oob_bytes"] == oob
     assert row["framed_bytes"] == tx.counter_value("data_bytes_sent")

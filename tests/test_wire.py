@@ -2,8 +2,11 @@
 payloads cross as raw buffers, everything else as pickled metadata."""
 
 import asyncio
+import functools
 import pickle
+import socket
 import struct
+import threading
 
 import numpy as np
 import pytest
@@ -100,6 +103,49 @@ async def _pair(port, on_connect=None):
     return cr, cw, sr, sw, srv
 
 
+def _stream_socks(port):
+    """Two loopback TCP connections, one a direction of a data plane
+    between ends a and b: ``(a_send, b_recv, a_recv, b_send)``."""
+    lsock = socket.create_server(("127.0.0.1", port))
+    socks = []
+    for _ in range(2):
+        dialed = socket.create_connection(("127.0.0.1", port))
+        socks += [dialed, lsock.accept()[0]]
+    lsock.close()
+    return socks
+
+
+async def _plane_pair(port, regs=(None, None)):
+    """A data plane over loopback, as two servers hold it: two one-way
+    TCP streams, and at each end a ``PlaneMux`` fed by a
+    ``wire.PlaneStreams``.  ``[(mux, streams), (mux, streams)]``."""
+    a_send, b_recv, a_recv, b_send = _stream_socks(port)
+    ends = []
+    for send, recv, reg in ((a_send, a_recv, regs[0]), (b_send, b_recv, regs[1])):
+        mux = sessions.PlaneMux()
+        epoch = mux.attach()
+        ends.append((mux, wire.PlaneStreams(
+            send, recv,
+            on_frame=functools.partial(mux.route, epoch),
+            on_lost=functools.partial(mux.lost, epoch), reg=reg,
+        )))
+    return ends
+
+
+async def _plane_send(streams, obj):
+    return await streams.send(wire.encode(obj)[0])
+
+
+async def _plane_close(*ends):
+    """Close the planes and wait for their threads to end."""
+    for _, streams in ends:
+        streams.close()
+    for _, streams in ends:
+        for t in streams.threads:
+            await asyncio.to_thread(t.join, 5)
+            assert not t.is_alive()
+
+
 async def _close(srv, *writers):
     for w in writers:
         if w is not None:
@@ -182,16 +228,11 @@ def _corrupt(obj, grow=8):
 def test_inner_lengths_not_summing_fail_the_plane():
     """A corrupt data-plane frame fails the mux with ConnectionError
     and delivers nothing of itself; the frames before it are intact."""
-    srv_obj = rpc.CollectorServer(0, _cfg())
-
     async def run():
-        cr, cw, sr, sw, srv = await _pair(BASE_PORT + 41)
-        mux = sessions.PlaneMux()
-        mux.attach(sr, srv_obj._recv_plane_frame)
+        (_, tx), (mux, rx) = ends = await _plane_pair(BASE_PORT + 41)
         good = ("chan", _big(2 * OOB, seed=9))
-        await rpc._send(cw, good)
-        cw.writelines([_corrupt(("chan", _big(2 * OOB, seed=10)))])
-        await cw.drain()
+        await _plane_send(tx, good)
+        await tx.send([_corrupt(("chan", _big(2 * OOB, seed=10)))])
         first = await asyncio.wait_for(mux.recv("chan"), 10)
         errs = []
         for _ in range(2):  # the failure stays visible
@@ -199,8 +240,8 @@ def test_inner_lengths_not_summing_fail_the_plane():
                 await asyncio.wait_for(mux.recv("chan"), 10)
             errs.append(ei.value)
         depth = mux._queue("chan").qsize()
-        mux.close()
-        await _close(srv, cw, sw)
+        assert rx.is_closing()  # a corrupt stream ends the plane
+        await _plane_close(*ends)
         return good, first, errs, depth
 
     good, first, errs, depth = asyncio.run(run())
@@ -297,34 +338,29 @@ def test_queued_frames_each_own_the_buffer_their_socket_read_filled(monkeypatch,
     if slabs:
         monkeypatch.setattr(wire, "_SLAB_MIN", OOB)
     filled = []
-    real = wire.FrameReader.readinto
+    real = wire._recv_buffer
 
-    async def spy(self, buf):
-        await real(self, buf)
-        if isinstance(buf, np.ndarray):
-            filled.append(buf)
+    def spy(size, reg=None):  # the buffers the reader thread reads into
+        filled.append(real(size, reg))
+        return filled[-1]
 
-    monkeypatch.setattr(wire.FrameReader, "readinto", spy)
-    srv_obj = rpc.CollectorServer(0, _cfg())
+    monkeypatch.setattr(wire, "_recv_buffer", spy)
 
     async def run():
-        cr, cw, sr, sw, srv = await _pair(BASE_PORT + 46 + 4 * slabs)
-        mux = sessions.PlaneMux()
-        mux.attach(sr, srv_obj._recv_plane_frame)
+        (_, tx), (mux, _) = ends = await _plane_pair(BASE_PORT + 46 + 4 * slabs)
         sent = [_big(4 * OOB, seed=20 + i) for i in range(3)]
-        await rpc._send(cw, ("chan", sent[0]))
-        await rpc._send(cw, ("chan", sent[1]))
+        await _plane_send(tx, ("chan", sent[0]))
+        await _plane_send(tx, ("chan", sent[1]))
         while mux._queue("chan").qsize() < 2:  # both queued, none consumed
             await asyncio.sleep(0.01)
         first = await mux.recv("chan")
         keep = first.copy()
-        await rpc._send(cw, ("chan", sent[2]))
+        await _plane_send(tx, ("chan", sent[2]))
         while mux._queue("chan").qsize() < 2:
             await asyncio.sleep(0.01)
         assert np.array_equal(first, keep)  # untouched by the third
         rest = [await mux.recv("chan"), await mux.recv("chan")]
-        mux.close()
-        await _close(srv, cw, sw)
+        await _plane_close(*ends)
         return sent, [first, *rest]
 
     sent, got = asyncio.run(asyncio.wait_for(run(), 30))
@@ -350,17 +386,14 @@ def test_slab_returns_only_when_its_last_reader_is_gone(monkeypatch, cpu_default
 
     monkeypatch.setattr(wire, "_SLAB_MIN", OOB)
     wire._free_slabs.clear()
-    srv_obj = rpc.CollectorServer(0, _cfg())
     size = 8 * OOB
 
     async def run():
-        cr, cw, sr, sw, srv = await _pair(BASE_PORT + 51)
-        mux = sessions.PlaneMux()
-        mux.attach(sr, srv_obj._recv_plane_frame)
+        (_, tx), (mux, _) = ends = await _plane_pair(BASE_PORT + 51)
         sent = [_big(size, seed=40 + i) for i in range(5)]
 
         async def frame(i):
-            await rpc._send(cw, ("chan", sent[i]))
+            await _plane_send(tx, ("chan", sent[i]))
             return await asyncio.wait_for(mux.recv("chan"), 10)
 
         first = await frame(0)
@@ -386,8 +419,7 @@ def test_slab_returns_only_when_its_last_reader_is_gone(monkeypatch, cpu_default
         fifth = await frame(4)
         assert np.array_equal(fifth, sent[4])
         assert sum(s.nbytes for s in wire._free_slabs) <= size
-        mux.close()
-        await _close(srv, cw, sw)
+        await _plane_close(*ends)
 
     asyncio.run(asyncio.wait_for(run(), 30))
     wire._free_slabs.clear()
@@ -409,27 +441,25 @@ def test_slab_counters_say_how_many_receive_buffers_were_new(
     if keep is not None:
         monkeypatch.setattr(wire, "_SLAB_KEEP", keep * size)
     wire._free_slabs.clear()
-    srv_obj = rpc.CollectorServer(0, _cfg())
+    reg = obsmetrics.Registry("rx")
 
     async def run():
-        cr, cw, sr, sw, srv = await _pair(BASE_PORT + 53 + in_flight)
-        mux = sessions.PlaneMux()
-        mux.attach(sr, srv_obj._recv_plane_frame)
+        (_, tx), (mux, _) = ends = await _plane_pair(
+            BASE_PORT + 53 + in_flight, regs=(None, reg))
         payload = _big(size, seed=60)
         held = []
         for i in range(frames):
-            await rpc._send(cw, ("chan", payload))
+            await _plane_send(tx, ("chan", payload))
             held.append(await asyncio.wait_for(mux.recv("chan"), 10))
             assert np.array_equal(held[-1], payload)
             if len(held) == in_flight:
                 held.clear()  # the consumer is done with these
-        mux.close()
-        await _close(srv, cw, sw)
+        await _plane_close(*ends)
 
     asyncio.run(asyncio.wait_for(run(), 60))
     wire._free_slabs.clear()
-    new = srv_obj.obs.counter_value("wire_slab_new_bytes")
-    reused = srv_obj.obs.counter_value("wire_slab_reused_bytes")
+    new = reg.counter_value("wire_slab_new_bytes")
+    reused = reg.counter_value("wire_slab_reused_bytes")
     assert new + reused == frames * size
     if keep is None:
         assert size <= new <= 3 * size
@@ -490,6 +520,183 @@ def test_writer_backpressure_and_close():
         await _close(srv)
 
     asyncio.run(run())
+
+
+# ---------------------------------------------------------------------------
+# the data plane's streams: a thread a direction (wire.PlaneStreams)
+# ---------------------------------------------------------------------------
+
+
+def _plane_threads():
+    return [t for t in threading.enumerate() if "-plane-" in t.name]
+
+
+@pytest.mark.parametrize("size", [1 << 10, 1 << 20, 40 << 20],
+                         ids=["1KiB", "1MiB", "40MiB"])
+def test_frames_both_ways_at_once_arrive_whole_and_in_order(size):
+    """Both ends send frames of ``size`` bytes at the same moment, on two
+    channels each: every frame arrives whole and each channel keeps its
+    order, whatever the other direction and the other channel do; every
+    frame went through the writer thread, one a time a sender."""
+    frames = 3 if size >= 32 << 20 else 6
+    before = set(_plane_threads())
+
+    async def run():
+        a, b = ends = await _plane_pair(BASE_PORT + 70 + size.bit_length())
+        assert len(set(_plane_threads()) - before) == 4
+
+        def payload(end, chan, i):
+            return _big(size, np.uint8, seed=hash((end, chan, i)) % 1000)
+
+        async def talk(me, mine, theirs, chan):
+            mux, streams = me
+            got, held = [], []
+            for i in range(frames):
+                held.append((await _plane_send(
+                    streams, (chan, (i, payload(mine, chan, i)))))[3])
+                got.append(await asyncio.wait_for(mux.recv(chan), 60))
+            for i, (k, arr) in enumerate(got):
+                assert k == i and np.array_equal(arr, payload(theirs, chan, i))
+            return held
+
+        held = await asyncio.wait_for(asyncio.gather(
+            talk(a, 0, 1, "x"), talk(a, 0, 1, "y"),
+            talk(b, 1, 0, "x"), talk(b, 1, 0, "y")), 120)
+        # two senders an end: the writer held one frame or two, never more
+        assert {h for hs in held for h in hs} <= {1, 2}
+        assert a[1].waiting == b[1].waiting == 0
+        await _plane_close(*ends)
+
+    asyncio.run(run())
+    assert set(_plane_threads()) <= before
+
+
+def test_two_sessions_interleaved_on_one_plane_keep_their_own_fifo():
+    """Two sessions' channels on one plane, one sending 40 small frames
+    while the other sends 5 large ones, nobody receiving until all are
+    sent: each channel yields its own frames in its own order."""
+    async def run():
+        (_, tx), (mux, _) = ends = await _plane_pair(BASE_PORT + 69)
+        big = [_big(3 << 20, np.uint8, seed=i) for i in range(5)]
+
+        async def send(chan, items):
+            for it in items:
+                await _plane_send(tx, (chan, it))
+
+        await asyncio.wait_for(asyncio.gather(
+            send("tenant-a", list(range(40))), send("tenant-b", big)), 60)
+        assert [await mux.recv("tenant-a") for _ in range(40)] == list(range(40))
+        for want in big:
+            assert np.array_equal(await mux.recv("tenant-b"), want)
+        await _plane_close(*ends)
+
+    asyncio.run(run())
+
+
+def test_plane_cut_under_a_blocked_send_and_a_blocked_receive():
+    """A send blocked on a peer that does not read, three more queued
+    behind it, and a receive blocked on a frame that never comes: the
+    plane's close fails every one of them with ConnectionError, later
+    sends fail at once, the mux hears of the loss, and no I/O thread is
+    left."""
+    before = set(_plane_threads())
+
+    async def run():
+        # the peer's ends: no thread on them
+        send_sock, deaf, recv_sock, mute = _stream_socks(BASE_PORT + 68)
+        mux = sessions.PlaneMux()
+        epoch = mux.attach()
+        streams = wire.PlaneStreams(
+            send_sock, recv_sock,
+            on_frame=functools.partial(mux.route, epoch),
+            on_lost=functools.partial(mux.lost, epoch))
+        big = _big(64 << 20, np.uint8)  # more than the socket buffers hold
+        sends = [asyncio.ensure_future(_plane_send(streams, ("c", big)))
+                 for _ in range(4)]
+        recv = asyncio.ensure_future(mux.recv("c"))
+        await asyncio.sleep(0.5)
+        assert not any(f.done() for f in (*sends, recv))
+        assert streams.waiting == 4
+        streams.close()
+        for f in (*sends, recv):
+            with pytest.raises(ConnectionError):
+                await asyncio.wait_for(f, 10)
+        assert streams.is_closing() and streams.waiting == 0
+        with pytest.raises(ConnectionError):
+            await _plane_send(streams, ("c", 1))
+        with pytest.raises(ConnectionError):
+            await mux.recv("other")
+        for t in streams.threads:
+            await asyncio.to_thread(t.join, 5)
+        for s in (deaf, mute):
+            s.close()
+
+    asyncio.run(asyncio.wait_for(run(), 60))
+    assert set(_plane_threads()) <= before
+
+
+def test_send_queue_is_bounded_and_a_lost_peer_fails_it():
+    """More senders than ``SEND_DEPTH`` on a peer that does not read:
+    the writer thread never holds more than its bound, the others wait
+    for a slot, and when the peer's end dies every one of them fails."""
+    async def run():
+        send_sock, deaf, recv_sock, mute = _stream_socks(BASE_PORT + 67)
+        lost = []
+        streams = wire.PlaneStreams(
+            send_sock, recv_sock, on_frame=lambda *a: None, on_lost=lost.append)
+        big = _big(64 << 20, np.uint8)
+        n = wire.PlaneStreams.SEND_DEPTH + 3
+        sends = [asyncio.ensure_future(_plane_send(streams, ("c", big)))
+                 for _ in range(n)]
+        await asyncio.sleep(0.5)
+        assert streams.waiting == wire.PlaneStreams.SEND_DEPTH
+        for s in (deaf, mute):  # the peer dies: a reset, an EOF
+            s.close()
+        for f in sends:
+            with pytest.raises(ConnectionError):
+                await asyncio.wait_for(f, 10)
+        assert len(lost) == 1 and streams.is_closing()
+        for t in streams.threads:
+            await asyncio.to_thread(t.join, 5)
+            assert not t.is_alive()
+
+    asyncio.run(asyncio.wait_for(run(), 60))
+
+
+@pytest.mark.skipif(not wire._LEASES, reason="slabs need PEP 688 (Python 3.12)")
+def test_two_reader_threads_take_from_one_slab_list(monkeypatch):
+    """Two planes in one process (both servers of a pair), their reader
+    threads taking receive buffers at the same time, 64 frames each with
+    one in flight: a taker never finds the list emptied by the other's
+    scan, so all but the first few buffers are reused."""
+    monkeypatch.setattr(wire, "_SLAB_MIN", OOB)
+    size, frames = 8 * OOB, 64
+    wire._free_slabs.clear()
+    regs = [obsmetrics.Registry("rx0"), obsmetrics.Registry("rx1")]
+
+    async def run():
+        planes = [await _plane_pair(BASE_PORT + 65 + i, regs=(None, regs[i]))
+                  for i in range(2)]
+        payload = _big(size, seed=61)
+
+        async def stream(ends):
+            (_, tx), (mux, _) = ends
+            for _ in range(frames):
+                await _plane_send(tx, ("chan", payload))
+                got = await asyncio.wait_for(mux.recv("chan"), 10)
+                assert np.array_equal(got, payload)
+                del got
+
+        await asyncio.gather(*(stream(p) for p in planes))
+        for p in planes:
+            await _plane_close(*p)
+
+    asyncio.run(asyncio.wait_for(run(), 60))
+    wire._free_slabs.clear()
+    new = sum(r.counter_value("wire_slab_new_bytes") for r in regs)
+    reused = sum(r.counter_value("wire_slab_reused_bytes") for r in regs)
+    assert new + reused == 2 * frames * size
+    assert size <= new <= 6 * size
 
 
 # ---------------------------------------------------------------------------
