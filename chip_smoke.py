@@ -32,6 +32,13 @@ over the sampled points written here, independent of ``protocol/``.  Any
 failed check raises: non-zero exit, no final ``ok`` line.  Without a TPU
 the script fails before it runs anything.
 
+``--n-dims 2`` runs ONLY a secure lane at the reference's two-dimensional
+shapes (``configs/amazon.json``: data_len 64, ball 8; strings of S = 4 bits,
+a 1-of-16 table a test, four child patterns a node), N=16384: the crawl to
+its own end (it dies out near depth 59: no box narrower than 2^6 a side
+holds 0.5% of the clients), compared with the plain count at depth 16 and
+at the depth it ended.  By hand, once a change to what d > 1 runs.
+
 ``--chips 4`` runs ONLY the sharded-server comparison: the secure lane
 with each server's client axis sharded over its own half of the host's
 chips (``server_data_devices=2`` there: server 0 on chips 0-1, server 1 on
@@ -55,6 +62,7 @@ import collections
 import contextlib
 import dataclasses
 import gc
+import itertools
 import json
 import sys
 import time
@@ -77,6 +85,8 @@ N_SECURE = 16384
 N_SHARDED = 2048  # --chips 4; planned N_SHARDED_PLANNED, see the docstring
 N_SHARDED_PLANNED = 65536
 GC_LEVELS = 16
+DATA_LEN_ND = 64  # --n-dims > 1: configs/amazon.json's data_len and ball
+BALL_SIZE_ND = 8
 NUM_SITES = 10000
 ZIPF_EXPONENT = 1.03
 BALL_SIZE = 2
@@ -141,33 +151,38 @@ def check_engines(data_len: int) -> dict:
 
 
 def _points_to_ints(pts_bits: np.ndarray) -> list:
-    """bool[N, 1, L] MSB-first -> Python ints."""
-    bits = pts_bits[:, 0, :]
-    pad = (-bits.shape[1]) % 8
+    """bool[N, d, L] MSB-first -> Python ints (d = 1) or tuples of d."""
+    n, d, _ = pts_bits.shape
+    pad = (-pts_bits.shape[2]) % 8
     if pad:
-        bits = np.pad(bits, ((0, 0), (pad, 0)))
-    return [int.from_bytes(r.tobytes(), "big") for r in np.packbits(bits, axis=1)]
+        pts_bits = np.pad(pts_bits, ((0, 0), (0, 0), (pad, 0)))
+    packed = np.packbits(pts_bits, axis=2)
+    vals = [[int.from_bytes(packed[i, j].tobytes(), "big") for j in range(d)]
+            for i in range(n)]
+    return [v[0] if d == 1 else tuple(v) for v in vals]
 
 
 def plain_count(pts_bits, ball: int, depth: int, thresh: int) -> dict:
-    """{prefix: count} over every ``depth``-bit prefix that at least
-    ``thresh`` clients' saturating balls [p - ball, p + ball] touch — what
-    a crawl must hold after ``depth`` levels (counts only shrink down the
-    tree, so the crawl's earlier prunes remove nothing this keeps).
-    Candidates: every distinct point's ball; Python ints throughout."""
-    L = pts_bits.shape[-1]
+    """{prefix: count} over every ``depth``-bit prefix (a tuple of one a
+    dimension where there are several) that at least ``thresh`` clients'
+    saturating balls [p - ball, p + ball] touch — what a crawl must hold
+    after ``depth`` levels (counts only shrink down the tree, so the
+    crawl's earlier prunes remove nothing this keeps).  Candidates: every
+    distinct point's ball; Python ints throughout."""
+    d, L = pts_bits.shape[1:]
     top = (1 << L) - 1
     shift = L - depth
     counts = collections.Counter()
-    for v, k in collections.Counter(_points_to_ints(pts_bits)).items():
-        lo, hi = max(0, v - ball) >> shift, min(top, v + ball) >> shift
-        for q in range(lo, hi + 1):
-            counts[q] += k
+    for p, k in collections.Counter(_points_to_ints(pts_bits)).items():
+        spans = [range(max(0, v - ball) >> shift, (min(top, v + ball) >> shift) + 1)
+                 for v in ((p,) if d == 1 else p)]
+        for q in itertools.product(*spans):
+            counts[q[0] if d == 1 else q] += k
     return {q: k for q, k in counts.items() if k >= thresh}
 
 
 def _as_dict(paths: np.ndarray, counts: np.ndarray) -> dict:
-    """A crawl's (paths bool[H, 1, depth], counts[H]) -> {prefix: count}."""
+    """A crawl's (paths bool[H, d, depth], counts[H]) -> {prefix: count}."""
     vals = _points_to_ints(paths) if paths.shape[0] else []
     out = dict(zip(vals, (int(c) for c in counts)))
     _check(len(out) == len(vals), "a crawl returned a duplicate path")
@@ -299,9 +314,9 @@ def _run_lane(*a, **kw) -> dict:
 
 
 def _config(data_len: int, num_sites: int, threshold: float,
-            f_max: int) -> Config:
+            f_max: int, n_dims: int = 1, ball: int = BALL_SIZE) -> Config:
     return Config(
-        data_len=data_len, n_dims=1, ball_size=BALL_SIZE,
+        data_len=data_len, n_dims=n_dims, ball_size=ball,
         addkey_batch_size=1024, num_sites=num_sites, threshold=threshold,
         zipf_exponent=ZIPF_EXPONENT, server0="", server1="",
         distribution="zipf", f_max=f_max, server_data_devices=1,
@@ -315,8 +330,8 @@ def _compare(name: str, got: dict, want: dict) -> None:
            f"counts differ: {[q for q in got if q in want and got[q] != want[q]][:4]})")
 
 
-def _keygen(pts, rng) -> tuple:
-    k0, k1 = ibdcf.gen_l_inf_ball(pts, BALL_SIZE, rng, engine=ibdcf.best_engine())
+def _keygen(pts, rng, ball: int = BALL_SIZE) -> tuple:
+    k0, k1 = ibdcf.gen_l_inf_ball(pts, ball, rng, engine=ibdcf.best_engine())
     jax.block_until_ready((k0, k1))
     return k0, k1
 
@@ -508,12 +523,61 @@ def run_sharded(n: int, data_len: int, *, seed: int = 0,
     return device
 
 
+def run_secure_nd(n: int, data_len: int, n_dims: int, ball: int, tap: int,
+                  *, seed: int = 0, num_sites: int = NUM_SITES,
+                  threshold: float = THRESHOLD, f_max: int = F_MAX,
+                  port: int = BASE_PORT + 200) -> dict:
+    """``--n-dims d`` (d > 1): ONLY the secure lane, ``ot_path=auto``, on
+    points of ``d`` strings: S = 2d bits a test, 2^d child patterns a
+    node.  The crawl runs to its own end and is compared with the plain
+    count at depth ``tap`` and at the depth it ended (the whole
+    ``data_len``, or where it died out: the plain count there is empty
+    too).  Returns the device (for the final line)."""
+    device, common = _start(1, data_len, seed, threshold, f_max)
+    cfg = dataclasses.replace(
+        _config(data_len, num_sites, threshold, f_max, n_dims, ball),
+        secure_exchange=True,
+    )
+    rng = np.random.default_rng(seed)
+    pts = sample_points(cfg, n, rng)
+    thresh = max(1, int(threshold * n))
+    k0, k1 = _keygen(pts, rng, ball)
+    lane = _run_lane(cfg, port, k0, k1, n, tap=tap)
+    (crawl,) = lane["crawls"]
+    res = crawl["result"]
+    depth = res.paths.shape[-1]
+    _check(lane["tapped"] is not None, f"secure lane died before depth {tap}")
+    at_tap = _as_dict(*lane["tapped"])
+    _compare(f"secure at depth {tap}", at_tap,
+             plain_count(pts, ball, tap, thresh))
+    _check(depth == data_len or not res.paths.shape[0],
+           f"crawl ended at depth {depth} of {data_len} with hitters")
+    _compare(f"secure at depth {depth}", _as_dict(res.paths, res.counts),
+             plain_count(pts, ball, depth, thresh))
+    tags = _check_lane_engines("secure", lane, 1)
+    _check(tags["ot_path"] == secure.ot_path(2 * n_dims, "auto"),
+           f"secure lane took ot_path {tags['ot_path']}")
+    _emit(phase="secure", n=n, n_dims=n_dims, ball=ball, levels=depth,
+          threshold_count=thresh, frontier_at_tap=len(at_tap),
+          hitters=int(res.paths.shape[0]),
+          upload_seconds=lane["upload_seconds"], **_crawl_fields(crawl),
+          key_plane_bytes=lane["key_plane_bytes"], engines=tags,
+          hbm_watermark_bytes=_hbm_watermark(), **common)
+    return device
+
+
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--chips", type=int, choices=(1, 4), default=1)
+    p.add_argument("--n-dims", type=int, choices=(1, 2, 3), default=1)
     args = p.parse_args(argv)
-    if args.chips == 4:
+    if args.n_dims > 1:
+        if args.chips != 1:
+            p.error("--n-dims runs on one chip")
+        device = run_secure_nd(N_SECURE, DATA_LEN_ND, args.n_dims,
+                               BALL_SIZE_ND, GC_LEVELS, seed=args.seed)
+    elif args.chips == 4:
         device = run_sharded(N_SHARDED, DATA_LEN, seed=args.seed)
     else:
         device = run_phases(N_TRUSTED, N_SECURE, DATA_LEN, GC_LEVELS,

@@ -47,8 +47,11 @@ Well-known metric names (what populates them):
   round trip: the COUNT is a latency term beside the byte count, so
   both are measured); ``gc_tests`` — secure-mode equality tests;
   ``checkpoint_writes`` / ``checkpoint_restores``.
-- gauges ``ot_batch_size`` (per level), ``survivors`` /
-  ``frontier_nodes`` (per level).
+- gauges ``ot_batch_size`` (per level), ``secure_string_bits`` and
+  ``child_patterns`` (per level: the bits a secure level's equality
+  test compares, S = 2 a dimension and radix step, and the child
+  patterns a node, 2^d a step; ``secure_kernels.string_bits`` /
+  ``.child_patterns``), ``survivors`` / ``frontier_nodes`` (per level).
 - counters ``keys_placed_bytes`` (bytes of a bulk upload's batches
   written to their rows of the resident key planes as they arrived,
   protocol/keyplanes.py: the key-plane bytes once an upload) and
@@ -342,6 +345,7 @@ def _secure_kernel_summary(registries: dict) -> dict | None:
     chunks: dict = {}
     held: dict = {}
     index_high = 0
+    shape = {"secure_string_bits": None, "child_patterns": None}
     kshards = None
     kgather = 0.0
     seen = False
@@ -374,6 +378,10 @@ def _secure_kernel_summary(registries: dict) -> dict | None:
         g = snap.get("gauges", {}).get("ot_index_high")
         if g is not None:
             index_high = max(index_high, g.get("last"))
+        for name in shape:
+            g = snap.get("gauges", {}).get(name)
+            if g is not None:
+                shape[name] = g.get("last")
         g = snap.get("gauges", {}).get("secure_t_rows_held_bytes")
         for lvl, b in (g or {}).get("by_level", {}).items():
             held[lvl] = max(held.get(lvl, 0), b)
@@ -406,6 +414,10 @@ def _secure_kernel_summary(registries: dict) -> dict | None:
         # the high word of the OT sessions' 64-bit pad index (gauge
         # ``ot_index_high``): above 0, a session has extended 2^32 OTs
         "ot_index_high": index_high,
+        # the last level's shape: bits an equality test compares (2 a
+        # dimension and radix step) and child patterns a node
+        "string_bits": shape["secure_string_bits"],
+        "child_patterns": shape["child_patterns"],
         # kernel-stage layout (multi-chip servers only; None/0.0 on a
         # single-device crawl — see the mesh section for the per-level
         # breakdown): the phase seconds above are the SHARDED kernels'

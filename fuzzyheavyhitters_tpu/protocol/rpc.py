@@ -1288,10 +1288,14 @@ class CollectorServer:
                             pay = open_()
                             await self._phase_sync(pay)
                     with cs.obs.span("b2a", level=level):
-                        v = secure.words_to_field(
-                            count_field, open_() if path == "ot2s" else pay
-                        )
-                        await self._phase_sync(v)
+                        if path == "ot2s":
+                            # the opening of the 2^S table, inside b2a
+                            with cs.obs.span("ot2s", level=level):
+                                v = secure.words_to_field(count_field, open_())
+                                await self._phase_sync(v)
+                        else:
+                            v = secure.words_to_field(count_field, pay)
+                            await self._phase_sync(v)
                     vals.append(v)
             return vals
 
@@ -1341,8 +1345,12 @@ class CollectorServer:
                             W, path, idx0, B, t0,
                         )
                         if path == "ot2s":
-                            msg = build_msg()
-                        await self._phase_sync(msg if path == "ot2s" else w1)
+                            # the 2^S table, inside b2a
+                            with cs.obs.span("ot2s", level=level):
+                                msg = build_msg()
+                                await self._phase_sync(msg)
+                        else:
+                            await self._phase_sync(w1)
                     if path != "ot2s":
                         with cs.obs.span("garble", level=level):
                             msg = build_msg()
@@ -1421,6 +1429,10 @@ class CollectorServer:
             B = F_ * C * N
             cs.obs.count("gc_tests", B, level=level)
             cs.obs.gauge("ot_batch_size", B * S, level=level)
+            # the level's shape: bits a test compares (2 a dimension and
+            # radix step) and child patterns a node
+            cs.obs.gauge("secure_string_bits", S, level=level)
+            cs.obs.gauge("child_patterns", C, level=level)
         with cs.obs.span("gc_ot", level=level) as sp_gc:
             w = secure.alive_weight(frontier.alive, cs.alive_keys, C)
             # crawl counter makes every garbling's randomness unique even
@@ -1535,11 +1547,12 @@ class CollectorServer:
             cs.obs.gauge("ot_index_high", index_high, level=level)
             # the span log carries no gauges: under fhh-trace the level's
             # K, what its evaluator held (the gauge ``_ev_chunks`` has
-            # just set) and the index's high word are an instant
+            # just set), the index's high word and its shape are an instant
             # (scripts/trace_spans.py ``secure_levels``)
             obstrace.instant(
                 "secure_level", comp=cs.obs.name, level=int(level),
                 chunks=len(chunks), index_high=index_high,
+                string_bits=S, patterns=C,
                 t_rows_held=cs.obs.gauge_value(
                     "secure_t_rows_held_bytes", level=level
                 ) if evaluates and ks is None else 0,

@@ -24,8 +24,10 @@ object:
 - ``secure_levels``: per component, over its ``secure_level`` instants
   (one a secure level), the most chunks a level crossed in, the most
   device bytes its evaluator held in unopened chunks (the gauge
-  ``secure_t_rows_held_bytes``) and the high word of the OT pad index
-  (the gauge ``ot_index_high``);
+  ``secure_t_rows_held_bytes``), the high word of the OT pad index
+  (the gauge ``ot_index_high``) and the widest shape a level had (bits
+  an equality test compares and child patterns a node: the gauges
+  ``secure_string_bits`` and ``child_patterns``);
 - ``plane_streams``: per component, over its ``plane_send`` instants
   (one a data-plane frame sent through its stream's writer thread:
   protocol/rpc.py ``_dp_send``), the frames (the counter
@@ -146,20 +148,25 @@ def secure_levels(events: list) -> dict:
     """Per component, over its ``secure_level`` instants (one a secure
     level: protocol/rpc.py ``_crawl_counts_secure``): the levels, the
     most chunks a level crossed in, the most device bytes its evaluator
-    held in unopened chunks (gauge ``secure_t_rows_held_bytes``) and the
-    high word of the 64-bit OT pad index (gauge ``ot_index_high``)."""
+    held in unopened chunks (gauge ``secure_t_rows_held_bytes``), the
+    high word of the 64-bit OT pad index (gauge ``ot_index_high``) and
+    the most bits a test compared and patterns a node had (gauges
+    ``secure_string_bits``, ``child_patterns``)."""
     out: dict = {}
     for e in events:
         if e.get("ph") == "i" and e.get("name") == "secure_level":
             a = e["args"]
             row = out.setdefault(e["comp"], {
                 "levels": 0, "chunks_max": 0, "t_rows_held_bytes_max": 0,
-                "ot_index_high": 0})
+                "ot_index_high": 0, "string_bits_max": 0,
+                "child_patterns_max": 0})
             row["levels"] += 1
             row["chunks_max"] = max(row["chunks_max"], a["chunks"])
             row["t_rows_held_bytes_max"] = max(
                 row["t_rows_held_bytes_max"], a["t_rows_held"])
             row["ot_index_high"] = max(row["ot_index_high"], a["index_high"])
+            row["string_bits_max"] = max(row["string_bits_max"], a["string_bits"])
+            row["child_patterns_max"] = max(row["child_patterns_max"], a["patterns"])
     return dict(sorted(out.items()))
 
 

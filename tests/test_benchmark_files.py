@@ -55,7 +55,7 @@ def test_the_hbm_cell_reports_what_the_secure_cell_reports():
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "zipf-flagship-secure-hbm", "steady-levels", 1)
     rate = next(m for m in bench["end_to_end"] if m["name"] == "crawl_clients_per_s")
-    assert rate["workloads"] == ["flagship-secure", "flagship-secure-hbm"]
+    assert rate["workloads"][:2] == ["flagship-secure", "flagship-secure-hbm"]
     by_name = {m["name"]: m for m in bench["per_layer"]}
     for m in bench["per_layer"]:
         if m.get("workloads") != ["flagship-secure"]:
@@ -67,3 +67,87 @@ def test_the_hbm_cell_reports_what_the_secure_cell_reports():
             k: m[k] for k in ("unit", "better", "source", "layer")}
         assert not os.path.exists(
             os.path.join(ROOT, "benchmark", "metrics", twin["name"] + ".json"))
+
+
+def test_the_2d_configuration_is_amazon_json_through_the_secure_lane():
+    """``amazon-zipf-2d`` runs the reference's second shipped
+    configuration (``configs/amazon.json``) as shipped: its ``config``
+    group equals that file key for key (but for the servers' addresses,
+    which the harness sets), and on every key that file lacks it equals
+    the secure flagship's; nothing published is cut, and the client count
+    the source does not give is listed as assumed."""
+    conf = _load("benchmark", "configs", "amazon-zipf-2d.json")
+    shipped = _load("configs", "amazon.json")
+    hbm = _load("benchmark", "configs", "zipf-flagship-secure-hbm.json")
+    assert conf.keys() == hbm.keys()
+    assert conf["config"].keys() == hbm["config"].keys()
+    for key, value in conf["config"].items():
+        if key in ("server0", "server1"):
+            assert value == ""
+        elif key in shipped:
+            assert value == shipped[key], key
+        else:
+            assert value == hbm["config"][key], key
+    assert {k: conf["config"][k] for k in ("data_len", "n_dims", "ball_size", "threshold")} == {
+        "data_len": 64, "n_dims": 2, "ball_size": 8, "threshold": 0.005}
+    assert conf["config"]["secure_exchange"] is True and conf["config"]["ot_path"] == "auto"
+    for key, value in shipped.items():
+        if key not in ("server0", "server1"):
+            assert conf["published"][key] == value, key
+    assert conf["reduced"] == [] and conf["reduced_why"] == {}
+    assert "clients" in conf["assumed"] and str(conf["clients"]) in conf["assumed"]["clients"]
+    assert conf["clients"] in (131072, 65536)
+    assert (conf["lane"], conf["reference"], conf["chips"]) == ("secure", "linf_ball_nd", 1)
+    assert conf["guarantees"] == hbm["guarantees"]
+    assert conf["lane_evidence"] == hbm["lane_evidence"]
+    assert "EMPTY AT DEPTH 59" in conf["deployment"].upper()
+    assert len(conf["source"]) <= 200
+
+
+# The hbm cell's entries that ``amazon-2d-secure`` leaves out: each reads
+# span names that a chunk opens once (some 17,600 spans of each a traced
+# run) and that are SHORT, and ``benchmark/trace_reduce.reduce`` walks
+# through every span shorter than a gap's owner for every idle gap of the
+# capture.  With them that search alone was an hour of a traced run of
+# this cell, without them a third of it (PERF.md section 7); they read
+# 82, 46, 27 and 26 ms of a 3.5 s level.
+_LEFT_OUT_2D = {"h2d_ms_per_level", "peer_wait_ms_per_level",
+                "wire_queue_ms_per_level", "wire_codec_ms_per_level"}
+
+
+def test_the_2d_cell_reports_what_the_hbm_cell_reports():
+    """Every per-layer metric of ``flagship-secure-hbm`` but the four of
+    ``_LEFT_OUT_2D`` has a ``.2d`` entry for ``amazon-2d-secure`` (an
+    entry, no file: the original's reader and arguments), the cell joins
+    ``crawl_clients_per_s``, and its own two metrics have files that
+    agree with their entries."""
+    bench = _load("BENCHMARK.json")
+    cell = next(w for w in bench["workloads"] if w["name"] == "amazon-2d-secure")
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "amazon-zipf-2d", "steady-levels", 1)
+    rate = next(m for m in bench["end_to_end"] if m["name"] == "crawl_clients_per_s")
+    assert rate["workloads"] == ["flagship-secure", "flagship-secure-hbm", "amazon-2d-secure"]
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    originals = [m for m in bench["per_layer"] if m.get("workloads") == ["flagship-secure-hbm"]]
+    assert len(originals) == 19
+    for m in originals:
+        base = m["name"][:-len(".hbm")] if m["name"].endswith(".hbm") else m["name"]
+        if base in _LEFT_OUT_2D:
+            assert base + ".2d" not in by_name
+            continue
+        twin = by_name[base + ".2d"]
+        assert twin["workloads"] == ["amazon-2d-secure"]
+        assert {k: twin[k] for k in ("unit", "better", "source", "layer", "moves")} == {
+            k: m[k] for k in ("unit", "better", "source", "layer", "moves")}
+        assert twin["moves"] == "setup_s"
+        assert not os.path.exists(
+            os.path.join(ROOT, "benchmark", "metrics", twin["name"] + ".json"))
+    own = [m for m in bench["per_layer"] if m.get("workloads") == ["amazon-2d-secure"]
+           and not m["name"].endswith(".2d")]
+    assert [m["name"] for m in own] == ["ot2s_table_ms_per_level", "equality_tests_per_level"]
+    for entry in own:
+        spec = _load("benchmark", "metrics", entry["name"] + ".json")
+        for key in ("name", "unit", "better", "source", "layer", "moves", "workloads"):
+            assert spec[key] == entry[key], key
+        assert spec["reader"] in ("counter_per_level", "span_ms_per_level") and spec["what"]
+        assert entry["moves"] == "setup_s"  # so that the rehearsal's twins stand as they are

@@ -31,6 +31,7 @@ from fuzzyheavyhitters_tpu.protocol import collect, keyplanes, secure
 
 # chip_smoke.py's table
 L = 512
+L_2D = 64  # configs/amazon.json: data_len 64, n_dims 2
 N_TRUSTED = 131072
 N_SECURE = 16384
 F = 64  # the widest frontier bucket the smoke's crawl reaches
@@ -87,31 +88,35 @@ def _compile(fn, *args, **static):
     return text
 
 
-def test_keygen(one_chip):
-    # one server pair's keys: N clients x (dim, side) = 2N ibDCF keys
-    n = 2 * N_TRUSTED
+@pytest.mark.parametrize("d,length", [(1, L), (2, L_2D)], ids=["flagship", "amazon2d"])
+def test_keygen(one_chip, d, length):
+    # one server pair's keys: N clients x (dim, side) = 2dN ibDCF keys
+    n = 2 * d * N_TRUSTED
     sds = _sds(one_chip)
     text = _compile(
         keygen_pallas._gen_pallas,
-        sds((n, 2, 4), jnp.uint32), sds((n, L), jnp.bool_), sds((n,), jnp.bool_),
+        sds((n, 2, 4), jnp.uint32), sds((n, length), jnp.bool_), sds((n,), jnp.bool_),
         derived_bits=True,
     )
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("d,length", [(1, L), (2, L_2D)], ids=["flagship", "amazon2d"])
 @pytest.mark.parametrize("want_children", [True, False])
-def test_expand_level(one_chip, want_children):
+def test_expand_level(one_chip, want_children, d, length):
     """One whole expand level as the server jits it — the resident key
     batch's level slice, the cw pack, and ``expand_pallas.expand_packed``
-    on the plane-major frontier — at the trusted lane's widest bucket."""
-    n, d = N_TRUSTED, 1
+    on the plane-major frontier — at the trusted lane's widest bucket,
+    and at the two-dimensional deployment's (``configs/amazon.json``:
+    four child patterns a node, a child cache twice as wide a client)."""
+    n = N_TRUSTED
     sds = _sds(one_chip)
     keys = IbDcfKeyBatch(
         key_idx=sds((n, d, 2), jnp.bool_),
         root_seed=sds((n, d, 2, 4), jnp.uint32),
-        cw_seed=sds((n, d, 2, L, 4), jnp.uint32),
-        cw_bits=sds((n, d, 2, L, 2), jnp.bool_),
-        cw_y_bits=sds((n, d, 2, L, 2), jnp.bool_),
+        cw_seed=sds((n, d, 2, length, 4), jnp.uint32),
+        cw_bits=sds((n, d, 2, length, 2), jnp.bool_),
+        cw_y_bits=sds((n, d, 2, length, 2), jnp.bool_),
     )
     frontier = collect.Frontier(
         states=EvalState(
@@ -191,8 +196,11 @@ def test_iknp_extension(one_chip):
              off, m=m)
 
 
+@pytest.mark.parametrize("S,b,k", [
+    (S, 32 * 2 * N_SECURE, 4), (4, 32 * 4 * N_TRUSTED, 256),
+], ids=["flagship", "amazon2d"])
 @pytest.mark.parametrize("words", [4, 8], ids=["FE62", "F255"])
-def test_secure_level_chunk_programs(one_chip, words):
+def test_secure_level_chunk_programs(one_chip, words, S, b, k):
     """What one chunk of a secure level dispatches on a server
     (``rpc._ev_chunks`` / ``_gb_chunks``), at the chunk
     ``secure.level_chunks`` cuts from the benchmark's steady level
@@ -200,10 +208,12 @@ def test_secure_level_chunk_programs(one_chip, words):
     wide, so its chunk is half the tests): the cut of the flat strings,
     both roles' rows of the extension, the planar table and its open.
     Offsets and pad indices are traced scalars, so these are every
-    chunk's programs from bucket 16 up."""
-    b = 32 * 2 * N_SECURE
+    chunk's programs from bucket 16 up.  ``amazon2d`` is the
+    two-dimensional deployment's steady level (S = 4, four patterns a
+    node, N=131,072): a 1-of-16 table of 256 bytes a test, 65,536
+    tests a frame."""
     chunks = secure.level_chunks(b, S, words, "ot2s")
-    assert len(chunks) == (4 if words == 4 else 8)
+    assert len(chunks) == (k if words == 4 else 2 * k)
     n = chunks[0][1]
     assert all(c[1] == n for c in chunks) and n % kernel_shard.BLOCK == 0
     sds = _sds(one_chip)

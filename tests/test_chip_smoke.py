@@ -19,14 +19,13 @@ PORT = 29231
 TINY = dict(num_sites=8, threshold=0.03, f_max=64)
 
 
-def test_cli_is_seed_and_chips_only(capsys):
-    with pytest.raises(SystemExit) as e:
-        chip_smoke.main(["--n", "8"])
-    assert e.value.code == 2
-    capsys.readouterr()
-    with pytest.raises(SystemExit) as e:
-        chip_smoke.main(["--chips", "2"])
-    assert e.value.code == 2
+def test_cli_is_seed_chips_and_dimensions_only(capsys):
+    for argv in (["--n", "8"], ["--chips", "2"], ["--n-dims", "4"],
+                 ["--n-dims", "2", "--chips", "4"]):
+        with pytest.raises(SystemExit) as e:
+            chip_smoke.main(argv)
+        assert e.value.code == 2
+        capsys.readouterr()
 
 
 def test_unpatched_run_fails_without_a_tpu(capsys):
@@ -46,6 +45,17 @@ def test_plain_count_is_a_saturating_ball_count():
     assert chip_smoke.plain_count(pts, 1, 3, 2) == {0: 2, 1: 2}
     # depth-1 prefixes: [0,1] x2 and [2,4] touch prefix 0; [2,4], [6,7] prefix 1
     assert chip_smoke.plain_count(pts, 1, 1, 1) == {0: 3, 1: 2}
+
+
+def test_plain_count_in_two_dimensions_is_a_product_of_balls():
+    # (0, 7) -> [0,1] x [6,7]; (3, 3) -> [2,4] x [2,4]
+    pts = np.array([[[0, 0, 0], [1, 1, 1]], [[0, 1, 1], [0, 1, 1]]], bool)
+    assert chip_smoke.plain_count(pts, 1, 3, 1) == {
+        **{(a, b): 1 for a in (0, 1) for b in (6, 7)},
+        **{(a, b): 1 for a in (2, 3, 4) for b in (2, 3, 4)},
+    }
+    assert chip_smoke.plain_count(pts, 1, 1, 1) == {
+        (0, 1): 2, (0, 0): 1, (1, 0): 1, (1, 1): 1}
 
 
 DEVICE = {"platform": "cpu", "kind": "rehearsal", "count": 1}
@@ -109,6 +119,20 @@ def test_sharded_comparison_at_tiny_size(patched, capsys):
     for rec in (sharded, one):
         assert len(rec["bytes_in_use_per_device_after_ingest"]) == 8
         assert len(rec["bytes_in_use_per_device_keys_resident"]) == 8
+
+
+def test_two_dimensional_secure_lane_at_tiny_size(patched, capsys):
+    """``--n-dims 2``'s path: the secure lane alone on two strings a
+    client (S = 4, the 1-of-16 table, four patterns a node), compared
+    with the plain count at the tapped depth and where the crawl ended."""
+    assert chip_smoke.run_secure_nd(256, 16, 2, 1, 4, port=PORT + 200, **TINY) == DEVICE
+    lines = [json.loads(x) for x in capsys.readouterr().out.strip().splitlines()]
+    assert [rec["phase"] for rec in lines] == ["start", "secure"]
+    rec = lines[1]
+    assert (rec["n"], rec["n_dims"], rec["ball"]) == (256, 2, 1)
+    assert rec["engines"]["ot_path"] == "ot2s" and rec["frontier_at_tap"] > 0
+    # to the leaf level with hitters, or died out on the way with none
+    assert (rec["levels"] == 16) == (rec["hitters"] > 0)
 
 
 def test_last_line_is_the_contract_and_nothing_more(monkeypatch, capsys):

@@ -252,7 +252,9 @@ def test_warmup_verb_compiles_without_touching_state(rng):
     sessions: results after warmup are identical to a cold crawl, and
     warmup before add_keys is a loud server error."""
     L, n = 5, 12
-    base = BASE_PORT + 700
+    # inside this file's own range (.. BASE_PORT + 699): + 700 is
+    # tests/test_rpc.py's BASE_PORT, which runs beside this file under xdist
+    base = BASE_PORT + 440
     _, (k0, k1) = _client_keys(rng, L, n)
     res_cold, _ = _crawl(
         _cfg(base, secure_exchange=True), base, k0, k1

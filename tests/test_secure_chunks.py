@@ -36,20 +36,28 @@ def _module_cpu(cpu_default):
     yield
 
 
-def _cfg(port, **kw):
+def _cfg(port, n_dims=1, **kw):
     return Config(
-        data_len=L, n_dims=1, ball_size=1, addkey_batch_size=1024,
+        data_len=L, n_dims=n_dims, ball_size=1, addkey_batch_size=1024,
         num_sites=4, threshold=0.2, zipf_exponent=1.03,
         server0=f"127.0.0.1:{port}", server1=f"127.0.0.1:{port + 10}",
         distribution="zipf", f_max=64, secure_exchange=True, **kw,
     )
 
 
-def _keys(n, seed=7):
+def _bits(pts):
+    """int[n] (one dimension) or int[n, d] -> bool[n, d, L]."""
+    return np.array([[bitutils.int_to_bits(L, int(v)) for v in np.atleast_1d(row)]
+                     for row in pts])
+
+
+def _keys(n, seed=7, pts=None):
+    """``pts``: int[n] (one dimension, drawn from ``seed`` if not given)
+    or int[n, d]."""
     rng = np.random.default_rng(seed)
-    pts = rng.integers(0, 1 << L, size=n)
-    bits = np.array([[bitutils.int_to_bits(L, int(v))] for v in pts])
-    return pts, ibdcf.gen_l_inf_ball(bits, 1, rng, engine="np")
+    if pts is None:
+        pts = rng.integers(0, 1 << L, size=n)
+    return pts, ibdcf.gen_l_inf_ball(_bits(pts), 1, rng, engine="np")
 
 
 def _root_counts(pts):
@@ -61,10 +69,10 @@ def _root_counts(pts):
 class _Pair:
     """Both servers, their clients and a leader in this process."""
 
-    def __init__(self, port, n, **cfg):
+    def __init__(self, port, n, pts=None, **cfg):
         self.port, self.n = port, n
-        self.cfg = _cfg(port, **cfg)
-        self.pts, (self.k0, self.k1) = _keys(n)
+        self.cfg = _cfg(port, n_dims=1 if pts is None else pts.shape[1], **cfg)
+        self.pts, (self.k0, self.k1) = _keys(n, pts=pts)
 
     async def __aenter__(self):
         p = self.port
@@ -373,6 +381,12 @@ def test_peer_with_another_cut_gets_connection_error(monkeypatch):
         (2 * 2 * 16384, 2, 4, "ot2s", [65536]),
         # the leaf level's table is twice as wide a test
         (32 * 2 * 16384, 2, 8, "ot2s", [131072] * 8),
+        # two dimensions (S = 4, four patterns a node) at N = 131,072: a
+        # 1-of-16 table of 256 bytes a test, 65,536 tests a frame, and
+        # 512 bytes and 32,768 at the leaf level
+        (32 * 4 * 131072, 4, 4, "ot2s", [65536] * 256),
+        (64 * 4 * 131072, 4, 4, "ot2s", [65536] * 512),
+        (32 * 4 * 131072, 4, 8, "ot2s", [32768] * 512),
         # the garbled batch of S = 8: 97 words a test
         (1 << 20, 8, 4, "gc", [40960] * 25 + [24576]),
         # a tail that is no whole block
@@ -640,3 +654,133 @@ def test_level_of_many_chunks_is_the_whole_level(monkeypatch, K, f):
     token = BLOCK * S * (1 + 16)
     assert held[0] is None
     assert token <= held[1] <= (rpc.CollectorServer.CHUNKS_AHEAD + 1) * token
+
+
+# -- two dimensions: strings of S = 4 bits, four child patterns a node --------
+
+
+def _plain_reference():
+    """The benchmark's d-dimensional plain reference, from its file."""
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                        "benchmark", "references", "linf_ball_nd.py")
+    spec = importlib.util.spec_from_file_location("linf_ball_nd", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _points_2d(n):
+    """Most clients at (4, 4), whose ball [3, 5] lies across the middle
+    of both dimensions (four nodes at depth 1 and at depth 2, nine
+    leaves), the rest anywhere."""
+    rng = np.random.default_rng(5)
+    pts = rng.integers(0, 1 << L, size=(n, 2))
+    pts[: n - n // 8] = 4
+    return pts
+
+
+@pytest.mark.parametrize("garbler", [0, 1])
+@pytest.mark.parametrize("last", [False, True], ids=["FE62", "F255-leaf"])
+def test_two_dimensional_level_in_chunks_is_the_whole_level(monkeypatch, garbler, last):
+    """``n_dims`` = 2 (configs/amazon.json): S = 4, a 1-of-16 table of
+    256 bytes a test (512 at the leaf level) and four patterns a node.
+    A level of two planar blocks in K = 2 chunks, garbled by either
+    server, in either field: the whole level's shares and cursors, the
+    chunks' frames side by side its two messages, and in FE62 the exact
+    counts of the plain reference."""
+    from fuzzyheavyhitters_tpu.parallel.kernel_shard import n_msg_planes
+
+    n, f, S, K = 1024, 4, 4, 2
+    field = F255 if last else FE62
+    W = secure.payload_words(field)
+    planes = n_msg_planes("ot2s", S, W)
+    assert secure.ot_path(S) == "ot2s" and planes == 16 * W
+    assert f * 4 * n == K * BLOCK
+    sent = _spy_frames(monkeypatch)
+    pts = _points_2d(n)
+    lv = L - 1 if last else 0
+
+    async def run():
+        async with _Pair(BASE_PORT + 700 + 20 * (2 * last + garbler), n, pts=pts) as pair:
+            await pair.both("tree_init", {"root_bucket": f})
+            del sent[:]
+            before = pair.ot_state()
+            monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", WHOLE)
+            whole = await pair.level(garbler, last=last)
+            after_whole, frames_whole = pair.ot_state(), list(sent)
+            pair.set_ot_state(before)
+            del sent[:]
+            monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", BLOCK * 4 * planes)
+            cut = await pair.level(garbler, last=last)
+            obs = [cs.obs for cs in pair.sessions]
+            return (whole, after_whole, frames_whole, cut, pair.ot_state(), list(sent),
+                    [o.counter_value("secure_chunks", level=lv) for o in obs],
+                    [o.counter_value("gc_tests", level=lv) for o in obs],
+                    [(o.gauge_value("secure_string_bits", level=lv),
+                      o.gauge_value("child_patterns", level=lv)) for o in obs])
+
+    (whole, after_whole, frames_whole, cut, after_cut, frames_cut, ks, tests,
+     shape) = _run(run())
+    for a, b in zip(whole, cut):
+        assert a.dtype == b.dtype and a.shape[:2] == (f, 4) and np.array_equal(a, b)
+    assert after_cut == after_whole
+    assert ks == [1 + K, 1 + K] and tests == [2 * f * 4 * n] * 2
+    assert shape == [(S, 4), (S, 4)]
+    u_whole, msg_whole = sorted(frames_whole, key=lambda a: a.nbytes)
+    us = [fr for fr in frames_cut if fr[2].ndim == 2]
+    msgs = [fr for fr in frames_cut if fr[2].ndim == 1]
+    assert [fr[:2] for fr in us] == [fr[:2] for fr in msgs] == [(k, K) for k in range(K)]
+    assert np.array_equal(np.concatenate([fr[2] for fr in us], axis=1), u_whole)
+    assert np.array_equal(
+        np.concatenate([fr[2].reshape(planes, -1) for fr in msgs], axis=1),
+        msg_whole.reshape(planes, -1),
+    )
+    if not last:
+        # the root's four children, pattern c taking bit (c >> j) & 1 in
+        # dimension j; the bucket's other slots are dead
+        got = np.asarray(FE62.canon(FE62.sub(cut[0], cut[1])))
+        want = _plain_reference().plain_count(_bits(pts), 1, 1, 1)
+        assert [int(v) for v in got[0]] == [want.get((c & 1, c >> 1), 0) for c in range(4)]
+        assert sum(int(v) for v in got[0]) > n and not got[1:].any()
+
+
+def test_two_dimensional_crawl_in_chunks_matches_the_driver_and_the_reference(monkeypatch):
+    """A whole served crawl at ``n_dims`` = 2 with a frame budget of one
+    planar block: its inner level of bucket 4 (FE62) and its leaf level
+    (F255) cross in K = 2 chunks, garbled by different servers; the
+    hitters and counts are the in-process ``driver.Leader``'s and the
+    plain reference's."""
+    from fuzzyheavyhitters_tpu.protocol import driver
+
+    n, S = 1024, 4
+    pts = _points_2d(n)
+    monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", BLOCK * 4 * 16 * 4)
+
+    async def run():
+        async with _Pair(BASE_PORT + 780, n, pts=pts) as pair:
+            lead = RpcLeader(pair.cfg, pair.c0, pair.c1)
+            res = await lead.run(n)
+            obs = [cs.obs for cs in pair.sessions]
+            return (res, pair.k0, pair.k1, pair.cfg,
+                    [[o.counter_value("secure_chunks", level=lv) for lv in range(L)]
+                     for o in obs],
+                    [[o.counter_value("gc_tests", level=lv) for lv in range(L)]
+                     for o in obs],
+                    [o.counter_value("ot_path_ot2s") for o in obs])
+
+    res, k0, k1, cfg, ks, tests, ot2s = _run(run())
+    assert ks == [[1, 2, 2]] * 2 and ot2s == [L, L]
+    assert tests == [[1 * 4 * n, 4 * 4 * n, 4 * 4 * n]] * 2
+    ref = _plain_reference()
+    thresh = max(1, int(cfg.threshold * n))
+    want = ref.frontiers(_bits(pts), cfg.ball_size, thresh, L)
+    got = ref.crawl_frontier(res.paths, res.counts)
+    assert got == want[L] and len(got) == 9
+    assert got == ref.plain_count(_bits(pts), cfg.ball_size, L, thresh)
+    s0, s1 = driver.make_servers(k0, k1)
+    inproc = driver.Leader(s0, s1, n_dims=2, data_len=L, f_max=cfg.f_max).run(
+        nreqs=n, threshold=cfg.threshold)
+    assert ref.crawl_frontier(inproc.paths, inproc.counts) == got

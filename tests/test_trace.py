@@ -914,6 +914,8 @@ def test_chunk_spans_carry_the_level_and_lie_inside_gc_ot(rng, trace_dir, monkey
     held = rep["secure_kernels"]["t_rows_held_bytes_by_level"]
     assert set(held) == {"0", "1"} and held["0"] > 0 and held["1"] > 0
     assert rep["secure_kernels"]["ot_index_high"] == 0
+    assert (rep["secure_kernels"]["string_bits"],
+            rep["secure_kernels"]["child_patterns"]) == (2, 2)
     evs = _events(trace_dir)
     assert tracemod.validate(evs)["ok"]
     spans = [e for e in evs if e["ph"] == "X"]
@@ -925,11 +927,18 @@ def test_chunk_spans_carry_the_level_and_lie_inside_gc_ot(rng, trace_dir, monkey
         lo, hi = g["ts"] - _EPS, g["ts"] + g["dur"] + _EPS
         chunked = [e for e in mine if "chunk" in e]
         # every chunk, in each span a role records once a chunk
-        for name in ("otext", "b2a", "d2h", "h2d", "wire_queue", "wire_write",
-                     "wire_wait", "peer_wait", "wire_read"):
+        for name in ("otext", "b2a", "ot2s", "d2h", "h2d", "wire_queue",
+                     "wire_write", "wire_wait", "peer_wait", "wire_read"):
             got = sorted(e["chunk"] for e in chunked if e["name"] == name)
             assert got == list(range(K)), (comp, name, got)
         for e in chunked:
+            if e["name"] == "ot2s":
+                # the 2^S table or its opening: inside its chunk's b2a
+                up = by_id[e["parent"]]
+                assert (up["name"], up["chunk"]) == ("b2a", e["chunk"])
+                assert up["ts"] - _EPS <= e["ts"]
+                assert e["ts"] + e["dur"] <= up["ts"] + up["dur"] + _EPS
+                continue
             assert e["name"] in _LEAVES + _RECV_LEAVES + ("wire_wait",), e
             # (a frame's read is stamped by the reader thread, which may
             # take it off the socket before this server's gc_ot began)
@@ -970,6 +979,7 @@ def test_chunk_spans_carry_the_level_and_lie_inside_gc_ot(rng, trace_dir, monkey
     for row in rows.values():
         assert row["levels"] == 2 and row["chunks_max"] == K
         assert row["ot_index_high"] == 0
+        assert (row["string_bits_max"], row["child_patterns_max"]) == (2, 2)
     assert max(r["t_rows_held_bytes_max"] for r in rows.values()) == max(
         held.values())
 

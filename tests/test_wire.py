@@ -381,7 +381,12 @@ def test_slab_returns_only_when_its_last_reader_is_gone(monkeypatch, cpu_default
     is lowered) serves the next frame only once the array made on it,
     every view of it and a ``jax.device_put`` of it are gone; until
     then later frames of the same size get other memory and what the
-    readers see does not change."""
+    readers see does not change.  WHEN ``jax.device_put`` lets go is
+    JAX's business (at once where it copied on the calling thread, with
+    the device array where it aliased the buffer, in between where a
+    thread of its own copies), so nothing here asks where the frames
+    after it land among the slabs it may hold: only that no reader's
+    bytes change."""
     import jax
 
     monkeypatch.setattr(wire, "_SLAB_MIN", OOB)
@@ -405,11 +410,12 @@ def test_slab_returns_only_when_its_last_reader_is_gone(monkeypatch, cpu_default
         addr2 = second.ctypes.data
         del second
         third = await frame(2)  # the device copy may hold frame 1's
-        assert len({addr, addr2, third.ctypes.data}) == 3
+        assert third.ctypes.data != addr
         assert np.array_equal(view, sent[0][1000:2000])
         del view
-        fourth = await frame(3)  # the newest released slab: frame 0's
-        assert fourth.ctypes.data == addr and np.array_equal(fourth, sent[3])
+        fourth = await frame(3)  # a released slab: frame 0's, or frame 1's if JAX let go since
+        assert fourth.ctypes.data in (addr, addr2) and fourth.ctypes.data != third.ctypes.data
+        assert np.array_equal(fourth, sent[3])
         assert np.array_equal(third, sent[2])
         assert np.array_equal(np.asarray(on_device), sent[1])
         del third, fourth, on_device
