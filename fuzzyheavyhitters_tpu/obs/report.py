@@ -343,6 +343,7 @@ def _secure_kernel_summary(registries: dict) -> dict | None:
     by_level: dict = {}
     paths = {"ot2s": 0, "gc": 0}
     chunks: dict = {}
+    programs: dict = {}
     held: dict = {}
     index_high = 0
     shape = {"secure_string_bits": None, "child_patterns": None}
@@ -370,6 +371,9 @@ def _secure_kernel_summary(registries: dict) -> dict | None:
         c = snap.get("counters", {}).get("secure_chunks")
         for lvl, k in (c or {}).get("by_level", {}).items():
             chunks[lvl] = max(chunks.get(lvl, 0), k)
+        c = snap.get("counters", {}).get("secure_chunk_programs")
+        for lvl, k in (c or {}).get("by_level", {}).items():
+            programs[lvl] = max(programs.get(lvl, 0), k)
         g = snap.get("gauges", {}).get("kernel_shards")
         if g is not None:
             kshards = g.get("last") if kshards is None else max(
@@ -404,6 +408,12 @@ def _secure_kernel_summary(registries: dict) -> dict | None:
         # ``_ev_chunks``; 1 = the level went whole)
         "chunks_by_level": dict(
             sorted(chunks.items(), key=lambda kv: int(kv[0]))
+        ),
+        # device programs a server handed over inside each level's
+        # ``otext`` + ``b2a`` spans (counter ``secure_chunk_programs``):
+        # one a span, 2 x chunks
+        "chunk_programs_by_level": dict(
+            sorted(programs.items(), key=lambda kv: int(kv[0]))
         ),
         # device bytes of the chunks the evaluator had sent u for and not
         # yet opened, at the fullest of each level (gauge

@@ -228,16 +228,22 @@ def _eval_kernel(S: int, W: int, sc_ref,
         pay_ref[w] = (ct ^ pad[w]).reshape(sh3)
 
 
-def _planarize(a, B: int, bp: int):
-    """[B, ...trailing] -> planar u32[prod(trailing), rows, 8, LANES]."""
+def _planes(a, bp: int):
+    """Test-minor [k, B] -> planar u32[k, rows, 8, LANES], zero past B."""
     a = jnp.asarray(a, jnp.uint32)
-    k = int(np.prod(a.shape[1:])) if a.ndim > 1 else 1
-    a = a.reshape(B, k).T  # [k, B]
+    k, B = a.shape
     if bp != B:
         a = jnp.concatenate(
             [a, jnp.zeros((k, bp - B), jnp.uint32)], axis=-1
         )
     return a.reshape(k, bp // GROUP, SUB, LANES)
+
+
+def _planarize(a, B: int, bp: int):
+    """[B, ...trailing] -> planar u32[prod(trailing), rows, 8, LANES]."""
+    a = jnp.asarray(a, jnp.uint32)
+    k = int(np.prod(a.shape[1:])) if a.ndim > 1 else 1
+    return _planes(a.reshape(B, k).T, bp)
 
 
 def _unplanarize(a, B: int):

@@ -34,6 +34,7 @@ channel position in the reference's ocelot session).
 
 from __future__ import annotations
 
+import math
 import secrets
 from functools import partial
 
@@ -75,12 +76,10 @@ def unpack_bits(words: jax.Array, m: int) -> jax.Array:
     return ((words[..., idx // 32] >> (idx % 32).astype(jnp.uint32)) & 1).astype(bool)
 
 
-@partial(jax.jit, static_argnames=("m",))
-def _transpose_pack(cols: jax.Array, m: int) -> jax.Array:
-    """Column-major bit matrix -> packed 128-bit rows.
-
-    cols: uint32[128, W] where bit j of cols[i] is entry (row j, column i).
-    Returns uint32[m, 4]: row j's 128 column bits packed into 4 words.
+def _butterfly(cols: jax.Array) -> jax.Array:
+    """Column-major bit matrix uint32[128, W] (bit j of cols[i] is entry
+    (row j, column i)) -> uint32[4, 32, W]: ``x[k, r, wj]`` is word k of
+    the packed 128-bit row ``j = wj*32 + r``.
 
     PACKED 32x32 butterfly transpose (the Hacker's Delight 7-3 network,
     little-endian orientation, vectorized over all word tiles): 5 stages
@@ -102,8 +101,42 @@ def _transpose_pack(cols: jax.Array, m: int) -> jax.Array:
         a0 = a0 ^ (t << j)
         a1 = a1 ^ t
         x = jnp.stack([a0, a1], axis=2).reshape(4, 32, w)
+    return x
+
+
+@partial(jax.jit, static_argnames=("m",))
+def _transpose_pack(cols: jax.Array, m: int) -> jax.Array:
+    """Column-major bit matrix -> packed 128-bit rows uint32[m, 4]: row
+    j's 128 column bits packed into 4 words (:func:`_butterfly`)."""
+    w = cols.shape[1]
     # x[k, r, wj] -> out[j = wj*32 + r, word k]
-    return jnp.transpose(x, (2, 1, 0)).reshape(w * 32, 4)[:m]
+    return jnp.transpose(_butterfly(cols), (2, 1, 0)).reshape(w * 32, 4)[:m]
+
+
+@partial(jax.jit, static_argnames=("m", "S"))
+def _transpose_planes(cols: jax.Array, m: int, S: int) -> jax.Array:
+    """The rows of :func:`_transpose_pack` as the planes the equality
+    kernels read (ops/gc_pallas.py ``_planarize`` of the rows as
+    ``[m // S, S, 4]``): uint32[S*4, m // S], plane ``s*4 + k`` holding
+    word k of rows ``s, S + s, 2S + s, ...`` — bit ``s`` of test
+    ``t = j // S`` for a batch whose row ``j = t*S + s`` is a test's
+    string bit.  ``m`` is a multiple of ``S``.
+
+    One transposition instead of two: rows as ``[m, 4]`` have a minor
+    dimension of 4, and cutting them into ``[m // S, S, 4]`` afterwards
+    sends a lane-padded copy of them (32 times their bytes) through HBM
+    in whichever program does it (PERF.md section 5, PR 38)."""
+    g = math.lcm(S, 32) // 32  # words of a column that hold whole tests
+    w = cols.shape[1]
+    if w % g:
+        cols = jnp.pad(cols, ((0, 0), (0, g - w % g)))
+        w = cols.shape[1]
+    x = _butterfly(cols)
+    # x[k, r, wj] -> [k, wj // g, (wj % g)*32 + r = t_lo*S + s]
+    #             -> [s, k, t = (wj // g)*(32g/S) + t_lo]
+    x = jnp.transpose(x.reshape(4, 32, w // g, g), (0, 2, 3, 1))
+    x = jnp.transpose(x.reshape(4, w // g, 32 * g // S, S), (3, 0, 1, 2))
+    return x.reshape(S * 4, w * 32 // S)[:, : m // S]
 
 
 @partial(jax.jit, static_argnames=("w",))
@@ -114,26 +147,34 @@ def _col_words(seeds: jax.Array, w: int, offset) -> jax.Array:
     return blocks.reshape(128, nb * 16)[:, :w]
 
 
-def _receiver_extend_core(seeds0, seeds1, choices, offset, m):
+def _pack_rows(cols, m: int, S):
+    """The transposed rows: uint32[m, 4], or with a string width ``S``
+    the test-planar uint32[S*4, m // S] of :func:`_transpose_planes`."""
+    return _transpose_pack(cols, m) if S is None else _transpose_planes(cols, m, S)
+
+
+def _receiver_extend_core(seeds0, seeds1, choices, offset, m, S=None):
     w = -(-m // 32)
     t = _col_words(seeds0, w, offset)
     g1 = _col_words(seeds1, w, offset)
     r_words = pack_bits(jnp.asarray(choices, bool))  # [w]
     u = t ^ g1 ^ r_words[None, :]
-    return u, _transpose_pack(t, m)
+    return u, _pack_rows(t, m, S)
 
 
-def _sender_extend_core(seeds, s_bits, u, offset, m):
+def _sender_extend_core(seeds, s_bits, u, offset, m, S=None):
     w = -(-m // 32)
     g = _col_words(seeds, w, offset)
     q = g ^ jnp.where(jnp.asarray(s_bits, bool)[:, None], u, jnp.uint32(0))
-    return _transpose_pack(q, m)
+    return _pack_rows(q, m, S)
 
 
-_receiver_extend = partial(jax.jit, static_argnames=("m",))(
+_receiver_extend = partial(jax.jit, static_argnames=("m", "S"))(
     _receiver_extend_core
 )
-_sender_extend = partial(jax.jit, static_argnames=("m",))(_sender_extend_core)
+_sender_extend = partial(jax.jit, static_argnames=("m", "S"))(
+    _sender_extend_core
+)
 
 
 # Row-sharded extension (the multi-chip kernel stage,
@@ -347,17 +388,20 @@ class OtExtSender:
         self._off += -(-w // 16)  # blocks consumed from each column stream
         self._sent += m
 
-    def extend_rows(self, m: int, u_cols, base_off: int, row0: int) -> jax.Array:
+    def extend_rows(self, m: int, u_cols, base_off: int, row0: int,
+                    S: int | None = None) -> jax.Array:
         """Q rows ``[row0, row0 + m)`` of the batch that began at stream
         offset ``base_off``, from the matching column words of the
         peer's u-matrix; the cursors do not move (the caller
         moves them past the whole batch with :meth:`advance`).  ``row0`` is a
         multiple of 512 (see :func:`sender_extend_rows`); it is a traced
         scalar of the one program :meth:`extend` runs, so a later row
-        range of a batch compiles nothing new."""
+        range of a batch compiles nothing new.  With a string width
+        ``S`` the rows come as the planes the equality kernels read,
+        uint32[S*4, m // S] (:func:`_transpose_planes`)."""
         return _sender_extend(
             self._seeds, self._s_dev, jnp.asarray(u_cols),
-            base_off + row0 // 512, m,
+            base_off + row0 // 512, m, S,
         )
 
     def extend(self, m: int, u_msg) -> jax.Array:

@@ -48,8 +48,7 @@ import numpy as np
 
 from . import otext
 from .gc_pallas import (
-    GROUP, R_BLK, _ot_pad, _planarize, _test_idx, _unplanarize,
-    padded_tests,
+    GROUP, R_BLK, _ot_pad, _planes, _test_idx, padded_tests,
 )
 from .keygen_pallas import LANES, SUB
 
@@ -163,12 +162,17 @@ def _ot2s_dec_kernel(S: int, W: int, sc_ref,
 
 
 @partial(jax.jit, static_argnames=("S", "W", "domain", "interpret"))
-def _enc_planar(q_rows, s_block, x_bits, m_v0, m_v1, idx_offset,
+def _enc_planes(q, s_block, x, m_v0, m_v1, idx_offset,
                 S: int, W: int, domain: int, interpret: bool):
+    """The sender's table from TEST-MINOR operands: q ``u32[S*4, B]``
+    (plane ``s*4 + w``, the extension's ``otext._transpose_planes``),
+    x ``[S, B]`` 0/1, m_v0 / m_v1 ``u32[W, B]``.  No transposition on
+    the way into the kernel: pad to whole blocks, cut the test axis
+    into (row, sublane, lane)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    B = x_bits.shape[0]
+    B = x.shape[1]
     bp = padded_tests(B)
     rows = bp // GROUP
     # gc_pallas._ot_pad hashes with the FIXED tweak word 1; the XLA
@@ -179,12 +183,7 @@ def _enc_planar(q_rows, s_block, x_bits, m_v0, m_v1, idx_offset,
     offs = otext.gf128_offsets(s_block, S)
     offs = offs.at[:, 1].set(offs[:, 1] ^ jnp.uint32(domain))
     sc = jnp.concatenate([jnp.ravel(offs), otext.index_base(idx_offset)])
-    ops = [
-        _planarize(q_rows, B, bp),
-        _planarize(jnp.asarray(x_bits, jnp.uint32), B, bp),
-        _planarize(m_v0, B, bp),
-        _planarize(m_v1, B, bp),
-    ]
+    ops = [_planes(a, bp) for a in (q, x, m_v0, m_v1)]
     z = np.int32(0)
     spec = lambda k: pl.BlockSpec((k, R_BLK, SUB, LANES),
                                   lambda j, c: (z, j, z, z))
@@ -210,26 +209,44 @@ def _enc_planar(q_rows, s_block, x_bits, m_v0, m_v1, idx_offset,
     return jnp.ravel(cts)
 
 
+def _test_minor(a):
+    """[B, ...trailing] -> [prod(trailing), B] (``_planarize``'s order)."""
+    a = jnp.asarray(a, jnp.uint32)
+    return a.reshape(a.shape[0], -1).T
+
+
 @partial(jax.jit, static_argnames=("S", "W", "domain", "interpret"))
-def _dec_planar(t_rows, y_bits, msg, idx_offset,
+def _enc_planar(q_rows, s_block, x_bits, m_v0, m_v1, idx_offset,
                 S: int, W: int, domain: int, interpret: bool):
+    return _enc_planes(
+        _test_minor(q_rows), s_block, _test_minor(x_bits),
+        _test_minor(m_v0), _test_minor(m_v1), idx_offset, S, W, domain,
+        interpret,
+    )
+
+
+@partial(jax.jit, static_argnames=("S", "W", "domain", "interpret"))
+def _dec_planes(t, y, msg, idx_offset,
+                S: int, W: int, domain: int, interpret: bool):
+    """The receiver's open from TEST-MINOR operands (see
+    :func:`_enc_planes`): t ``u32[S*4, B]``, y ``[S, B]`` 0/1.  Returns
+    the payload words test-minor too, ``u32[W, B]``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    B = y_bits.shape[0]
+    B = y.shape[1]
     bp = padded_tests(B)
     rows = bp // GROUP
     n_cts = (1 << S) * W
     sc = otext.index_base(idx_offset)
     # receiver-side domain fold: the kernel hashes comb(t) under the
     # fixed tweak; comb is linear with coefficient x^0 = 1 on row 0, so
-    # XORing the domain into row 0's word 1 lands it on comb's word 1 —
-    # the same place the XLA ot_hash tweak puts it.
-    t_rows = jnp.asarray(t_rows, jnp.uint32)
-    t_rows = t_rows.at[:, 0, 1].set(t_rows[:, 0, 1] ^ jnp.uint32(domain))
+    # XORing the domain into row 0's word 1 (plane 1) lands it on comb's
+    # word 1 — the same place the XLA ot_hash tweak puts it.
+    t = jnp.asarray(t, jnp.uint32)
+    t = t.at[1].set(t[1] ^ jnp.uint32(domain))
     ops = [
-        _planarize(t_rows, B, bp),
-        _planarize(jnp.asarray(y_bits, jnp.uint32), B, bp),
+        _planes(t, bp), _planes(y, bp),
         jnp.asarray(msg, jnp.uint32).reshape(n_cts, rows, SUB, LANES),
     ]
     z = np.int32(0)
@@ -252,7 +269,16 @@ def _dec_planar(t_rows, y_bits, msg, idx_offset,
         ],
         interpret=interpret,
     )(sc, *ops)
-    return _unplanarize(pay, B).reshape(B, W)
+    return pay.reshape(W, -1)[:, :B]
+
+
+@partial(jax.jit, static_argnames=("S", "W", "domain", "interpret"))
+def _dec_planar(t_rows, y_bits, msg, idx_offset,
+                S: int, W: int, domain: int, interpret: bool):
+    return _dec_planes(
+        _test_minor(t_rows), _test_minor(y_bits), msg, idx_offset, S, W,
+        domain, interpret,
+    ).T
 
 
 def ot2s_encrypt(q_rows, s_block, x_flat, m_v0, m_v1, n_words: int,
@@ -277,4 +303,26 @@ def ot2s_decrypt(t_rows, y_flat, msg, n_words: int, idx_offset,
     return _dec_planar(
         t_rows, jnp.asarray(y_flat, bool), jnp.asarray(msg, jnp.uint32),
         idx_offset, S, n_words, domain, interpret,
+    )
+
+
+def ot2s_encrypt_planes(q, s_block, x, m_v0, m_v1, n_words: int,
+                        idx_offset, domain: int, interpret: bool = False):
+    """:func:`ot2s_encrypt` from test-minor operands (a chunk's step,
+    protocol/secure.py): q ``u32[S*4, B]`` as
+    ``otext._transpose_planes`` leaves the extension's rows, x
+    ``[S, B]``, payloads ``u32[n_words, B]``.  The same planar wire."""
+    return _enc_planes(
+        q, jnp.asarray(s_block, jnp.uint32), x, m_v0, m_v1, idx_offset,
+        x.shape[0], n_words, domain, interpret,
+    )
+
+
+def ot2s_decrypt_planes(t, y, msg, n_words: int, idx_offset, domain: int,
+                        interpret: bool = False):
+    """:func:`ot2s_decrypt` from test-minor operands, returning the
+    payload words test-minor too: ``u32[n_words, B]``."""
+    return _dec_planes(
+        t, y, jnp.asarray(msg, jnp.uint32), idx_offset, y.shape[0],
+        n_words, domain, interpret,
     )

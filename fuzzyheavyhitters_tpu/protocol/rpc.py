@@ -1112,6 +1112,16 @@ class CollectorServer:
         if self.cfg.secure_phase_sync:
             await asyncio.to_thread(jax.block_until_ready, x)
 
+    @staticmethod
+    def _program(cs, level: int, fn, *args):
+        """Hand the device ONE program of a secure chunk, inside its
+        ``otext`` or ``b2a`` span, and count it: the counter
+        ``secure_chunk_programs`` reads 2 x K a level on either server
+        (tests/test_secure_chunks.py holds the calls to one jitted
+        program each)."""
+        cs.obs.count("secure_chunk_programs", level=level)
+        return fn(*args)
+
     def _zero_phases(self, cs, level: int, *names: str) -> None:
         """Materialize zero-valued phase timers so the secure-kernel
         split always carries all four keys on both servers (a garbler
@@ -1259,9 +1269,9 @@ class CollectorServer:
             for k, (t0, n) in enumerate(chunks):
                 with self._chunk_label(k, K):
                     with cs.obs.span("otext", level=level):
-                        y = secure.chunk_rows(flat, t0, n)
-                        u, t_rows = rcv.extend_rows(
-                            y.reshape(n * S), off, t0 * S
+                        u, t_rows, y = self._program(
+                            cs, level, secure.ev_chunk_extend,
+                            rcv, flat, off, t0, n,
                         )
                         # the extension, device-synced as on the sender
                         # side; the fetch is then the copy alone
@@ -1279,22 +1289,26 @@ class CollectorServer:
                     bmsg = self._h2d(
                         cs, level, await self._chunk_recv(cs, k, K)
                     )
-                    open_ = functools.partial(
-                        secure.ev_chunk_words,
-                        t_rows, y, bmsg, W, path, idx0, t0,
-                    )
                     if path != "ot2s":
                         with cs.obs.span("eval", level=level):
-                            pay = open_()
+                            pay = secure.ev_chunk_eval(
+                                t_rows, y, bmsg, W, idx0, t0
+                            )
                             await self._phase_sync(pay)
                     with cs.obs.span("b2a", level=level):
                         if path == "ot2s":
                             # the opening of the 2^S table, inside b2a
                             with cs.obs.span("ot2s", level=level):
-                                v = secure.words_to_field(count_field, open_())
+                                v = self._program(
+                                    cs, level, secure.ev_chunk_open,
+                                    count_field, t_rows, y, bmsg, idx0, t0,
+                                )
                                 await self._phase_sync(v)
                         else:
-                            v = secure.words_to_field(count_field, pay)
+                            v = self._program(
+                                cs, level, secure.ev_chunk_field,
+                                count_field, pay,
+                            )
                             await self._phase_sync(v)
                     vals.append(v)
             return vals
@@ -1323,6 +1337,11 @@ class CollectorServer:
         snd = cs._ot_snd
         idx0, off = snd.consumed, snd.stream_offset
         snd.advance(B * S)  # see _ev_chunks
+        # the level's two 16-byte constants go to the device once, not
+        # with every chunk's program (a sharded server's to its own first
+        # chip, where its one-device kernel stage runs: see _h2d)
+        put = jax.device_put if cs._mesh is None else cs._mesh.gather
+        b2a_seed, s_block = put(b2a_seed), put(snd.s_block)
         built: asyncio.Queue = asyncio.Queue(maxsize=2)
 
         async def build():
@@ -1333,27 +1352,33 @@ class CollectorServer:
                         cs, level, await self._chunk_recv(cs, k, K)
                     )
                     with cs.obs.span("otext", level=level):
-                        q = snd.extend_rows(n * S, u, off, t0 * S)
+                        q = self._program(
+                            cs, level, secure.gb_chunk_extend,
+                            snd, u, S, off, t0, n,
+                        )
                         await self._phase_sync(q)
                     with cs.obs.span("b2a", level=level):
-                        v, w0, w1 = secure.b2a_payload_pair(
-                            count_field, b2a_seed, n, garbler, t0
-                        )
-                        build_msg = functools.partial(
-                            secure.gb_chunk_msg, snd.s_block, q,
-                            secure.chunk_rows(flat, t0, n), w0, w1, gc_seed,
-                            W, path, idx0, B, t0,
-                        )
                         if path == "ot2s":
-                            # the 2^S table, inside b2a
+                            # the share pair and the 2^S table, inside b2a
                             with cs.obs.span("ot2s", level=level):
-                                msg = build_msg()
+                                msg, v = self._program(
+                                    cs, level, secure.gb_chunk_table,
+                                    count_field, b2a_seed, q, flat,
+                                    s_block, idx0, t0, n, garbler,
+                                )
                                 await self._phase_sync(msg)
                         else:
+                            v, w0, w1 = self._program(
+                                cs, level, secure.gb_chunk_pair,
+                                b2a_seed, t0, count_field, garbler, n,
+                            )
                             await self._phase_sync(w1)
                     if path != "ot2s":
                         with cs.obs.span("garble", level=level):
-                            msg = build_msg()
+                            msg = secure.gb_chunk_garble(
+                                s_block, q, gc_seed, flat, w0, w1, W,
+                                idx0, t0, n,
+                            )
                             await self._phase_sync(msg)
                     vals.append(v)
                 # fhh-lint: disable=unbounded-await (drained by a sibling task, which _chunk_tasks cancels with this one)
@@ -1401,7 +1426,11 @@ class CollectorServer:
         benchmark's cell, every small test) gives, bit for bit, and the
         frames side by side are its two messages
         (tests/test_secure_chunks.py).  The counter ``secure_chunks``
-        says K, level by level.  The row-sharded kernel stage
+        says K, level by level, and ``secure_chunk_programs`` the device
+        programs handed over inside its ``otext`` and ``b2a`` spans: one
+        a span, 2 x K a level on either server (``secure``'s chunk
+        functions are one jitted program each, the chunk's first test
+        traced).  The row-sharded kernel stage
         (``ks``, parallel/kernel_shard.py) keeps one frame a message: a
         sharded server and an unsharded peer agree only while the level
         is under two chunks.
@@ -1456,6 +1485,9 @@ class CollectorServer:
                 else secure.level_chunks(B, S, W, path)
             )
             cs.obs.count("secure_chunks", len(chunks), level=level)
+            programs0 = cs.obs.counter_value(
+                "secure_chunk_programs", level=level
+            )
             if cs._mesh is not None:
                 # per-level kernel layout: the active row-shard count (1
                 # = the degraded gather path) feeds the mesh report
@@ -1552,6 +1584,11 @@ class CollectorServer:
             obstrace.instant(
                 "secure_level", comp=cs.obs.name, level=int(level),
                 chunks=len(chunks), index_high=index_high,
+                # device programs of this pass's ``otext`` + ``b2a`` spans
+                # (the counter ``secure_chunk_programs``): 2 a chunk
+                programs=cs.obs.counter_value(
+                    "secure_chunk_programs", level=level
+                ) - programs0,
                 string_bits=S, patterns=C,
                 t_rows_held=cs.obs.gauge_value(
                     "secure_t_rows_held_bytes", level=level
@@ -3008,6 +3045,7 @@ class CollectorServer:
                     share_sums=mesh.node_share_sums if mesh is not None
                     else None,
                     radix=r,
+                    put=jax.device_put if mesh is None else mesh.gather,
                 )
             else:
                 masks = collect.pattern_masks_radix(d, r)

@@ -28,11 +28,16 @@ emitting the PLANAR wire (the 1-of-2^S payload table for S ≤
 ``OT2S_MAX_S``, else the packed garbled batch with the b2a payloads
 riding the output labels):
 
-- the CHUNK functions (``level_chunks``, ``chunk_rows``,
-  ``gb_chunk_msg``, ``ev_chunk_words``) are what protocol/rpc.py
-  dispatches: a level crosses the data-plane socket as K frames a
-  direction, each a run of whole planar blocks (K = 1 is one round
-  trip of one message a side);
+- the CHUNK functions (``level_chunks``; the receiver's
+  ``ev_chunk_extend`` and ``ev_chunk_open``, the sender's
+  ``gb_chunk_extend`` and ``gb_chunk_table``; on the garbled-circuit
+  path ``gb_chunk_pair`` + ``gb_chunk_garble`` and ``ev_chunk_eval`` +
+  ``ev_chunk_field``) are what protocol/rpc.py dispatches: a level
+  crosses the data-plane socket as K frames a direction, each a run of
+  whole planar blocks (K = 1 is one round trip of one message a side),
+  and each function is ONE jitted device program, one inside each of a
+  chunk's spans, with the chunk's first test traced (the servers'
+  counter ``secure_chunk_programs`` reads 2 x K a level);
 - the WHOLE-LEVEL trio (``ev_step1_fused``, ``gb_step_level`` /
   ``ev_open_level``) is the same level as ONE message from one call a
   side.  Nothing in the package calls it: it is the oracle the chunk
@@ -549,45 +554,172 @@ def level_chunks(B: int, S: int, W: int, path: str) -> list:
     return [(t0, min(n, B - t0)) for t0 in range(0, B, n)]
 
 
+# Each device step of a chunk is ONE jitted program, one inside each of
+# the chunk's spans (protocol/rpc.py ``_ev_chunks`` / ``_gb_chunks``):
+# the row cut of the level's flat strings, the share pair and the way
+# from the extension's rows to the kernels' planes are traced into the
+# program that needs them, with the chunk's first test ``t0`` and its
+# pad index traced scalars, so the K chunks of a level and every later
+# level of the bucket run one executable a span.  Nothing is dispatched
+# eagerly between them, and the extension hands its rows over as the
+# planes the kernels read (``otext._transpose_planes``, uint32[S*4, n]):
+# rows as uint32[n*S, 4] cut into [n, S, 4] send a lane-padded copy of
+# themselves, 32 times their bytes, through HBM, in a program of their
+# own or inside the kernel's (PERF.md section 5, PR 38).  A level gone
+# whole is the chunk ``t0 = 0, n = B``.
+
+
+def _test_rows(planes, S: int):
+    """The extension's planes uint32[S*4, n] as rows uint32[n, S, 4], for
+    the XLA twins and the garbled-circuit path."""
+    return jnp.transpose(planes.reshape(S, 4, -1), (2, 0, 1))
+
+
 @partial(jax.jit, static_argnames=("n",))
-def _test_rows(flat, t0, n: int):
-    return jax.lax.dynamic_slice_in_dim(flat, t0, n)
+def _ev_extend(seeds0, seeds1, flat, block_off, t0, n: int):
+    S = flat.shape[1]
+    y = jax.lax.dynamic_slice_in_dim(flat, t0, n)
+    u, t_planes = otext._receiver_extend_core(
+        seeds0, seeds1, y.reshape(n * S), block_off, n * S, S
+    )
+    return u, t_planes, y
 
 
-def chunk_rows(flat, t0: int, n: int):
-    """Tests ``[t0, t0 + n)`` of the level's flat strings, on the device
-    (``t0`` traced: one program a chunk size)."""
-    return flat if n == flat.shape[0] else _test_rows(flat, t0, n)
-
-
-def gb_chunk_msg(s_block, q_rows, x_flat, w0, w1, gc_seed, W: int,
-                 path: str, idx0: int, B: int, t0: int):
-    """The sender's planar message for tests ``[t0, t0 + n)`` of a
-    ``B``-test level, from those tests' extension rows, strings and
-    payload pair: the 1-of-2^S table or the packed garbled batch."""
-    n, S = x_flat.shape
-    q_rows = q_rows.reshape(n, S, 4)
-    # result 1 (strings equal) -> receiver learns r0 (collect.rs:439-456)
-    if path == "ot2s":
-        return ot2s_encrypt_packed(
-            q_rows, jnp.asarray(s_block), x_flat, w1, w0, W, idx0 + t0
-        )
-    return gc.garble_equality_payload_packed_rows(
-        jnp.asarray(s_block), q_rows, gc_seed, x_flat, w1, w0, W,
-        idx0 + t0, B, t0,
+def ev_chunk_extend(rcv: otext.OtExtReceiver, flat, base_off: int, t0: int,
+                    n: int):
+    """The receiver's ``otext`` step for tests ``[t0, t0 + n)`` of the
+    level's flat strings bool[B, S]: their rows of the level's one
+    extension, which began at stream offset ``base_off`` (the cursors do
+    not move: ``OtExtReceiver.extend_rows``).  Returns (u column words,
+    T rows as planes uint32[S*4, n], the range's strings bool[n, S])."""
+    S = flat.shape[1]
+    return _ev_extend(
+        *rcv.shard_state, flat, base_off + t0 * S // 512, t0, n
     )
 
 
-def ev_chunk_words(t_rows, y_flat, msg, W: int, path: str, idx0: int,
-                   t0: int):
-    """The receiver's payload words uint32[n, W] for tests
-    ``[t0, t0 + n)``: opens that range's planar message with its T
-    rows."""
-    n, S = y_flat.shape
-    t_rows = jnp.asarray(t_rows).reshape(n, S, 4)
-    if path == "ot2s":
-        return ot2s_decrypt_packed(t_rows, y_flat, msg, W, idx0 + t0)
-    return gc.eval_equality_payload_packed(msg, t_rows, W, idx0 + t0)[1]
+def gb_chunk_extend(snd: otext.OtExtSender, u_cols, S: int, base_off: int,
+                    t0: int, n: int):
+    """The sender's ``otext`` step: Q rows of tests ``[t0, t0 + n)`` from
+    those rows' column words of the peer's u-matrix, as planes
+    uint32[S*4, n] (``OtExtSender.extend_rows``)."""
+    return snd.extend_rows(n * S, u_cols, base_off, t0 * S, S)
+
+
+@partial(jax.jit, static_argnames=("field", "garbler", "n"))
+def gb_chunk_pair(b2a_seed, t0, field, garbler: int, n: int):
+    """:func:`b2a_payload_pair` of tests ``[t0, t0 + n)`` as a program of
+    its own: the garbled-circuit path's ``b2a`` step (its message is the
+    ``garble`` span's, :func:`gb_chunk_garble`)."""
+    return b2a_payload_pair(field, b2a_seed, n, garbler, t0)
+
+
+@partial(jax.jit, static_argnames=("field", "garbler", "n", "pallas"))
+def _gb_table(b2a_seed, q, flat, s_block, idx, t0, field, garbler: int,
+              n: int, pallas: bool):
+    S, W = flat.shape[1], payload_words(field)
+    r1, w0, w1 = b2a_payload_pair(field, b2a_seed, n, garbler, t0)
+    x = jax.lax.dynamic_slice_in_dim(flat, t0, n)
+    # result 1 (strings equal) -> receiver learns r0 (collect.rs:439-456)
+    if pallas:
+        from ..ops import otext_pallas
+
+        msg = otext_pallas.ot2s_encrypt_planes(
+            q, s_block, x.T, w1.T, w0.T, W, idx, domain=_OT2S_DOMAIN
+        )
+    else:
+        msg = _ot2s_encrypt_packed_xla(
+            _test_rows(q, S), s_block, x, w1, w0, W, idx
+        )
+    return msg, r1
+
+
+def gb_chunk_table(field, b2a_seed, q, flat, s_block, idx0: int, t0: int,
+                   n: int, garbler: int):
+    """The sender's ``b2a`` step on the 1-of-2^S path for tests
+    ``[t0, t0 + n)`` of the level's flat strings: the share pair of those
+    tests (:func:`b2a_payload_pair`) and their planar payload table, from
+    their extension rows ``q`` (planes uint32[S*4, n],
+    :func:`gb_chunk_extend`).  Returns (the table, r1 — the sender's
+    additive shares)."""
+    return _gb_table(
+        b2a_seed, q, flat, s_block, idx0 + t0, t0, field, garbler, n,
+        _ot2s_pallas_engine(),
+    )
+
+
+@partial(jax.jit, static_argnames=("W", "n", "pallas"))
+def _gb_garble(s_block, q, gc_seed, flat, w1, w0, idx, t0, W: int, n: int,
+               pallas: bool):
+    B, S = flat.shape
+    return gc._garble_rows_packed(
+        s_block, _test_rows(q, S), gc_seed,
+        jax.lax.dynamic_slice_in_dim(flat, t0, n), w1, w0, W, idx, B, t0,
+        pallas,
+    )
+
+
+def gb_chunk_garble(s_block, q, gc_seed, flat, w0, w1, W: int, idx0: int,
+                    t0: int, n: int):
+    """The sender's ``garble`` step for tests ``[t0, t0 + n)``: their
+    planar range of the level's packed garbled batch
+    (``gc.garble_equality_payload_packed_rows``), the payload pair of
+    :func:`gb_chunk_pair` riding the output labels."""
+    # result 1 (strings equal) -> receiver learns r0 (collect.rs:439-456)
+    return _gb_garble(
+        s_block, q, gc_seed, flat, w1, w0, idx0 + t0, t0, W, n,
+        flat.shape[1] >= 2 and gc._pallas_engine(),
+    )
+
+
+@partial(jax.jit, static_argnames=("S", "W", "pallas"))
+def _ev_eval(t_planes, msg, idx, S: int, W: int, pallas: bool):
+    t_rows = _test_rows(t_planes, S)
+    if pallas:
+        from ..ops import gc_pallas
+
+        return gc_pallas.eval_equality_payload_packed(msg, t_rows, W, idx)[1]
+    return gc._eval_equality_payload_packed_xla(msg, t_rows, S, W, idx)[1]
+
+
+def ev_chunk_eval(t_planes, y, msg, W: int, idx0: int, t0: int):
+    """The receiver's ``eval`` step on the garbled-circuit path: the
+    payload words uint32[n, W] of tests ``[t0, t0 + n)``, from that
+    range's planar message and its T rows (planes uint32[S*4, n])."""
+    S = y.shape[1]
+    return _ev_eval(
+        t_planes, msg, idx0 + t0, S, W, S >= 2 and gc._pallas_engine()
+    )
+
+
+@partial(jax.jit, static_argnames=("field", "pallas"))
+def _ev_open(t_planes, y, msg, idx, field, pallas: bool):
+    S, W = y.shape[1], payload_words(field)
+    if pallas:
+        from ..ops import otext_pallas
+
+        w = otext_pallas.ot2s_decrypt_planes(
+            t_planes, y.T, msg, W, idx, domain=_OT2S_DOMAIN
+        ).T
+    else:
+        w = _ot2s_decrypt_packed_xla(
+            _test_rows(t_planes, S), y, msg, S, W, idx
+        )
+    return words_to_field(field, w)
+
+
+def ev_chunk_open(field, t_planes, y, msg, idx0: int, t0: int):
+    """The receiver's ``b2a`` step on the 1-of-2^S path: opens the planar
+    table of tests ``[t0, t0 + n)`` with their T rows (planes
+    uint32[S*4, n], :func:`ev_chunk_extend`) -> field values
+    [n(, limbs)] (r0 where the strings are equal, else r1)."""
+    return _ev_open(
+        t_planes, y, msg, idx0 + t0, field, _ot2s_pallas_engine()
+    )
+
+
+# the garbled-circuit path's ``b2a`` step on the receiver's side
+ev_chunk_field = jax.jit(words_to_field, static_argnums=0)
 
 
 # ---------------------------------------------------------------------------
@@ -613,13 +745,15 @@ def _warm_pair():
 
 
 def warm_level_kernels(packed, d: int, field, path: str = "auto",
-                       share_sums=None, radix: int = 1) -> None:
+                       share_sums=None, radix: int = 1,
+                       put=jax.device_put) -> None:
     """Run the WHOLE per-level 2PC kernel chain — string extraction,
-    then for each of the level's chunks (:func:`level_chunks`) its rows
-    of the Δ-OT extension, its b2a share pair (both garbling signs), its
-    equality message (1-of-2^S table or packed garbled batch, whichever
-    :func:`ot_path` picks for this shape under the config's ``path``
-    knob — the fused otext/gc programs included) and payload open, then
+    then for each of the level's chunks (:func:`level_chunks`) the
+    programs of its spans (``ev_chunk_extend``, the sender's rows of the
+    Δ-OT extension, ``gb_chunk_table`` at both garbling signs or
+    ``gb_chunk_pair`` + ``gb_chunk_garble``, whichever :func:`ot_path`
+    picks for this shape under the config's ``path`` knob, and
+    ``ev_chunk_open``), then
     the alive-gated share sums — on a THROWAWAY in-process OT
     session, so every jit program a real level of this shape will
     dispatch is compiled (and lands in the persistent compile cache,
@@ -641,6 +775,10 @@ def warm_level_kernels(packed, d: int, field, path: str = "auto",
     the sharded reduction program is warmed too); None = the
     single-device :func:`node_share_sums`.
 
+    ``put`` places the level's constants (the b2a seed, the sender's
+    ``s``) as the live level does: ``jax.device_put``, or a multi-chip
+    server's ``ServerMesh.gather`` (its own first chip).
+
     ``radix`` > 1 warms the fused radix-2^k shapes: the string stage
     reads the radix packed layout and the equality chain runs at the
     fused width S' = 2*d*radix (which may route through the GC ladder
@@ -660,25 +798,39 @@ def warm_level_kernels(packed, d: int, field, path: str = "auto",
     idx0, off_r, off_s = rcv.consumed, rcv.stream_offset, snd.stream_offset
     rcv.advance(B * S)
     snd.advance(B * S)
-    vals = []
+    # a level's constants are on the device before its first chunk
+    bseed, s_block = put(bseed), put(snd.s_block)
+    vals, warmed = [], set()
     for t0, n in level_chunks(B, S, W, p):
-        y = chunk_rows(flat, t0, n)
-        u, t_rows = rcv.extend_rows(y.reshape(n * S), off_r, t0 * S)
+        u, t_rows, y = ev_chunk_extend(rcv, flat, off_r, t0, n)
         # fhh-lint: disable=chunked-device-readback,host-sync-in-hot-loop (warmup only: the live level's per-chunk wire round trip, see docstring)
         u = np.asarray(u)
-        q = snd.extend_rows(n * S, u, off_s, t0 * S)
+        q = gb_chunk_extend(snd, u, S, off_s, t0, n)
         # the real crawl alternates the garbler per level, so each
-        # server runs BOTH payload-pair signs (r0 + 1 and r0 - 1)
-        b2a_payload_pair(field, bseed, n, 1, t0)
-        _, w0, w1 = b2a_payload_pair(field, bseed, n, 0, t0)
-        msg = gb_chunk_msg(
-            snd.s_block, q, y, w0, w1, gseed, W, p, idx0, B, t0
-        )
+        # server runs BOTH signs of the share pair (r0 + 1 and r0 - 1),
+        # and the sign is a static of the program that draws it: the
+        # other sign's program once a chunk size
+        signs = (0,) if n in warmed else (1, 0)
+        warmed.add(n)
+        for g in signs:
+            if p == "ot2s":
+                msg, _ = gb_chunk_table(
+                    field, bseed, q, flat, s_block, idx0, t0, n, g
+                )
+            else:
+                # fhh-lint: disable=recompile-churn (warmup only: the two signs and each chunk size are the compiles it is here to make)
+                _, w0, w1 = gb_chunk_pair(bseed, t0, field, g, n)
+                msg = gb_chunk_garble(
+                    s_block, q, gseed, flat, w0, w1, W, idx0, t0, n
+                )
         # fhh-lint: disable=chunked-device-readback,host-sync-in-hot-loop (as above)
         msg = np.asarray(msg)
-        vals.append(words_to_field(
-            field, ev_chunk_words(t_rows, y, msg, W, p, idx0, t0)
-        ))
+        if p == "ot2s":
+            vals.append(ev_chunk_open(field, t_rows, y, msg, idx0, t0))
+        else:
+            vals.append(ev_chunk_field(
+                field, ev_chunk_eval(t_rows, y, msg, W, idx0, t0)
+            ))
     vals = vals[0] if len(vals) == 1 else jnp.concatenate(vals)
     w = jnp.ones((F_, C, N), bool)
     reduce_fn = node_share_sums if share_sums is None else share_sums

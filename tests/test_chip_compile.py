@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from fuzzyheavyhitters_tpu.ops import gc_pallas, keygen_pallas, otext, otext_pallas
+from fuzzyheavyhitters_tpu.ops.fields import F255, FE62
 from fuzzyheavyhitters_tpu.ops.ibdcf import EvalState, IbDcfKeyBatch
 from fuzzyheavyhitters_tpu.parallel import kernel_shard, server_mesh
 from fuzzyheavyhitters_tpu.parallel.server_mesh import DATA
@@ -76,7 +77,7 @@ def _sds(sharding):
     return lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
 
 
-def _compile(fn, *args, **static):
+def _compile(fn, *args, temp_under=None, **static):
     compiled = fn.lower(*args, **static).compile()
     text = compiled.as_text()
     mem = compiled.memory_analysis()
@@ -85,6 +86,9 @@ def _compile(fn, *args, **static):
         + mem.temp_size_in_bytes
     )
     assert total < HBM_BYTES, f"program needs {total / 2**30:.1f} GiB of HBM"
+    if temp_under is not None:
+        assert mem.temp_size_in_bytes < temp_under, (
+            f"{mem.temp_size_in_bytes / 2**20:.0f} MiB of temporaries")
     return text
 
 
@@ -197,47 +201,55 @@ def test_iknp_extension(one_chip):
 
 
 @pytest.mark.parametrize("S,b,k", [
-    (S, 32 * 2 * N_SECURE, 4), (4, 32 * 4 * N_TRUSTED, 256),
-], ids=["flagship", "amazon2d"])
-@pytest.mark.parametrize("words", [4, 8], ids=["FE62", "F255"])
-def test_secure_level_chunk_programs(one_chip, words, S, b, k):
-    """What one chunk of a secure level dispatches on a server
-    (``rpc._ev_chunks`` / ``_gb_chunks``), at the chunk
-    ``secure.level_chunks`` cuts from the benchmark's steady level
-    (bucket 32 at N=16,384; the leaf level's F255 table is twice as
-    wide, so its chunk is half the tests): the cut of the flat strings,
-    both roles' rows of the extension, the planar table and its open.
-    Offsets and pad indices are traced scalars, so these are every
-    chunk's programs from bucket 16 up.  ``amazon2d`` is the
-    two-dimensional deployment's steady level (S = 4, four patterns a
-    node, N=131,072): a 1-of-16 table of 256 bytes a test, 65,536
-    tests a frame."""
+    (S, 32 * 2 * N_SECURE, 4), (S, 32 * 2 * N_TRUSTED, 32),
+    (4, 32 * 4 * N_TRUSTED, 256),
+], ids=["flagship", "hbm", "amazon2d"])
+@pytest.mark.parametrize("field", [FE62, F255], ids=["FE62", "F255"])
+def test_secure_level_chunk_programs(one_chip, field, S, b, k):
+    """What one chunk of a secure level hands the device on a server
+    (``rpc._ev_chunks`` / ``_gb_chunks``), one program a span, at the
+    chunk ``secure.level_chunks`` cuts from the benchmark's steady level
+    (bucket 32 at N=16,384 and, ``hbm``, at N=131,072: n = 262,144 tests
+    a chunk either way; the leaf level's F255 table is twice as wide, so
+    its chunk is half the tests): the evaluator's slice + extension
+    (``otext``) and open + field (``b2a``), the garbler's extension
+    (``otext``) and share pair + table (``b2a``) at both signs.  The
+    chunk's first test, offsets and pad indices are traced scalars, so
+    these are every chunk's programs from bucket 16 up.  ``amazon2d`` is
+    the two-dimensional deployment's steady level (S = 4, four patterns
+    a node, N=131,072): a 1-of-16 table of 256 bytes a test, 65,536
+    tests a frame.  No program keeps a lane-padded copy of the
+    extension's rows (32 times their bytes) among its temporaries."""
+    words = secure.payload_words(field)
     chunks = secure.level_chunks(b, S, words, "ot2s")
     assert len(chunks) == (k if words == 4 else 2 * k)
     n = chunks[0][1]
     assert all(c[1] == n for c in chunks) and n % kernel_shard.BLOCK == 0
     sds = _sds(one_chip)
     at = sds((), jnp.int64)  # a Python int of the level's plan
-    _compile(secure._test_rows, sds((b, S), jnp.bool_), at, n=n)
     m = n * S
+    rows_bytes = 16 * m
     seeds = sds((128, 4), jnp.uint32)
-    _compile(otext._receiver_extend, seeds, seeds, sds((m,), jnp.bool_),
-             at, m=m)
+    flat = sds((b, S), jnp.bool_)
+    planes = sds((S * 4, n), jnp.uint32)
+    _compile(secure._ev_extend, seeds, seeds, flat, at, at, n=n,
+             temp_under=8 * rows_bytes)
     _compile(otext._sender_extend, seeds, sds((128,), jnp.bool_),
-             sds((128, m // 32), jnp.uint32), at, m=m)
+             sds((128, m // 32), jnp.uint32), at, m=m, S=S,
+             temp_under=8 * rows_bytes)
+    for garbler in (0, 1):
+        text = _compile(
+            secure._gb_table,
+            sds((4,), jnp.uint32), planes, flat, sds((4,), jnp.uint32), at, at,
+            field=field, garbler=garbler, n=n, pallas=True,
+            temp_under=16 * rows_bytes + (32 << 20),
+        )
+        assert "tpu_custom_call" in text
     text = _compile(
-        otext_pallas._enc_planar,
-        sds((n, S, 4), jnp.uint32), sds((4,), jnp.uint32),
-        sds((n, S), jnp.bool_), sds((n, words), jnp.uint32),
-        sds((n, words), jnp.uint32), at,
-        S=S, W=words, domain=secure._OT2S_DOMAIN, interpret=False,
-    )
-    assert "tpu_custom_call" in text
-    text = _compile(
-        otext_pallas._dec_planar,
-        sds((n, S, 4), jnp.uint32), sds((n, S), jnp.bool_),
+        secure._ev_open,
+        planes, sds((n, S), jnp.bool_),
         sds(((1 << S) * words * n,), jnp.uint32), at,
-        S=S, W=words, domain=secure._OT2S_DOMAIN, interpret=False,
+        field=field, pallas=True, temp_under=8 * rows_bytes,
     )
     assert "tpu_custom_call" in text
 
@@ -258,6 +270,17 @@ def test_gc_chunk_garble(one_chip):
         sds((4,), jnp.uint32), sds((n, S), jnp.bool_),
         sds((n, W), jnp.uint32), sds((n, W), jnp.uint32),
         idx_offset=at, t0=at, n_words=W, B=b, pallas=True,
+    )
+    assert "tpu_custom_call" in text
+    # the chunk's ``garble`` step as the servers call it: the cut of the
+    # level's flat strings and the extension's planes turned to rows
+    # inside the one program
+    text = _compile(
+        secure._gb_garble,
+        sds((4,), jnp.uint32), sds((S * 4, n), jnp.uint32),
+        sds((4,), jnp.uint32), sds((b, S), jnp.bool_),
+        sds((n, W), jnp.uint32), sds((n, W), jnp.uint32), at, at,
+        W=W, n=n, pallas=True,
     )
     assert "tpu_custom_call" in text
 

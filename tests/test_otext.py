@@ -94,6 +94,34 @@ def test_ragged_extend_sizes(pair, rng):
         np.testing.assert_array_equal(np.asarray(t), want)
 
 
+@pytest.mark.parametrize("S,n", [
+    (1, 100), (2, 800), (2, 8192), (4, 1024), (4, 1000), (6, 115), (6, 512),
+    (10, 77),
+])
+def test_rows_as_planes_are_the_rows(pair, rng, S, n):
+    """``extend_rows(..., S)`` / ``_receiver_extend(..., S=S)`` give the
+    rows of the plain extension as the planes the equality kernels read:
+    plane ``s*4 + k`` is word k of rows ``s, S + s, ...`` (``_planarize``
+    of the rows as ``[n, S, 4]``), for widths that divide a 32-row word
+    tile, that do not (6, 10), and for batches that end inside one."""
+    snd, rcv = pair
+    m = n * S
+    r = rng.integers(0, 2, size=m).astype(bool)
+    off_r, off_s = rcv.stream_offset, snd.stream_offset
+    u, t = rcv.extend(r)
+    q = snd.extend(m, np.asarray(u))
+    u2, t_planes = otext._receiver_extend(
+        *rcv.shard_state, r, off_r, m, S
+    )
+    q_planes = snd.extend_rows(m, np.asarray(u), off_s, 0, S)
+    np.testing.assert_array_equal(np.asarray(u2), np.asarray(u))
+    for rows, planes in ((t, t_planes), (q, q_planes)):
+        assert planes.shape == (S * 4, n)
+        np.testing.assert_array_equal(
+            np.asarray(planes), np.asarray(rows).reshape(n, S * 4).T
+        )
+
+
 def test_pack_unpack_roundtrip(rng):
     for m in (1, 31, 32, 33, 128, 129):
         bits = rng.integers(0, 2, size=m).astype(bool)

@@ -13,8 +13,10 @@ level differently gets ``ConnectionError`` and nobody hangs.
 """
 
 import asyncio
+import logging
 import threading
 
+import jax
 import numpy as np
 import pytest
 
@@ -449,7 +451,8 @@ def _interpret_engines(monkeypatch):
     from fuzzyheavyhitters_tpu.ops import otext_pallas
 
     monkeypatch.setattr(secure, "_ot2s_pallas_engine", lambda: True)
-    for name in ("ot2s_encrypt", "ot2s_decrypt"):
+    for name in ("ot2s_encrypt", "ot2s_decrypt",
+                 "ot2s_encrypt_planes", "ot2s_decrypt_planes"):
         monkeypatch.setattr(
             otext_pallas, name,
             functools.partial(getattr(otext_pallas, name), interpret=True),
@@ -784,3 +787,101 @@ def test_two_dimensional_crawl_in_chunks_matches_the_driver_and_the_reference(mo
     inproc = driver.Leader(s0, s1, n_dims=2, data_len=L, f_max=cfg.f_max).run(
         nreqs=n, threshold=cfg.threshold)
     assert ref.crawl_frontier(inproc.paths, inproc.counts) == got
+
+
+# -- one device program a span ------------------------------------------------
+
+# what the eager chunk dispatched beside its kernels until PR 38: each a
+# program of its own, several of them a lane-padded copy through HBM
+_EAGER_GLUE = {"reshape", "convert_element_type", "add", "sub", "sample",
+               "to_blocks", "from_blocks"}
+# ports of this file's range that no other case binds (a case's servers
+# are closed before the next case's start)
+_PROGRAM_PORTS = [480, 580, 660, 680, 800, 820, 840, 860]
+
+
+def _compiled(caplog):
+    """The names of the programs XLA has compiled since the last call
+    (``jax.log_compiles`` records under ``caplog``)."""
+    names = [
+        r.getMessage().split()[1].removeprefix("jit(").removesuffix(")")
+        for r in caplog.records if r.getMessage().startswith("Compiling ")
+    ]
+    caplog.clear()
+    return names
+
+
+@pytest.mark.parametrize("K", [2, 1])
+@pytest.mark.parametrize("garbler", [0, 1])
+@pytest.mark.parametrize("last", [False, True], ids=["FE62", "F255-leaf"])
+@pytest.mark.parametrize("S", [2, 4])
+def test_a_chunk_is_one_program_a_span(monkeypatch, caplog, S, last, garbler, K):
+    """A level of two planar blocks on the table path, whole (K = 1) or
+    in two chunks, after the same level cut the OTHER way has compiled
+    everything a level needs but the chunk's own programs.  Then (c) the
+    level's cut compiles four programs, once each though two servers and
+    a second chunk (``t0 > 0``) run them too: the evaluator's slice + extension and
+    its open + field, the garbler's extension and its pair + table; none
+    is a piece of the eager glue, and with the level's other programs
+    already there all of them lie inside ``gc_ot``; (b) the level run
+    again compiles nothing; (a) ``secure_chunk_programs`` reads 2 x K
+    on either server."""
+    from fuzzyheavyhitters_tpu.ops import otext
+    from fuzzyheavyhitters_tpu.parallel.kernel_shard import n_msg_planes
+
+    n, f = (2048, 4) if S == 2 else (1024, 4)
+    C = 1 << (S // 2)
+    assert f * C * n == 2 * BLOCK
+    W = secure.payload_words(F255 if last else FE62)
+    one_block = BLOCK * max(16 * S, 4 * n_msg_planes("ot2s", S, W))
+    first, second = (WHOLE, one_block) if K == 2 else (one_block, WHOLE)
+    case = ((S == 4) * 2 + last) * 4 + garbler * 2 + (K == 1)
+    port = BASE_PORT + _PROGRAM_PORTS[case % len(_PROGRAM_PORTS)]
+    pts = None if S == 2 else _points_2d(n)
+    lv = L - 1 if last else 0
+
+    # both servers share this process's executables, and so do the cases:
+    # each starts with none of the chunk's four
+    for program in (secure._ev_extend, secure._ev_open, secure._gb_table,
+                    otext._sender_extend):
+        program.clear_cache()
+
+    async def run():
+        async with _Pair(port, n, pts=pts) as pair:
+            await pair.both("tree_init", {"root_bucket": f})
+            before = pair.ot_state()
+            monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", first)
+            await pair.level(garbler, last=last)
+            monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", second)
+            counted = []
+            with jax.log_compiles(True), caplog.at_level(logging.WARNING, "jax"):
+                caplog.clear()
+                for _ in "ab":
+                    pair.set_ot_state(before)
+                    p0 = [cs.obs.counter_value("secure_chunk_programs", level=lv)
+                          for cs in pair.sessions]
+                    shares = await pair.level(garbler, last=last)
+                    counted.append((
+                        _compiled(caplog),
+                        [cs.obs.counter_value("secure_chunk_programs", level=lv) - p
+                         for cs, p in zip(pair.sessions, p0)],
+                        [cs.obs.counter_value("secure_chunks", level=lv)
+                         for cs in pair.sessions],
+                        shares,
+                    ))
+            return counted
+
+    (new, programs, chunks, shares), (again, programs2, _, shares2) = _run(run())
+    assert chunks == [1 + 2, 1 + 2]  # the level whole and in two, either order
+    # (c) one compile a program, whichever chunk met it first
+    own = sorted(x for x in new if x != "concatenate")
+    assert own == ["_ev_extend", "_ev_open", "_gb_table", "_sender_extend_core"]
+    assert not _EAGER_GLUE & set(new)
+    # the chunks' shares side by side, once a level of K > 1
+    assert new.count("concatenate") <= (K == 2)
+    # (b) the second level of the bucket
+    assert again == []
+    # (a)
+    assert programs == programs2 == [2 * K, 2 * K]
+    for a, b in zip(shares, shares2):
+        assert np.array_equal(a, b)

@@ -910,6 +910,9 @@ def test_chunk_spans_carry_the_level_and_lie_inside_gc_ot(rng, trace_dir, monkey
     for srv in ("s0", "s1"):
         assert ks[srv]["by_level"] == {"0": K, "1": 1}
     assert rep["secure_kernels"]["chunks_by_level"] == {"0": K, "1": 1}
+    # one device program inside each ``otext`` and each ``b2a`` span
+    assert rep["secure_kernels"]["chunk_programs_by_level"] == {
+        "0": 2 * K, "1": 2}
     # the evaluator's gauge of what it held, and the index's high word
     held = rep["secure_kernels"]["t_rows_held_bytes_by_level"]
     assert set(held) == {"0", "1"} and held["0"] > 0 and held["1"] > 0
@@ -972,12 +975,14 @@ def test_chunk_spans_carry_the_level_and_lie_inside_gc_ot(rng, trace_dir, monkey
         assert 0 < row["share_min"] <= row["share_median"] <= 1 + 1e-3
         assert row["busy_share_median"] >= row["share_median"] - 1e-9
         assert {"otext", "b2a", "d2h"} <= set(row["chunk_leaf_ms_median"])
-    # one ``secure_level`` instant a level and server: K, the held bytes
-    # and the index's high word, for the span log that carries no gauges
+    # one ``secure_level`` instant a level and server: K, the programs of
+    # its ``otext`` + ``b2a`` spans, the held bytes and the index's high
+    # word, for the span log that carries no gauges or counters
     rows = mod.secure_levels(evs)
     assert set(rows) == {"server0", "server1"}
     for row in rows.values():
         assert row["levels"] == 2 and row["chunks_max"] == K
+        assert row["secure_chunk_programs_max"] == 2 * K
         assert row["ot_index_high"] == 0
         assert (row["string_bits_max"], row["child_patterns_max"]) == (2, 2)
     assert max(r["t_rows_held_bytes_max"] for r in rows.values()) == max(
