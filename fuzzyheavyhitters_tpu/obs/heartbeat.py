@@ -62,6 +62,9 @@ class Heartbeat(threading.Thread):
                 )
         if not active:
             logs.emit("heartbeat", idle=True)
+        # the ring is block-buffered: a wedged run's spans reach the
+        # disk with the beat that names its phase
+        tracemod.flush()
 
     def stop(self) -> None:
         self._stop_evt.set()

@@ -32,6 +32,15 @@ Well-known metric names (what populates them):
   chunks a level's two messages crossed in (1 = whole).  Rolled up across
   registries into a top-level ``secure_kernels`` section whenever a
   secure crawl ran.
+- phases ``stage_wall:<stage>`` / ``stage_starved:<stage>`` /
+  ``stage_blocked:<stage>`` — the wait account of the secure level's
+  chunk pipeline (protocol/rpc.py ``_Stage``: the evaluator's stages
+  ``extend``, ``u_fetch``, ``u_send``, ``open``, the garbler's
+  ``build``, ``msg_fetch``, ``msg_send``), and ``program_dispatch`` /
+  ``program_device`` / ``program_hop``, ``d2h_ready`` / ``d2h_copy`` /
+  ``d2h_hop``, ``send_resume`` — how a span that waits on a thread
+  splits; timers alone (no span-log record), per server in
+  ``secure_kernels.stages``.
 - counters ``data_bytes_sent`` / ``data_bytes_recv`` /
   ``data_msgs_sent`` — server↔server data plane, per level;
   ``control_bytes_*`` — leader↔server control plane;
@@ -394,6 +403,10 @@ def _secure_kernel_summary(registries: dict) -> dict | None:
             kgather += t.get("seconds", 0.0)
     if not seen:
         return None
+    stages = {
+        name: acct for name, snap in registries.items()
+        if (acct := _stage_account(snap.get("phases", {}))) is not None
+    }
     if paths["ot2s"] and paths["gc"]:
         ot_path = "mixed"
     elif paths["gc"]:
@@ -439,6 +452,57 @@ def _secure_kernel_summary(registries: dict) -> dict | None:
             lvl: {n: round(v[n], 6) for n in names}
             for lvl, v in sorted(by_level.items(), key=lambda kv: int(kv[0]))
         },
+        # the chunk pipeline's wait account, per registry that ran a
+        # stage (protocol/rpc.py ``_Stage``): see _stage_account
+        "stages": stages,
+    }
+
+
+_SPLIT_TIMERS = (
+    "program_dispatch", "program_device", "program_hop",
+    "d2h_ready", "d2h_copy", "d2h_hop", "send_resume",
+)
+
+
+def _stage_account(phases: dict) -> dict | None:
+    """One registry's wait account of the secure level's chunk pipeline
+    (timers ``stage_wall:<stage>`` / ``stage_starved:`` /
+    ``stage_blocked:``, protocol/rpc.py): per stage its wall, what it
+    waited for input and for room downstream, the rest (busy) and busy
+    as a share of ``gc_ot`` over the levels the stage ran in (a server
+    garbles every other level); ``pace_setter``, the stage busy for the
+    largest share of it (where every stage lives about one ``gc_ot``, as
+    in a level of many chunks, the one that waited least: never starved,
+    never blocked); and how the spans that wait on a thread
+    split (``program_*`` of ``otext`` + ``b2a`` + ``eval`` + ``garble``,
+    ``d2h_*`` of ``d2h``, ``send_resume``).  None where no stage ran."""
+    seconds = lambda name: phases.get(name, {}).get("seconds", 0.0)
+    gc_by_level = phases.get("gc_ot", {}).get("by_level", {})
+    by_stage = {}
+    for name, t in phases.items():
+        kind, _, stage = name.partition(":")
+        if kind != "stage_wall":
+            continue
+        wall = t.get("seconds", 0.0)
+        starved = seconds(f"stage_starved:{stage}")
+        blocked = seconds(f"stage_blocked:{stage}")
+        gc_ot = sum(gc_by_level.get(lvl, 0.0) for lvl in t.get("by_level", {}))
+        busy = wall - starved - blocked
+        by_stage[stage] = {
+            "wall_seconds": round(wall, 6),
+            "starved_seconds": round(starved, 6),
+            "blocked_seconds": round(blocked, 6),
+            "busy_seconds": round(busy, 6),
+            "busy_share_of_gc_ot": round(busy / gc_ot, 4) if gc_ot else 0.0,
+        }
+    if not by_stage:
+        return None
+    return {
+        "by_stage": by_stage,
+        "pace_setter": max(
+            by_stage, key=lambda st: by_stage[st]["busy_share_of_gc_ot"]
+        ),
+        "split": {n: round(seconds(n), 6) for n in _SPLIT_TIMERS},
     }
 
 

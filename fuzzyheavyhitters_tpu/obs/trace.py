@@ -21,6 +21,13 @@ tie, with the same zero-cost-when-disabled contract as
   ``$FHH_TRACE_DIR/fhh_trace_<tag>_<pid>.jsonl``; at
   ``FHH_TRACE_RING`` events (default 200k) the file rotates once to a
   ``.1`` sibling, so a long-lived server is bounded at two segments.
+  The file is BLOCK-buffered (a secure level writes thousands of spans
+  from the loop thread, and a ``write(2)`` a span was paid there): it
+  is flushed where a server's verb has answered, where the leader's
+  level ends, by :func:`flush` / ``close`` and by every heartbeat, so
+  a killed process loses at most the level in flight (and its torn
+  last line, which :func:`load_events` skips), and a reader in the
+  same process finds every span of an answered verb on disk.
 - **Clock correction** — every ``__hello__`` and ``status`` response
   carries the server's wall clock; the client records the NTP-style
   midpoint offset (server_clock - leader_clock) as a ``C`` record.
@@ -77,6 +84,8 @@ ENV_PROFILE = "FHH_PROFILE"
 ENV_PROFILE_LEVELS = "FHH_PROFILE_LEVELS"
 
 _DEFAULT_RING = 200_000
+# the ring file's buffer: several levels' spans between two flushes
+_BUFFER_BYTES = 1 << 20
 
 # (trace_id, current_span_id) for the running task; None = no trace
 _CTX: contextvars.ContextVar = contextvars.ContextVar(
@@ -154,10 +163,15 @@ class _Writer:
         self.path = os.path.join(trace_dir, f"fhh_trace_{tag}.jsonl")
         self.ring = max(1024, ring)
         self._lock = threading.Lock()
-        # line-buffered: a SIGKILLed process loses at most the torn tail
-        # line (which load_events skips), not a whole buffer of spans
-        self._f = open(self.path, "w", encoding="utf-8", buffering=1)
+        self._f = self._open()
         self._n = 0
+
+    def _open(self):
+        # block-buffered: the flushes are the callers' (module
+        # docstring), so a SIGKILLed process loses the level in flight
+        return open(
+            self.path, "w", encoding="utf-8", buffering=_BUFFER_BYTES
+        )
 
     def write(self, rec: dict) -> None:
         line = json.dumps(rec, separators=(",", ":"))
@@ -167,7 +181,7 @@ class _Writer:
             if self._n >= self.ring:
                 self._f.close()
                 os.replace(self.path, self.path + ".1")
-                self._f = open(self.path, "w", encoding="utf-8", buffering=1)
+                self._f = self._open()
                 self._n = 0
             self._f.write(line + "\n")
             self._n += 1
@@ -230,8 +244,9 @@ def _event(rec: dict) -> None:
 
 
 def flush() -> None:
-    with _LOCK:
-        w = _WRITER
+    """Hand the ring's buffered lines to the kernel.  A flag read while
+    no event has been written (tracing off: always)."""
+    w = _WRITER  # set once under _LOCK, like _ENABLED
     if w is not None:
         w.flush()
 

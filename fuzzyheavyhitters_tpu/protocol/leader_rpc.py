@@ -519,6 +519,9 @@ class RpcLeader:
             # leader-side per-level latency histogram (SLO surface:
             # p50/p95 in the run report's slo section + the bench line)
             self.obs.observe("level_latency", sp_level.seconds)
+            # the block-buffered span log (obs/trace.py), a level at most
+            # behind the crawl
+            obstrace.flush()
             if alive is not None:
                 alive_before_leaf = alive
             if counts_kept is None:
@@ -818,6 +821,7 @@ class RpcLeader:
                         level, nreqs, thresh
                     )
                 self.obs.observe("level_latency", sp_level.seconds)
+                obstrace.flush()  # as in run()
                 if alive is not None:
                     alive_before_leaf = alive
                 if counts_kept is None:

@@ -233,12 +233,39 @@ async def _fetch(
     the byte count, and the run report carries both.  It also times it
     as a ``d2h`` span: ``copy_to_host_async`` issued -> the numpy array
     is held, so the wait for the device program that makes ``x`` and the
-    thread hop are inside.  ``level`` attributes the fetch when the call
+    thread hop are inside.  Three timers (no span-log records) say how
+    the span splits: ``d2h_ready`` (on the thread, its first line ->
+    ``block_until_ready`` returned: the program that makes ``x`` and the
+    device queue ahead of it), ``d2h_copy`` (``np.asarray`` after it:
+    the copy alone) and ``d2h_hop`` (the rest of the span: the hand-over
+    to the thread and the finished thread's wait for the loop).
+    ``level`` attributes the fetch when the call
     site sits outside any span (span-active callers inherit)."""
     reg.count("device_fetches", level=level)
-    with reg.span("d2h", level=level):
+    with reg.span("d2h", level=level) as sp:
         _start_host_copy(x)
-        return await asyncio.to_thread(np.asarray, x)
+        out, ready, copy = await asyncio.to_thread(_fetch_on_thread, x)
+    reg.timer_add("d2h_ready", ready, sp.level)
+    reg.timer_add("d2h_copy", copy, sp.level)
+    reg.timer_add("d2h_hop", sp.seconds - ready - copy, sp.level)
+    return out
+
+
+def _fetch_on_thread(x) -> tuple:
+    """(the numpy array, seconds until ``x`` was ready, seconds of the
+    copy): the fetch thread stamps its own clock as its last act and
+    :func:`_fetch` does the subtraction on the loop."""
+    ready = _sync_on_thread(x)
+    t0 = time.perf_counter()
+    out = np.asarray(x)
+    return out, ready, time.perf_counter() - t0
+
+
+def _sync_on_thread(x) -> float:
+    """Seconds this thread waited for ``x`` (``_phase_sync``, ``_fetch``)."""
+    t0 = time.perf_counter()
+    jax.block_until_ready(x)
+    return time.perf_counter() - t0
 
 
 def _start_host_copy(x) -> None:
@@ -256,6 +283,64 @@ def _start_host_copy(x) -> None:
         fn()
     except Exception:  # fhh-lint: disable=broad-except (pure prefetch hint: any failure means the sync np.asarray path simply does the whole copy)
         pass
+
+
+# -- the wait account of the secure level's chunk pipeline -------------------
+#
+# A secure level runs seven stages as tasks (``_ev_chunks`` /
+# ``_gb_chunks`` / ``_chunk_senders``).  Each times what it waited for:
+# input from the stage before it (``stage_starved:<stage>``), room in
+# the stage after it (``stage_blocked:<stage>``), and its own life
+# (``stage_wall:<stage>``, once a level).  A stage's busy seconds in a
+# level are its wall less its two waits; the stage with the least of
+# both waits sets the level's pace.  Timers alone (``Registry.timer_add``
+# under the level): no span-log record, no profiler annotation.
+
+# The stages: the evaluator's ``extend``, ``u_fetch``, ``u_send`` and
+# ``open``, the garbler's ``build``, ``msg_fetch`` and ``msg_send``.
+
+STAGE_TIMERS = ("stage_starved", "stage_blocked", "stage_wall")
+
+
+async def _timed(reg: obsmetrics.Registry, name: str, level, aw):
+    """``await aw``, its seconds added to timer ``name`` of ``reg``
+    under ``level`` (a wait that is cancelled or fails counts too)."""
+    t0 = time.perf_counter()
+    try:
+        return await aw
+    finally:
+        reg.timer_add(name, time.perf_counter() - t0, level)
+
+
+class _Stage:
+    """One stage of a level's chunk pipeline: ``with`` it around the
+    task's body (``stage_wall``), ``await st.starved(q.get())`` where it
+    waits for input and ``await st.blocked(q.put(x))`` where it waits
+    for room.  All three timers exist from the first line on, so a stage
+    that never waited reads 0 and not absent."""
+
+    __slots__ = ("_reg", "_level", "_names", "_t0")
+
+    def __init__(self, reg: obsmetrics.Registry, stage: str, level):
+        self._reg, self._level = reg, level
+        self._names = tuple(f"{t}:{stage}" for t in STAGE_TIMERS)
+
+    def starved(self, aw):
+        return _timed(self._reg, self._names[0], self._level, aw)
+
+    def blocked(self, aw):
+        return _timed(self._reg, self._names[1], self._level, aw)
+
+    def __enter__(self) -> "_Stage":
+        for name in self._names[:2]:
+            self._reg.timer_add(name, 0.0, self._level)
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._reg.timer_add(
+            self._names[2], time.perf_counter() - self._t0, self._level
+        )
 
 
 # ---------------------------------------------------------------------------
@@ -853,7 +938,9 @@ class CollectorServer:
         when the send returns, as ``PlaneMux.recv`` does for a read:
         ``wire_queue`` (frame handed over -> its send begins; the thread
         hop alone where the stream was free) and ``wire_write`` (the
-        thread's send of this frame).  ``plane_stream_frames`` counts
+        thread's send of this frame); the timer ``send_resume`` is the
+        way back, the thread's send ended -> this coroutine resumed on
+        the loop.  ``plane_stream_frames`` counts
         the frames that went this way (all of ``data_msgs_sent``), the
         gauge ``plane_send_queue_high`` the most frames the writer held
         at a hand-over of the level, this one included (1: free;
@@ -873,6 +960,9 @@ class CollectorServer:
         if peer is None:
             raise ConnectionResetError("no peer data plane")
         t_put, t_begin, t_end, held = await peer.send(pieces)
+        # the hop back (``wire_queue`` is the hop in): the writer
+        # thread's last stamp -> this coroutine runs again; a timer alone
+        reg.timer_add("send_resume", max(0.0, time.time() - t_end), level)
         reg.count("plane_stream_frames", level=level)
         if held > (reg.gauge_value("plane_send_queue_high", level) or 0):
             reg.gauge("plane_send_queue_high", held, level=level)
@@ -1102,25 +1192,49 @@ class CollectorServer:
                 return cs._mesh.gather(x)
             return cs._mesh.put(x, spec)
 
-    async def _phase_sync(self, x) -> None:
+    async def _phase_sync(self, cs, level: int, x) -> None:
         """Device sync at a secure-kernel phase boundary (OFF the event
         loop — a bare block_until_ready would starve keepalives exactly
         like a bare np.asarray).  Gated by ``cfg.secure_phase_sync``: the
         phases are sequential data-dependent steps, so syncing costs only
         the dispatch-ahead slack, and buys the phase_otext/garble/eval/
-        b2a spans real device seconds instead of dispatch time."""
+        b2a spans real device seconds instead of dispatch time.
+
+        Two timers split the wait: ``program_device`` (on the thread,
+        its first line -> ``block_until_ready`` returned: the program's
+        remaining run time and the device queue ahead of it) and
+        ``program_hop`` (the rest of this await: the hand-over to the
+        thread and the finished thread's wait for the loop); both 0
+        without the sync."""
+        device = hop = 0.0
         if self.cfg.secure_phase_sync:
-            await asyncio.to_thread(jax.block_until_ready, x)
+            t0 = time.perf_counter()
+            device = await asyncio.to_thread(_sync_on_thread, x)
+            hop = time.perf_counter() - t0 - device
+        cs.obs.timer_add("program_device", device, level)
+        cs.obs.timer_add("program_hop", hop, level)
 
     @staticmethod
-    def _program(cs, level: int, fn, *args):
+    def _dispatched(cs, level: int, fn, *args):
+        """``fn(*args)``, a jitted call's host side ON the loop thread,
+        its seconds added to the timer ``program_dispatch``."""
+        t0 = time.perf_counter()
+        try:
+            return fn(*args)
+        finally:
+            cs.obs.timer_add(
+                "program_dispatch", time.perf_counter() - t0, level
+            )
+
+    @classmethod
+    def _program(cls, cs, level: int, fn, *args):
         """Hand the device ONE program of a secure chunk, inside its
         ``otext`` or ``b2a`` span, and count it: the counter
         ``secure_chunk_programs`` reads 2 x K a level on either server
         (tests/test_secure_chunks.py holds the calls to one jitted
         program each)."""
         cs.obs.count("secure_chunk_programs", level=level)
-        return fn(*args)
+        return cls._dispatched(cs, level, fn, *args)
 
     def _zero_phases(self, cs, level: int, *names: str) -> None:
         """Materialize zero-valued phase timers so the secure-kernel
@@ -1202,33 +1316,36 @@ class CollectorServer:
         return [t.result() for t in tasks]
 
     def _chunk_senders(self, cs, level: int, K: int, made: asyncio.Queue,
-                       on_sent=None):
+                       stages: tuple, on_sent=None):
         """The two tasks that take a level's K chunk arrays from the
         device to the peer, in order: one fetches, one sends, two
         chunks at most between them, so chunk k+1's fetch runs while
         chunk k is on the socket.  ``made`` yields ``(array, token)``;
         ``on_sent`` is awaited with the token once that chunk's frame
-        is with the kernel."""
+        is with the kernel.  ``stages`` names the two in the level's
+        wait account (:class:`_Stage`)."""
         fetched: asyncio.Queue = asyncio.Queue(maxsize=2)
 
         async def fetch():
-            for k in range(K):
-                # fhh-lint: disable=unbounded-await (fed by a sibling task, which _chunk_tasks cancels with this one)
-                arr, token = await made.get()
-                with self._chunk_label(k, K):
-                    # fhh-lint: disable=chunked-device-readback (the point of the chunks: chunk k's fetch runs while chunk k-1 is on the socket and the peer works on it; one whole-level fetch put 72 ms of a 173 ms level in series, PERF.md PR 31)
-                    arr = await _fetch(arr, cs.obs, level=level)
-                # fhh-lint: disable=unbounded-await (drained by a sibling task, as above)
-                await fetched.put((arr, token))
+            with _Stage(cs.obs, stages[0], level) as st:
+                for k in range(K):
+                    # fhh-lint: disable=unbounded-await (fed by a sibling task, which _chunk_tasks cancels with this one)
+                    arr, token = await st.starved(made.get())
+                    with self._chunk_label(k, K):
+                        # fhh-lint: disable=chunked-device-readback (the point of the chunks: chunk k's fetch runs while chunk k-1 is on the socket and the peer works on it; one whole-level fetch put 72 ms of a 173 ms level in series, PERF.md PR 31)
+                        arr = await _fetch(arr, cs.obs, level=level)
+                    # fhh-lint: disable=unbounded-await (drained by a sibling task, as above)
+                    await st.blocked(fetched.put((arr, token)))
 
         async def send():
-            for k in range(K):
-                # fhh-lint: disable=unbounded-await (fed by a sibling task, as above)
-                arr, token = await fetched.get()
-                with self._chunk_label(k, K):
-                    await self._dp_send(cs, self._chunk_frame(k, K, arr))
-                if on_sent is not None:
-                    await on_sent(token)
+            with _Stage(cs.obs, stages[1], level) as st:
+                for k in range(K):
+                    # fhh-lint: disable=unbounded-await (fed by a sibling task, as above)
+                    arr, token = await st.starved(fetched.get())
+                    with self._chunk_label(k, K):
+                        await self._dp_send(cs, self._chunk_frame(k, K, arr))
+                    if on_sent is not None:
+                        await st.blocked(on_sent(token))
 
         return fetch(), send()
 
@@ -1237,8 +1354,9 @@ class CollectorServer:
         count_field,
     ):
         """Evaluator and OT receiver of a level: one task extends chunk
-        after chunk, two carry each u to the peer
-        (:meth:`_chunk_senders`), one opens each table as it arrives.
+        after chunk (stage ``extend``), two carry each u to the peer
+        (:meth:`_chunk_senders`: ``u_fetch``, ``u_send``), one opens
+        each table as it arrives (``open``).
         Returns the level's field values [B(, limbs)] on the device."""
         S, K = flat.shape[1], len(chunks)
         rcv = cs._ot_rcv
@@ -1266,55 +1384,63 @@ class CollectorServer:
             await sent.put(token)
 
         async def extend():
-            for k, (t0, n) in enumerate(chunks):
-                with self._chunk_label(k, K):
-                    with cs.obs.span("otext", level=level):
-                        u, t_rows, y = self._program(
-                            cs, level, secure.ev_chunk_extend,
-                            rcv, flat, off, t0, n,
-                        )
-                        # the extension, device-synced as on the sender
-                        # side; the fetch is then the copy alone
-                        await self._phase_sync(u)
-                # fhh-lint: disable=unbounded-await (drained by a sibling task, which _chunk_tasks cancels with this one)
-                await made.put((u, (y, t_rows)))
+            with _Stage(cs.obs, "extend", level) as st:
+                for k, (t0, n) in enumerate(chunks):
+                    with self._chunk_label(k, K):
+                        with cs.obs.span("otext", level=level):
+                            u, t_rows, y = self._program(
+                                cs, level, secure.ev_chunk_extend,
+                                rcv, flat, off, t0, n,
+                            )
+                            # the extension, device-synced as on the sender
+                            # side; the fetch is then the copy alone
+                            await self._phase_sync(cs, level, u)
+                    # fhh-lint: disable=unbounded-await (drained by a sibling task, which _chunk_tasks cancels with this one)
+                    await st.blocked(made.put((u, (y, t_rows))))
 
         async def consume():
             vals = []
-            for k, (t0, _) in enumerate(chunks):
-                # fhh-lint: disable=unbounded-await (fed by the sibling task, which _chunk_tasks cancels with this one)
-                y, t_rows = await sent.get()
-                held[0] -= nbytes((y, t_rows))
-                with self._chunk_label(k, K):
-                    bmsg = self._h2d(
-                        cs, level, await self._chunk_recv(cs, k, K)
-                    )
-                    if path != "ot2s":
-                        with cs.obs.span("eval", level=level):
-                            pay = secure.ev_chunk_eval(
-                                t_rows, y, bmsg, W, idx0, t0
-                            )
-                            await self._phase_sync(pay)
-                    with cs.obs.span("b2a", level=level):
-                        if path == "ot2s":
-                            # the opening of the 2^S table, inside b2a
-                            with cs.obs.span("ot2s", level=level):
-                                v = self._program(
-                                    cs, level, secure.ev_chunk_open,
-                                    count_field, t_rows, y, bmsg, idx0, t0,
+            with _Stage(cs.obs, "open", level) as st:
+                for k, (t0, _) in enumerate(chunks):
+                    # fhh-lint: disable=unbounded-await (fed by the sibling task, which _chunk_tasks cancels with this one)
+                    y, t_rows = await st.starved(sent.get())
+                    held[0] -= nbytes((y, t_rows))
+                    with self._chunk_label(k, K):
+                        bmsg = self._h2d(
+                            cs, level,
+                            await st.starved(self._chunk_recv(cs, k, K)),
+                        )
+                        if path != "ot2s":
+                            with cs.obs.span("eval", level=level):
+                                pay = self._dispatched(
+                                    cs, level, secure.ev_chunk_eval,
+                                    t_rows, y, bmsg, W, idx0, t0,
                                 )
-                                await self._phase_sync(v)
-                        else:
-                            v = self._program(
-                                cs, level, secure.ev_chunk_field,
-                                count_field, pay,
-                            )
-                            await self._phase_sync(v)
-                    vals.append(v)
+                                await self._phase_sync(cs, level, pay)
+                        with cs.obs.span("b2a", level=level):
+                            if path == "ot2s":
+                                # the opening of the 2^S table, inside b2a
+                                with cs.obs.span("ot2s", level=level):
+                                    v = self._program(
+                                        cs, level, secure.ev_chunk_open,
+                                        count_field, t_rows, y, bmsg, idx0,
+                                        t0,
+                                    )
+                                    await self._phase_sync(cs, level, v)
+                            else:
+                                v = self._program(
+                                    cs, level, secure.ev_chunk_field,
+                                    count_field, pay,
+                                )
+                                await self._phase_sync(cs, level, v)
+                        vals.append(v)
             return vals
 
         *_, vals = await self._chunk_tasks(
-            extend(), *self._chunk_senders(cs, level, K, made, on_sent),
+            extend(),
+            *self._chunk_senders(
+                cs, level, K, made, ("u_fetch", "u_send"), on_sent
+            ),
             consume(),
         )
         cs.obs.gauge("secure_t_rows_held_bytes", held[1], level=level)
@@ -1328,8 +1454,9 @@ class CollectorServer:
         count_field, garbler: int, gc_seed, b2a_seed,
     ):
         """Garbler and OT sender of a level: one task receives each u
-        and builds that chunk's message, two carry it to the peer
-        (:meth:`_chunk_senders`), two chunks at most between each, so
+        and builds that chunk's message (stage ``build``), two carry it
+        to the peer (:meth:`_chunk_senders`: ``msg_fetch``,
+        ``msg_send``), two chunks at most between each, so
         chunk k's fetch overlaps chunk k+1's kernel and chunk k-1's
         write.  Returns the level's share values
         [B(, limbs)] on the device."""
@@ -1346,47 +1473,53 @@ class CollectorServer:
 
         async def build():
             vals = []
-            for k, (t0, n) in enumerate(chunks):
-                with self._chunk_label(k, K):
-                    u = self._h2d(
-                        cs, level, await self._chunk_recv(cs, k, K)
-                    )
-                    with cs.obs.span("otext", level=level):
-                        q = self._program(
-                            cs, level, secure.gb_chunk_extend,
-                            snd, u, S, off, t0, n,
+            with _Stage(cs.obs, "build", level) as st:
+                for k, (t0, n) in enumerate(chunks):
+                    with self._chunk_label(k, K):
+                        u = self._h2d(
+                            cs, level,
+                            await st.starved(self._chunk_recv(cs, k, K)),
                         )
-                        await self._phase_sync(q)
-                    with cs.obs.span("b2a", level=level):
-                        if path == "ot2s":
-                            # the share pair and the 2^S table, inside b2a
-                            with cs.obs.span("ot2s", level=level):
-                                msg, v = self._program(
-                                    cs, level, secure.gb_chunk_table,
-                                    count_field, b2a_seed, q, flat,
-                                    s_block, idx0, t0, n, garbler,
+                        with cs.obs.span("otext", level=level):
+                            q = self._program(
+                                cs, level, secure.gb_chunk_extend,
+                                snd, u, S, off, t0, n,
+                            )
+                            await self._phase_sync(cs, level, q)
+                        with cs.obs.span("b2a", level=level):
+                            if path == "ot2s":
+                                # the share pair and the 2^S table, inside b2a
+                                with cs.obs.span("ot2s", level=level):
+                                    msg, v = self._program(
+                                        cs, level, secure.gb_chunk_table,
+                                        count_field, b2a_seed, q, flat,
+                                        s_block, idx0, t0, n, garbler,
+                                    )
+                                    await self._phase_sync(cs, level, msg)
+                            else:
+                                v, w0, w1 = self._program(
+                                    cs, level, secure.gb_chunk_pair,
+                                    b2a_seed, t0, count_field, garbler, n,
                                 )
-                                await self._phase_sync(msg)
-                        else:
-                            v, w0, w1 = self._program(
-                                cs, level, secure.gb_chunk_pair,
-                                b2a_seed, t0, count_field, garbler, n,
-                            )
-                            await self._phase_sync(w1)
-                    if path != "ot2s":
-                        with cs.obs.span("garble", level=level):
-                            msg = secure.gb_chunk_garble(
-                                s_block, q, gc_seed, flat, w0, w1, W,
-                                idx0, t0, n,
-                            )
-                            await self._phase_sync(msg)
-                    vals.append(v)
-                # fhh-lint: disable=unbounded-await (drained by a sibling task, which _chunk_tasks cancels with this one)
-                await built.put((msg, None))
+                                await self._phase_sync(cs, level, w1)
+                        if path != "ot2s":
+                            with cs.obs.span("garble", level=level):
+                                msg = self._dispatched(
+                                    cs, level, secure.gb_chunk_garble,
+                                    s_block, q, gc_seed, flat, w0, w1, W,
+                                    idx0, t0, n,
+                                )
+                                await self._phase_sync(cs, level, msg)
+                        vals.append(v)
+                    # fhh-lint: disable=unbounded-await (drained by a sibling task, which _chunk_tasks cancels with this one)
+                    await st.blocked(built.put((msg, None)))
             return vals
 
         vals, *_ = await self._chunk_tasks(
-            build(), *self._chunk_senders(cs, level, K, built)
+            build(),
+            *self._chunk_senders(
+                cs, level, K, built, ("msg_fetch", "msg_send")
+            ),
         )
         self._zero_phases(
             cs, level, "eval", *(("garble",) if path == "ot2s" else ())
@@ -1438,7 +1571,10 @@ class CollectorServer:
         The ``gc_ot`` span splits into the secure-kernel phases
         ``otext`` (extension), ``garble``/``eval`` (circuit work — zero
         on the ot2s path), and ``b2a`` (payload table / open + field
-        conversion); wire waits are the gc_ot remainder."""
+        conversion); wire waits are the gc_ot remainder.  What each
+        stage task of the chunk pipeline WAITED for, and how the spans
+        that wait on a thread split, is in the timers of
+        :class:`_Stage`, ``_phase_sync``, ``_fetch`` and ``_dp_send``."""
         with cs.obs.span("fss", level=level) as sp_fss:
             # dispatch time only: the FSS expansion itself overlaps the
             # exchange below (no sync — a block_until_ready here would
@@ -1507,17 +1643,19 @@ class CollectorServer:
                     with cs.obs.span("h2d", level=level):
                         u = kernel_shard.put_u(ks, u)
                     with cs.obs.span("otext", level=level):
-                        q, idx0 = kernel_shard.snd_extend(
-                            ks, cs._ot_snd, u
+                        q, idx0 = self._dispatched(
+                            cs, level, kernel_shard.snd_extend,
+                            ks, cs._ot_snd, u,
                         )
-                        await self._phase_sync(q)
+                        await self._phase_sync(cs, level, q)
                     kphase = "b2a" if path == "ot2s" else "garble"
                     with cs.obs.span(kphase, level=level):
-                        planes, vals = kernel_shard.gb_kernel(
+                        planes, vals = self._dispatched(
+                            cs, level, kernel_shard.gb_kernel,
                             ks, cs._ot_snd.s_block, q, flat, gc_seed,
                             b2a_seed, count_field, garbler, path, idx0,
                         )
-                        await self._phase_sync(planes)
+                        await self._phase_sync(cs, level, planes)
                     self._zero_phases(
                         cs,
                         level, "eval",
@@ -1539,10 +1677,11 @@ class CollectorServer:
                 # np.asarray here would be a blocking device->host fetch)
                 if ks is not None:
                     with cs.obs.span("otext", level=level):
-                        u_arr, t_rows, idx0 = kernel_shard.rcv_extend(
-                            ks, cs._ot_rcv, flat
+                        u_arr, t_rows, idx0 = self._dispatched(
+                            cs, level, kernel_shard.rcv_extend,
+                            ks, cs._ot_rcv, flat,
                         )
-                        await self._phase_sync(u_arr)
+                        await self._phase_sync(cs, level, u_arr)
                     cs.obs.count("device_fetches", ks.k, level=level)
                     # u_wire starts the per-shard D2H copies itself
                     with cs.obs.span("d2h", level=level):
@@ -1557,10 +1696,11 @@ class CollectorServer:
                         )
                     kphase = "b2a" if path == "ot2s" else "eval"
                     with cs.obs.span(kphase, level=level):
-                        vals = kernel_shard.ev_open(
-                            ks, t_rows, flat, bmsg, count_field, path, idx0
+                        vals = self._dispatched(
+                            cs, level, kernel_shard.ev_open,
+                            ks, t_rows, flat, bmsg, count_field, path, idx0,
                         )
-                        await self._phase_sync(vals)
+                        await self._phase_sync(cs, level, vals)
                     self._zero_phases(
                         cs,
                         level, "garble",
@@ -3381,6 +3521,9 @@ class CollectorServer:
             await respond(
                 req_id, await self._dispatch(sess, cs, req_id, verb, req)
             )
+            # the span log is block-buffered (obs/trace.py): every span
+            # of an answered verb, its response's too, is on disk now
+            obstrace.flush()
 
         tasks = set()
         try:
@@ -3826,6 +3969,7 @@ class CollectorClient:
         if self._flush_task is not None and not self._flush_task.done():
             self._flush_task.set_exception(self._dead)
         self._fail_pending(self._dead)
+        obstrace.flush()  # this client's last ``call:*`` lines
 
     def _fail_pending(self, err: ConnectionError) -> None:
         for fut in self._pending.values():

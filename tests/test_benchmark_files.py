@@ -151,3 +151,74 @@ def test_the_2d_cell_reports_what_the_hbm_cell_reports():
             assert spec[key] == entry[key], key
         assert spec["reader"] in ("counter_per_level", "span_ms_per_level") and spec["what"]
         assert entry["moves"] == "setup_s"  # so that the rehearsal's twins stand as they are
+
+
+# The stage account's twelve metrics (PR 39): one file and ONE entry
+# each, for the three secure cells together, no ``.hbm`` / ``.2d`` twins.
+_STAGE_ACCOUNT = {
+    **{f"{stage}_idle_ms_per_level":
+       [f"stage_starved:{stage}", f"stage_blocked:{stage}"]
+       for stage in ("build", "msg_fetch", "msg_send", "extend", "u_fetch",
+                     "u_send", "open")},
+    "program_dispatch_ms_per_level": ["program_dispatch"],
+    "program_device_ms_per_level": ["program_device"],
+    "loop_hop_ms_per_level": ["program_hop", "d2h_hop", "send_resume"],
+    "d2h_ready_ms_per_level": ["d2h_ready"],
+    "d2h_copy_ms_per_level": ["d2h_copy"],
+}
+_SECURE_CELLS = ["flagship-secure", "flagship-secure-hbm", "amazon-2d-secure"]
+
+
+def _manifest():
+    import sys
+
+    bench = os.path.join(ROOT, "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    import manifest
+
+    return manifest
+
+
+@pytest.mark.parametrize("metric", list(_STAGE_ACCOUNT))
+def test_stage_account_metrics_load_and_read_what_rpc_records(metric):
+    """Each of the twelve loads through ``manifest.cell`` for the three
+    secure cells and no other, agrees with its one entry, reads through
+    ``span_ms_per_level`` (the idle of a stage summed over the servers,
+    one of which runs it in a level; the rest their mean), and every
+    timer it names is one ``protocol/rpc.py`` records."""
+    import re
+
+    manifest = _manifest()
+    bench = _load("BENCHMARK.json")
+    (entry,) = [m for m in bench["per_layer"] if m["name"].split(".")[0] == metric]
+    assert entry == {
+        "name": metric, "unit": "ms", "better": "lower", "source": "program_span",
+        "layer": "2PC exchange", "moves": "setup_s", "workloads": _SECURE_CELLS}
+    for w in bench["workloads"]:
+        specs = [s for s in manifest.cell(w["name"]).per_layer if s["name"] == metric]
+        assert len(specs) == (w["name"] in _SECURE_CELLS)
+    (spec,) = specs = [s for s in manifest.cell("amazon-2d-secure").per_layer
+                       if s["name"] == metric]
+    assert {k: spec[k] for k in entry} == entry
+    assert spec["reader"] == "span_ms_per_level"
+    assert spec["args"] == {
+        "spans": _STAGE_ACCOUNT[metric],
+        "servers": "sum" if metric.endswith("_idle_ms_per_level") else "mean",
+        "levels": "mean"}
+    assert "gc_ot_ms_per_level" in spec["what"] and "K chunks" in spec["what"]
+    with open(os.path.join(ROOT, "fuzzyheavyhitters_tpu", "protocol", "rpc.py"),
+              encoding="utf-8") as f:
+        source = f.read()
+    from fuzzyheavyhitters_tpu.protocol import rpc
+
+    for name in spec["args"]["spans"]:
+        kind, _, stage = name.partition(":")
+        if stage:
+            # a stage's timers: ``_Stage`` makes the three names from its
+            # stage, which a task (or ``_chunk_senders``' caller) gives
+            assert kind in rpc.STAGE_TIMERS[:2]
+            assert re.search(rf'_Stage\(cs\.obs, "{stage}", level\)', source) or re.search(
+                rf'\("\w+", "{stage}"\)|\("{stage}", "\w+"\)', source), name
+        else:
+            assert re.search(rf'timer_add\(\s*"{name}"', source), name

@@ -1,0 +1,281 @@
+"""The wait account of the secure level's chunk pipeline (protocol/rpc.py
+``_Stage``, ``_timed``; ``_program`` / ``_phase_sync`` / ``_fetch`` /
+``_dp_send``): a CPU pair over real sockets, ``secure.CHUNK_FRAME_BYTES``
+patched small so that a level crosses in K > 1 chunks, as
+tests/test_secure_chunks.py does.
+
+What is held, for every level and server: no stage waited longer than
+the level's ``gc_ot``; each stage's waits and the leaf spans of its work
+fill its wall; a span that waits on a thread is the sum of its three
+parts; the account writes no span-log line; and an untraced level writes
+nothing at all.
+"""
+
+import asyncio
+import collections
+
+import numpy as np
+import pytest
+
+from fuzzyheavyhitters_tpu.obs import report as obsreport
+from fuzzyheavyhitters_tpu.obs import trace as tracemod
+from fuzzyheavyhitters_tpu.ops.fields import F255, FE62
+from fuzzyheavyhitters_tpu.protocol import rpc, secure
+
+from test_secure_chunks import BLOCK, L, WHOLE, _Pair, _points_2d, _root_counts
+
+BASE_PORT = 32331  # a range of its own (.. 32542: above test_rpc's 32231-32302), under the ephemeral ports
+
+EV_STAGES = ("extend", "u_fetch", "u_send", "open")
+GB_STAGES = ("build", "msg_fetch", "msg_send")
+# what each stage does between its waits: the leaf spans and timers
+WORK = {
+    "extend": ("otext",),
+    "open": ("h2d", "eval", "b2a"),
+    "build": ("h2d", "otext", "b2a", "garble"),
+    "u_fetch": ("d2h",), "msg_fetch": ("d2h",),
+    "u_send": ("wire_pickle", "wire_queue", "wire_write", "send_resume"),
+    "msg_send": ("wire_pickle", "wire_queue", "wire_write", "send_resume"),
+}
+
+
+@pytest.fixture(autouse=True)
+def _module_cpu(cpu_default):
+    yield
+
+
+def _frame_bytes(path, S, field, blocks=1):
+    """A frame budget of ``blocks`` planar blocks a chunk."""
+    from fuzzyheavyhitters_tpu.parallel.kernel_shard import n_msg_planes
+
+    W = secure.payload_words(field)
+    return blocks * BLOCK * max(16 * S, 4 * n_msg_planes(path, S, W))
+
+
+def _field_fetches(monkeypatch):
+    """{(registry, level): seconds of ``d2h`` inside the ``field`` phase}:
+    the one fetch of a secure level that no chunk stage makes."""
+    seen = {}
+    real = rpc.CollectorServer._reduced_fetch
+
+    async def spy(self, cs, level, fn, *args):
+        before = cs.obs.timer_seconds("d2h", level)
+        out = await real(self, cs, level, fn, *args)
+        seen[cs.obs.name, level] = cs.obs.timer_seconds("d2h", level) - before
+        return out
+
+    monkeypatch.setattr(rpc.CollectorServer, "_reduced_fetch", spy)
+    return seen
+
+
+def _close(a, b):
+    """Within 5% or 2 ms."""
+    return abs(a - b) <= max(0.05 * max(a, b), 2e-3)
+
+
+def _check_account(reg, lv, stages, field_d2h, synced=True):
+    """Identities (a), (b) and (c) on one server's registry at one level."""
+    t = lambda name: reg.timer_seconds(name, level=lv)
+    gc_ot = t("gc_ot")
+    assert gc_ot > 0
+    for st in stages:
+        starved, blocked, wall = (t(f"{k}:{st}") for k in rpc.STAGE_TIMERS)
+        # (a) no stage waited longer than the level took
+        assert 0 <= starved + blocked <= gc_ot, (st, starved, blocked, gc_ot)
+        # (b) its waits and its work fill its wall, inside gc_ot
+        assert 0 < wall <= gc_ot, (st, wall, gc_ot)
+        work = sum(t(name) for name in WORK[st])
+        if "d2h" in WORK[st]:
+            work -= field_d2h[reg.name, lv]
+        assert _close(starved + blocked + work, wall), (
+            st, starved, blocked, work, wall)
+    for st in set(EV_STAGES + GB_STAGES) - set(stages):
+        assert t(f"stage_wall:{st}") == 0.0  # the other server's
+    # (c) a span that waits on a thread is its three parts
+    parts = t("program_dispatch") + t("program_device") + t("program_hop")
+    assert _close(parts, t("otext") + t("b2a") + t("eval") + t("garble"))
+    assert t("program_dispatch") > 0
+    if synced:
+        assert t("program_device") > 0
+    else:
+        assert t("program_device") == t("program_hop") == 0.0
+    assert _close(t("d2h_ready") + t("d2h_copy") + t("d2h_hop"), t("d2h"))
+    assert t("d2h_ready") >= 0 and t("d2h_copy") > 0 and t("send_resume") >= 0
+
+
+_CASES = {
+    # name: (path, garbler, last, two dimensions, n, bucket, K, Config fields)
+    "ot2s_garbler0": ("ot2s", 0, False, False, 4096, 4, 4, {}),
+    "ot2s_garbler1": ("ot2s", 1, False, False, 4096, 4, 4, {}),
+    "gc_garbler0": ("gc", 0, False, False, 4096, 4, 4, {}),
+    "gc_garbler1": ("gc", 1, False, False, 4096, 4, 4, {}),
+    "leaf_level": ("ot2s", 1, True, False, 3072, 4, 3, {}),
+    "two_dimensions": ("auto", 0, False, True, 1024, 4, 2, {}),
+    # one chunk: the same calls, one after the other, the same timers
+    "whole_level": ("ot2s", 0, False, False, 1024, 4, 1, {}),
+    "no_phase_sync": ("ot2s", 1, False, False, 4096, 4, 4,
+                      {"secure_phase_sync": False}),
+}
+
+
+@pytest.mark.parametrize("case", list(_CASES))
+def test_every_stage_accounts_for_its_level(monkeypatch, case):
+    """(a), (b), (c) of the stage account at a level of K chunks, on both
+    equality paths, with either server garbling, at the leaf level, in
+    two dimensions, at K = 1 and without the phase sync."""
+    path, garbler, last, two_d, n, f, K, cfg = _CASES[case]
+    S = 4 if two_d else 2
+    real_path = secure.ot_path(S) if path == "auto" else path
+    field = F255 if last else FE62
+    lv = L - 1 if last else 0
+    field_d2h = _field_fetches(monkeypatch)
+    monkeypatch.setattr(
+        secure, "CHUNK_FRAME_BYTES",
+        WHOLE if K == 1 else _frame_bytes(real_path, S, field),
+    )
+    port = BASE_PORT + 20 * list(_CASES).index(case)
+
+    async def run():
+        pts = _points_2d(n) if two_d else None
+        async with _Pair(port, n, pts=pts, **cfg) as pair:
+            await pair.both("tree_init", {"root_bucket": f})
+            shares = await pair.level(garbler, last=last, path=path)
+            regs = [cs.obs for cs in pair.sessions]
+            ks = [r.counter_value("secure_chunks", level=lv) for r in regs]
+            rep = obsreport.run_report(regs)
+            return shares, regs, ks, rep, pair.pts
+
+    shares, regs, ks, rep, pts = asyncio.run(run())
+    assert ks == [K, K]
+    for sid, reg in enumerate(regs):
+        _check_account(
+            reg, lv, GB_STAGES if sid == garbler else EV_STAGES, field_d2h,
+            synced=cfg.get("secure_phase_sync", True),
+        )
+    if not last and not two_d:
+        got = np.asarray(FE62.canon(FE62.sub(shares[0], shares[1])))
+        assert np.array_equal(got[0], _root_counts(pts)) and not got[1:].any()
+    # the operator's reader: the run report's ``stages`` block
+    stages = rep["secure_kernels"]["stages"]
+    assert set(stages) == {r.name for r in regs}
+    for sid, reg in enumerate(regs):
+        mine = stages[reg.name]
+        want = GB_STAGES if sid == garbler else EV_STAGES
+        assert set(mine["by_stage"]) == set(want)
+        for st, row in mine["by_stage"].items():
+            assert _close(
+                row["busy_seconds"],
+                row["wall_seconds"] - row["starved_seconds"]
+                - row["blocked_seconds"],
+            )
+            assert 0 <= row["busy_share_of_gc_ot"] <= 1.0
+        assert mine["pace_setter"] in want
+        assert set(mine["split"]) == {
+            "program_dispatch", "program_device", "program_hop",
+            "d2h_ready", "d2h_copy", "d2h_hop", "send_resume",
+        }
+
+
+@pytest.fixture
+def trace_dir(tmp_path, monkeypatch):
+    """fhh-trace armed into a directory of the test's own."""
+    d = tmp_path / "trace"
+    monkeypatch.setenv(tracemod.ENV_DIR, str(d))
+    tracemod._refresh()
+    yield d
+    monkeypatch.delenv(tracemod.ENV_DIR, raising=False)
+    tracemod._refresh()
+
+
+async def _one_level(port):
+    """``tree_init`` and a K = 4 level garbled by server 0, under a
+    trace root where tracing is on."""
+    async with _Pair(port, 4096) as pair:
+        with tracemod.root("crawl"):
+            await pair.both("tree_init", {"root_bucket": 4})
+            await pair.level(0, path="ot2s")
+        return [cs.obs for cs in pair.sessions]
+
+
+# the span-log lines of ``_one_level``, by name, counted on the parent of
+# the stage account (commit c70dd32, both servers and the leader): four
+# chunks' spans a stage, the level's three phases, the field's fetch,
+# the sessions' handshake and both verbs' control-plane frames
+_PARENT_LINES = {
+    "b2a": 8, "call:tree_crawl": 2, "call:tree_init": 2, "d2h": 10,
+    "device_turn": 2, "field": 2, "frontier_init": 2, "fss": 2, "gc_ot": 2,
+    "h2d": 8, "key_place": 2, "ot2s": 8, "otext": 8, "peer_wait": 14,
+    "plane_handshake": 2, "verb:tree_crawl": 2, "verb:tree_init": 2,
+    "wire_pickle": 18, "wire_queue": 14, "wire_read": 14,
+    "wire_unpickle": 14, "wire_wait": 14, "wire_write": 18,
+}
+
+
+def test_the_account_writes_no_span_log_line(trace_dir, monkeypatch):
+    """A traced K = 4 level writes the lines the parent wrote, name by
+    name: the account is timers alone, so ``benchmark/trace_reduce`` is
+    handed not one span more."""
+    monkeypatch.setattr(
+        secure, "CHUNK_FRAME_BYTES", _frame_bytes("ot2s", 2, FE62))
+    regs = asyncio.run(_one_level(BASE_PORT + 160))
+    tracemod.flush()
+    lines = collections.Counter(
+        e["name"] for e in tracemod.load_events(str(trace_dir))
+        if e["ph"] == "X"
+    )
+    assert dict(lines) == _PARENT_LINES
+    # and the timers are there all the same: every name the benchmark's
+    # twelve metric files of the account read, on one server or the other
+    for reg in regs:
+        assert reg.timer_seconds("program_dispatch", level=0) > 0
+        assert reg.timer_seconds("d2h_copy", level=0) > 0
+        assert reg.counter_value("secure_chunk_programs", level=0) == 8
+    recorded = {name for reg in regs for name in reg.report()["phases"]}
+    from test_benchmark_files import _STAGE_ACCOUNT, _load
+
+    for metric in _STAGE_ACCOUNT:
+        spec = _load("benchmark", "metrics", f"{metric}.json")
+        assert set(spec["args"]["spans"]) <= recorded, metric
+
+
+def test_an_untraced_level_writes_nothing(tmp_path, monkeypatch):
+    """``FHH_TRACE_DIR`` unset: no writer, no file, and ``obs`` has not
+    imported ``jax.profiler`` (it does at the first TRACED span)."""
+    monkeypatch.delenv(tracemod.ENV_DIR, raising=False)
+    monkeypatch.chdir(tmp_path)
+    tracemod._refresh()
+    monkeypatch.setattr(tracemod, "_ANNOTATION", None)
+    monkeypatch.setattr(
+        secure, "CHUNK_FRAME_BYTES", _frame_bytes("ot2s", 2, FE62))
+    regs = asyncio.run(_one_level(BASE_PORT + 180))
+    tracemod.flush()
+    assert tracemod.enabled() is False
+    assert tracemod._WRITER is None and tracemod._ANNOTATION is None
+    assert not list(tmp_path.iterdir())
+    assert all(r.timer_seconds("stage_wall:open") + r.timer_seconds(
+        "stage_wall:build") > 0 for r in regs)
+
+
+def test_the_span_log_is_whole_when_a_verb_has_answered(trace_dir):
+    """The ring file is block-buffered and flushed where a server's verb
+    has answered: a reader in the process (benchmark/run.py, which calls
+    no flush) finds every span of the verb on disk."""
+
+    async def run():
+        async with _Pair(BASE_PORT + 200, 1024) as pair:
+            with tracemod.root("crawl"):
+                await pair.both("tree_init", {"root_bucket": 4})
+                await pair.level(0, path="ot2s")
+            # no tracemod.flush() here
+            return collections.Counter(
+                e["name"] for e in tracemod.load_events(str(trace_dir))
+                if e["ph"] == "X" and e.get("level") == 0
+            )
+
+    lines = asyncio.run(run())
+    assert tracemod._BUFFER_BYTES > 1 << 16
+    # K = 1: one of each a server, and the field's fetch
+    for name in ("gc_ot", "fss", "field", "otext", "b2a", "h2d",
+                 "wire_queue", "wire_write", "peer_wait"):
+        assert lines[name] == 2, name
+    assert lines["d2h"] == 4
