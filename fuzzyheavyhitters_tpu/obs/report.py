@@ -29,7 +29,14 @@ Well-known metric names (what populates them):
   a BREAKDOWN of gc_ot, not additive with it, and the wire wait is the
   gc_ot remainder.  Counters ``ot_path_ot2s`` / ``ot_path_gc`` count
   levels by the equality-test engine taken, ``secure_chunks`` the
-  chunks a level's two messages crossed in (1 = whole).  Rolled up across
+  chunks a level's two messages crossed in (1 = whole),
+  ``secure_chunk_programs`` the device programs of its ``otext`` +
+  ``b2a`` spans, ``secure_fetch_syncs`` / ``secure_phase_waits`` where
+  they were waited for (a fetch's thread / the stage); gauges
+  ``device_waits_high`` / ``device_wait_threads`` the most such thread
+  calls a server had parked at once in a level and the threads it has
+  for them, counter ``secure_account_errors`` the fetches whose stamps
+  could not be recorded.  Rolled up across
   registries into a top-level ``secure_kernels`` section whenever a
   secure crawl ran.
 - phases ``stage_wall:<stage>`` / ``stage_starved:<stage>`` /
@@ -338,6 +345,11 @@ def _pipeline_summary(registries: dict) -> dict | None:
     }
 
 
+def _by_level(d: dict) -> dict:
+    """``{level (a string): value}`` in the levels' order."""
+    return dict(sorted(d.items(), key=lambda kv: int(kv[0])))
+
+
 def _secure_kernel_summary(registries: dict) -> dict | None:
     """Cross-registry secure-kernel rollup (the acceptance instrument of
     the device-resident GC/OT work): per phase, total seconds summed
@@ -352,9 +364,18 @@ def _secure_kernel_summary(registries: dict) -> dict | None:
     by_level: dict = {}
     paths = {"ot2s": 0, "gc": 0}
     chunks: dict = {}
-    programs: dict = {}
+    # per-level counters both servers keep, the larger of the two
+    larger = {
+        "secure_chunk_programs": {}, "secure_fetch_syncs": {},
+        "secure_phase_waits": {},
+    }
     held: dict = {}
     index_high = 0
+    # a server's waits for the device (protocol/rpc.py ``_DeviceWaits``):
+    # the most thread calls one had parked at once in a level, the
+    # threads it has for them, and faults in a fetch's recording
+    waits = {"device_waits_high": 0, "device_wait_threads": 0}
+    account_errors = 0
     shape = {"secure_string_bits": None, "child_patterns": None}
     kshards = None
     kgather = 0.0
@@ -380,9 +401,10 @@ def _secure_kernel_summary(registries: dict) -> dict | None:
         c = snap.get("counters", {}).get("secure_chunks")
         for lvl, k in (c or {}).get("by_level", {}).items():
             chunks[lvl] = max(chunks.get(lvl, 0), k)
-        c = snap.get("counters", {}).get("secure_chunk_programs")
-        for lvl, k in (c or {}).get("by_level", {}).items():
-            programs[lvl] = max(programs.get(lvl, 0), k)
+        for name, by in larger.items():
+            c = snap.get("counters", {}).get(name)
+            for lvl, k in (c or {}).get("by_level", {}).items():
+                by[lvl] = max(by.get(lvl, 0), k)
         g = snap.get("gauges", {}).get("kernel_shards")
         if g is not None:
             kshards = g.get("last") if kshards is None else max(
@@ -391,6 +413,14 @@ def _secure_kernel_summary(registries: dict) -> dict | None:
         g = snap.get("gauges", {}).get("ot_index_high")
         if g is not None:
             index_high = max(index_high, g.get("last"))
+        for name in waits:
+            g = snap.get("gauges", {}).get(name) or {}
+            waits[name] = max(
+                waits[name], g.get("last", 0), *g.get("by_level", {}).values()
+            )
+        account_errors += snap.get("counters", {}).get(
+            "secure_account_errors", {}
+        ).get("total", 0)
         for name in shape:
             g = snap.get("gauges", {}).get(name)
             if g is not None:
@@ -419,21 +449,32 @@ def _secure_kernel_summary(registries: dict) -> dict | None:
         "levels_gc": paths["gc"],
         # chunks each level's two messages crossed in (protocol/rpc.py
         # ``_ev_chunks``; 1 = the level went whole)
-        "chunks_by_level": dict(
-            sorted(chunks.items(), key=lambda kv: int(kv[0]))
-        ),
+        "chunks_by_level": _by_level(chunks),
         # device programs a server handed over inside each level's
         # ``otext`` + ``b2a`` spans (counter ``secure_chunk_programs``):
         # one a span, 2 x chunks
-        "chunk_programs_by_level": dict(
-            sorted(programs.items(), key=lambda kv: int(kv[0]))
-        ),
+        "chunk_programs_by_level": _by_level(larger["secure_chunk_programs"]),
+        # where those programs (and the circuit's) were waited for: on
+        # the thread of the fetch that takes their output (counter
+        # ``secure_fetch_syncs``: the garbling server's 2 a chunk on the
+        # table path, against the other's 1) or by the stage that
+        # dispatched them (``secure_phase_waits``: the evaluating
+        # server's 1 a chunk for the opening, against the other's 0);
+        # both 0 with ``secure_phase_sync`` off
+        "fetch_syncs_by_level": _by_level(larger["secure_fetch_syncs"]),
+        "phase_waits_by_level": _by_level(larger["secure_phase_waits"]),
+        # the most thread calls a server had parked in waits for the
+        # device at once in a level (gauge ``device_waits_high``) beside
+        # the threads of its own it has for them (``device_wait_threads``:
+        # a reading above it is calls queued for a thread), and fetches
+        # whose stamps could not be recorded (counter
+        # ``secure_account_errors``; the level went on)
+        **waits,
+        "account_errors": account_errors,
         # device bytes of the chunks the evaluator had sent u for and not
         # yet opened, at the fullest of each level (gauge
         # ``secure_t_rows_held_bytes``)
-        "t_rows_held_bytes_by_level": dict(
-            sorted(held.items(), key=lambda kv: int(kv[0]))
-        ),
+        "t_rows_held_bytes_by_level": _by_level(held),
         # the high word of the OT sessions' 64-bit pad index (gauge
         # ``ot_index_high``): above 0, a session has extended 2^32 OTs
         "ot_index_high": index_high,

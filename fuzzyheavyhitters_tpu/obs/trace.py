@@ -408,15 +408,19 @@ def span_end(state: list, error: bool = False) -> None:
     _span_event(name, comp, t0, dur, tid, sid, parent, level, error)
 
 
-def span_at(name: str, comp: str, ts: float, dur: float, level=None) -> None:
+def span_at(name: str, comp: str, ts: float, dur: float, level=None,
+            parent=None) -> "str | None":
     """Record a complete span whose times were taken elsewhere (the
     data-plane pump stamps a frame's read and unpickle; the receiver
-    records them), as a child of the active span.  ``ts`` is wall-clock
-    seconds.  No-op outside a trace."""
+    records them), as a child of the active span, or of ``parent``, the
+    id an earlier call returned.  ``ts`` is wall-clock seconds.  No-op
+    outside a trace."""
     ctx = _CTX.get()
     if ctx is None:
-        return
-    _span_event(name, comp, ts, dur, ctx[0], _new_id(), ctx[1], level, False)
+        return None
+    sid = _new_id()
+    _span_event(name, comp, ts, dur, ctx[0], sid, parent or ctx[1], level, False)
+    return sid
 
 
 @contextlib.contextmanager

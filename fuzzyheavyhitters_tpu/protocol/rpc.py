@@ -98,6 +98,7 @@ from __future__ import annotations
 
 import asyncio
 import collections as _collections
+import concurrent.futures
 import contextlib
 import functools
 import os
@@ -219,7 +220,7 @@ async def _recv(reader: wire.FrameReader, reg=None, counter=None):
 
 
 async def _fetch(
-    x, reg: obsmetrics.Registry, level: int | None = None
+    x, reg: obsmetrics.Registry, level: int | None = None, waits=None
 ) -> np.ndarray:
     """Device->host fetch OFF the event loop.  A bare ``np.asarray`` on a
     device array blocks the whole loop for a full device->host transfer
@@ -240,25 +241,43 @@ async def _fetch(
     the copy alone) and ``d2h_hop`` (the rest of the span: the hand-over
     to the thread and the finished thread's wait for the loop).
     ``level`` attributes the fetch when the call
-    site sits outside any span (span-active callers inherit)."""
+    site sits outside any span (span-active callers inherit).  ``waits``
+    is the calling server's :class:`_DeviceWaits`: the thread call parks
+    in ``block_until_ready`` on a thread of that server's own; without
+    one (a caller that is no server) asyncio's default executor runs it."""
     reg.count("device_fetches", level=level)
+    call = asyncio.to_thread if waits is None else waits.call
     with reg.span("d2h", level=level) as sp:
         _start_host_copy(x)
-        out, ready, copy = await asyncio.to_thread(_fetch_on_thread, x)
+        out, ready, copy, _ = await call(_fetch_on_thread, x)
     reg.timer_add("d2h_ready", ready, sp.level)
     reg.timer_add("d2h_copy", copy, sp.level)
     reg.timer_add("d2h_hop", sp.seconds - ready - copy, sp.level)
     return out
 
 
-def _fetch_on_thread(x) -> tuple:
+def _fetch_on_thread(x, waits=(), note=None) -> tuple:
     """(the numpy array, seconds until ``x`` was ready, seconds of the
-    copy): the fetch thread stamps its own clock as its last act and
-    :func:`_fetch` does the subtraction on the loop."""
+    copy, wall-clock stamps): the fetch thread stamps its own clock as
+    its last act and the loop does the subtraction.  ``waits`` are the
+    outputs of device programs nobody has waited for, in the order they
+    were handed to the device, each with the names of its span
+    (``CollectorServer._fetch_behind``): this thread waits for each in
+    turn and stamps when it was ready, so the stamps are its first
+    line, one a wait, and the array held.  ``note(name)`` is the
+    profiler annotation of a wait under fhh-trace."""
+    stamps = [time.time()]
+    for names, arr in waits:
+        with contextlib.ExitStack() as notes:
+            for name in names:
+                notes.enter_context(note(name))
+            jax.block_until_ready(arr)
+        stamps.append(time.time())
     ready = _sync_on_thread(x)
     t0 = time.perf_counter()
-    out = np.asarray(x)
-    return out, ready, time.perf_counter() - t0
+    with note("d2h") if waits else _NO_CTX:
+        out = np.asarray(x)
+    return out, ready, time.perf_counter() - t0, stamps + [time.time()]
 
 
 def _sync_on_thread(x) -> float:
@@ -283,6 +302,50 @@ def _start_host_copy(x) -> None:
         fn()
     except Exception:  # fhh-lint: disable=broad-except (pure prefetch hint: any failure means the sync np.asarray path simply does the whole copy)
         pass
+
+
+class _DeviceWaits:
+    """The threads on which ONE server's calls park in
+    ``jax.block_until_ready`` (``_fetch_on_thread``, ``_sync_on_thread``):
+    an executor of the server's own, so that a wait for the device
+    never queues behind the process's other thread calls (asyncio's
+    default executor, which keeps everything else and is a few workers
+    on a small host), nor they behind it.  ``workers`` is what one
+    level can have in flight (``CollectorServer.DEVICE_WAITS``); a call
+    beyond that waits for a worker, and every parked call ends by
+    itself, so none waits on another.  ``now`` / ``high`` count the
+    calls handed over and not yet done, on the loop thread alone (the
+    gauge ``device_waits_high``)."""
+
+    def __init__(self, name: str, workers: int):
+        self.workers = workers
+        self._pool = concurrent.futures.ThreadPoolExecutor(
+            workers, thread_name_prefix=f"{name}-device-wait"
+        )
+        self.now = self.high = 0
+
+    def call(self, fn, *args) -> asyncio.Future:
+        """``fn(*args)`` on one of the threads, begun now; the future is
+        the running loop's."""
+        fut = asyncio.get_running_loop().run_in_executor(
+            self._pool, fn, *args
+        )
+        self.now += 1
+        self.high = max(self.high, self.now)
+        fut.add_done_callback(self._done)
+        return fut
+
+    def _done(self, _fut) -> None:
+        self.now -= 1
+
+    def take_high(self) -> int:
+        """The most calls in flight since the last take."""
+        high, self.high = self.high, self.now
+        return high
+
+    def close(self) -> None:
+        """Calls not begun are cancelled; a parked one ends by itself."""
+        self._pool.shutdown(wait=False, cancel_futures=True)
 
 
 # -- the wait account of the secure level's chunk pipeline -------------------
@@ -538,6 +601,8 @@ class CollectorServer:
             route_count=self._plane_count, tag=f"server{server_id}"
         )
         self._peer_addr: tuple | None = None
+        # the threads this server's waits for the device park on
+        self._waits = _DeviceWaits(f"server{server_id}", self.DEVICE_WAITS)
         # the listener's half-open plane: (token, {direction: socket})
         # of the dial whose second connection has not said hello yet
         self._plane_half: tuple | None = None
@@ -904,7 +969,7 @@ class CollectorServer:
             # the check batch's SINGLE post-verify readback: the verdict
             # vector (checks is never empty — level 0 contributes its
             # full check, a fused prune always stores its final depth)
-            ok = await _fetch(ok_all, cs.obs, level=level)
+            ok = await _fetch(ok_all, cs.obs, level, self._waits)
             cs.alive_keys &= ok
         if level != 0:
             # one-shot within a boot: each stored depth's pairs open once;
@@ -1132,7 +1197,7 @@ class CollectorServer:
                 ex["packed"], ex["children"], ex["frontier"]
             )
             # forces the device work to finish
-            packed_np = await _fetch(packed, cs.obs)
+            packed_np = await _fetch(packed, cs.obs, waits=self._waits)
         with cs.obs.span("gc_ot", level=level) as sp_gc:
             # data plane: swap packed share bits with the peer server
             peer = await self._swap(cs, packed_np)
@@ -1168,8 +1233,8 @@ class CollectorServer:
             cs.obs.gauge("data_shards", cs._mesh.shards, level=level)
             with cs.obs.span("ici_reduce", level=level):
                 out = getattr(cs._mesh, single_fn.__name__)(*args)
-                return await _fetch(out, cs.obs)
-        return await _fetch(single_fn(*args), cs.obs)
+                return await _fetch(out, cs.obs, waits=self._waits)
+        return await _fetch(single_fn(*args), cs.obs, waits=self._waits)
 
     @staticmethod
     def _h2d(cs, level: int, x: np.ndarray, spec=None):
@@ -1195,29 +1260,164 @@ class CollectorServer:
     async def _phase_sync(self, cs, level: int, x) -> None:
         """Device sync at a secure-kernel phase boundary (OFF the event
         loop — a bare block_until_ready would starve keepalives exactly
-        like a bare np.asarray).  Gated by ``cfg.secure_phase_sync``: the
+        like a bare np.asarray), for a program whose output NOTHING
+        fetches: the evaluator's opening of a chunk (``_ev_chunks``) and
+        the row-sharded kernel stage.  A chunk program whose output a
+        fetch stage takes is waited for on that fetch's thread instead
+        (``_fetch_behind``).  Gated by ``cfg.secure_phase_sync``: the
         phases are sequential data-dependent steps, so syncing costs only
-        the dispatch-ahead slack, and buys the phase_otext/garble/eval/
-        b2a spans real device seconds instead of dispatch time.
+        the dispatch-ahead slack, and buys the eval/b2a spans real device
+        seconds instead of dispatch time.
 
         Two timers split the wait: ``program_device`` (on the thread,
         its first line -> ``block_until_ready`` returned: the program's
         remaining run time and the device queue ahead of it) and
         ``program_hop`` (the rest of this await: the hand-over to the
         thread and the finished thread's wait for the loop); both 0
-        without the sync."""
+        without the sync.  The counter ``secure_phase_waits`` counts the
+        awaited syncs.  The thread is one of the server's device-wait
+        threads (``_DeviceWaits``)."""
         device = hop = 0.0
         if self.cfg.secure_phase_sync:
+            cs.obs.count("secure_phase_waits", level=level)
             t0 = time.perf_counter()
-            device = await asyncio.to_thread(_sync_on_thread, x)
+            device = await self._waits.call(_sync_on_thread, x)
             hop = time.perf_counter() - t0 - device
         cs.obs.timer_add("program_device", device, level)
         cs.obs.timer_add("program_hop", hop, level)
 
+    def _fetch_behind(self, cs, level: int, x, marks: list, waits: tuple):
+        """Begin the fetch of ``x``, the last output of the programs a
+        chunk stage has JUST dispatched (``marks``: the wall clock before
+        the first dispatch and after each), and return the future of the
+        numpy array for the fetch stage to await (``_fetch_taken``); the
+        stage that dispatched awaits nothing between its dispatch and
+        its ``put``.  The host copy is queued behind the programs on the
+        device, with no loop turn between, and ONE thread call, started
+        here on the server's device-wait threads so that its stamps are
+        the device's ends, waits for each output of ``waits`` (``(span
+        names, output)`` in dispatch order), stamps when it was ready,
+        and copies (``_fetch_on_thread``).  With
+        ``cfg.secure_phase_sync`` off it waits for ``x`` alone, as
+        ``_fetch`` does.  ``_fetched`` records the stamps on the loop
+        and resolves the future, whatever the thread call came to."""
+        _start_host_copy(x)
+        held = asyncio.get_running_loop().create_future()
+        self._waits.call(
+            _fetch_on_thread, x,
+            waits if self.cfg.secure_phase_sync else (),
+            lambda name: obstrace.annotate(cs.obs.name, name, level) or _NO_CTX,
+        ).add_done_callback(
+            functools.partial(self._fetched, cs.obs, level, marks, waits, held)
+        )
+        return held
+
+    @classmethod
+    def _fetched(cls, reg, level: int, marks: list, waits: tuple, held, fut) -> None:
+        """One ``_fetch_behind``'s thread call is over: ``held``, which
+        a fetch stage awaits, is resolved ON EVERY PATH out of here: the
+        array, the thread's error (that stage then fails the verb), an
+        error where the call was cancelled before it ran (the server is
+        closing); and a fault in the recording of the stamps is logged
+        and counted (``secure_account_errors``) and the array handed
+        over all the same: the account never fails a level."""
+        if held.done():  # cancelled with its stage: nobody takes it
+            if not fut.cancelled():
+                fut.exception()  # seen, so asyncio does not log it
+            return
+        if fut.cancelled():
+            held.set_exception(ConnectionError(
+                f"secure level {level}: the chunk's fetch was cancelled "
+                "before its thread ran (the server is closing)"
+            ))
+            return
+        if fut.exception() is not None:
+            held.set_exception(fut.exception())
+            return
+        out, *stamps = fut.result()
+        try:
+            cls._record_fetch(reg, level, marks, waits, *stamps)
+        except Exception as err:  # fhh-lint: disable=broad-except (the account of a fetch that succeeded: a fault in it costs the level its timers, not its result)
+            reg.count("secure_account_errors", level=level)
+            obs.emit(
+                "secure.account_error", severity="warning", comp=reg.name,
+                level=int(level), error=f"{type(err).__name__}: {err}",
+            )
+        finally:
+            held.set_result(out)
+
     @staticmethod
-    def _dispatched(cs, level: int, fn, *args):
+    def _record_fetch(reg, level: int, marks: list, waits: tuple,
+                      ready: float, copy: float, stamps: list) -> None:
+        """The stamps of one ``_fetch_behind`` as this registry's spans
+        and timers ON THE LOOP, as ``_dp_send`` records a send.  One
+        line a span and chunk, disjoint and in order: the first
+        program's span from the first dispatch's begin to its output
+        ready, each later one from the output before it to its own,
+        ``d2h`` from the last output ready to this callback (without
+        the sync: each program's dispatch, and ``d2h`` as ``_fetch``
+        has it).  ``program_device`` is the thread's waits,
+        ``program_hop`` the hand-over (last dispatch ended -> the
+        thread's first line), ``d2h_ready`` / ``d2h_copy`` what
+        ``_fetch`` finds after the waits, ``d2h_hop`` the finished
+        thread's wait for the loop; ``secure_fetch_syncs`` counts the
+        outputs waited for."""
+        began, *ends, _ = stamps
+        now = time.time()
+        device = hop = 0.0
+        if ends:  # a span ends where the device finished its program
+            edges = [marks[0], *ends]
+            device, hop = ends[-1] - began, began - marks[-1]
+            reg.count("secure_fetch_syncs", len(ends), level=level)
+        else:  # no sync: dispatch time
+            edges = marks
+        tracing = obstrace.enabled()
+        spans = [(w[0], a, b) for w, a, b in zip(waits, edges, edges[1:])]
+        for names, a, b in (*spans, (("d2h",), edges[-1], now)):
+            up = None  # a second name lies inside the first (ot2s in b2a)
+            for name in names:
+                reg.timer_add(name, b - a, level)
+                if tracing:
+                    up = obstrace.span_at(
+                        name, reg.name, a, b - a, level, parent=up
+                    )
+        reg.count("device_fetches", level=level)
+        for name, seconds in (
+            ("program_device", device), ("program_hop", hop),
+            ("d2h_ready", ready), ("d2h_copy", copy),
+            ("d2h_hop", now - edges[-1] - ready - copy),
+        ):
+            reg.timer_add(name, seconds, level)
+
+    async def _fetch_taken(self, cs, level: int, stage: str, k: int, held):
+        """Await a chunk's fetch (``_fetch_behind``'s future) for the
+        fetch stage ``stage``, no longer than the plane lets a peer stay
+        silent (``_plane_silence_s``: past it the peer's verb would have
+        lost this server anyway).  A thread call that has not come back
+        by then fails the verb here, with the stage and the chunk it
+        stood in; the leader's quiesce fails the peer's, and the level
+        run again is exact (tests/test_secure_chunks.py)."""
+        bound = self._plane_silence_s()
+        try:
+            done, _ = await asyncio.wait({held}, timeout=bound)
+        except asyncio.CancelledError:
+            held.cancel()  # the level is over: nobody takes it
+            raise
+        if not done:
+            held.cancel()
+            cs.obs.count("device_wait_timeouts", level=level)
+            raise TimeoutError(
+                f"secure level {level}: {stage} waited {bound:g} s for "
+                f"chunk {k}'s device programs and copy (a fetch thread "
+                "that does not return)"
+            )
+        return held.result()
+
+    @staticmethod
+    def _dispatched(cs, level: int, fn, *args, marks=None):
         """``fn(*args)``, a jitted call's host side ON the loop thread,
-        its seconds added to the timer ``program_dispatch``."""
+        its seconds added to the timer ``program_dispatch``; ``marks``
+        takes the wall clock when it returned (``_fetch_behind``)."""
         t0 = time.perf_counter()
         try:
             return fn(*args)
@@ -1225,16 +1425,18 @@ class CollectorServer:
             cs.obs.timer_add(
                 "program_dispatch", time.perf_counter() - t0, level
             )
+            if marks is not None:
+                marks.append(time.time())
 
     @classmethod
-    def _program(cls, cs, level: int, fn, *args):
+    def _program(cls, cs, level: int, fn, *args, marks=None):
         """Hand the device ONE program of a secure chunk, inside its
         ``otext`` or ``b2a`` span, and count it: the counter
         ``secure_chunk_programs`` reads 2 x K a level on either server
         (tests/test_secure_chunks.py holds the calls to one jitted
         program each)."""
         cs.obs.count("secure_chunk_programs", level=level)
-        return cls._dispatched(cs, level, fn, *args)
+        return cls._dispatched(cs, level, fn, *args, marks=marks)
 
     def _zero_phases(self, cs, level: int, *names: str) -> None:
         """Materialize zero-valued phase timers so the secure-kernel
@@ -1252,17 +1454,38 @@ class CollectorServer:
     # between them — kernel, fetch and send of what it makes
     # (``_chunk_senders``), receive and kernel of what it is sent — so
     # chunk k+1's kernel and chunk k's fetch run while chunk k-1 is on
-    # the socket and the peer works on chunk k-2.  Fetches stay on
-    # threads (``_fetch``), a send is a whole frame on the plane's
-    # writer thread, its reader thread always reads, and ``PlaneMux``
-    # holds frames ahead of their receiver in order.  One chunk (K = 1)
-    # is the whole level: the same calls, one after the other as they
-    # always were.
+    # the socket and the peer works on chunk k-2.  A stage that hands
+    # the device a chunk's programs does not wait for them: it starts
+    # the ONE thread call of the chunk's fetch where it dispatches
+    # (``_fetch_behind``), and that thread waits for each program,
+    # stamps the spans' device ends and copies; the fetch stage awaits
+    # it, no longer than the plane lets a peer stay silent
+    # (``_fetch_taken``).  Only the opening, whose output nothing
+    # fetches, is awaited by its stage (``_phase_sync``).  Every call
+    # that parks in ``block_until_ready`` does so on a thread of the
+    # server's own (``_DeviceWaits``, ``DEVICE_WAITS`` of them), not on
+    # asyncio's default executor.  A send is a whole frame on the
+    # plane's writer thread, its reader thread always reads, and
+    # ``PlaneMux`` holds frames ahead of their receiver in order.  One
+    # chunk (K = 1) is the whole level: the same calls.
 
     # how many chunks the evaluator's u may run ahead of the tables it
     # has opened (``_ev_chunks``): every level of eight chunks or fewer
     # runs as far ahead as it has chunks
     CHUNKS_AHEAD = 8
+
+    # chunks a stage may run ahead of the stage after it: the bound of
+    # the queues ``made`` / ``built`` / ``fetched``
+    STAGE_QUEUE = 2
+
+    # the threads of a server's waits for the device (``_DeviceWaits``),
+    # from what a level can have parked at once: a fetch's thread call
+    # for every chunk in ``made`` (or ``built``), for the one its
+    # dispatching stage holds while it waits for room there and for the
+    # one the fetch stage awaits; the opening's sync; and one more for a
+    # fetch outside the chunk stages (the ``field`` phase's, another
+    # verb's).  The gauge ``device_waits_high`` reads against it.
+    DEVICE_WAITS = STAGE_QUEUE + 1 + 1 + 1 + 1
 
     @staticmethod
     def _chunk_frame(k: int, K: int, arr: np.ndarray):
@@ -1318,22 +1541,25 @@ class CollectorServer:
     def _chunk_senders(self, cs, level: int, K: int, made: asyncio.Queue,
                        stages: tuple, on_sent=None):
         """The two tasks that take a level's K chunk arrays from the
-        device to the peer, in order: one fetches, one sends, two
-        chunks at most between them, so chunk k+1's fetch runs while
-        chunk k is on the socket.  ``made`` yields ``(array, token)``;
+        device to the peer, in order: one awaits each fetch, one sends,
+        two chunks at most between them, so chunk k+1's fetch runs
+        while chunk k is on the socket.  ``made`` yields ``(fetch,
+        token)``, the fetch a future that the stage before began where
+        it dispatched the chunk's programs (``_fetch_behind``) and that
+        is awaited under the plane's bound (``_fetch_taken``);
         ``on_sent`` is awaited with the token once that chunk's frame
         is with the kernel.  ``stages`` names the two in the level's
         wait account (:class:`_Stage`)."""
-        fetched: asyncio.Queue = asyncio.Queue(maxsize=2)
+        fetched: asyncio.Queue = asyncio.Queue(self.STAGE_QUEUE)
 
         async def fetch():
             with _Stage(cs.obs, stages[0], level) as st:
                 for k in range(K):
                     # fhh-lint: disable=unbounded-await (fed by a sibling task, which _chunk_tasks cancels with this one)
                     arr, token = await st.starved(made.get())
-                    with self._chunk_label(k, K):
-                        # fhh-lint: disable=chunked-device-readback (the point of the chunks: chunk k's fetch runs while chunk k-1 is on the socket and the peer works on it; one whole-level fetch put 72 ms of a 173 ms level in series, PERF.md PR 31)
-                        arr = await _fetch(arr, cs.obs, level=level)
+                    arr = await self._fetch_taken(
+                        cs, level, stages[0], k, arr
+                    )
                     # fhh-lint: disable=unbounded-await (drained by a sibling task, as above)
                     await st.blocked(fetched.put((arr, token)))
 
@@ -1353,10 +1579,13 @@ class CollectorServer:
         self, cs, level: int, flat, chunks, B: int, W: int, path: str,
         count_field,
     ):
-        """Evaluator and OT receiver of a level: one task extends chunk
-        after chunk (stage ``extend``), two carry each u to the peer
-        (:meth:`_chunk_senders`: ``u_fetch``, ``u_send``), one opens
-        each table as it arrives (``open``).
+        """Evaluator and OT receiver of a level: one task hands the
+        device the extension of chunk after chunk (stage ``extend``: it
+        awaits room in ``made`` and nothing else; ``otext`` is stamped
+        on the fetch's thread, ``_fetch_behind``), two carry each u to
+        the peer (:meth:`_chunk_senders`: ``u_fetch``, ``u_send``), one
+        opens each table as it arrives (``open``, which awaits its
+        ``_phase_sync``: nothing fetches what it makes).
         Returns the level's field values [B(, limbs)] on the device."""
         S, K = flat.shape[1], len(chunks)
         rcv = cs._ot_rcv
@@ -1364,7 +1593,7 @@ class CollectorServer:
         # fails midway leaves them where the whole-level flow would
         idx0, off = rcv.consumed, rcv.stream_offset
         rcv.advance(B * S)
-        made: asyncio.Queue = asyncio.Queue(maxsize=2)
+        made: asyncio.Queue = asyncio.Queue(self.STAGE_QUEUE)
         # chunks whose u is on the wire, for the task that opens them;
         # their (y, T rows) wait on the device meanwhile, and the gauge
         # ``secure_t_rows_held_bytes`` says how many bytes at the fullest.
@@ -1387,14 +1616,15 @@ class CollectorServer:
             with _Stage(cs.obs, "extend", level) as st:
                 for k, (t0, n) in enumerate(chunks):
                     with self._chunk_label(k, K):
-                        with cs.obs.span("otext", level=level):
-                            u, t_rows, y = self._program(
-                                cs, level, secure.ev_chunk_extend,
-                                rcv, flat, off, t0, n,
-                            )
-                            # the extension, device-synced as on the sender
-                            # side; the fetch is then the copy alone
-                            await self._phase_sync(cs, level, u)
+                        marks = [time.time()]
+                        u, t_rows, y = self._program(
+                            cs, level, secure.ev_chunk_extend,
+                            rcv, flat, off, t0, n, marks=marks,
+                        )
+                        # the fetch's thread waits for the extension
+                        u = self._fetch_behind(
+                            cs, level, u, marks, ((("otext",), u),)
+                        )
                     # fhh-lint: disable=unbounded-await (drained by a sibling task, which _chunk_tasks cancels with this one)
                     await st.blocked(made.put((u, (y, t_rows))))
 
@@ -1454,12 +1684,15 @@ class CollectorServer:
         count_field, garbler: int, gc_seed, b2a_seed,
     ):
         """Garbler and OT sender of a level: one task receives each u
-        and builds that chunk's message (stage ``build``), two carry it
-        to the peer (:meth:`_chunk_senders`: ``msg_fetch``,
-        ``msg_send``), two chunks at most between each, so
-        chunk k's fetch overlaps chunk k+1's kernel and chunk k-1's
-        write.  Returns the level's share values
-        [B(, limbs)] on the device."""
+        and hands the device that chunk's programs back to back (stage
+        ``build``: no await between a chunk's dispatch and its ``put``;
+        ``otext``, ``b2a`` / ``ot2s`` (``garble``) are stamped on the
+        fetch's thread, ``_fetch_behind``), two carry the message to the
+        peer (:meth:`_chunk_senders`: ``msg_fetch``, ``msg_send``), two
+        chunks at most between each, so chunk k's fetch overlaps chunk
+        k+1's kernel and chunk k-1's write and ``build`` runs ahead of
+        the device by those queues and no further.  Returns the level's
+        share values [B(, limbs)] on the device."""
         S, K = flat.shape[1], len(chunks)
         snd = cs._ot_snd
         idx0, off = snd.consumed, snd.stream_offset
@@ -1469,7 +1702,7 @@ class CollectorServer:
         # chip, where its one-device kernel stage runs: see _h2d)
         put = jax.device_put if cs._mesh is None else cs._mesh.gather
         b2a_seed, s_block = put(b2a_seed), put(snd.s_block)
-        built: asyncio.Queue = asyncio.Queue(maxsize=2)
+        built: asyncio.Queue = asyncio.Queue(self.STAGE_QUEUE)
 
         async def build():
             vals = []
@@ -1480,37 +1713,37 @@ class CollectorServer:
                             cs, level,
                             await st.starved(self._chunk_recv(cs, k, K)),
                         )
-                        with cs.obs.span("otext", level=level):
-                            q = self._program(
-                                cs, level, secure.gb_chunk_extend,
-                                snd, u, S, off, t0, n,
+                        marks = [time.time()]
+                        q = self._program(
+                            cs, level, secure.gb_chunk_extend,
+                            snd, u, S, off, t0, n, marks=marks,
+                        )
+                        if path == "ot2s":
+                            # the share pair and the 2^S table: ot2s is b2a
+                            msg, v = self._program(
+                                cs, level, secure.gb_chunk_table,
+                                count_field, b2a_seed, q, flat,
+                                s_block, idx0, t0, n, garbler, marks=marks,
                             )
-                            await self._phase_sync(cs, level, q)
-                        with cs.obs.span("b2a", level=level):
-                            if path == "ot2s":
-                                # the share pair and the 2^S table, inside b2a
-                                with cs.obs.span("ot2s", level=level):
-                                    msg, v = self._program(
-                                        cs, level, secure.gb_chunk_table,
-                                        count_field, b2a_seed, q, flat,
-                                        s_block, idx0, t0, n, garbler,
-                                    )
-                                    await self._phase_sync(cs, level, msg)
-                            else:
-                                v, w0, w1 = self._program(
-                                    cs, level, secure.gb_chunk_pair,
-                                    b2a_seed, t0, count_field, garbler, n,
-                                )
-                                await self._phase_sync(cs, level, w1)
-                        if path != "ot2s":
-                            with cs.obs.span("garble", level=level):
-                                msg = self._dispatched(
-                                    cs, level, secure.gb_chunk_garble,
-                                    s_block, q, gc_seed, flat, w0, w1, W,
-                                    idx0, t0, n,
-                                )
-                                await self._phase_sync(cs, level, msg)
+                            waits = (("otext",), q), (("b2a", "ot2s"), msg)
+                        else:
+                            v, w0, w1 = self._program(
+                                cs, level, secure.gb_chunk_pair,
+                                b2a_seed, t0, count_field, garbler, n,
+                                marks=marks,
+                            )
+                            msg = self._dispatched(
+                                cs, level, secure.gb_chunk_garble,
+                                s_block, q, gc_seed, flat, w0, w1, W,
+                                idx0, t0, n, marks=marks,
+                            )
+                            waits = (
+                                (("otext",), q), (("b2a",), w1),
+                                (("garble",), msg),
+                            )
                         vals.append(v)
+                        # the fetch's thread waits for them, in order
+                        msg = self._fetch_behind(cs, level, msg, marks, waits)
                     # fhh-lint: disable=unbounded-await (drained by a sibling task, which _chunk_tasks cancels with this one)
                     await st.blocked(built.put((msg, None)))
             return vals
@@ -1571,10 +1804,20 @@ class CollectorServer:
         The ``gc_ot`` span splits into the secure-kernel phases
         ``otext`` (extension), ``garble``/``eval`` (circuit work — zero
         on the ot2s path), and ``b2a`` (payload table / open + field
-        conversion); wire waits are the gc_ot remainder.  What each
-        stage task of the chunk pipeline WAITED for, and how the spans
-        that wait on a thread split, is in the timers of
-        :class:`_Stage`, ``_phase_sync``, ``_fetch`` and ``_dp_send``."""
+        conversion); wire waits are the gc_ot remainder.  A chunk
+        program whose output a fetch takes (the extensions, the table,
+        the circuit) is waited for on that fetch's thread, which stamps
+        where its span ends (``_fetch_behind``; counter
+        ``secure_fetch_syncs``); the opening is awaited by its stage
+        (``_phase_sync``; ``secure_phase_waits``).  What each stage task
+        of the chunk pipeline WAITED for, and how the spans that wait
+        on a thread split, is in the timers of :class:`_Stage`,
+        ``_phase_sync``, ``_fetch_behind``, ``_fetch`` and
+        ``_dp_send``.  Those threads are the server's own
+        (:class:`_DeviceWaits`): the gauge ``device_waits_high`` is the
+        most calls the level had parked there at once, beside
+        ``device_wait_threads``."""
+        self._waits.take_high()  # this level's high-water mark from here
         with cs.obs.span("fss", level=level) as sp_fss:
             # dispatch time only: the FSS expansion itself overlaps the
             # exchange below (no sync — a block_until_ready here would
@@ -1621,9 +1864,12 @@ class CollectorServer:
                 else secure.level_chunks(B, S, W, path)
             )
             cs.obs.count("secure_chunks", len(chunks), level=level)
-            programs0 = cs.obs.counter_value(
-                "secure_chunk_programs", level=level
-            )
+            # what this pass adds to these goes into its ``secure_level``
+            # instant: programs handed over, and where they were waited for
+            passed = ("chunk_programs", "fetch_syncs", "phase_waits")
+            before = [
+                cs.obs.counter_value(f"secure_{n}", level=level) for n in passed
+            ]
             if cs._mesh is not None:
                 # per-level kernel layout: the active row-shard count (1
                 # = the degraded gather path) feeds the mesh report
@@ -1725,10 +1971,14 @@ class CollectorServer:
                 "secure_level", comp=cs.obs.name, level=int(level),
                 chunks=len(chunks), index_high=index_high,
                 # device programs of this pass's ``otext`` + ``b2a`` spans
-                # (the counter ``secure_chunk_programs``): 2 a chunk
-                programs=cs.obs.counter_value(
-                    "secure_chunk_programs", level=level
-                ) - programs0,
+                # (the counter ``secure_chunk_programs``): 2 a chunk; of
+                # them and the circuit's, how many a fetch's thread
+                # waited for and how many a stage awaited
+                **{
+                    n.removeprefix("chunk_"):
+                    cs.obs.counter_value(f"secure_{n}", level=level) - b
+                    for n, b in zip(passed, before)
+                },
                 string_bits=S, patterns=C,
                 t_rows_held=cs.obs.gauge_value(
                     "secure_t_rows_held_bytes", level=level
@@ -1744,13 +1994,17 @@ class CollectorServer:
                     out = kernel_shard.share_sums(
                         ks, count_field, vals, w, F_, C, N
                     )
-                    shares = await _fetch(out, cs.obs)
+                    shares = await _fetch(out, cs.obs, waits=self._waits)
             else:
                 vals = vals.reshape((F_, C, N) + count_field.limb_shape)
                 shares = await self._reduced_fetch(
                     cs, level, secure.node_share_sums,
                     count_field, vals, jnp.asarray(w),
                 )
+        # the most thread calls this server had parked in waits for the
+        # device at once in the level, beside the threads it has for them
+        cs.obs.gauge("device_waits_high", self._waits.take_high(), level=level)
+        cs.obs.gauge("device_wait_threads", self._waits.workers, level=level)
         cs.obs.observe(
             "level_latency", sp_fss.seconds + sp_gc.seconds + sp_field.seconds
         )
@@ -3642,21 +3896,37 @@ class CollectorServer:
         if self._peer is not None:
             self._peer.close()
         self._plane.close()
+        self._waits.close()
         for srv in srvs:
             await srv.wait_closed()
 
-    @staticmethod
-    def _keepalive(sock) -> None:
+    # TCP keepalive of a plane stream: idle seconds before the first
+    # probe, seconds between probes, probes unanswered before the
+    # stream is dead
+    PLANE_KEEPALIVE = (60, 20, 3)
+
+    @classmethod
+    def _plane_silence_s(cls) -> float:
+        """Seconds a silent peer keeps a plane stream (``_keepalive``):
+        the bound of a wait on the data plane, and of a fetch stage's
+        wait for its chunk (``_fetch_taken``)."""
+        idle, interval, probes = cls.PLANE_KEEPALIVE
+        return float(idle + interval * probes)
+
+    @classmethod
+    def _keepalive(cls, sock) -> None:
         """Aggressive-ish TCP keepalive on a stream of the persistent
         data plane so a SILENTLY dead peer (partition, power loss — no
         FIN/RST) surfaces as a connection error within ~2 minutes
-        instead of hanging a blocked send or receive forever (kernels
-        default to ~2 hours).  It is what bounds the streams' threads."""
+        (``_plane_silence_s``) instead of hanging a blocked send or
+        receive forever (kernels default to ~2 hours).  It is what
+        bounds the streams' threads."""
         import socket
 
         sock.setsockopt(socket.SOL_SOCKET, socket.SO_KEEPALIVE, 1)
-        for opt, val in (
-            ("TCP_KEEPIDLE", 60), ("TCP_KEEPINTVL", 20), ("TCP_KEEPCNT", 3)
+        for opt, val in zip(
+            ("TCP_KEEPIDLE", "TCP_KEEPINTVL", "TCP_KEEPCNT"),
+            cls.PLANE_KEEPALIVE,
         ):
             if hasattr(socket, opt):
                 sock.setsockopt(socket.IPPROTO_TCP, getattr(socket, opt), val)

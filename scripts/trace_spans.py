@@ -24,7 +24,9 @@ object:
 - ``secure_levels``: per component, over its ``secure_level`` instants
   (one a secure level), the most chunks a level crossed in, the most
   device programs one handed over inside its ``otext`` + ``b2a`` spans
-  (the counter ``secure_chunk_programs``: 2 a chunk), the most
+  (the counter ``secure_chunk_programs``: 2 a chunk), the most of them
+  waited for on a fetch's thread and the most awaited by their stage
+  (``secure_fetch_syncs``, ``secure_phase_waits``), the most
   device bytes its evaluator held in unopened chunks (the gauge
   ``secure_t_rows_held_bytes``), the high word of the OT pad index
   (the gauge ``ot_index_high``) and the widest shape a level had (bits
@@ -151,7 +153,9 @@ def secure_levels(events: list) -> dict:
     level: protocol/rpc.py ``_crawl_counts_secure``): the levels, the
     most chunks a level crossed in, the most device programs of its
     ``otext`` + ``b2a`` spans (counter ``secure_chunk_programs``), the
-    most device bytes its evaluator
+    most of them a fetch's thread waited for and the most a stage
+    awaited (counters ``secure_fetch_syncs``, ``secure_phase_waits``),
+    the most device bytes its evaluator
     held in unopened chunks (gauge ``secure_t_rows_held_bytes``), the
     high word of the 64-bit OT pad index (gauge ``ot_index_high``) and
     the most bits a test compared and patterns a node had (gauges
@@ -162,14 +166,17 @@ def secure_levels(events: list) -> dict:
             a = e["args"]
             row = out.setdefault(e["comp"], {
                 "levels": 0, "chunks_max": 0, "secure_chunk_programs_max": 0,
+                "secure_fetch_syncs_max": 0, "secure_phase_waits_max": 0,
                 "t_rows_held_bytes_max": 0,
                 "ot_index_high": 0, "string_bits_max": 0,
                 "child_patterns_max": 0})
             row["levels"] += 1
             row["chunks_max"] = max(row["chunks_max"], a["chunks"])
             # a span log older than the counter has no ``programs``
-            row["secure_chunk_programs_max"] = max(
-                row["secure_chunk_programs_max"], a.get("programs", 0))
+            for arg, name in (("programs", "secure_chunk_programs"),
+                              ("fetch_syncs", "secure_fetch_syncs"),
+                              ("phase_waits", "secure_phase_waits")):
+                row[f"{name}_max"] = max(row[f"{name}_max"], a.get(arg, 0))
             row["t_rows_held_bytes_max"] = max(
                 row["t_rows_held_bytes_max"], a["t_rows_held"])
             row["ot_index_high"] = max(row["ot_index_high"], a["index_high"])

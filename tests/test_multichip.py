@@ -649,9 +649,9 @@ def test_co_resident_sharded_servers_keep_to_their_own_devices(
 
     real_fetch = rpc._fetch
 
-    async def fetch(x, reg, level=None):
+    async def fetch(x, reg, level=None, waits=None):
         note(reg.name, "fetched", x)
-        return await real_fetch(x, reg, level)
+        return await real_fetch(x, reg, level, waits)
 
     monkeypatch.setattr(rpc, "_fetch", fetch)
     real_h2d = rpc.CollectorServer._h2d

@@ -8,8 +8,10 @@ level are those of the level gone whole (K = 1), bit for bit, on both
 equality paths and with either server garbling; the chunks' frames put
 side by side are the whole level's two messages; ``secure_chunks``
 counts K; a plane cut mid-level fails the verb, leaves no task behind
-and the level run again gives the exact counts; a peer that cut the
-level differently gets ``ConnectionError`` and nobody hangs.
+and the level run again gives the exact counts, and so does a chunk's
+device program or fetch thread that fails; a stage runs ahead of the
+device by its queues and no further; a peer that cut the level
+differently gets ``ConnectionError`` and nobody hangs.
 """
 
 import asyncio
@@ -27,7 +29,8 @@ from fuzzyheavyhitters_tpu.protocol.leader_rpc import RpcLeader
 from fuzzyheavyhitters_tpu.utils import bits as bitutils
 from fuzzyheavyhitters_tpu.utils.config import Config
 
-BASE_PORT = 30731  # a range of its own, under the ephemeral ports
+BASE_PORT = 30731  # a range of its own (.. 31622: tests/test_resilience.py begins at 31631), under the ephemeral ports
+WAITS_PORT = 32631  # a second one (.. 32702: above tests/test_stage_account.py's), for the tests of a chunk's waits
 BLOCK = gc_pallas.R_BLK * gc_pallas.GROUP  # tests of one planar block
 L = 3
 WHOLE = 1 << 40  # a frame budget no level of these tests reaches
@@ -330,6 +333,286 @@ def test_plane_cut_mid_level_fails_the_verb_and_the_retry_is_exact(monkeypatch):
     assert threads == [4, 0, 4]
     assert ks == [8, 8]  # four chunks each time
     got = np.asarray(FE62.canon(FE62.sub(again[0], again[1])))
+    assert np.array_equal(got[0], _root_counts(pts)) and not got[1:].any()
+
+
+def _fail_second(real, armed, mine=lambda *a, **kw: True):
+    """``real``, raising on the second of its calls that ``mine`` picks
+    once ``armed["calls"]`` is a number: the level's chunk 1."""
+
+    def failing(*args, **kw):
+        if armed["calls"] is not None and mine(*args, **kw):
+            armed["calls"] += 1
+            if armed["calls"] == 2:
+                armed["calls"] = None
+                raise RuntimeError("injected into chunk 1")
+        return real(*args, **kw)
+
+    return failing
+
+
+@pytest.mark.parametrize("fault", ["device_program", "fetch_thread"])
+def test_chunk_fault_mid_level_fails_both_verbs_and_the_retry_is_exact(monkeypatch, fault):
+    """Chunk 1 of 4 fails on the garbling server: the table's program
+    where ``build`` hands it to the device, or the thread that waits for
+    the chunk's programs and copies (nobody awaits either before the
+    fetch stage does).  That server's verb fails with the injected
+    error and every sibling task goes with it; its peer, which waits
+    for a message that will not come, fails when the plane is broken
+    (the leader's quiesce), nobody hangs, no chunk task is left, and
+    after a plane reset the same level gives the exact counts."""
+    port = BASE_PORT + 880
+    monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", BLOCK * 64)
+    armed = {"calls": None}
+    if fault == "device_program":
+        monkeypatch.setattr(
+            secure, "gb_chunk_table", _fail_second(secure.gb_chunk_table, armed))
+    else:
+        # the garbling server's fetch: two programs to wait for
+        monkeypatch.setattr(rpc, "_fetch_on_thread", _fail_second(
+            rpc._fetch_on_thread, armed,
+            lambda x, waits=(), note=None: len(waits) == 2))
+
+    async def run():
+        async with _Pair(port, 4096) as pair:
+            await pair.both("tree_init", {"root_bucket": 4})
+            armed["calls"] = 0
+            tasks0 = asyncio.all_tasks()
+            calls = [
+                asyncio.ensure_future(
+                    c.call("tree_crawl", {"level": 0, "garbler": 0}))
+                for c in (pair.c0, pair.c1)
+            ]
+            done, _ = await asyncio.wait(
+                calls, timeout=60, return_when=asyncio.FIRST_COMPLETED)
+            first = [c in done for c in calls]
+            await pair.both("plane_break")
+            res = await asyncio.wait_for(
+                asyncio.gather(*calls, return_exceptions=True), 60)
+            await asyncio.sleep(0.05)
+            left = [
+                t for t in asyncio.all_tasks() - tasks0
+                if "_chunks" in repr(t.get_coro())
+            ]
+            await pair.both("plane_reset")
+            again = await pair.level(0)
+            ks = [
+                cs.obs.counter_value("secure_chunks", level=0)
+                for cs in pair.sessions
+            ]
+            return first, res, left, again, ks, pair.pts
+
+    first, res, left, again, ks, pts = _run(run())
+    assert first == [True, False]  # the garbling server's verb, at once
+    assert all(isinstance(r, Exception) for r in res), res
+    assert "injected into chunk 1" in str(res[0]), res
+    assert armed["calls"] is None and not left
+    assert ks == [8, 8]  # four chunks each time
+    got = np.asarray(FE62.canon(FE62.sub(again[0], again[1])))
+    assert np.array_equal(got[0], _root_counts(pts)) and not got[1:].any()
+
+
+def test_a_stage_runs_ahead_of_the_device_by_its_queues_and_no_further(monkeypatch):
+    """K = 32: ``build`` and ``extend`` await nothing between a chunk's
+    dispatch and its ``put``, so they run ahead of the device, but only
+    as far as the queues that were there let them: chunks dispatched
+    less chunks handed to the data plane never pass the two queues of
+    two (``built`` / ``made``, ``fetched``) and the one in each of the
+    two stages' hands."""
+    from fuzzyheavyhitters_tpu.parallel.kernel_shard import n_msg_planes
+
+    n, S, W, K = 4096, 2, secure.payload_words(FE62), 32
+    per_test = max(16 * S, 4 * n_msg_planes("ot2s", S, W))
+    monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", BLOCK * per_test)
+    ahead = {"gb": [0, 0], "ev": [0, 0]}  # role: now, the high-water mark
+
+    def counting(role, real):
+        def spy(*args, **kw):
+            ahead[role][0] += 1
+            ahead[role][1] = max(ahead[role])
+            return real(*args, **kw)
+        return spy
+
+    monkeypatch.setattr(
+        secure, "gb_chunk_table", counting("gb", secure.gb_chunk_table))
+    monkeypatch.setattr(
+        secure, "ev_chunk_extend", counting("ev", secure.ev_chunk_extend))
+    real_send = rpc.CollectorServer._dp_send
+
+    async def handed(self, cs, obj):
+        if isinstance(obj, tuple) and obj[1] == K:
+            ahead["gb" if obj[2].ndim == 1 else "ev"][0] -= 1
+        await real_send(self, cs, obj)
+
+    monkeypatch.setattr(rpc.CollectorServer, "_dp_send", handed)
+
+    async def run():
+        async with _Pair(WAITS_PORT + 60, n) as pair:
+            await pair.both("tree_init", {"root_bucket": 32})
+            shares = await pair.level(0, path="ot2s")
+            ks = [cs.obs.counter_value("secure_chunks", level=0)
+                  for cs in pair.sessions]
+            return shares, ks, pair.pts
+
+    shares, ks, pts = _run(run())
+    assert ks == [K, K]
+    for role in ("gb", "ev"):
+        now, high = ahead[role]
+        assert now == 0 and 1 <= high <= 2 + 2 + 2, (role, ahead)
+    got = np.asarray(FE62.canon(FE62.sub(shares[0], shares[1])))
+    assert np.array_equal(got[0], _root_counts(pts)) and not got[1:].any()
+
+
+def test_a_fault_in_the_recording_of_a_fetch_resolves_it_and_the_level_is_exact(monkeypatch):
+    """The callback that records a chunk's stamps raises (chunk 1 on
+    either server): the fetch stage gets its array all the same, the
+    level is exact, and the fault is counted (``secure_account_errors``;
+    the run report's ``account_errors``) and logged."""
+    from fuzzyheavyhitters_tpu.obs import report as obsreport
+
+    monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", BLOCK * 64)
+    armed = {"calls": None}
+    monkeypatch.setattr(
+        rpc.CollectorServer, "_record_fetch", staticmethod(_fail_second(
+            rpc.CollectorServer._record_fetch, armed,
+            lambda reg, *a: reg.name == "server0")))
+
+    async def run():
+        async with _Pair(WAITS_PORT, 4096) as pair:
+            await pair.both("tree_init", {"root_bucket": 4})
+            armed["calls"] = 0
+            shares = await asyncio.wait_for(pair.level(0), 120)
+            regs = [cs.obs for cs in pair.sessions]
+            return shares, regs, obsreport.run_report(regs), pair.pts
+
+    shares, regs, rep, pts = _run(run())
+    assert armed["calls"] is None
+    assert [r.counter_value("secure_account_errors", level=0) for r in regs] == [1, 0]
+    assert rep["secure_kernels"]["account_errors"] == 1
+    # the chunk whose recording failed is not in the count of syncs
+    assert regs[0].counter_value("secure_fetch_syncs", level=0) == 2 * 3
+    got = np.asarray(FE62.canon(FE62.sub(shares[0], shares[1])))
+    assert np.array_equal(got[0], _root_counts(pts)) and not got[1:].any()
+
+
+def test_a_fetch_thread_that_does_not_return_fails_both_verbs_within_the_bound(monkeypatch):
+    """Chunk 1's fetch on the garbling server parks for ever (a thread
+    call that never comes back from the device): ``msg_fetch`` gives up
+    at the bound the plane gives a silent peer (shortened here), its
+    verb fails with the stage and chunk it stood in, the peer's fails
+    with the leader's quiesce, no chunk task is left, and after a plane
+    reset the same level gives the exact counts."""
+    monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", BLOCK * 64)
+    release = threading.Event()
+    armed = {"calls": None}
+    real = rpc._fetch_on_thread
+
+    def parked(x, waits=(), note=None):
+        if armed["calls"] is not None and len(waits) == 2:
+            armed["calls"] += 1
+            if armed["calls"] == 2:
+                armed["calls"] = None
+                release.wait(120)
+        return real(x, waits, note)
+
+    monkeypatch.setattr(rpc, "_fetch_on_thread", parked)
+
+    async def run():
+        async with _Pair(WAITS_PORT + 20, 4096) as pair:
+            await pair.both("tree_init", {"root_bucket": 4})
+            before = pair.ot_state()
+            await pair.level(0)  # every program compiled, under the real bound
+            pair.set_ot_state(before)
+            keepalive = rpc.CollectorServer.PLANE_KEEPALIVE
+            monkeypatch.setattr(rpc.CollectorServer, "PLANE_KEEPALIVE", (1, 1, 2))
+            bound = rpc.CollectorServer._plane_silence_s()
+            armed["calls"] = 0
+            tasks0 = asyncio.all_tasks()
+            loop = asyncio.get_running_loop()
+            t0 = loop.time()
+            calls = [
+                asyncio.ensure_future(
+                    c.call("tree_crawl", {"level": 0, "garbler": 0}))
+                for c in (pair.c0, pair.c1)
+            ]
+            done, _ = await asyncio.wait(
+                calls, timeout=bound + 30, return_when=asyncio.FIRST_COMPLETED)
+            took = loop.time() - t0
+            first = [c in done for c in calls]
+            await pair.both("plane_break")
+            res = await asyncio.wait_for(
+                asyncio.gather(*calls, return_exceptions=True), 60)
+            await asyncio.sleep(0.05)
+            left = [
+                t for t in asyncio.all_tasks() - tasks0
+                if "_chunks" in repr(t.get_coro())
+            ]
+            timeouts = pair.sessions[0].obs.counter_value(
+                "device_wait_timeouts", level=0)
+            release.set()  # the thread comes back to a level that is over
+            monkeypatch.setattr(rpc.CollectorServer, "PLANE_KEEPALIVE", keepalive)
+            await pair.both("plane_reset")
+            again = await pair.level(0)
+            return bound, took, first, res, left, timeouts, again, pair.pts
+
+    try:
+        bound, took, first, res, left, timeouts, again, pts = _run(run())
+    finally:
+        release.set()
+    assert first == [True, False]  # the garbling server's verb, by itself
+    assert bound == 3.0 and bound <= took < bound + 20, took
+    assert all(isinstance(r, Exception) for r in res), res
+    assert "msg_fetch waited 3 s for chunk 1" in str(res[0]), res
+    assert timeouts == 1 and not left
+    got = np.asarray(FE62.canon(FE62.sub(again[0], again[1])))
+    assert np.array_equal(got[0], _root_counts(pts)) and not got[1:].any()
+
+
+def test_a_level_of_32_chunks_on_two_threads_each_is_the_whole_level(monkeypatch):
+    """The small-host case: each server's device-wait threads AND the
+    loop's default executor held to two workers.  A level of K = 32
+    finishes, its shares and cursors are the whole level's bit for bit,
+    and the waits that found no thread free show in
+    ``device_waits_high``."""
+    import concurrent.futures
+
+    from fuzzyheavyhitters_tpu.parallel.kernel_shard import n_msg_planes
+
+    n, S, W, K = 4096, 2, secure.payload_words(FE62), 32
+    small = BLOCK * max(16 * S, 4 * n_msg_planes("ot2s", S, W))
+
+    async def run():
+        asyncio.get_running_loop().set_default_executor(
+            concurrent.futures.ThreadPoolExecutor(2))
+        async with _Pair(WAITS_PORT + 40, n) as pair:
+            for s in (pair.s0, pair.s1):
+                s._waits.close()
+                s._waits = rpc._DeviceWaits(s.obs.name, 2)
+            await pair.both("tree_init", {"root_bucket": 32})
+            before = pair.ot_state()
+            monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", WHOLE)
+            whole = await pair.level(0, path="ot2s")
+            after_whole = pair.ot_state()
+            pair.set_ot_state(before)
+            monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", small)
+            cut = await asyncio.wait_for(pair.level(0, path="ot2s"), 300)
+            ks = [cs.obs.counter_value("secure_chunks", level=0)
+                  for cs in pair.sessions]
+            highs = [cs.obs.gauge_value("device_waits_high", level=0)
+                     for cs in pair.sessions]
+            threads = [cs.obs.gauge_value("device_wait_threads", level=0)
+                       for cs in pair.sessions]
+            return whole, after_whole, cut, pair.ot_state(), ks, highs, threads, pair.pts
+
+    whole, after_whole, cut, after_cut, ks, highs, threads, pts = _run(run())
+    assert ks == [1 + K, 1 + K] and threads == [2, 2]
+    # in flight at once: never more than a level can park
+    # (``DEVICE_WAITS``), whatever the threads there are for them
+    assert all(1 <= h <= rpc.CollectorServer.DEVICE_WAITS for h in highs), highs
+    for a, b in zip(whole, cut):
+        assert a.dtype == b.dtype and np.array_equal(a, b)
+    assert after_cut == after_whole
+    got = np.asarray(FE62.canon(FE62.sub(cut[0], cut[1])))
     assert np.array_equal(got[0], _root_counts(pts)) and not got[1:].any()
 
 

@@ -1,18 +1,28 @@
 """The wait account of the secure level's chunk pipeline (protocol/rpc.py
-``_Stage``, ``_timed``; ``_program`` / ``_phase_sync`` / ``_fetch`` /
-``_dp_send``): a CPU pair over real sockets, ``secure.CHUNK_FRAME_BYTES``
-patched small so that a level crosses in K > 1 chunks, as
-tests/test_secure_chunks.py does.
+``_Stage``, ``_timed``; ``_program`` / ``_phase_sync`` / ``_fetch_behind``
+/ ``_fetch`` / ``_dp_send``): a CPU pair over real sockets,
+``secure.CHUNK_FRAME_BYTES`` patched small so that a level crosses in
+K > 1 chunks, as tests/test_secure_chunks.py does.
 
 What is held, for every level and server: no stage waited longer than
-the level's ``gc_ot``; each stage's waits and the leaf spans of its work
-fill its wall; a span that waits on a thread is the sum of its three
-parts; the account writes no span-log line; and an untraced level writes
+the level's ``gc_ot``; a stage that dispatches (``build``, ``extend``)
+is its waits, its ``h2d`` and its dispatches and awaits nothing else; a
+stage that sends or opens is its waits and its leaf spans; a fetch stage
+does nothing between its waits but await the thread that waits for the
+chunk's programs and copies, so its busy seconds lie inside the program
+wait and ``d2h``; a span
+that waits on a thread is the sum of its three parts; one chunk's
+``otext``, ``b2a`` and ``d2h`` are disjoint and in order; the counters
+say where each program was waited for, and the gauge that no more
+waits for the device were parked at once than a server has threads for;
+the account writes no span-log line; and an untraced level writes
 nothing at all.
 """
 
 import asyncio
 import collections
+import sys
+import time
 
 import numpy as np
 import pytest
@@ -24,19 +34,21 @@ from fuzzyheavyhitters_tpu.protocol import rpc, secure
 
 from test_secure_chunks import BLOCK, L, WHOLE, _Pair, _points_2d, _root_counts
 
-BASE_PORT = 32331  # a range of its own (.. 32542: above test_rpc's 32231-32302), under the ephemeral ports
+BASE_PORT = 32331  # a range of its own (.. 32602: above test_rpc's 32231-32302; tests/test_secure_chunks.py's second range begins at 32631), under the ephemeral ports
 
 EV_STAGES = ("extend", "u_fetch", "u_send", "open")
 GB_STAGES = ("build", "msg_fetch", "msg_send")
 # what each stage does between its waits: the leaf spans and timers
+# (``dispatch``: the stage's own share of ``program_dispatch``, which
+# ``_dispatches`` reads; a fetch stage has no span of its own, below)
 WORK = {
-    "extend": ("otext",),
+    "extend": ("dispatch",),
     "open": ("h2d", "eval", "b2a"),
-    "build": ("h2d", "otext", "b2a", "garble"),
-    "u_fetch": ("d2h",), "msg_fetch": ("d2h",),
+    "build": ("h2d", "dispatch"),
     "u_send": ("wire_pickle", "wire_queue", "wire_write", "send_resume"),
     "msg_send": ("wire_pickle", "wire_queue", "wire_write", "send_resume"),
 }
+FETCH_STAGES = ("u_fetch", "msg_fetch")
 
 
 @pytest.fixture(autouse=True)
@@ -68,12 +80,37 @@ def _field_fetches(monkeypatch):
     return seen
 
 
+def _dispatches(monkeypatch):
+    """{(registry, level, stage task): seconds the task spent handing
+    work over}: inside its jitted calls (``program_dispatch`` by task)
+    and inside ``_fetch_behind`` (the copy queued, the thread called)."""
+    seen = collections.Counter()
+
+    def timed(real, first):
+        def spy(*args, **kw):
+            cs, level = args[first:first + 2]
+            t0 = time.perf_counter()
+            try:
+                return real(*args, **kw)
+            finally:
+                task = asyncio.current_task().get_coro().__name__
+                stage = {"consume": "open"}.get(task, task)
+                seen[cs.obs.name, level, stage] += time.perf_counter() - t0
+        return spy
+
+    cls = rpc.CollectorServer
+    monkeypatch.setattr(cls, "_fetch_behind", timed(cls._fetch_behind, 1))
+    monkeypatch.setattr(
+        cls, "_dispatched", staticmethod(timed(cls._dispatched, 0)))
+    return seen
+
+
 def _close(a, b):
     """Within 5% or 2 ms."""
     return abs(a - b) <= max(0.05 * max(a, b), 2e-3)
 
 
-def _check_account(reg, lv, stages, field_d2h, synced=True):
+def _check_account(reg, lv, stages, field_d2h, dispatched, synced=True, K=None):
     """Identities (a), (b) and (c) on one server's registry at one level."""
     t = lambda name: reg.timer_seconds(name, level=lv)
     gc_ot = t("gc_ot")
@@ -82,19 +119,36 @@ def _check_account(reg, lv, stages, field_d2h, synced=True):
         starved, blocked, wall = (t(f"{k}:{st}") for k in rpc.STAGE_TIMERS)
         # (a) no stage waited longer than the level took
         assert 0 <= starved + blocked <= gc_ot, (st, starved, blocked, gc_ot)
-        # (b) its waits and its work fill its wall, inside gc_ot
         assert 0 < wall <= gc_ot, (st, wall, gc_ot)
-        work = sum(t(name) for name in WORK[st])
-        if "d2h" in WORK[st]:
-            work -= field_d2h[reg.name, lv]
-        assert _close(starved + blocked + work, wall), (
-            st, starved, blocked, work, wall)
+        busy = wall - starved - blocked
+        if st in FETCH_STAGES:
+            # (b') between its waits a fetch stage awaits the chunk's
+            # thread: at most the program wait, the hand-over and d2h,
+            # and some of them where no chunk ran ahead to hide it
+            most = (t("program_device") + t("program_hop") + t("d2h")
+                    - field_d2h[reg.name, lv])
+            assert -1e-4 <= busy <= most + 2e-3, (st, busy, most)
+            assert K != 1 or busy > 0, (st, busy)
+            continue
+        # (b) its waits and its work fill its wall, inside gc_ot: a
+        # stage that dispatches awaits nothing but input and room
+        work = sum(
+            dispatched[reg.name, lv, st] if name == "dispatch" else t(name)
+            for name in WORK[st]
+        )
+        # (between the timed calls of a stage that dispatches, a thread
+        # that wakes may be handed the interpreter lock: twice a level
+        # at most is let pass)
+        gap = wall - starved - blocked - work
+        assert _close(starved + blocked + work, wall) or (
+            "dispatch" in WORK[st] and 0 <= gap <= 2 * sys.getswitchinterval() + 2e-3
+        ), (st, starved, blocked, work, wall)
     for st in set(EV_STAGES + GB_STAGES) - set(stages):
         assert t(f"stage_wall:{st}") == 0.0  # the other server's
     # (c) a span that waits on a thread is its three parts
     parts = t("program_dispatch") + t("program_device") + t("program_hop")
     assert _close(parts, t("otext") + t("b2a") + t("eval") + t("garble"))
-    assert t("program_dispatch") > 0
+    assert t("program_dispatch") > 0 and t("otext") > 0
     if synced:
         assert t("program_device") > 0
     else:
@@ -115,6 +169,10 @@ _CASES = {
     "whole_level": ("ot2s", 0, False, False, 1024, 4, 1, {}),
     "no_phase_sync": ("ot2s", 1, False, False, 4096, 4, 4,
                       {"secure_phase_sync": False}),
+    "gc_no_phase_sync": ("gc", 0, False, False, 4096, 4, 4,
+                         {"secure_phase_sync": False}),
+    "gc_whole_level": ("gc", 1, False, False, 1024, 4, 1, {}),
+    "eight_chunks": ("ot2s", 0, False, False, 4096, 8, 8, {}),
 }
 
 
@@ -129,6 +187,7 @@ def test_every_stage_accounts_for_its_level(monkeypatch, case):
     field = F255 if last else FE62
     lv = L - 1 if last else 0
     field_d2h = _field_fetches(monkeypatch)
+    dispatched = _dispatches(monkeypatch)
     monkeypatch.setattr(
         secure, "CHUNK_FRAME_BYTES",
         WHOLE if K == 1 else _frame_bytes(real_path, S, field),
@@ -147,11 +206,37 @@ def test_every_stage_accounts_for_its_level(monkeypatch, case):
 
     shares, regs, ks, rep, pts = asyncio.run(run())
     assert ks == [K, K]
+    synced = cfg.get("secure_phase_sync", True)
     for sid, reg in enumerate(regs):
         _check_account(
             reg, lv, GB_STAGES if sid == garbler else EV_STAGES, field_d2h,
-            synced=cfg.get("secure_phase_sync", True),
+            dispatched, synced=synced, K=K,
         )
+    # (e) where each program was waited for: the garbling server's on
+    # its fetch's thread (two a chunk, the circuit's a third), the other
+    # server's extension there too and what it opens by its stage (the
+    # circuit's evaluation and the field conversion are two); no waits
+    # at all without the sync, and the programs counted as before
+    gc = real_path == "gc"
+    for name, want in (
+        ("secure_chunk_programs", (2 * K, 2 * K)),
+        ("secure_fetch_syncs", ((3 if gc else 2) * K * synced, K * synced)),
+        ("secure_phase_waits", (0, (2 if gc else 1) * K * synced)),
+    ):
+        got = [r.counter_value(name, level=lv) for r in regs]
+        assert (got[garbler], got[1 - garbler]) == want, (name, got)
+    sk = rep["secure_kernels"]
+    assert sk["chunk_programs_by_level"] == {str(lv): 2 * K}
+    assert sk["fetch_syncs_by_level"] == (
+        {str(lv): (3 if gc else 2) * K} if synced else {})
+    assert sk["phase_waits_by_level"] == (
+        {str(lv): (2 if gc else 1) * K} if synced else {})
+    # (f) the waits for the device parked on the servers' own threads:
+    # never more at once than a level can have in flight, which is the
+    # threads a server has for them; and every fetch was recorded
+    assert sk["device_wait_threads"] == rpc.CollectorServer.DEVICE_WAITS
+    assert 1 <= sk["device_waits_high"] <= sk["device_wait_threads"]
+    assert sk["account_errors"] == 0
     if not last and not two_d:
         got = np.asarray(FE62.canon(FE62.sub(shares[0], shares[1])))
         assert np.array_equal(got[0], _root_counts(pts)) and not got[1:].any()
@@ -169,6 +254,10 @@ def test_every_stage_accounts_for_its_level(monkeypatch, case):
                 - row["blocked_seconds"],
             )
             assert 0 <= row["busy_share_of_gc_ot"] <= 1.0
+            # a fetch stage only awaits its chunk's thread: the report
+            # adds none of that thread's spans to it (its busy seconds
+            # are its wall less its waits, never below zero)
+            assert row["busy_seconds"] >= -1e-4, (st, row)
         assert mine["pace_setter"] in want
         assert set(mine["split"]) == {
             "program_dispatch", "program_device", "program_hop",
@@ -217,19 +306,36 @@ def test_the_account_writes_no_span_log_line(trace_dir, monkeypatch):
     handed not one span more."""
     monkeypatch.setattr(
         secure, "CHUNK_FRAME_BYTES", _frame_bytes("ot2s", 2, FE62))
-    regs = asyncio.run(_one_level(BASE_PORT + 160))
+    regs = asyncio.run(_one_level(BASE_PORT + 220))
     tracemod.flush()
-    lines = collections.Counter(
-        e["name"] for e in tracemod.load_events(str(trace_dir))
-        if e["ph"] == "X"
-    )
+    events = [e for e in tracemod.load_events(str(trace_dir)) if e["ph"] == "X"]
+    lines = collections.Counter(e["name"] for e in events)
     assert dict(lines) == _PARENT_LINES
+    # (d) one chunk's spans are stamped on its fetch's thread, disjoint
+    # and in order: on the garbling server otext ends where b2a (which
+    # is ot2s) begins and b2a where the message's d2h begins; on the
+    # other otext ends where u's d2h begins (its b2a is the opening's)
+    at = {
+        (e["comp"], e["chunk"], e["name"]): (e["ts"], e["ts"] + e["dur"])
+        for e in events if "chunk" in e
+    }
+    same = lambda a, b: abs(a - b) <= 3e-6  # the log rounds to 1 us
+    for k in range(4):
+        otext, b2a, ot2s, d2h = (
+            at["server0", k, name] for name in ("otext", "b2a", "ot2s", "d2h"))
+        assert otext[0] < otext[1] and same(otext[1], b2a[0])
+        assert b2a[0] < b2a[1] and same(b2a[1], d2h[0]) and d2h[0] < d2h[1]
+        assert same(ot2s[0], b2a[0]) and same(ot2s[1], b2a[1])
+        otext, d2h = at["server1", k, "otext"], at["server1", k, "d2h"]
+        assert otext[0] < otext[1] and same(otext[1], d2h[0]) and d2h[0] < d2h[1]
     # and the timers are there all the same: every name the benchmark's
     # twelve metric files of the account read, on one server or the other
     for reg in regs:
         assert reg.timer_seconds("program_dispatch", level=0) > 0
         assert reg.timer_seconds("d2h_copy", level=0) > 0
         assert reg.counter_value("secure_chunk_programs", level=0) == 8
+    assert [reg.counter_value("secure_fetch_syncs", level=0) for reg in regs] == [8, 4]
+    assert [reg.counter_value("secure_phase_waits", level=0) for reg in regs] == [0, 4]
     recorded = {name for reg in regs for name in reg.report()["phases"]}
     from test_benchmark_files import _STAGE_ACCOUNT, _load
 
@@ -247,7 +353,7 @@ def test_an_untraced_level_writes_nothing(tmp_path, monkeypatch):
     monkeypatch.setattr(tracemod, "_ANNOTATION", None)
     monkeypatch.setattr(
         secure, "CHUNK_FRAME_BYTES", _frame_bytes("ot2s", 2, FE62))
-    regs = asyncio.run(_one_level(BASE_PORT + 180))
+    regs = asyncio.run(_one_level(BASE_PORT + 240))
     tracemod.flush()
     assert tracemod.enabled() is False
     assert tracemod._WRITER is None and tracemod._ANNOTATION is None
@@ -262,7 +368,7 @@ def test_the_span_log_is_whole_when_a_verb_has_answered(trace_dir):
     no flush) finds every span of the verb on disk."""
 
     async def run():
-        async with _Pair(BASE_PORT + 200, 1024) as pair:
+        async with _Pair(BASE_PORT + 260, 1024) as pair:
             with tracemod.root("crawl"):
                 await pair.both("tree_init", {"root_bucket": 4})
                 await pair.level(0, path="ot2s")
