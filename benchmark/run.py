@@ -3,10 +3,11 @@
     python benchmark/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 from the root of a checkout, on a machine that holds the chip.  One process:
-fail unless JAX sees a TPU (no fallback); draw the clients' points from the
-seed; generate both servers' keys on the device; bring the collector pair and
-the leader up over localhost sockets; upload the keys (timed: the ingest
-reading); warm up by one crawl through the widening levels and the servers'
+set the allocator's thresholds where the configuration states them
+(``process.malloc``); fail unless JAX sees a TPU (no fallback); draw the
+clients' points from the seed; generate both servers' keys on the device;
+bring the collector pair and the leader up over localhost sockets; upload the
+keys (timed: the ingest reading); warm up by one crawl through the widening levels and the servers'
 own warm-up of the leaf level's programs; then the window: a new crawl from
 level 0, level after level until ``--seconds`` have passed.  Once the window
 has closed the crawl in flight goes on, untimed, to its leaf level where that
@@ -49,6 +50,33 @@ import traffic  # noqa: E402
 def out_dir(cell_name: str) -> str:
     """Where a traced run keeps its capture and span log; git-ignored."""
     return os.path.join(manifest.ROOT, ".bench_out", cell_name)
+
+
+# glibc's mallopt parameters, by the names a configuration's ``process.malloc``
+# group uses
+_MALLOPT = {"trim_threshold": -1, "top_pad": -2, "mmap_threshold": -3}
+
+
+def pin_allocator(spec: dict | None) -> dict:
+    """Set glibc malloc's thresholds as the configuration's ``process.malloc``
+    group states them; nothing where it states none.  Left alone, malloc
+    moves its mmap and trim thresholds with the process's history, so whether
+    a request of 128 KiB to 32 MiB gets new pages (1 ms a MB at their first
+    touch on the chip's host) or its own heap differs from process to
+    process: the upload and the trusted level run at one of two speeds
+    (PERF.md section 6, PRs 29 and 42).  Setting any of them ends the moving.
+    Called once the cell is known, before the run allocates anything in bulk
+    (points, keys, frames); a threshold that cannot be set fails the run."""
+    import ctypes
+
+    done = {}
+    for name, value in sorted((spec or {}).items()):
+        if name not in _MALLOPT:
+            continue  # prose beside the numbers ("what")
+        if ctypes.CDLL("libc.so.6").mallopt(_MALLOPT[name], int(value)) != 1:
+            raise RuntimeError(f"mallopt({name}, {value}) was refused")
+        done[name] = int(value)
+    return done
 
 
 class NoChip(RuntimeError):
@@ -129,6 +157,7 @@ class Capture:
         files = sorted(glob.glob(os.path.join(self.dir, "plugins", "profile", "*", "*.xplane.pb")))
         if self.state != "done" or not files:
             return None
+        t_read = time.perf_counter()
         cap = trace_reduce.read_capture(files[-1])
         spans = []
         offset = trace_reduce.sync_offset_ns(cap, self.wall_ns_at_sync)
@@ -136,12 +165,14 @@ class Capture:
             for path in sorted(glob.glob(os.path.join(fhh_dir, "fhh_trace_*.jsonl*"))):
                 with open(path, encoding="utf-8") as f:
                     spans += trace_reduce.program_spans(f, offset, set(span_names))
+        t_reduce = time.perf_counter()
         out = trace_reduce.reduce(cap, spans)
         log(phase="capture", file=os.path.relpath(files[-1], manifest.ROOT),
             wall_ns_at_sync=self.wall_ns_at_sync, program_spans=len(spans),
-            planes=cap["planes"],
+            planes=cap["planes"], read_s=t_reduce - t_read,
+            reduce_s=time.perf_counter() - t_reduce,
             **({"note": "no device plane with operations"} if out is None else
-               {k: out[k] for k in ("busy_s", "window_s", "levels_in_capture")}))
+               {k: out[k] for k in ("busy_s", "window_s", "levels_in_capture", "gap_search_s")}))
         return out
 
 
@@ -452,6 +483,7 @@ def main(argv=None) -> int:
     if args.seed < 0 or args.seconds <= 0:
         p.error("--seed is a whole number >= 0 and --seconds is > 0")
     cell = manifest.cell(args.workload)
+    log(phase="process", malloc=pin_allocator(cell.config.get("process", {}).get("malloc")))
     if args.trace:
         # the program writes its spans with wall-clock times where
         # FHH_TRACE_DIR says (obs/trace.py, read at its first use); the

@@ -29,7 +29,9 @@ which ``sync_offset_ns`` turns into the offset of wall-clock spans.
 
 from __future__ import annotations
 
+import heapq
 import json
+import time
 
 DEVICE_PLANE_PREFIX = "/device:TPU:"
 OPS_LINE = "XLA Ops"
@@ -117,6 +119,26 @@ def sync_offset_ns(capture: dict, wall_ns_at_sync: int):
     return capture["sync"][0][0] - wall_ns_at_sync
 
 
+def _owners(mids: list, spans: list) -> list:
+    """For each of the ascending ``mids`` the name of the innermost span open
+    there, or None: the shortest of the ``(name, start, end)`` that hold it,
+    both ends counted in, and of several that short the first in ``spans``.
+    One sweep in time order: a span joins the heap when a middle has reached
+    its start and leaves from the top once a middle has passed its end (one
+    that has ended deeper down is never the answer before it gets there)."""
+    # (start, then the heap's key: length, place in spans; end, name)
+    waiting = sorted((a, b - a, i, b, name) for i, (name, a, b) in enumerate(spans))
+    open_, nxt, out = [], 0, []
+    for mid in mids:
+        while nxt < len(waiting) and waiting[nxt][0] <= mid:
+            heapq.heappush(open_, waiting[nxt][1:])
+            nxt += 1
+        while open_ and open_[0][2] < mid:
+            heapq.heappop(open_)
+        out.append(open_[0][3] if open_ else None)
+    return out
+
+
 def reduce(capture: dict, host_spans: list = ()) -> dict | None:
     """busy_s, window_s, device_ops and idle_gaps of a capture, or None
     where it holds no device plane with operations."""
@@ -143,15 +165,18 @@ def reduce(capture: dict, host_spans: list = ()) -> dict | None:
     edges = [lo] + [t for iv in first for t in iv] + [hi]
     gaps = [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2)
             if edges[i + 1] > edges[i]]
-    spans = sorted(host_spans, key=lambda sp: sp[2] - sp[1])  # innermost first
+    t_search = time.perf_counter()
+    mids = [(s + e) / 2 for s, e in gaps]
+    # a span that ends before the window or starts after it holds no gap's
+    # middle: a traced run's log is the whole process's, the capture 4 s of it
+    owners = _owners(mids, [sp for sp in host_spans if sp[2] >= lo and sp[1] <= hi])
     by_name: dict = {}
-    for s, e in gaps:
-        mid = (s + e) / 2
-        owner = next((n for n, a, b in spans if a <= mid <= b), None)
+    for (s, e), mid, owner in zip(gaps, mids, owners):
         if owner is None:
             in_level = any(a <= mid <= b for a, b in capture["levels"])
             owner = LEVEL_EVENT if in_level else "between levels"
         by_name[owner] = by_name.get(owner, 0.0) + (e - s)
+    search_s = time.perf_counter() - t_search
 
     def top(d):
         return [[k, v / 1e9] for k, v in
@@ -164,6 +189,7 @@ def reduce(capture: dict, host_spans: list = ()) -> dict | None:
         "levels_in_capture": len(capture["levels"]),
         "device_ops": top(ops),
         "idle_gaps": top(by_name),
+        "gap_search_s": search_s,
     }
 
 

@@ -1,17 +1,31 @@
 """The one general generator: client points from a configuration and a seed,
 and the plan of warm-up, window and capture from a traffic mix's data file.
 
-The zipf client simulation is a copy of the program's
-(``fuzzyheavyhitters_tpu/workloads/strings.py`` + ``AUG_LEN`` of
-``workloads/__init__.py``; ref: leader.rs:38-66, 130-151), so that a later
-change to the program's sampler cannot change what a cell is asked to do:
-sites, popularity and low bits are all drawn from ``--seed``, draw for draw as
-the program draws them.  The original is listed in PERF.md.
+Each client simulation is a copy of the program's, so that a later change to
+the program's sampler cannot change what a cell is asked to do, and every
+draw comes from the run's ``rng`` (``--seed``), draw for draw as the program
+draws them from its own generator:
+
+- ``zipf``: ``fuzzyheavyhitters_tpu/workloads/strings.py`` + ``AUG_LEN`` of
+  ``workloads/__init__.py`` (ref: leader.rs:38-66, 130-151): sites,
+  popularity and low bits;
+- ``rides``: ``workloads/rides.synthetic_austin_locations`` +
+  ``utils/bits.i16_to_ob_bits`` (ref: sample_driving_data.rs; the RideAustin
+  CSV is in neither tree, the clustered stand-in is the program's own);
+- ``covid``: the branch of ``workloads/covid.sample_covid_locations`` that
+  runs where the 9 GB case file is absent, as it is from the reference's
+  tree (ref: sample_covid_data.rs), over ``benchmark/data/county_centroids.csv``,
+  a copy of the shipped ``data/county_centroids.csv``.
+
+The originals are listed in PERF.md; ``benchmark/tests/test_traffic.py`` holds
+each copy to its original.
 """
 
 from __future__ import annotations
 
+import csv
 import dataclasses
+import os
 import string
 
 import numpy as np
@@ -61,7 +75,78 @@ def zipf_points(rng: np.random.Generator, *, num_sites: int, data_len: int,
     return _augment(rng, sites[idx])
 
 
-DISTRIBUTIONS = {"zipf": zipf_points}
+def _geo_only(name: str, data_len: int, n_dims: int, want_len: int) -> None:
+    if (data_len, n_dims) != (want_len, 2):
+        raise ValueError(
+            f"{name} points are (latitude, longitude) of {want_len} bits each: "
+            f"data_len {data_len}, n_dims {n_dims}")
+
+
+def _msb_first(words: np.ndarray) -> np.ndarray:
+    """Whole-byte words [...] -> bool[..., bits of a word], the most
+    significant bit first."""
+    big = words.astype(words.dtype.newbyteorder(">"))
+    return np.unpackbits(big.view(np.uint8).reshape(*words.shape, -1), axis=-1).astype(bool)
+
+
+RIDES_CENTRE = (3026, -9774)  # downtown Austin (30.26, -97.74) in centidegrees
+RIDES_HOTSPOTS = 6
+
+
+def rides_points(rng: np.random.Generator, *, data_len: int, n_dims: int,
+                 clients: int, **_) -> np.ndarray:
+    """bool[clients, 2, 16]: pickups clustered on ``RIDES_HOTSPOTS`` hotspots
+    within 0.60 degrees of downtown Austin, a pickup its hotspot plus a
+    rounded normal of one centidegree (some 1.1 km), as i16 centidegrees in
+    offset binary (the sign bit flipped, so that the strings sort as the
+    values do), MSB first."""
+    _geo_only("rides", data_len, n_dims, 16)
+    hot = np.array(RIDES_CENTRE) + rng.integers(-60, 60, size=(RIDES_HOTSPOTS, 2))
+    idx = rng.integers(0, RIDES_HOTSPOTS, size=clients)
+    pts = hot[idx] + rng.normal(0, 1.0, size=(clients, 2)).round().astype(int)
+    pts = np.clip(pts, -32768, 32767).astype(np.int16)
+    return _msb_first(pts.view(np.uint16) ^ np.uint16(0x8000))
+
+
+CENTROIDS_CSV = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                             "data", "county_centroids.csv")
+KM_PER_DEGREE = 111.32
+
+
+def _centroids() -> np.ndarray:
+    """float64[counties, 2] (latitude, longitude), in the order of the FIPS
+    codes (the file begins with a UTF-8 BOM; a code met twice keeps its last
+    row, as the program's dict does)."""
+    by_fips = {}
+    with open(CENTROIDS_CSV, newline="", encoding="utf-8-sig") as f:
+        for row in csv.DictReader(f):
+            by_fips[row["fips_code"]] = (float(row["latitude"]), float(row["longitude"]))
+    return np.array([by_fips[k] for k in sorted(by_fips)], dtype=np.float64)
+
+
+def covid_points(rng: np.random.Generator, *, data_len: int, n_dims: int,
+                 clients: int, **_) -> np.ndarray:
+    """bool[clients, 2, 64]: a county drawn uniformly with replacement for
+    each client, its centroid moved uniformly inside a square of ``AUG_LEN``
+    km a side at that latitude, each coordinate as the 64 IEEE-754 bits of
+    its f64, MSB first (the reference's tree domain for this workload is the
+    raw float bit pattern).  One ``rng.uniform`` over ``[clients, 2]`` draws
+    what the program draws client by client, latitude then longitude."""
+    _geo_only("covid", data_len, n_dims, 64)
+    centroids = _centroids()
+    half_km = AUG_LEN / 2.0
+    # a county's half sides in degrees, by the program's own scalar
+    # expressions (an array cosine may round its last bit another way)
+    half = np.array([(half_km / KM_PER_DEGREE,
+                      half_km / (KM_PER_DEGREE * np.cos(np.radians(lat))))
+                     for lat, _ in centroids.tolist()])
+    take = rng.choice(len(centroids), size=clients, replace=True)
+    moved = centroids[take] + rng.uniform(-half[take], half[take])
+    moved = np.clip(moved, (-90.0, -180.0), (90.0, 180.0))
+    return _msb_first(moved)
+
+
+DISTRIBUTIONS = {"zipf": zipf_points, "rides": rides_points, "covid": covid_points}
 
 
 def client_points(config: dict, clients: int, rng: np.random.Generator) -> np.ndarray:
