@@ -11,9 +11,10 @@ keys (timed: the ingest reading); warm up by one crawl through the widening leve
 own warm-up of the leaf level's programs; then the window: a new crawl from
 level 0, level after level until ``--seconds`` have passed.  Once the window
 has closed the crawl in flight goes on, untimed, to its leaf level where that
-fits the mix's ``tail``; then every level's frontier and counts, the tail's
-too, are compared with the configuration's plain reference over the same
-points.  The last line of
+fits the mix's ``tail`` (a mix of whole crawls, ``window.close_on: "crawl"``,
+closes its window where the crawl in flight ends instead, and has no tail);
+then every level's frontier and counts, the tail's too, are compared with the
+configuration's plain reference over the same points.  The last line of
 standard output is the result object; see README.md beside this file.
 """
 
@@ -121,15 +122,17 @@ def memory_peak_bytes():
 
 
 class Capture:
-    """A profiler capture inside the window, started and stopped at level
-    boundaries (``--trace 1`` only)."""
+    """A profiler capture inside the window (``--trace 1`` only), started and
+    stopped where the window itself may close: at level boundaries, or where
+    a crawl ends under ``window.close_on: "crawl"``, so that such a mix's
+    capture holds whole crawls and no phase of one."""
 
     def __init__(self, out_dir: str, start_after_s: float, capture_s: float):
         self.dir, self.start_after_s, self.capture_s = out_dir, start_after_s, capture_s
         self.state, self.t_window, self.t_on = "waiting", None, None
         self.wall_ns_at_sync = None
 
-    def on_level(self, now: float) -> None:
+    def at_boundary(self, now: float) -> None:
         import jax
 
         if self.state == "waiting" and now - self.t_window >= self.start_after_s:
@@ -189,26 +192,38 @@ def _warm_rule(lead, plan):
 
 async def _window(lead, n, cfg, plan, seconds, capture, close):
     """The measured window and, after it, the untimed tail.  Returns (the
-    window's levels, its seconds, the tail's levels): the clock stops, and
-    ``close`` takes its readings, when the level in flight at the deadline
-    has finished.  The crawl in flight then goes on to its leaf level if the
-    levels left, at the median of the last fifty, fit ``plan.tail_max_s``:
-    so a run compares the final hitter set although no window holds a whole
-    crawl."""
+    window's levels, its seconds, the tail's levels, how it closed).
+
+    ``plan.close_on`` "level": the clock stops, and ``close`` takes its
+    readings, when the level in flight at the deadline has finished.  The
+    crawl in flight then goes on to its leaf level if the levels left, at the
+    median of the last fifty, fit ``plan.tail_max_s``: so a run compares the
+    final hitter set although no window holds a whole crawl.
+
+    ``plan.close_on`` "crawl", for a mix whose crawls are short and whose
+    levels cost from one to a hundred: no level stops anything; the crawl in
+    flight at the deadline runs to its own end, none is started after it, and
+    the clock stops when it has returned, so the window is whole crawls, each
+    with its hand-over, over their own seconds, and the reading has no phase
+    of the crawl in it.  A crawl that cannot end inside the run's time limit
+    is mis-paired with such a mix and the limit refuses the run.  How it
+    closed is a dict for the ``window`` log line (empty under "level")."""
     lead.records, lead.crawl = [], 0
     radix = max(1, int(cfg.crawl_radix_bits))
+    by_crawl = plan.close_on == "crawl"
     t_start = time.perf_counter()
     deadline = t_start + seconds
     closed_at = None  # levels on record when the window closed
+    whole, t_end = 0, None  # under "crawl": crawls that ran to their end, and when the last had
     if capture is not None:
         capture.t_window = t_start
 
     def after(rec) -> bool:
         nonlocal closed_at
-        if closed_at is not None:
-            return False  # the tail runs to the crawl's own end
+        if by_crawl or closed_at is not None:
+            return False  # a whole crawl, or the tail, runs to the crawl's own end
         if capture is not None:
-            capture.on_level(rec.t1)
+            capture.at_boundary(rec.t1)
         if time.perf_counter() < deadline:
             return False
         closed_at = len(lead.records)
@@ -221,17 +236,26 @@ async def _window(lead, n, cfg, plan, seconds, capture, close):
         try:
             ended = await lane.crawl(lead, n, after)
         except Exception as e:  # the level is on record with its error; the run ends
+            ended = False
             log(phase="window", error=f"{type(e).__name__}: {e}")
             break
+        if by_crawl and ended:
+            whole, t_end = whole + 1, time.perf_counter()
         if (not ended or closed_at is not None or not plan.restart_when_crawl_ends
                 or time.perf_counter() >= deadline):
             break
+        if by_crawl and capture is not None:
+            capture.at_boundary(t_end)
     if closed_at is None:
         closed_at = len(lead.records)
         close()
     levels, tail = lead.records[:closed_at], lead.records[closed_at:]
-    t_end = levels[-1].t1 if levels else time.perf_counter()
-    return levels, t_end - t_start, tail
+    if not (by_crawl and ended):
+        t_end = levels[-1].t1 if levels else time.perf_counter()
+    # "level" under such a mix: the crawl in flight raised, and its level is the last
+    how = {"closed_on": "crawl" if ended else "level", "whole_crawls": whole,
+           "overrun_s": t_end - deadline}
+    return levels, t_end - t_start, tail, how if by_crawl else {}
 
 
 def _counters(servers: dict, names: dict) -> dict:
@@ -389,7 +413,8 @@ async def run_cell(cell, seed: int, seconds: float, trace: bool, *,
                 compile_s=compile_cache.backend_compile_seconds(),
                 peak=memory_peak_bytes())
 
-        levels, window_s, tail = await _window(lead, n, cfg, plan, seconds, capture, close)
+        levels, window_s, tail, how_closed = await _window(
+            lead, n, cfg, plan, seconds, capture, close)
         tail_s = tail[-1].t1 - tail[0].t0 if tail else 0.0
         tail_compiles = compile_cache.backend_compiles() - at_close["compiles"]
         engines = s0.engine_tags()
@@ -409,7 +434,7 @@ async def run_cell(cell, seed: int, seconds: float, trace: bool, *,
         warmup_buckets=sorted({lv.bucket for lv in warm}),
         setup_compiles=compiles0, setup_compile_s=compile_s0,
         compile_cache_dir=compile_cache.enable(), key_plane_bytes=key_plane_bytes)
-    log(phase="window", seconds=window_s, levels=len(levels),
+    log(phase="window", seconds=window_s, levels=len(levels), **how_closed,
         crawls=len({lv.crawl for lv in levels}),
         first_level=levels[0].level if levels else None,
         last_level=levels[-1].level if levels else None,

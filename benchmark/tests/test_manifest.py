@@ -123,3 +123,29 @@ def test_a_missing_file_or_name_is_refused(bench, tmp_path, monkeypatch):
 def test_an_unknown_reader_is_refused():
     with pytest.raises(ValueError, match="no reader"):
         readers.read({"name": "x", "reader": "nope"}, None)
+
+
+def _mixes():
+    folder = os.path.join(manifest.ROOT, "benchmark", "traffic")
+    return sorted(f[:-len(".json")] for f in os.listdir(folder) if f.endswith(".json"))
+
+
+@pytest.mark.parametrize("mix", _mixes())
+def test_every_committed_mix_is_one_the_generator_reads(mix):
+    with open(os.path.join(manifest.ROOT, "benchmark", "traffic", f"{mix}.json"),
+              encoding="utf-8") as f:
+        spec = json.load(f)
+    assert spec["name"] == mix and spec["why"]
+    plan = traffic.plan(spec)
+    # a mix that states no ``close_on`` closes at the level in flight
+    assert plan.close_on == spec["window"].get("close_on", "level")
+
+
+def test_an_unknown_close_on_is_refused_by_name():
+    with open(os.path.join(manifest.ROOT, "benchmark", "traffic", "whole-crawls.json"),
+              encoding="utf-8") as f:
+        spec = json.load(f)
+    assert traffic.plan(spec).close_on == "crawl"
+    spec["window"]["close_on"] = "hitter-set"
+    with pytest.raises(ValueError, match=r"close_on 'hitter-set'.*level.*crawl"):
+        traffic.plan(spec)

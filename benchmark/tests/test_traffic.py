@@ -41,6 +41,60 @@ def test_rides_points_are_the_programs():
     assert np.array_equal(got, want)
 
 
+def _centidegrees(points: np.ndarray) -> np.ndarray:
+    """bool[N, 2, 16] offset binary, MSB first -> int[N, 2] centidegrees."""
+    return np.packbits(points, axis=-1).view(">u2")[..., 0].astype(int) - 0x8000
+
+
+def test_the_rides_hotspots_are_the_programs_own():
+    """The one city is the one ``bin/leader`` is served with: the program's
+    stand-in at the seed ``workloads.sample_points`` gives it, 42."""
+    from fuzzyheavyhitters_tpu.workloads import rides
+
+    hot = traffic.RIDES_HOTSPOTS
+    assert hot.shape == (6, 2)
+    # what the program's sampler clusters around: every hotspot is the most
+    # frequent point of its neighbourhood, and no client lies far from all six
+    theirs = rides.synthetic_austin_locations(8192, seed=42).astype(int)
+    near = np.abs(theirs[:, None, :] - hot[None]).max(axis=-1)
+    assert (near.min(axis=1) <= 5).all()
+    for k, h in enumerate(hot):
+        mine = theirs[near.argmin(axis=1) == k]
+        vals, counts = np.unique(mine, axis=0, return_counts=True)
+        assert np.array_equal(vals[counts.argmax()], h)
+
+
+def test_rides_points_are_one_city_whatever_the_seed():
+    """The seed draws the clients (which hotspot, the jitter), never the
+    geography."""
+    hot = traffic.RIDES_HOTSPOTS
+    draws = [_centidegrees(traffic.client_points(_group("config"), 4096, np.random.default_rng(seed)))
+             for seed in (0, 1, 2, 3, 42, 97531, 2**31 + 5, 2**32 + 1)]
+    for pts in draws:
+        near = np.abs(pts[:, None, :] - hot[None]).max(axis=-1)
+        assert (near.min(axis=1) <= 5).all()
+        assert len(set(near.argmin(axis=1).tolist())) == 6
+    assert not np.array_equal(draws[0], draws[1])
+
+
+def test_the_rides_frontier_is_the_same_work_on_every_seed():
+    """What the fixed city is for: the nodes alive at every depth of the
+    shipped rides deployment (ball 1, threshold 0.075), and so the bucket of
+    every level of a crawl, do not depend on the seed."""
+    import manifest
+
+    ref = manifest.reference({"reference": "linf_ball_nd"})
+    group, n = _group("config"), 32768
+    ball, thresh = group["config"]["ball_size"], int(group["config"]["threshold"] * n)
+    alive = {
+        tuple(len(f) for f in ref.frontiers(
+            traffic.client_points(group, n, np.random.default_rng(seed)), ball, thresh, 16))
+        for seed in (0, 1, 2, 42, 2**31 + 5, 2**32 + 1)}
+    assert len(alive) == 1
+    by_depth = alive.pop()
+    assert by_depth[1] == 1 and by_depth[16] == 54  # nine leaf boxes a hotspot
+
+
 @pytest.mark.parametrize("seed", [0, 7, 2**31 + 5])
 def test_covid_points_are_the_programs(seed, tmp_path):
     """Against the program's sampler where the 9 GB case file is absent, on

@@ -11,7 +11,9 @@ draws them from its own generator:
   popularity and low bits;
 - ``rides``: ``workloads/rides.synthetic_austin_locations`` +
   ``utils/bits.i16_to_ob_bits`` (ref: sample_driving_data.rs; the RideAustin
-  CSV is in neither tree, the clustered stand-in is the program's own);
+  CSV is in neither tree, the clustered stand-in is the program's own), with
+  the hotspots held to those of the one seed the program runs
+  (``RIDES_HOTSPOTS``): the seed draws the clients, not the city;
 - ``covid``: the branch of ``workloads/covid.sample_covid_locations`` that
   runs where the 9 GB case file is absent, as it is from the reference's
   tree (ref: sample_covid_data.rs), over ``benchmark/data/county_centroids.csv``,
@@ -90,19 +92,32 @@ def _msb_first(words: np.ndarray) -> np.ndarray:
 
 
 RIDES_CENTRE = (3026, -9774)  # downtown Austin (30.26, -97.74) in centidegrees
-RIDES_HOTSPOTS = 6
+# ONE city, whatever the seed.  The reference samples one data set (the
+# RideAustin CSV, sample_driving_data.rs) and the program's stand-in is one
+# city too: ``workloads.sample_points`` seeds its sampler with 42 on every run,
+# so these six hotspots, that sampler's first draw, are the ones ``bin/leader``
+# is served with: (2976, -9742), (3044, -9782), (3017, -9731), (2976, -9751),
+# (2990, -9823), (3029, -9717).  Where they fall against the prefix grid
+# decides the frontier of every level, so a city a seed would be other WORK a
+# seed (58 to 88 bucket-units a crawl over twelve seeds at N = 131,072;
+# PERF.md section 6, PR 44); the clients are the seed's.
+RIDES_HOTSPOTS = np.array(RIDES_CENTRE) + np.random.default_rng(42).integers(-60, 60, size=(6, 2))
 
 
 def rides_points(rng: np.random.Generator, *, data_len: int, n_dims: int,
                  clients: int, **_) -> np.ndarray:
-    """bool[clients, 2, 16]: pickups clustered on ``RIDES_HOTSPOTS`` hotspots
-    within 0.60 degrees of downtown Austin, a pickup its hotspot plus a
-    rounded normal of one centidegree (some 1.1 km), as i16 centidegrees in
-    offset binary (the sign bit flipped, so that the strings sort as the
-    values do), MSB first."""
+    """bool[clients, 2, 16]: pickups clustered on the six ``RIDES_HOTSPOTS``
+    (within 0.60 degrees of downtown Austin), a pickup its hotspot, drawn
+    from ``rng``, plus a rounded normal of one centidegree (some 1.1 km), as
+    i16 centidegrees in offset binary (the sign bit flipped, so that the
+    strings sort as the values do), MSB first."""
     _geo_only("rides", data_len, n_dims, 16)
-    hot = np.array(RIDES_CENTRE) + rng.integers(-60, 60, size=(RIDES_HOTSPOTS, 2))
-    idx = rng.integers(0, RIDES_HOTSPOTS, size=clients)
+    hot = RIDES_HOTSPOTS
+    # the program draws its hotspots first; the run's generator makes that
+    # draw too and the result is not used, so that the stream stands where the
+    # program's does: at seed 42 the clients are the program's point for point
+    rng.integers(-60, 60, size=hot.shape)
+    idx = rng.integers(0, len(hot), size=clients)
     pts = hot[idx] + rng.normal(0, 1.0, size=(clients, 2)).round().astype(int)
     pts = np.clip(pts, -32768, 32767).astype(np.int16)
     return _msb_first(pts.view(np.uint16) ^ np.uint16(0x8000))
@@ -164,6 +179,9 @@ def client_points(config: dict, clients: int, rng: np.random.Generator) -> np.nd
                 clients=clients)
 
 
+CLOSE_ON = ("level", "crawl")
+
+
 @dataclasses.dataclass(frozen=True)
 class Plan:
     """A traffic mix of kind ``crawl_levels``, as its data file gives it."""
@@ -172,6 +190,7 @@ class Plan:
     min_levels: int
     max_levels: int
     restart_when_crawl_ends: bool
+    close_on: str  # where the window's clock stops, one of CLOSE_ON
     tail_max_s: float  # 0: no tail
     trace_start_after_s: float
     trace_capture_s: float
@@ -180,11 +199,15 @@ class Plan:
 def plan(mix: dict) -> Plan:
     if mix.get("kind") != "crawl_levels":
         raise ValueError(f"traffic kind {mix.get('kind')!r}: only 'crawl_levels' is generated")
-    w, t = mix["warmup"], mix["trace"]
+    w, win, t = mix["warmup"], mix["window"], mix["trace"]
+    close_on = win.get("close_on", "level")
+    if close_on not in CLOSE_ON:
+        raise ValueError(f"window.close_on {close_on!r}: one of {list(CLOSE_ON)}")
     return Plan(
         steady_levels=int(w["steady_levels"]), min_levels=int(w["min_levels"]),
         max_levels=int(w["max_levels"]),
-        restart_when_crawl_ends=bool(mix["window"]["restart_when_crawl_ends"]),
+        restart_when_crawl_ends=bool(win["restart_when_crawl_ends"]),
+        close_on=close_on,
         tail_max_s=float(mix.get("tail", {}).get("max_s", 0.0)),
         trace_start_after_s=float(t["start_after_s"]),
         trace_capture_s=float(t["capture_s"]),
