@@ -298,7 +298,9 @@ class LintConfig:
     # Every byte still leaves through these two: rpc._send hands the
     # frame built by wire.encode (pickled metadata + the arrays' own
     # buffers) to the transport, and nothing else calls the writer
-    taint_wire_calls: tuple = ("_send", "_dp_send", "_encode")
+    taint_wire_calls: tuple = (
+        "_send", "_dp_send", "_dp_send_begin", "_encode",
+    )
     # declared declassifiers: masking/opening operations whose output
     # is public by protocol argument — pad-XOR encryptions, share
     # openings, one-way commitments.  `declassified(reason)` contracts

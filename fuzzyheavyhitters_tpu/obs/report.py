@@ -46,7 +46,10 @@ Well-known metric names (what populates them):
   ``build``, ``msg_fetch``, ``msg_send``), and ``program_dispatch`` /
   ``program_device`` / ``program_hop``, ``d2h_ready`` / ``d2h_copy`` /
   ``d2h_hop``, ``send_resume`` — how a span that waits on a thread
-  splits; timers alone (no span-log record), per server in
+  splits; ``stream_gap`` — what the data plane's writer thread waited
+  between two frames of a level's send stage, by its own stamps (a send
+  stage keeps two frames with it, so its next frame is queued when a
+  write ends); timers alone (no span-log record), per server in
   ``secure_kernels.stages``.
 - counters ``data_bytes_sent`` / ``data_bytes_recv`` /
   ``data_msgs_sent`` — server↔server data plane, per level;
@@ -55,7 +58,10 @@ Well-known metric names (what populates them):
   plane) that crossed as raw array buffers, not through pickle
   (protocol/wire.py); ``plane_stream_frames`` — data-plane frames sent
   through their stream's writer thread (``wire.PlaneStreams``: all of
-  ``data_msgs_sent``), with the gauge ``plane_send_queue_high`` (the
+  ``data_msgs_sent``), ``plane_sends_overlapped`` those of them handed
+  over while the thread still held another (a chunk level's send stage:
+  near (K-1)/K of its frames; 0 where a level is one frame), with the
+  gauge ``plane_send_queue_high`` (the
   most frames that thread held at a hand-over of the level, that frame
   included: 1 = the stream was free) — rolled up per server into a
   top-level ``plane`` section whenever a data plane carried a frame;
@@ -499,6 +505,7 @@ def _secure_kernel_summary(registries: dict) -> dict | None:
     }
 
 
+_SEND_STAGES = ("u_send", "msg_send")
 _SPLIT_TIMERS = (
     "program_dispatch", "program_device", "program_hop",
     "d2h_ready", "d2h_copy", "d2h_hop", "send_resume",
@@ -516,9 +523,23 @@ def _stage_account(phases: dict) -> dict | None:
     in a level of many chunks, the one that waited least: never starved,
     never blocked); and how the spans that wait on a thread
     split (``program_*`` of ``otext`` + ``b2a`` + ``eval`` + ``garble``,
-    ``d2h_*`` of ``d2h``, ``send_resume``).  None where no stage ran."""
+    ``d2h_*`` of ``d2h``, ``send_resume``).  A send stage (``u_send``,
+    ``msg_send``) keeps two frames with the plane's writer thread, so
+    its row also has that thread's account of the stage's frames over
+    the same levels: ``wire_write_seconds`` (the socket's own) and
+    ``stream_gap_seconds`` (the thread's waits between them).  Their
+    sum is the thread's life with the stage's frames and lies inside
+    the stage's wall: busy is at least that sum less the stage's waits
+    (a stage that starves while a frame is written is not busy) and at
+    most ``wire_pickle`` + that sum + the first frame's ``wire_queue``
+    + the last frame's ``send_resume``; where the stage sets the pace,
+    busy is the sum and little else.  None where no stage ran."""
     seconds = lambda name: phases.get(name, {}).get("seconds", 0.0)
     gc_by_level = phases.get("gc_ot", {}).get("by_level", {})
+    over = lambda name, levels: sum(
+        phases.get(name, {}).get("by_level", {}).get(lvl, 0.0)
+        for lvl in levels
+    )
     by_stage = {}
     for name, t in phases.items():
         kind, _, stage = name.partition(":")
@@ -527,7 +548,8 @@ def _stage_account(phases: dict) -> dict | None:
         wall = t.get("seconds", 0.0)
         starved = seconds(f"stage_starved:{stage}")
         blocked = seconds(f"stage_blocked:{stage}")
-        gc_ot = sum(gc_by_level.get(lvl, 0.0) for lvl in t.get("by_level", {}))
+        levels = t.get("by_level", {})
+        gc_ot = sum(gc_by_level.get(lvl, 0.0) for lvl in levels)
         busy = wall - starved - blocked
         by_stage[stage] = {
             "wall_seconds": round(wall, 6),
@@ -536,6 +558,11 @@ def _stage_account(phases: dict) -> dict | None:
             "busy_seconds": round(busy, 6),
             "busy_share_of_gc_ot": round(busy / gc_ot, 4) if gc_ot else 0.0,
         }
+        if stage in _SEND_STAGES:
+            by_stage[stage].update(
+                wire_write_seconds=round(over("wire_write", levels), 6),
+                stream_gap_seconds=round(over("stream_gap", levels), 6),
+            )
     if not by_stage:
         return None
     return {
@@ -550,9 +577,10 @@ def _stage_account(phases: dict) -> dict | None:
 def _plane_summary(registries: dict) -> dict | None:
     """The data plane's streams, per registry that sent on one: frames
     sent, how many of them went through the stream's writer thread (a
-    frame that took any other way shows as the difference), and the
-    most frames that thread held at once; None when no plane carried
-    a frame."""
+    frame that took any other way shows as the difference), how many of
+    those were handed over while the thread still held another (a chunk
+    level's send stage keeps two with it), and the most frames that
+    thread held at once; None when no plane carried a frame."""
     out = {}
     for name, snap in registries.items():
         counters = snap.get("counters", {})
@@ -564,6 +592,9 @@ def _plane_summary(registries: dict) -> dict | None:
             "msgs_sent": sent.get("total", 0),
             "stream_frames": counters.get(
                 "plane_stream_frames", {}
+            ).get("total", 0),
+            "sends_overlapped": counters.get(
+                "plane_sends_overlapped", {}
             ).get("total", 0),
             "send_queue_high": max(
                 [g.get("last", 0), *g.get("by_level", {}).values()]

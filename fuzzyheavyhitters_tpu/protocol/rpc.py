@@ -105,6 +105,7 @@ import os
 import pickle
 import secrets as _secrets
 import time
+import typing
 import weakref
 
 import jax
@@ -358,6 +359,30 @@ class _DeviceWaits:
 # level are its wall less its two waits; the stage with the least of
 # both waits sets the level's pace.  Timers alone (``Registry.timer_add``
 # under the level): no span-log record, no profiler annotation.
+#
+# What fills a stage's busy seconds: a stage that dispatches (``build``,
+# ``extend``) its ``h2d`` and its dispatches, ``open`` its leaf spans, a
+# fetch stage what of the chunk's thread call no chunk ahead hid.  A
+# send stage (``u_send``, ``msg_send``) keeps ``FRAMES_OUT`` frames with
+# the plane's writer thread, so its leaf spans overlap (frame k+1's
+# ``wire_queue`` runs beside frame k's ``wire_write``) and no longer sum
+# to its busy seconds.  What holds instead is the thread's own account
+# of the stage's frames: ``wire_write`` (the socket's seconds) plus
+# ``stream_gap`` (the thread's waits between one frame's end and the
+# next's start, by its own stamps) is the thread's life with them, and
+# that life lies inside the stage's:
+#
+#   wire_write + stream_gap - starved - blocked
+#     <= busy <=
+#   wire_pickle + wire_write + stream_gap
+#     + the first frame's wire_queue + the last frame's send_resume
+#
+# (``wire_write`` alone is no lower bound: a stage that starves while a
+# frame is written is not busy then.  Where the stage sets the pace it
+# hardly starves, and busy is ``wire_write`` + ``stream_gap`` and little
+# else; at K = 1 both sides meet in the old sum, pickle + queue + write
+# + resume.)  The counter ``plane_sends_overlapped`` says how many
+# frames were handed over while the thread still held another.
 
 # The stages: the evaluator's ``extend``, ``u_fetch``, ``u_send`` and
 # ``open``, the garbler's ``build``, ``msg_fetch`` and ``msg_send``.
@@ -404,6 +429,19 @@ class _Stage:
         self._reg.timer_add(
             self._names[2], time.perf_counter() - self._t0, self._level
         )
+
+
+class _FrameOut(typing.NamedTuple):
+    """A data-plane frame with the writer thread, between
+    ``_dp_send_begin`` and ``_dp_send_finish``: whose registry and level
+    its spans go to, and what ``wire.PlaneStreams.hand_over`` gave."""
+
+    reg: obsmetrics.Registry
+    level: "int | None"
+    tracing: bool
+    done: asyncio.Future  # of (t_begin, t_end, t_seen)
+    t_put: float
+    held: int
 
 
 # ---------------------------------------------------------------------------
@@ -999,17 +1037,20 @@ class CollectorServer:
         """One frame to the peer, through the plane's writer thread:
         pickled and counted here on the loop (the pieces are views,
         never joined), written there, and "sent" when the kernel has
-        every byte.  The thread's clock becomes this registry's spans
-        when the send returns, as ``PlaneMux.recv`` does for a read:
-        ``wire_queue`` (frame handed over -> its send begins; the thread
-        hop alone where the stream was free) and ``wire_write`` (the
-        thread's send of this frame); the timer ``send_resume`` is the
-        way back, the thread's send ended -> this coroutine resumed on
-        the loop.  ``plane_stream_frames`` counts
-        the frames that went this way (all of ``data_msgs_sent``), the
-        gauge ``plane_send_queue_high`` the most frames the writer held
-        at a hand-over of the level, this one included (1: free;
-        ``wire.PlaneStreams.SEND_DEPTH``: at its bound)."""
+        every byte: :meth:`_dp_send_begin` and :meth:`_dp_send_finish`,
+        one after the other.  (A chunk level's send stage calls the two
+        apart and keeps a second frame behind the one on the socket,
+        :meth:`_chunk_senders`; every other sender calls this.)"""
+        return await self._dp_send_finish(await self._dp_send_begin(cs, obj))
+
+    async def _dp_send_begin(self, cs: CollectionSession, obj) -> _FrameOut:
+        """The hand-over of one frame: counted (``data_msgs_sent``,
+        ``data_bytes_sent``), pickled (``wire_pickle``) and put on the
+        writer thread's queue (``wire.PlaneStreams.hand_over``: after a
+        wait for a slot there, none where fewer than ``SEND_DEPTH``
+        frames are out).  Returns what :meth:`_dp_send_finish` takes;
+        the frame's arrays are pinned until that returns, or until the
+        future in it is cancelled and the thread is done with them."""
         reg = cs.obs
         reg.count("data_msgs_sent")
         # under fhh-trace the frame's session header carries this verb's
@@ -1024,11 +1065,43 @@ class CollectorServer:
         peer = self._peer
         if peer is None:
             raise ConnectionResetError("no peer data plane")
-        t_put, t_begin, t_end, held = await peer.send(pieces)
+        return _FrameOut(reg, level, tracing, *await peer.hand_over(pieces))
+
+    async def _dp_send_finish(self, out: _FrameOut) -> tuple[float, float]:
+        """Wait until the kernel has every byte of a frame handed over
+        (:meth:`_dp_send_begin`'s ``out``), then turn the thread's clock
+        into this registry's spans, as ``PlaneMux.recv`` does for a
+        read: ``wire_queue`` (frame handed over -> its send begins; the
+        thread hop alone where the stream was free, the rest of the
+        frame ahead where it was not) and ``wire_write`` (the thread's
+        send of this frame); the timer ``send_resume`` is the way back:
+        the thread's send ended -> the loop heard of it, and from there,
+        or from this call where it came later (a send stage was at its
+        next frame meanwhile: no hop of the loop's), until this
+        coroutine ran again; for a caller that waited all along, the
+        thread's send ended -> this coroutine resumed.
+        ``plane_stream_frames`` counts the frames that went this way
+        (all of ``data_msgs_sent``), ``plane_sends_overlapped`` those
+        of them handed over while the thread still held another, the
+        gauge ``plane_send_queue_high`` the most frames the writer held
+        at a hand-over of the level, this one included (1: free;
+        ``wire.PlaneStreams.SEND_DEPTH``: at its bound).  Returns the
+        thread's ``(t_begin, t_end)``; raises ``ConnectionError`` where
+        the plane was cut first."""
+        reg, level, tracing, done, t_put, held = out
+        asked = time.time()
+        # fhh-lint: disable=unbounded-await (resolved by the writer thread for every frame it was handed, sent or failed: bounded by the plane's TCP keepalive and by close(), wire.PlaneStreams.hand_over)
+        t_begin, t_end, t_seen = await done
         # the hop back (``wire_queue`` is the hop in): the writer
-        # thread's last stamp -> this coroutine runs again; a timer alone
-        reg.timer_add("send_resume", max(0.0, time.time() - t_end), level)
+        # thread's last stamp -> the loop hears, and -> this coroutine
+        # runs again, less what it spent elsewhere between; a timer alone
+        reg.timer_add(
+            "send_resume",
+            max(0.0, t_seen - t_end) + max(0.0, time.time() - max(t_seen, asked)),
+            level,
+        )
         reg.count("plane_stream_frames", level=level)
+        reg.count("plane_sends_overlapped", int(held > 1), level=level)
         if held > (reg.gauge_value("plane_send_queue_high", level) or 0):
             reg.gauge("plane_send_queue_high", held, level=level)
         for name, a, b in (
@@ -1041,6 +1114,7 @@ class CollectorServer:
             # the span log carries no gauges (scripts/trace_spans.py
             # ``plane_streams``)
             obstrace.instant("plane_send", comp=reg.name, held=held)
+        return t_begin, t_end
 
     async def _dp_recv(self, cs: CollectionSession):
         # the whole data-plane receive; its children peer_wait,
@@ -1465,18 +1539,39 @@ class CollectorServer:
     # that parks in ``block_until_ready`` does so on a thread of the
     # server's own (``_DeviceWaits``, ``DEVICE_WAITS`` of them), not on
     # asyncio's default executor.  A send is a whole frame on the
-    # plane's writer thread, its reader thread always reads, and
-    # ``PlaneMux`` holds frames ahead of their receiver in order.  One
-    # chunk (K = 1) is the whole level: the same calls.
+    # plane's writer thread, and a send stage keeps ``FRAMES_OUT`` of
+    # them there: it hands chunk k+1's frame over while chunk k's is on
+    # the socket and waits for the older one only then, so the thread
+    # finds its next frame queued when a write ends (the loop's turns
+    # between two frames run beside the write, not between writes).  The
+    # peer's reader thread always reads, and ``PlaneMux`` holds frames
+    # ahead of their receiver in order.  One chunk (K = 1) is the whole
+    # level: the same calls, one after the other.
 
     # how many chunks the evaluator's u may run ahead of the tables it
     # has opened (``_ev_chunks``): every level of eight chunks or fewer
-    # runs as far ahead as it has chunks
+    # runs as far ahead as it has chunks.  It bounds the chunks whose u
+    # the kernel has whole (``on_sent``) and whose table is not opened:
+    # CHUNKS_AHEAD in ``sent`` and the one that waits to get in, which
+    # is what the gauge ``secure_t_rows_held_bytes`` counts.  Since the
+    # send stage keeps FRAMES_OUT frames with the writer thread, ONE
+    # chunk more may have its u on the socket behind those (handed over,
+    # ``on_sent`` not yet called): its strings and T rows are on the
+    # device too, as are those of every chunk ``extend`` runs ahead
+    # (``made``, ``fetched``), none of which the gauge counts
     CHUNKS_AHEAD = 8
 
     # chunks a stage may run ahead of the stage after it: the bound of
     # the queues ``made`` / ``built`` / ``fetched``
     STAGE_QUEUE = 2
+
+    # frames a send stage keeps with the plane's writer thread: the one
+    # on the socket and one behind it (``_chunk_senders``).  The hop
+    # back to the loop and the turn that takes and pickles the next
+    # chunk are 1.3-3.2 ms against a write of 2.6-5.1 (PERF.md), so one
+    # frame behind is what hides them; a stage pins the arrays of this
+    # many fetched chunks beside the STAGE_QUEUE in ``fetched``
+    FRAMES_OUT = 2
 
     # the threads of a server's waits for the device (``_DeviceWaits``),
     # from what a level can have parked at once: a fetch's thread call
@@ -1546,10 +1641,21 @@ class CollectorServer:
         while chunk k is on the socket.  ``made`` yields ``(fetch,
         token)``, the fetch a future that the stage before began where
         it dispatched the chunk's programs (``_fetch_behind``) and that
-        is awaited under the plane's bound (``_fetch_taken``);
-        ``on_sent`` is awaited with the token once that chunk's frame
-        is with the kernel.  ``stages`` names the two in the level's
-        wait account (:class:`_Stage`)."""
+        is awaited under the plane's bound (``_fetch_taken``).  The send
+        stage hands each frame to the plane's writer thread as soon as
+        ``fetched`` yields it (``_dp_send_begin``) and waits for the
+        OLDEST frame out (``_dp_send_finish``) only when ``FRAMES_OUT``
+        are with the thread, and for those still out at the level's
+        end: the thread is FIFO, so frames leave and end in order, and
+        chunk k+1's is queued when chunk k's write ends.  ``on_sent``
+        is awaited with the token once a chunk, in chunk order, after
+        THAT chunk's frame is with the kernel.  The timer ``stream_gap``
+        is, by the thread's own stamps, what it waited between the end
+        of one frame of this stage and the start of the next.  A frame
+        that fails raises where it is waited for and fails the verb; a
+        stage that is cancelled, or failed, gives up the frames still
+        out without waiting for them.  ``stages`` names the two in the
+        level's wait account (:class:`_Stage`)."""
         fetched: asyncio.Queue = asyncio.Queue(self.STAGE_QUEUE)
 
         async def fetch():
@@ -1564,14 +1670,47 @@ class CollectorServer:
                     await st.blocked(fetched.put((arr, token)))
 
         async def send():
-            with _Stage(cs.obs, stages[1], level) as st:
-                for k in range(K):
-                    # fhh-lint: disable=unbounded-await (fed by a sibling task, as above)
-                    arr, token = await st.starved(fetched.get())
-                    with self._chunk_label(k, K):
-                        await self._dp_send(cs, self._chunk_frame(k, K, arr))
-                    if on_sent is not None:
-                        await st.blocked(on_sent(token))
+            reg = cs.obs
+            # frames with the writer thread, oldest first, and where the
+            # thread ended the last one this stage has seen the end of
+            out: _collections.deque = _collections.deque(maxlen=self.FRAMES_OUT)
+            last_end = None
+
+            async def finish():
+                nonlocal last_end
+                k, token, frame = out.popleft()
+                with self._chunk_label(k, K):
+                    t_begin, t_end = await self._dp_send_finish(frame)
+                if last_end is not None:
+                    reg.timer_add(
+                        "stream_gap", max(0.0, t_begin - last_end), level
+                    )
+                last_end = t_end
+                if on_sent is not None:
+                    await st.blocked(on_sent(token))
+
+            with _Stage(reg, stages[1], level) as st:
+                reg.timer_add("stream_gap", 0.0, level)
+                try:
+                    for k in range(K):
+                        # fhh-lint: disable=unbounded-await (fed by a sibling task, as above)
+                        arr, token = await st.starved(fetched.get())
+                        with self._chunk_label(k, K):
+                            frame = await self._dp_send_begin(
+                                cs, self._chunk_frame(k, K, arr)
+                            )
+                        out.append((k, token, frame))
+                        arr = None
+                        if len(out) == self.FRAMES_OUT:
+                            await finish()
+                    while out:
+                        await finish()
+                finally:
+                    # cancelled, or a frame failed: the frames still out
+                    # are not awaited (the thread sends or fails them all
+                    # the same, ``wire.PlaneStreams._sent``)
+                    for *_, frame in out:
+                        frame.done.cancel()
 
         return fetch(), send()
 

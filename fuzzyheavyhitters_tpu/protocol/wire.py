@@ -637,7 +637,9 @@ class PlaneStreams:
     """One server's end of the server↔server data plane: two one-way
     TCP streams on blocking sockets, the one this server writes with a
     writer thread on it, the one it reads with a reader thread on it.
-    The loop thread hands frames over (:meth:`send`) and takes frames
+    The loop thread hands frames over (:meth:`send`, or
+    :meth:`hand_over` for a sender that keeps a second frame behind the
+    one on the socket) and takes frames
     back (``on_frame``) and never copies a byte of either; a socket is
     never shared by a writer and a reader of bulk (two directions on
     one socket fight for its lock, PERF.md section 6, PR 36).
@@ -698,28 +700,44 @@ class PlaneStreams:
 
     # -- the loop thread's side ----------------------------------------------
 
-    async def send(self, pieces) -> tuple[float, float, float, int]:
-        """Hand one frame's pieces (:func:`encode`) to the writer
-        thread and wait until the kernel has every byte: no view of the
-        caller's arrays is left behind.  Frames go out in the order of
-        their hand-over.  Returns the wall clock at the hand-over, at
-        the start and at the end of the thread's send, and how many
-        frames the thread then held, this one included (1: the stream
-        was free).  Raises ``ConnectionError`` on a plane that is, or
-        while it waits becomes, closed or lost."""
+    async def hand_over(self, pieces) -> tuple[asyncio.Future, float, int]:
+        """The first half of :meth:`send`: take a slot, put one frame's
+        pieces (:func:`encode`) on the writer thread's queue and return
+        without waiting for the write.  Frames go out in the order of
+        their hand-over, so a sender that hands over a second frame
+        while the first is on the socket finds the stream busy again
+        the moment the first ends.  Returns the future of ``(t_begin,
+        t_end, t_seen)``, the thread's wall clock at the start and the
+        end of this frame's send and the loop's when it heard of it (it
+        raises ``ConnectionError`` where the plane is closed or lost
+        first), the wall clock at the hand-over, and how many frames
+        the thread then held, this one included (1: the stream was
+        free).  The views of the caller's arrays live until that future
+        is done.  Whoever gives the future up cancels it: the frame
+        goes whole all the same."""
         # fhh-lint: disable=unbounded-await (a slot comes back when the thread is done with a frame, sent or failed: bounded by the socket's TCP keepalive and by close(), like the send itself)
         await self._slots.acquire()
         if self._closed:
             self._slots.release()
             raise ConnectionResetError("data plane closed")
         self.waiting += 1
-        held = self.waiting
         done = self._loop.create_future()
         t_put = time.time()
         self._sendq.put((pieces, done))
+        return done, t_put, self.waiting
+
+    async def send(self, pieces) -> tuple[float, float, float, int]:
+        """:meth:`hand_over` and the wait in one: hand the frame to the
+        writer thread and wait until the kernel has every byte, so that
+        no view of the caller's arrays is left behind.  Returns the
+        wall clock at the hand-over, at the start and at the end of the
+        thread's send, and how many frames the thread then held, this
+        one included.  Raises ``ConnectionError`` on a plane that is,
+        or while it waits becomes, closed or lost."""
+        done, t_put, held = await self.hand_over(pieces)
         pieces = None
-        # fhh-lint: disable=unbounded-await (resolved by the writer thread for every frame it was handed, sent or failed; see above)
-        t_begin, t_end = await done
+        # fhh-lint: disable=unbounded-await (resolved by the writer thread for every frame it was handed, sent or failed; see hand_over)
+        t_begin, t_end, _ = await done
         return t_put, t_begin, t_end, held
 
     def _sent(self, done: asyncio.Future, t_begin: float, t_end: float,
@@ -729,7 +747,7 @@ class PlaneStreams:
         if done.done():  # its sender was cancelled: the frame went whole all the same
             return
         if err is None:
-            done.set_result((t_begin, t_end))
+            done.set_result((t_begin, t_end, time.time()))
         else:
             done.set_exception(ConnectionResetError(f"data plane lost: {err!r}"))
 

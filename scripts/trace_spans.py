@@ -34,9 +34,14 @@ object:
   ``secure_string_bits`` and ``child_patterns``);
 - ``plane_streams``: per component, over its ``plane_send`` instants
   (one a data-plane frame sent through its stream's writer thread:
-  protocol/rpc.py ``_dp_send``), the frames (the counter
-  ``plane_stream_frames``) and the most frames the writer held at a
-  hand-over (the gauge ``plane_send_queue_high``; 1: the stream was free);
+  protocol/rpc.py ``_dp_send_finish``), the frames (the counter
+  ``plane_stream_frames``), those of them handed over while the writer
+  still held another (the counter ``plane_sends_overlapped``: a chunk
+  level's send stage keeps two frames with the thread), the most frames
+  the writer held at a hand-over (the gauge ``plane_send_queue_high``;
+  1: the stream was free), and, over its ``wire_write`` spans, what the
+  thread waited between one chunk's frame and the next of a level (the
+  timer ``stream_gap``);
 - ``clock`` (with ``--capture``): the program's spans are also profiler
   annotations (``<comp>:<name>``) on the profiler's own clock.  The
   benchmark lays the JSONL lines over a capture by one sync mark
@@ -188,16 +193,35 @@ def secure_levels(events: list) -> dict:
 def plane_streams(events: list) -> dict:
     """Per component, over its ``plane_send`` instants (one a frame that
     went through the data plane's writer thread: protocol/rpc.py
-    ``_dp_send``): the frames (counter ``plane_stream_frames``) and the
-    most the writer held at a hand-over, that frame included (gauge
-    ``plane_send_queue_high``)."""
+    ``_dp_send_finish``): the frames (counter ``plane_stream_frames``),
+    those handed over while the writer held another (counter
+    ``plane_sends_overlapped``) and the most it held at a hand-over,
+    that frame included (gauge ``plane_send_queue_high``); and over its
+    ``wire_write`` spans that name a chunk, the seconds between the end
+    of chunk k's and the start of chunk k+1's in one level (timer
+    ``stream_gap``: the thread waited for the stage's next frame)."""
     out: dict = {}
+    writes: dict = {}
     for e in events:
         if e.get("ph") == "i" and e.get("name") == "plane_send":
-            row = out.setdefault(e["comp"], {"frames": 0, "send_queue_high": 0})
+            row = out.setdefault(e["comp"], {
+                "frames": 0, "sends_overlapped": 0, "send_queue_high": 0,
+                "stream_gap_seconds": 0.0})
             row["frames"] += 1
+            row["sends_overlapped"] += e["args"]["held"] > 1
             row["send_queue_high"] = max(
                 row["send_queue_high"], e["args"]["held"])
+        elif (e.get("ph") == "X" and e.get("name") == "wire_write"
+              and "chunk" in e):
+            writes.setdefault((e["comp"], e.get("level")), []).append(e)
+    for (comp, _), spans in writes.items():
+        spans.sort(key=lambda e: e["ts"])
+        for a, b in zip(spans, spans[1:]):
+            if b["chunk"] == a["chunk"] + 1 and comp in out:
+                out[comp]["stream_gap_seconds"] += max(
+                    0.0, b["ts"] - a["ts"] - a["dur"])
+    for row in out.values():
+        row["stream_gap_seconds"] = round(row["stream_gap_seconds"], 6)
     return dict(sorted(out.items()))
 
 

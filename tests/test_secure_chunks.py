@@ -17,6 +17,7 @@ differently gets ``ConnectionError`` and nobody hangs.
 import asyncio
 import logging
 import threading
+import time
 
 import jax
 import numpy as np
@@ -24,7 +25,7 @@ import pytest
 
 from fuzzyheavyhitters_tpu.ops import gc_pallas, ibdcf
 from fuzzyheavyhitters_tpu.ops.fields import F255, FE62
-from fuzzyheavyhitters_tpu.protocol import rpc, secure
+from fuzzyheavyhitters_tpu.protocol import rpc, secure, wire
 from fuzzyheavyhitters_tpu.protocol.leader_rpc import RpcLeader
 from fuzzyheavyhitters_tpu.utils import bits as bitutils
 from fuzzyheavyhitters_tpu.utils.config import Config
@@ -277,22 +278,51 @@ def test_leaf_level_in_chunks(monkeypatch):
         assert a.shape[-1] == 8 and np.array_equal(a, b)
 
 
-def test_plane_cut_mid_level_fails_the_verb_and_the_retry_is_exact(monkeypatch):
-    """The plane closed under chunk 1's frame: both verbs fail, no chunk
-    task and no I/O thread of that plane is left, and after a plane
-    reset (four new threads) the same level gives the exact counts."""
-    port = BASE_PORT + 360
+@pytest.mark.parametrize("when", ["before_chunk_1", "two_frames_out"])
+def test_plane_cut_mid_level_fails_the_verb_and_the_retry_is_exact(monkeypatch, when):
+    """The plane closed under chunk 1's frame, before its hand-over or
+    right after it, with two frames of the stage with the writer thread
+    and neither waited for yet: both verbs fail, no chunk task and no
+    I/O thread of that plane is left, and after a plane reset (four new
+    threads) the same level gives the exact counts."""
+    port = BASE_PORT + (360 if when == "before_chunk_1" else 320)
     monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", BLOCK * 64)
-    real = rpc.CollectorServer._dp_send
+    real = rpc.CollectorServer._dp_send_begin
     cut = {"armed": False}
+    # the writer threads held before a write, for the second case: so
+    # that chunk 0's frame is still whole with its thread when chunk
+    # 1's is handed over, whatever the host's speed
+    gate, send_all = threading.Event(), wire._send_all
+    gate.set()
+
+    def gated(sock, pieces):  # on a writer thread
+        gate.wait(30)
+        send_all(sock, pieces)
+
+    monkeypatch.setattr(wire, "_send_all", gated)
 
     async def cutting(self, cs, obj):
-        if cut["armed"] and isinstance(obj, tuple) and obj[0] == 1:
+        # the evaluating server's u, the level's first frames
+        k = obj[0] if (
+            cut["armed"] and isinstance(obj, tuple) and self.server_id == 1
+        ) else None
+        if k == 1 and when == "before_chunk_1":
             cut["armed"] = False
             self._peer.close()
-        await real(self, cs, obj)
+        if k == 0 and when == "two_frames_out":
+            gate.clear()
+        out = await real(self, cs, obj)
+        if k == 1 and when == "two_frames_out":
+            # chunk 0's frame and this one, neither written, neither
+            # waited for
+            assert out.held == self._peer.waiting == 2
+            assert not out.done.done()
+            cut["armed"] = False
+            self._peer.close()
+            gate.set()
+        return out
 
-    monkeypatch.setattr(rpc.CollectorServer, "_dp_send", cutting)
+    monkeypatch.setattr(rpc.CollectorServer, "_dp_send_begin", cutting)
 
     async def run():
         async with _Pair(port, 4096) as pair:
@@ -437,14 +467,14 @@ def test_a_stage_runs_ahead_of_the_device_by_its_queues_and_no_further(monkeypat
         secure, "gb_chunk_table", counting("gb", secure.gb_chunk_table))
     monkeypatch.setattr(
         secure, "ev_chunk_extend", counting("ev", secure.ev_chunk_extend))
-    real_send = rpc.CollectorServer._dp_send
+    real_send = rpc.CollectorServer._dp_send_begin
 
     async def handed(self, cs, obj):
         if isinstance(obj, tuple) and obj[1] == K:
             ahead["gb" if obj[2].ndim == 1 else "ev"][0] -= 1
-        await real_send(self, cs, obj)
+        return await real_send(self, cs, obj)
 
-    monkeypatch.setattr(rpc.CollectorServer, "_dp_send", handed)
+    monkeypatch.setattr(rpc.CollectorServer, "_dp_send_begin", handed)
 
     async def run():
         async with _Pair(WAITS_PORT + 60, n) as pair:
@@ -459,6 +489,177 @@ def test_a_stage_runs_ahead_of_the_device_by_its_queues_and_no_further(monkeypat
     for role in ("gb", "ev"):
         now, high = ahead[role]
         assert now == 0 and 1 <= high <= 2 + 2 + 2, (role, ahead)
+    got = np.asarray(FE62.canon(FE62.sub(shares[0], shares[1])))
+    assert np.array_equal(got[0], _root_counts(pts)) and not got[1:].any()
+
+
+def test_a_send_stage_keeps_two_frames_with_the_writer_and_no_third(monkeypatch):
+    """K = 8 with the garbling server's reader thread held inside the
+    first u (and the sockets of that direction too small for a frame):
+    the evaluator's send stage has handed over exactly ``FRAMES_OUT``
+    frames, the one on the socket and one behind it, and no third;
+    ``fetched`` holds ``STAGE_QUEUE`` chunks and the fetch stage the
+    next; and when the reader goes on, the level ends with the shares
+    of the whole level, bit for bit."""
+    import socket
+
+    cls = rpc.CollectorServer
+    assert cls.FRAMES_OUT == 2
+    hold, real_buffer = threading.Event(), wire._recv_buffer
+    hold.set()
+
+    def held_buffer(size, reg=None):  # on a reader thread
+        if threading.current_thread().name.startswith("server0-"):
+            hold.wait(60)
+        return real_buffer(size, reg)
+
+    monkeypatch.setattr(wire, "_recv_buffer", held_buffer)
+    seen = {"begun": 0, "fetched": 0}
+    real_begin, real_taken = cls._dp_send_begin, cls._fetch_taken
+
+    async def begin(self, cs, obj):
+        out = await real_begin(self, cs, obj)
+        seen["begun"] += self.server_id == 1 and isinstance(obj, tuple)
+        return out
+
+    async def taken(self, cs, level, stage, k, fut):
+        arr = await real_taken(self, cs, level, stage, k, fut)
+        seen["fetched"] += stage == "u_fetch"
+        return arr
+
+    monkeypatch.setattr(cls, "_dp_send_begin", begin)
+    monkeypatch.setattr(cls, "_fetch_taken", taken)
+
+    async def run():
+        async with _Pair(WAITS_PORT + 80, 4096) as pair:
+            await pair.both("tree_init", {"root_bucket": 8})
+            before = pair.ot_state()
+            monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", WHOLE)
+            whole = await pair.level(0, path="ot2s")
+            pair.set_ot_state(before)
+            monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", BLOCK * 64)
+            # u's direction: server 1 writes, server 0 reads
+            for sock, opt in ((pair.s1._peer._socks[0], socket.SO_SNDBUF),
+                              (pair.s0._peer._socks[1], socket.SO_RCVBUF)):
+                sock.setsockopt(socket.SOL_SOCKET, opt, 1 << 14)
+            seen.update(begun=0, fetched=0)  # the whole level's
+            hold.clear()
+            level = asyncio.ensure_future(pair.level(0, path="ot2s"))
+            want = (cls.FRAMES_OUT, cls.FRAMES_OUT + cls.STAGE_QUEUE + 1)
+            for _ in range(600):
+                if (seen["begun"], seen["fetched"]) == want:
+                    break
+                await asyncio.sleep(0.05)
+            await asyncio.sleep(0.5)  # and no third, however long it waits
+            stalled = (seen["begun"], seen["fetched"], pair.s1._peer.waiting,
+                       level.done())
+            hold.set()
+            cut = await asyncio.wait_for(level, 60)
+            ev = pair.sessions[1].obs
+            return (whole, cut, want, stalled, dict(seen), [
+                ev.counter_value(n, level=0)
+                for n in ("secure_chunks", "plane_stream_frames",
+                          "plane_sends_overlapped")
+            ], ev.gauge_value("plane_send_queue_high", level=0))
+
+    whole, cut, want, stalled, seen, counts, high = _run(run())
+    assert stalled == (*want, cls.FRAMES_OUT, False), stalled
+    assert seen == {"begun": 8, "fetched": 8}
+    # the whole level's one frame and the eight; of these every one but
+    # the first was handed over behind another at the most
+    assert counts[:2] == [1 + 8, 1 + 8] and 1 <= counts[2] <= 7, counts
+    assert high == 2
+    for a, b in zip(whole, cut):
+        assert a.dtype == b.dtype and np.array_equal(a, b)
+
+
+def test_on_sent_follows_each_frame_in_chunk_order(monkeypatch):
+    """K = 32: ``on_sent`` is awaited once a chunk, with that chunk's
+    token, in chunk order, and never before the writer thread ended
+    that chunk's frame, though the next frame is handed over before;
+    the evaluator's unopened chunks (``secure_t_rows_held_bytes``) stay
+    within the ``CHUNKS_AHEAD`` of its queue and the one that waits to
+    get in."""
+    from fuzzyheavyhitters_tpu.parallel.kernel_shard import n_msg_planes
+
+    cls = rpc.CollectorServer
+    n, S, W, K = 4096, 2, secure.payload_words(FE62), 32
+    per_test = max(16 * S, 4 * n_msg_planes("ot2s", S, W))
+    monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", BLOCK * per_test)
+    rows, ends, calls = [], [], []
+    real_extend, real_finish = secure.ev_chunk_extend, cls._dp_send_finish
+    real_senders = cls._chunk_senders
+
+    # the evaluator's writer thread begins a frame only when the next is
+    # queued behind it, so every frame but the first is handed over
+    # beside another whatever the host's speed
+    behind, last = threading.Event(), threading.Event()
+    last.set()  # (until the level: nothing comes behind a handshake's frame)
+    real_begin, send_all = cls._dp_send_begin, wire._send_all
+
+    def gated(sock, pieces):  # on a writer thread
+        if threading.current_thread().name.startswith("server1-"):
+            if not last.is_set():
+                behind.wait(30)
+                behind.clear()
+        send_all(sock, pieces)
+
+    async def begin(self, cs, obj):
+        out = await real_begin(self, cs, obj)
+        if self.server_id == 1 and isinstance(obj, tuple):
+            if obj[0] == K - 1:
+                last.set()
+            if out.held == 2 or last.is_set():
+                behind.set()
+        return out
+
+    def extend(*args, **kw):
+        u, t_rows, y = real_extend(*args, **kw)
+        rows.append(t_rows)
+        return u, t_rows, y
+
+    async def finish(self, out):
+        t_begin, t_end = await real_finish(self, out)
+        if self.server_id == 1:
+            ends.append(t_end)
+        return t_begin, t_end
+
+    def senders(self, cs, level, K, made, stages, on_sent=None):
+        async def spy(token):
+            # the frames whose end the stage has seen, and the clock
+            calls.append((token, len(ends), time.time()))
+            await on_sent(token)
+
+        return real_senders(
+            self, cs, level, K, made, stages, on_sent and spy)
+
+    monkeypatch.setattr(secure, "ev_chunk_extend", extend)
+    monkeypatch.setattr(wire, "_send_all", gated)
+    monkeypatch.setattr(cls, "_dp_send_begin", begin)
+    monkeypatch.setattr(cls, "_dp_send_finish", finish)
+    monkeypatch.setattr(cls, "_chunk_senders", senders)
+
+    async def run():
+        async with _Pair(WAITS_PORT + 100, n) as pair:
+            await pair.both("tree_init", {"root_bucket": 32})
+            del rows[:], ends[:], calls[:]
+            last.clear()
+            shares = await pair.level(0, path="ot2s")
+            ev = pair.sessions[1].obs
+            return shares, pair.pts, [
+                ev.counter_value("plane_sends_overlapped", level=0),
+                ev.gauge_value("secure_t_rows_held_bytes", level=0),
+            ]
+
+    shares, pts, (over, held) = _run(run())
+    assert len(rows) == len(ends) == len(calls) == K
+    for k, (token, seen, at) in enumerate(calls):
+        # chunk k's token, after chunk k's frame and before the next's
+        # end was seen, the writer thread done with it
+        assert token[1] is rows[k] and seen == k + 1 and at >= ends[k], k
+    assert ends == sorted(ends) and over == K - 1
+    token = BLOCK * S * (1 + 16)
+    assert token <= held <= (cls.CHUNKS_AHEAD + 1) * token
     got = np.asarray(FE62.canon(FE62.sub(shares[0], shares[1])))
     assert np.array_equal(got[0], _root_counts(pts)) and not got[1:].any()
 

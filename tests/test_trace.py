@@ -987,6 +987,21 @@ def test_chunk_spans_carry_the_level_and_lie_inside_gc_ot(rng, trace_dir, monkey
         assert (row["string_bits_max"], row["child_patterns_max"]) == (2, 2)
     assert max(r["t_rows_held_bytes_max"] for r in rows.values()) == max(
         held.values())
+    # the streams by the span log against the registries' own account:
+    # the frames handed over behind another, and what the writer thread
+    # waited between a level's chunks (the timer ``stream_gap``, from
+    # the ``wire_write`` spans that name a chunk)
+    streams = mod.plane_streams(evs)
+    assert set(streams) == set(rep["plane"]) == {"server0", "server1"}
+    for comp, row in streams.items():
+        mine = rep["plane"][comp]
+        assert row["frames"] == mine["stream_frames"] == mine["msgs_sent"]
+        assert row["sends_overlapped"] == mine["sends_overlapped"] <= K - 1
+        assert row["send_queue_high"] == mine["send_queue_high"]
+        gaps = sum(
+            st.get("stream_gap_seconds", 0.0) for st in
+            rep["secure_kernels"]["stages"][comp]["by_stage"].values())
+        assert row["stream_gap_seconds"] == pytest.approx(gaps, abs=1e-4)
 
 
 def test_wire_spans_of_a_frame_with_two_out_of_band_buffers(trace_dir, monkeypatch):
@@ -1086,7 +1101,9 @@ def test_wire_spans_of_a_frame_with_two_out_of_band_buffers(trace_dir, monkeypat
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     assert mod.plane_streams(evs) == {
-        "server0": {"frames": 1, "send_queue_high": 1}}
+        "server0": {"frames": 1, "sends_overlapped": 0, "send_queue_high": 1,
+                    "stream_gap_seconds": 0.0}}
+    assert tx.counter_value("plane_sends_overlapped", level=3) == 0
     row = mod.wire_oob(evs)["server0"]
     assert row["frames"] == 1 and row["wire_oob_bytes"] == oob
     assert row["framed_bytes"] == tx.counter_value("data_bytes_sent")

@@ -669,6 +669,65 @@ def test_send_queue_is_bounded_and_a_lost_peer_fails_it():
     asyncio.run(asyncio.wait_for(run(), 60))
 
 
+@pytest.mark.parametrize("how", ["two_handed_over", "send_alone"])
+def test_a_second_frame_handed_over_waits_behind_the_first(how):
+    """``hand_over`` is the first half of ``send``: two frames handed
+    over back to back, neither waited for, are with the writer thread
+    together (``held`` 1 then 2), leave in the order of their hand-over
+    (the second begins where the first ended, no sooner) and arrive so;
+    ``send`` alone, frame after frame, holds one at a time as before."""
+    async def run():
+        (_, tx), (mux, _) = ends = await _plane_pair(
+            BASE_PORT + 61 + (how == "send_alone"))
+        frames = [_big(24 << 20, np.uint8, seed=i) for i in range(2)]
+        if how == "send_alone":
+            stamps = [await _plane_send(tx, ("c", f)) for f in frames]
+            assert [s[3] for s in stamps] == [1, 1]
+        else:
+            out = [await tx.hand_over(wire.encode(("c", f))[0]) for f in frames]
+            assert [held for _, _, held in out] == [1, 2]
+            assert tx.waiting == 2 and not out[0][0].done()
+            # the younger frame first: it ends no sooner for that
+            ends_ = [await asyncio.wait_for(done, 60) for done, _, _ in out[::-1]]
+            # (and the loop heard of each after its end)
+            assert all(e[1] <= e[2] for e in ends_)
+            stamps = [(t_put, *ends_[1 - i][:2], held)
+                      for i, (_, t_put, held) in enumerate(out)]
+            # handed over before the first was written: queued behind it
+            assert stamps[1][0] <= stamps[0][2] <= stamps[1][1]
+        (p0, b0, e0, _), (p1, b1, e1, _) = stamps
+        assert p0 <= b0 <= e0 <= b1 <= e1 and p0 <= p1
+        assert tx.waiting == 0
+        for want in frames:
+            assert np.array_equal(await asyncio.wait_for(mux.recv("c"), 60), want)
+        await _plane_close(*ends)
+
+    asyncio.run(asyncio.wait_for(run(), 120))
+
+
+def test_a_frame_given_up_goes_whole_and_gives_its_slot_back():
+    """A sender that hands a frame over and cancels the future instead
+    of waiting for it (a send stage that was cancelled): the frame
+    arrives whole all the same, the slot comes back, and nothing is
+    logged about a result nobody took."""
+    async def run():
+        (_, tx), (mux, _) = ends = await _plane_pair(BASE_PORT + 63)
+        want = _big(8 << 20, np.uint8, seed=3)
+        done, _, held = await tx.hand_over(wire.encode(("c", want))[0])
+        done.cancel()
+        assert held == 1
+        assert np.array_equal(await asyncio.wait_for(mux.recv("c"), 60), want)
+        for _ in range(100):
+            if not tx.waiting:
+                break
+            await asyncio.sleep(0.01)
+        assert tx.waiting == 0 and tx._slots._value == tx.SEND_DEPTH
+        assert (await _plane_send(tx, ("c", 1)))[3] == 1
+        await _plane_close(*ends)
+
+    asyncio.run(asyncio.wait_for(run(), 60))
+
+
 @pytest.mark.skipif(not wire._LEASES, reason="slabs need PEP 688 (Python 3.12)")
 def test_two_reader_threads_take_from_one_slab_list(monkeypatch):
     """Two planes in one process (both servers of a pair), their reader
