@@ -1520,6 +1520,21 @@ class CollectorServer:
         for n in names:
             cs.obs.timer_add(n, 0.0, level=level)
 
+    @staticmethod
+    @contextlib.contextmanager
+    def _leaf_exchange(cs, level: int, tests: int):
+        """The LAST level's exchange told from the inner levels': the
+        span ``leaf_gc_ot`` inside its ``gc_ot`` and the counter
+        ``leaf_tests`` of its ``B = F*C*N``.  The leaf level compares
+        over F255 (a payload twice as wide) at whatever bucket the crawl
+        ends in, and in a short crawl run to its hitter set round after
+        round it is the largest single share of the time (16 levels of
+        2 x 16 bits: 40-50%), which ``gc_ot`` summed over the levels
+        cannot show."""
+        cs.obs.count("leaf_tests", tests, level=level)
+        with cs.obs.span("leaf_gc_ot", level=level):
+            yield
+
     # -- the secure level as a stream of row chunks -------------------------
     #
     # ``secure.level_chunks`` cuts the level's test batch into K runs of
@@ -1943,7 +1958,10 @@ class CollectorServer:
         The ``gc_ot`` span splits into the secure-kernel phases
         ``otext`` (extension), ``garble``/``eval`` (circuit work — zero
         on the ot2s path), and ``b2a`` (payload table / open + field
-        conversion); wire waits are the gc_ot remainder.  A chunk
+        conversion); wire waits are the gc_ot remainder.  The last
+        level's exchange is also the span ``leaf_gc_ot``, inside
+        ``gc_ot``, with its ``B`` in the counter ``leaf_tests``
+        (``_leaf_exchange``).  A chunk
         program whose output a fetch takes (the extensions, the table,
         the circuit) is waited for on that fetch's thread, which stamps
         where its span ends (``_fetch_behind``; counter
@@ -1980,7 +1998,9 @@ class CollectorServer:
             # radix step) and child patterns a node
             cs.obs.gauge("secure_string_bits", S, level=level)
             cs.obs.gauge("child_patterns", C, level=level)
-        with cs.obs.span("gc_ot", level=level) as sp_gc:
+        with cs.obs.span("gc_ot", level=level) as sp_gc, (
+            self._leaf_exchange(cs, level, B) if last else _NO_CTX
+        ):
             w = secure.alive_weight(frontier.alive, cs.alive_keys, C)
             # crawl counter makes every garbling's randomness unique even
             # if a leader re-crawls a level without reset (seed reuse with
