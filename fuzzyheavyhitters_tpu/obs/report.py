@@ -69,11 +69,15 @@ Well-known metric names (what populates them):
   round trip: the COUNT is a latency term beside the byte count, so
   both are measured); ``gc_tests`` — secure-mode equality tests;
   ``checkpoint_writes`` / ``checkpoint_restores``.
-- gauges ``ot_batch_size`` (per level), ``secure_string_bits`` and
-  ``child_patterns`` (per level: the bits a secure level's equality
-  test compares, S = 2 a dimension and radix step, and the child
-  patterns a node, 2^d a step; ``secure_kernels.string_bits`` /
-  ``.child_patterns``), ``survivors`` / ``frontier_nodes`` (per level).
+- gauges ``ot_batch_size`` (per level), ``secure_string_bits``,
+  ``child_patterns`` and ``secure_payload_words`` (per level: the bits a
+  secure level's equality test compares, S = 2 a dimension and radix
+  step, the child patterns a node, 2^d a step, and the u32 words of its
+  message's payload, the width of the field the level counts over: 2
+  for FE62, 8 for F255; ``secure_kernels.string_bits`` /
+  ``.child_patterns`` of the last level, ``.payload_words`` every
+  width a level had), ``survivors`` /
+  ``frontier_nodes`` (per level).
 - counters ``keys_placed_bytes`` (bytes of a bulk upload's batches
   written to their rows of the resident key planes as they arrived,
   protocol/keyplanes.py: the key-plane bytes once an upload) and
@@ -383,6 +387,7 @@ def _secure_kernel_summary(registries: dict) -> dict | None:
     waits = {"device_waits_high": 0, "device_wait_threads": 0}
     account_errors = 0
     shape = {"secure_string_bits": None, "child_patterns": None}
+    widths: set = set()
     kshards = None
     kgather = 0.0
     seen = False
@@ -431,6 +436,8 @@ def _secure_kernel_summary(registries: dict) -> dict | None:
             g = snap.get("gauges", {}).get(name)
             if g is not None:
                 shape[name] = g.get("last")
+        g = snap.get("gauges", {}).get("secure_payload_words")
+        widths.update((g or {}).get("by_level", {}).values())
         g = snap.get("gauges", {}).get("secure_t_rows_held_bytes")
         for lvl, b in (g or {}).get("by_level", {}).items():
             held[lvl] = max(held.get(lvl, 0), b)
@@ -485,9 +492,12 @@ def _secure_kernel_summary(registries: dict) -> dict | None:
         # ``ot_index_high``): above 0, a session has extended 2^32 OTs
         "ot_index_high": index_high,
         # the last level's shape: bits an equality test compares (2 a
-        # dimension and radix step) and child patterns a node
+        # dimension and radix step) and child patterns a node; and every
+        # width a level's payload crossed in, u32 words (2 = FE62, 8 =
+        # F255: [2, 8] for a crawl that reached its leaf)
         "string_bits": shape["secure_string_bits"],
         "child_patterns": shape["child_patterns"],
+        "payload_words": sorted(widths),
         # kernel-stage layout (multi-chip servers only; None/0.0 on a
         # single-device crawl — see the mesh section for the per-level
         # breakdown): the phase seconds above are the SHARDED kernels'

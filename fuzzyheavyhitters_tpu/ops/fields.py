@@ -36,6 +36,7 @@ class FE62:
     P = _P62
     dtype = jnp.uint64
     limb_shape = ()  # scalar per element
+    SAMPLE_WORDS = 4  # uniform u32 words one :meth:`sample` draw consumes
 
     @staticmethod
     def _bit_reduce(v):
@@ -219,6 +220,7 @@ class F255:
     P = _P255
     dtype = jnp.uint32
     limb_shape = (8,)
+    SAMPLE_WORDS = 8  # uniform u32 words one :meth:`sample` draw consumes
 
     @classmethod
     def zeros(cls, shape):
@@ -511,6 +513,7 @@ class U63:
     P = _P63
     dtype = jnp.uint64
     limb_shape = ()
+    SAMPLE_WORDS = 4  # uniform u32 words one :meth:`sample` draw consumes
 
     @staticmethod
     def _reduce63(v):

@@ -113,6 +113,28 @@ def _transpose_pack(cols: jax.Array, m: int) -> jax.Array:
     return jnp.transpose(_butterfly(cols), (2, 1, 0)).reshape(w * 32, 4)[:m]
 
 
+# Extension rows :func:`_transpose_planes` turns at a time.  Its
+# de-interleave's last transposition has a minor dimension of 32g / S
+# words and a butterfly stage fed straight from the column streams one of
+# 16, which the TPU pads to its 128 lanes: 4S / g and 8 times the rows'
+# bytes.  The compiler keeps such a temporary in VMEM (128 MiB on a v5e)
+# only while it fits: at a chunk of 1M rows (S = 2) or 512K (S = 4) it
+# was 128 MiB itself and went through HBM (PERF.md section 6, PR 47).  A
+# tile's is 4 MiB at S = 2 and 8 at S = 4, whatever the chunk.
+PLANE_TILE_ROWS = 1 << 15
+
+
+def _deinterleave(x: jax.Array, g: int, S: int) -> jax.Array:
+    """``x[k, r, wj]`` of :func:`_butterfly` (``wj`` a multiple of ``g``
+    words) -> uint32[S*4, wj * 32 // S], plane ``s*4 + k``."""
+    w = x.shape[2]
+    # x[k, r, wj] -> [k, wj // g, (wj % g)*32 + r = t_lo*S + s]
+    #             -> [s, k, t = (wj // g)*(32g/S) + t_lo]
+    x = jnp.transpose(x.reshape(4, 32, w // g, g), (0, 2, 3, 1))
+    x = jnp.transpose(x.reshape(4, w // g, 32 * g // S, S), (3, 0, 1, 2))
+    return x.reshape(S * 4, w * 32 // S)
+
+
 @partial(jax.jit, static_argnames=("m", "S"))
 def _transpose_planes(cols: jax.Array, m: int, S: int) -> jax.Array:
     """The rows of :func:`_transpose_pack` as the planes the equality
@@ -125,18 +147,21 @@ def _transpose_planes(cols: jax.Array, m: int, S: int) -> jax.Array:
     One transposition instead of two: rows as ``[m, 4]`` have a minor
     dimension of 4, and cutting them into ``[m // S, S, 4]`` afterwards
     sends a lane-padded copy of them (32 times their bytes) through HBM
-    in whichever program does it (PERF.md section 5, PR 38)."""
+    in whichever program does it (PERF.md section 5, PR 38).  The
+    butterfly and the de-interleave run a tile of whole tests at a time
+    (``PLANE_TILE_ROWS``) so their own lane-padded temporaries stay in
+    VMEM at every chunk size."""
     g = math.lcm(S, 32) // 32  # words of a column that hold whole tests
     w = cols.shape[1]
     if w % g:
         cols = jnp.pad(cols, ((0, 0), (0, g - w % g)))
         w = cols.shape[1]
-    x = _butterfly(cols)
-    # x[k, r, wj] -> [k, wj // g, (wj % g)*32 + r = t_lo*S + s]
-    #             -> [s, k, t = (wj // g)*(32g/S) + t_lo]
-    x = jnp.transpose(x.reshape(4, 32, w // g, g), (0, 2, 3, 1))
-    x = jnp.transpose(x.reshape(4, w // g, 32 * g // S, S), (3, 0, 1, 2))
-    return x.reshape(S * 4, w * 32 // S)[:, : m // S]
+    tw = max(PLANE_TILE_ROWS // 32 // g, 1) * g
+    tiles = [
+        _deinterleave(_butterfly(cols[:, i : i + tw]), g, S)
+        for i in range(0, w, tw)
+    ]
+    return jnp.concatenate(tiles, axis=1)[:, : m // S]
 
 
 @partial(jax.jit, static_argnames=("w",))

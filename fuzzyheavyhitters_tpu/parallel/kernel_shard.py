@@ -61,7 +61,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..ops import gc, gc_pallas, otext, prg
+from ..ops import gc, gc_pallas, otext
 from ..ops.fields import F255, FE62
 from ..ops.gc_pallas import GROUP, LANES, SUB, padded_tests
 from .mesh import field_psum
@@ -239,25 +239,15 @@ def _rcv_extend_fn(devices: tuple, B: int, S: int):
     )
 
 
-def _b2a_pair_shard(field, b2a_seed, B: int, bloc: int, t0, W: int,
-                    garbler: int):
+def _b2a_pair_shard(field, b2a_seed, B: int, bloc: int, t0, garbler: int):
     """Shard slice [t0, t0 + bloc) of :func:`secure.b2a_payload_pair`'s
-    per-level stream draw (word ``t*W`` onward for test t; ``t0*W`` is
-    block-aligned because shards are whole planar blocks).  Returns
-    (r1 — the sender's additive shares, w0, w1 payload words), with the
-    payload words ZEROED for global-pad tests (the single-device twin
-    pads them the same way)."""
+    per-level stream draw (shards are whole planar blocks, so the seek
+    is block-aligned).  Returns (r1 — the sender's additive shares, w0,
+    w1 payload words), with the payload words ZEROED for global-pad tests
+    (the single-device twin pads them the same way)."""
     from ..protocol import secure
 
-    nb = bloc * W // 16
-    r_words = prg.stream_blocks(
-        jnp.asarray(b2a_seed, jnp.uint32), nb, t0 * W // 16
-    ).reshape(bloc, W)
-    r0 = field.sample(r_words)
-    one = field.from_int(1)
-    r1 = field.sub(r0, one) if garbler else field.add(r0, one)
-    w0 = secure.field_to_words(field, r0)
-    w1 = secure.field_to_words(field, r1)
+    r1, w0, w1 = secure.b2a_payload_pair(field, b2a_seed, bloc, garbler, t0)
     live = (t0 + jnp.arange(bloc)) < B
     return r1, jnp.where(live[:, None], w0, 0), jnp.where(live[:, None], w1, 0)
 
@@ -281,9 +271,7 @@ def _gb_kernel_fn(devices: tuple, field_name: str, B: int, S: int, W: int,
         t0 = jax.lax.axis_index(DATA).astype(jnp.int64) * bloc
         q_rows = q_loc.reshape(bloc, S, 4)
         idx = idx0 + t0.astype(jnp.uint64)
-        r1, w0, w1 = _b2a_pair_shard(
-            field, b2a_seed, B, bloc, t0, W, garbler
-        )
+        r1, w0, w1 = _b2a_pair_shard(field, b2a_seed, B, bloc, t0, garbler)
         # result 1 (strings equal) -> receiver learns r0, exactly
         # secure.gb_step_level's payload order (collect.rs:439-456)
         if path == "ot2s":

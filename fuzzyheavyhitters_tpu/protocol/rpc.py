@@ -1526,11 +1526,11 @@ class CollectorServer:
         """The LAST level's exchange told from the inner levels': the
         span ``leaf_gc_ot`` inside its ``gc_ot`` and the counter
         ``leaf_tests`` of its ``B = F*C*N``.  The leaf level compares
-        over F255 (a payload twice as wide) at whatever bucket the crawl
-        ends in, and in a short crawl run to its hitter set round after
-        round it is the largest single share of the time (16 levels of
-        2 x 16 bits: 40-50%), which ``gc_ot`` summed over the levels
-        cannot show."""
+        over F255 (a payload of eight words to FE62's two) at whatever
+        bucket the crawl ends in, and in a short crawl run to its hitter
+        set round after round it is the largest single share of the time
+        (16 levels of 2 x 16 bits: 40-50%), which ``gc_ot`` summed over
+        the levels cannot show."""
         cs.obs.count("leaf_tests", tests, level=level)
         with cs.obs.span("leaf_gc_ot", level=level):
             yield
@@ -1995,9 +1995,12 @@ class CollectorServer:
             cs.obs.count("gc_tests", B, level=level)
             cs.obs.gauge("ot_batch_size", B * S, level=level)
             # the level's shape: bits a test compares (2 a dimension and
-            # radix step) and child patterns a node
+            # radix step), child patterns a node, and the u32 words of
+            # its message's payload (the width of the field it counts over)
+            W = secure.payload_words(count_field)
             cs.obs.gauge("secure_string_bits", S, level=level)
             cs.obs.gauge("child_patterns", C, level=level)
+            cs.obs.gauge("secure_payload_words", W, level=level)
         with cs.obs.span("gc_ot", level=level) as sp_gc, (
             self._leaf_exchange(cs, level, B) if last else _NO_CTX
         ):
@@ -2015,7 +2018,6 @@ class CollectorServer:
             # server's config decides
             path = secure.ot_path(S, ot_path or self.cfg.ot_path)
             cs.obs.count(f"ot_path_{path}", level=level)
-            W = secure.payload_words(count_field)
             ks = ex.get("kernel")
             # the row-sharded stage keeps its one frame a message
             chunks = (
@@ -2138,7 +2140,7 @@ class CollectorServer:
                     cs.obs.counter_value(f"secure_{n}", level=level) - b
                     for n, b in zip(passed, before)
                 },
-                string_bits=S, patterns=C,
+                string_bits=S, patterns=C, payload_words=W,
                 t_rows_held=cs.obs.gauge_value(
                     "secure_t_rows_held_bytes", level=level
                 ) if evaluates and ks is None else 0,

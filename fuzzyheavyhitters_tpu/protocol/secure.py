@@ -17,9 +17,12 @@ TPU-native shape: everything is batched device tensors —
   labels ride the IKNP Δ-OT (ops/otext.py) with ``R = s``, so label
   delivery costs one u-matrix message (vs the reference's per-wire OT);
 - the b2a payloads travel under chosen-payload OT pads from the same
-  extension session; FE62 payloads are one 128-bit block, F255 payloads
-  two blocks — the reference's ``Block`` vs ``BlockPair`` split
-  (collect.rs:439-471 vs 775-916);
+  extension session, each at the width of its field
+  (:func:`payload_words`): an FE62 payload is two u32 words, an F255
+  payload eight.  (The reference sends a 128-bit ``Block`` and a
+  ``BlockPair``, collect.rs:439-471 vs 775-916; FE62's Block is two
+  words of value and two of zeros, and a pad is cut to the payload's
+  length, so the zeros and the pad words over them stay home);
 - per-node share sums are alive-gated field reductions on device
   (collect.rs:487-501's ``add_lazy`` loop as one ``field.sum``).
 
@@ -136,25 +139,31 @@ def child_strings_radix(packed: jax.Array, d: int, radix: int) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# Field payload codecs (OT payload width: FE62 one block, F255 two blocks)
+# Field payload codecs: a payload travels at the width of its field —
+# FE62 in two u32 words ``[lo, hi]``, F255 in eight.  (The reference sends
+# FE62 as a 128-bit ``Block`` whose upper half is zeros, fastfield.rs:
+# 414-431, here ``FE62.to_blocks``: a pad is cut to the payload's length,
+# so the wire carries the Block's value half alone.)
 # ---------------------------------------------------------------------------
 
 
 def payload_words(field) -> int:
-    return 8 if field is F255 else 4
+    """u32 words of one OT payload of ``field`` on the wire."""
+    return 8 if field is F255 else 2
 
 
 def field_to_words(field, v) -> jax.Array:
     b = field.to_blocks(v)
     if field is F255:
         return b.reshape(b.shape[:-2] + (8,))
-    return b
+    return b[..., :2]
 
 
 def words_to_field(field, w) -> jax.Array:
     if field is F255:
         return field.from_blocks(w.reshape(w.shape[:-1] + (2, 4)))
-    return field.from_blocks(w)
+    w = jnp.asarray(w, jnp.uint64)
+    return field.new(w[..., 0] | (w[..., 1] << 32))
 
 
 def derive_seed(base: np.ndarray, purpose: int, level: int, ctr: int = 0) -> np.ndarray:
@@ -179,15 +188,17 @@ def b2a_payload_pair(field, b2a_seed, B: int, garbler: int, t0: int = 0):
     ``v0 - v1`` reconstruction holds whichever server sends
     (collect.rs:439-456's ordering with the alternating-garbler sign).
     ``t0`` seeks: the pair of tests ``[t0, t0 + B)`` of the seed's
-    stream (test ``t`` owns words ``[t*W, (t+1)*W)``; ``t0*W`` a
+    stream (test ``t`` owns words ``[t*D, (t+1)*D)``, ``D`` =
+    ``field.SAMPLE_WORDS`` — the draw's stride (FE62 folds 126 uniform
+    bits into its 62), not the wire's; ``t0*D`` a
     multiple of 16, as every multiple of a planar block is), the same
     words a draw from 0 holds there.
     Returns (r1 — the sender's additive shares, w0, w1 — the two payloads
-    as OT words)."""
-    W = payload_words(field)
+    as OT words, :func:`payload_words` each)."""
+    D = field.SAMPLE_WORDS
     r_words = prg.stream_words(
-        jnp.asarray(b2a_seed, jnp.uint32), B * W, t0 * W // 16
-    ).reshape(B, W)
+        jnp.asarray(b2a_seed, jnp.uint32), B * D, t0 * D // 16
+    ).reshape(B, D)
     r0 = field.sample(r_words)
     one = field.from_int(1)
     r1 = field.sub(r0, one) if garbler else field.add(r0, one)
@@ -219,13 +230,16 @@ def b2a_payload_pair(field, b2a_seed, B: int, garbler: int, t0: int = 0):
 # linear function of s).
 #
 # Cost crossover: per test the table costs 2^S·W ciphertext words vs the
-# GC batch's (S-1)·8 + 4S + 1 + 2W — at S = 2 the table is ~40% of the
-# GC bytes and 5 vs 9 hashes; at S = 6 it is ~3.5x the bytes but still
-# ~1/3 the hash count and no tree — ``OT2S_MAX_S`` caps the auto path at
-# the point where the 2^S table stops paying (beyond it the GC path,
-# whose wire is linear in S, takes over).  The GC path also remains the
-# arbitrary-S fallback and the reference-parity oracle; ``Config.ot_path
-# = "gc"`` turns the fast path off entirely.
+# GC batch's (S-1)·8 + 4S + 1 + 2W, W the payload's words (2 for FE62,
+# 8 for F255).  Inner levels (W = 2): at S = 2 the table is 8 words to
+# the batch's 21 (~40%) and 5 vs 9 hashes; at S = 4 it is 32 to 45; at
+# S = 6 128 to 69 (~1.9x the bytes) but still ~1/3 the hash count and no
+# tree.  The leaf (W = 8) pays 2^S·8 against the batch's 17 + 8(S-1) +
+# 4S: 32 to 33 at S = 2, 128 to 57 at S = 4 — ``OT2S_MAX_S`` caps the
+# auto path at the point where the 2^S table stops paying (beyond it the
+# GC path, whose wire is linear in S, takes over).  The GC path also
+# remains the arbitrary-S fallback and the reference-parity oracle;
+# ``Config.ot_path = "gc"`` turns the fast path off entirely.
 
 # auto-path ceiling for the 1-of-2^S table (S = 2·n_dims; 6 covers the
 # 3-dim roadmap workloads).  Protocol-legal up to 128; the 2^S·W wire
@@ -535,7 +549,10 @@ def alive_weight(alive_nodes, alive_keys, C: int) -> np.ndarray:
 # level by it from dimensions they already agree on; measured on the
 # chip at 8, 16 and 32 MiB (PERF.md section 6, PR 31).  At 16 MiB and
 # under, every fetch and receive buffer also stays below the 32 MiB from
-# which glibc maps new pages for each request (protocol/wire.py).
+# which glibc maps new pages for each request (protocol/wire.py).  Which
+# frame is the larger goes by the level's field: over FE62 at S = 2 the
+# u rows (16·S bytes a test) and the table (4·2^S·2) weigh the same 32,
+# from S = 4 up and at every F255 leaf it is the table.
 CHUNK_FRAME_BYTES: int = 16 << 20
 
 

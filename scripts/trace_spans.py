@@ -29,9 +29,12 @@ object:
   (``secure_fetch_syncs``, ``secure_phase_waits``), the most
   device bytes its evaluator held in unopened chunks (the gauge
   ``secure_t_rows_held_bytes``), the high word of the OT pad index
-  (the gauge ``ot_index_high``) and the widest shape a level had (bits
-  an equality test compares and child patterns a node: the gauges
-  ``secure_string_bits`` and ``child_patterns``);
+  (the gauge ``ot_index_high``), the widest shape a level had (bits an
+  equality test compares and child patterns a node: the gauges
+  ``secure_string_bits``, ``child_patterns``) and every width a level's
+  payload crossed in, u32 words (the gauge ``secure_payload_words``:
+  ``[2, 8]`` where inner levels went at FE62's two and a leaf at F255's
+  eight);
 - ``plane_streams``: per component, over its ``plane_send`` instants
   (one a data-plane frame sent through its stream's writer thread:
   protocol/rpc.py ``_dp_send_finish``), the frames (the counter
@@ -164,7 +167,9 @@ def secure_levels(events: list) -> dict:
     held in unopened chunks (gauge ``secure_t_rows_held_bytes``), the
     high word of the 64-bit OT pad index (gauge ``ot_index_high``) and
     the most bits a test compared and patterns a node had (gauges
-    ``secure_string_bits``, ``child_patterns``)."""
+    ``secure_string_bits``, ``child_patterns``) and every width a
+    level's payload crossed in, u32 words, sorted (gauge
+    ``secure_payload_words``)."""
     out: dict = {}
     for e in events:
         if e.get("ph") == "i" and e.get("name") == "secure_level":
@@ -174,7 +179,7 @@ def secure_levels(events: list) -> dict:
                 "secure_fetch_syncs_max": 0, "secure_phase_waits_max": 0,
                 "t_rows_held_bytes_max": 0,
                 "ot_index_high": 0, "string_bits_max": 0,
-                "child_patterns_max": 0})
+                "child_patterns_max": 0, "payload_words": []})
             row["levels"] += 1
             row["chunks_max"] = max(row["chunks_max"], a["chunks"])
             # a span log older than the counter has no ``programs``
@@ -187,6 +192,10 @@ def secure_levels(events: list) -> dict:
             row["ot_index_high"] = max(row["ot_index_high"], a["index_high"])
             row["string_bits_max"] = max(row["string_bits_max"], a["string_bits"])
             row["child_patterns_max"] = max(row["child_patterns_max"], a["patterns"])
+            # a span log older than the gauge has no ``payload_words``
+            w = a.get("payload_words")
+            if w and w not in row["payload_words"]:
+                row["payload_words"] = sorted(row["payload_words"] + [w])
     return dict(sorted(out.items()))
 
 

@@ -37,7 +37,7 @@ N_TRUSTED = 131072
 N_SECURE = 16384
 F = 64  # the widest frontier bucket the smoke's crawl reaches
 S = 2  # 1-dim L-inf string pair
-W = 4  # FE62 payload words
+W = secure.payload_words(FE62)  # an FE62 payload's u32 words on the wire: 2
 B_SECURE = F * 2 * N_SECURE  # (node, child, client) tests of one level (--chips 4: the same N over four chips)
 
 HBM_BYTES = 16 * 1024**3
@@ -201,28 +201,33 @@ def test_iknp_extension(one_chip):
 
 
 @pytest.mark.parametrize("S,b,k", [
-    (S, 32 * 2 * N_SECURE, 4), (S, 32 * 2 * N_TRUSTED, 32),
-    (4, 32 * 4 * N_TRUSTED, 256),
+    (S, 32 * 2 * N_SECURE, 2), (S, 32 * 2 * N_TRUSTED, 16),
+    (4, 32 * 4 * N_TRUSTED, 128),
 ], ids=["flagship", "hbm", "amazon2d"])
 @pytest.mark.parametrize("field", [FE62, F255], ids=["FE62", "F255"])
 def test_secure_level_chunk_programs(one_chip, field, S, b, k):
     """What one chunk of a secure level hands the device on a server
     (``rpc._ev_chunks`` / ``_gb_chunks``), one program a span, at the
     chunk ``secure.level_chunks`` cuts from the benchmark's steady level
-    (bucket 32 at N=16,384 and, ``hbm``, at N=131,072: n = 262,144 tests
-    a chunk either way; the leaf level's F255 table is twice as wide, so
-    its chunk is half the tests): the evaluator's slice + extension
+    (bucket 32 at N=16,384 and, ``hbm``, at N=131,072: n = 524,288 tests
+    a chunk either way, K = 2 and 16; the leaf level's F255 table is four
+    times as wide, so its chunk is a quarter of the tests): the
+    evaluator's slice + extension
     (``otext``) and open + field (``b2a``), the garbler's extension
     (``otext``) and share pair + table (``b2a``) at both signs.  The
     chunk's first test, offsets and pad indices are traced scalars, so
     these are every chunk's programs from bucket 16 up.  ``amazon2d`` is
     the two-dimensional deployment's steady level (S = 4, four patterns
-    a node, N=131,072): a 1-of-16 table of 256 bytes a test, 65,536
-    tests a frame.  No program keeps a lane-padded copy of the
-    extension's rows (32 times their bytes) among its temporaries."""
+    a node, N=131,072): a 1-of-16 table of 128 bytes a test, 131,072
+    tests a frame (K = 128; 512 chunks of 32,768 at its F255 leaf).  No
+    program keeps a lane-padded copy of the extension's rows among its
+    temporaries: neither the 32 times their bytes of rows cut as
+    ``[n, S, 4]`` (PR 38) nor the 4 S times of an untiled de-interleave
+    in ``otext._transpose_planes``, which at these chunks (1M and 512K
+    extension rows) is 128 MiB and no longer fits VMEM (PR 47)."""
     words = secure.payload_words(field)
     chunks = secure.level_chunks(b, S, words, "ot2s")
-    assert len(chunks) == (k if words == 4 else 2 * k)
+    assert len(chunks) == (k if field is FE62 else 4 * k)
     n = chunks[0][1]
     assert all(c[1] == n for c in chunks) and n % kernel_shard.BLOCK == 0
     sds = _sds(one_chip)

@@ -96,14 +96,16 @@ def test_ragged_extend_sizes(pair, rng):
 
 @pytest.mark.parametrize("S,n", [
     (1, 100), (2, 800), (2, 8192), (4, 1024), (4, 1000), (6, 115), (6, 512),
-    (10, 77),
+    (10, 77), (2, 40000), (4, 20001), (6, 12000),
 ])
 def test_rows_as_planes_are_the_rows(pair, rng, S, n):
     """``extend_rows(..., S)`` / ``_receiver_extend(..., S=S)`` give the
     rows of the plain extension as the planes the equality kernels read:
     plane ``s*4 + k`` is word k of rows ``s, S + s, ...`` (``_planarize``
     of the rows as ``[n, S, 4]``), for widths that divide a 32-row word
-    tile, that do not (6, 10), and for batches that end inside one."""
+    tile, that do not (6, 10), for batches that end inside one, and for
+    batches of several tiles of the de-interleave
+    (``otext.PLANE_TILE_ROWS``; the last three, the last tile ragged)."""
     snd, rcv = pair
     m = n * S
     r = rng.integers(0, 2, size=m).astype(bool)

@@ -411,7 +411,7 @@ def test_secure_lane_in_eight_chunks_matches_the_plain_reference(monkeypatch, se
     bits = np.array([[bitutils.int_to_bits(L, int(v))] for v in pts])
     k0, k1 = ibdcf.gen_l_inf_ball(bits, ball, rng, engine="np")
     block = gc_pallas.R_BLK * gc_pallas.GROUP
-    monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", block * 64)
+    monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", block * 32)
     cfg = _cfg(port_base=BASE_PORT + 80 + 40 * (seed % 2), data_len=L,
                ball_size=ball, threshold=0.1, addkey_batch_size=1024, f_max=32,
                secure_exchange=True)

@@ -37,6 +37,18 @@ L = 3
 WHOLE = 1 << 40  # a frame budget no level of these tests reaches
 
 
+def _blocks(blocks, S=2, field=FE62, path="ot2s"):
+    """The frame budget that cuts a level of string width ``S`` over
+    ``field`` into chunks of ``blocks`` planar blocks: the larger
+    frame's bytes a test, as ``secure.level_chunks`` counts them (an
+    FE62 level at S = 2: 32 either way, the u rows' 16 * S and the
+    table's 4 * 2^S * 2)."""
+    from fuzzyheavyhitters_tpu.parallel.kernel_shard import n_msg_planes
+
+    W = secure.payload_words(field)
+    return blocks * BLOCK * max(16 * S, 4 * n_msg_planes(path, S, W))
+
+
 @pytest.fixture(autouse=True)
 def _module_cpu(cpu_default):
     yield
@@ -191,8 +203,7 @@ def test_chunked_level_is_the_whole_level(monkeypatch, shape, path, garbler):
     S, W = 2, secure.payload_words(FE62)
     from fuzzyheavyhitters_tpu.parallel.kernel_shard import n_msg_planes
 
-    per_test = max(16 * S, 4 * n_msg_planes(path, S, W))
-    small = sh["blocks"] * BLOCK * per_test
+    small = _blocks(sh["blocks"], path=path)
 
     async def run():
         async with _Pair(port, sh["n"]) as pair:
@@ -278,6 +289,51 @@ def test_leaf_level_in_chunks(monkeypatch):
         assert a.shape[-1] == 8 and np.array_equal(a, b)
 
 
+@pytest.mark.parametrize("n_dims", [1, 2])
+def test_a_levels_bytes_are_its_tests_at_the_width_of_its_field(n_dims):
+    """What a level puts on the data plane: ``16 * S`` bytes of u rows a
+    test one way and ``4 * 2^S * W`` of table the other, the table padded
+    to whole planar blocks, plus framing; ``W`` is two words at an inner
+    level (FE62) and eight at the leaf (F255), and the gauge
+    ``secure_payload_words`` says which."""
+    n, f = 1024, 4
+    S, C = 2 * n_dims, 1 << n_dims
+    B = f * C * n
+    pts = None if n_dims == 1 else _points_2d(n)
+
+    async def run():
+        # (the range is full: these sit between the first pair's ports,
+        # which are BASE_PORT, + 10 and + 11)
+        async with _Pair(BASE_PORT + 2 + 2 * n_dims, n, pts=pts) as pair:
+            await pair.both("tree_init", {"root_bucket": f})
+            await pair.level(0)
+            await pair.level(1, last=True)
+            obs = [cs.obs for cs in pair.sessions]
+            return [
+                ([o.counter_value(f"data_bytes_{way}", level=lv) for o in obs
+                  for way in ("sent", "recv")],
+                 [o.gauge_value("secure_payload_words", level=lv) for o in obs],
+                 [o.counter_value("secure_chunks", level=lv) for o in obs])
+                for lv in (0, L - 1)
+            ]
+
+    inner, leaf = _run(run())
+    for (nbytes, words, ks), W in ((inner, 2), (leaf, 8)):
+        assert words == [W, W] and ks == [1, 1]
+        assert W == secure.payload_words(FE62 if W == 2 else F255)
+        u = B * 16 * S
+        table = gc_pallas.padded_tests(B) * 4 * (1 << S) * W
+        # each server sends one frame and receives the other: both
+        # frames are counted at both ends, and a frame's header and
+        # pickle are a few hundred bytes
+        assert sum(nbytes) == 2 * sum(nbytes[0::2])
+        over = sum(nbytes[0::2]) - (u + table)
+        assert 0 < over < 2048, (nbytes, u, table)
+    # the inner level's table is the u rows' size at S = 2 and twice it
+    # at S = 4; the old four-word table was twice and four times
+    assert gc_pallas.padded_tests(B) * 4 * (1 << S) * 2 == (S // 2) * B * 16 * S
+
+
 @pytest.mark.parametrize("when", ["before_chunk_1", "two_frames_out"])
 def test_plane_cut_mid_level_fails_the_verb_and_the_retry_is_exact(monkeypatch, when):
     """The plane closed under chunk 1's frame, before its hand-over or
@@ -286,7 +342,7 @@ def test_plane_cut_mid_level_fails_the_verb_and_the_retry_is_exact(monkeypatch, 
     I/O thread of that plane is left, and after a plane reset (four new
     threads) the same level gives the exact counts."""
     port = BASE_PORT + (360 if when == "before_chunk_1" else 320)
-    monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", BLOCK * 64)
+    monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", _blocks(1))
     real = rpc.CollectorServer._dp_send_begin
     cut = {"armed": False}
     # the writer threads held before a write, for the second case: so
@@ -392,7 +448,7 @@ def test_chunk_fault_mid_level_fails_both_verbs_and_the_retry_is_exact(monkeypat
     (the leader's quiesce), nobody hangs, no chunk task is left, and
     after a plane reset the same level gives the exact counts."""
     port = BASE_PORT + 880
-    monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", BLOCK * 64)
+    monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", _blocks(1))
     armed = {"calls": None}
     if fault == "device_program":
         monkeypatch.setattr(
@@ -449,11 +505,8 @@ def test_a_stage_runs_ahead_of_the_device_by_its_queues_and_no_further(monkeypat
     less chunks handed to the data plane never pass the two queues of
     two (``built`` / ``made``, ``fetched``) and the one in each of the
     two stages' hands."""
-    from fuzzyheavyhitters_tpu.parallel.kernel_shard import n_msg_planes
-
-    n, S, W, K = 4096, 2, secure.payload_words(FE62), 32
-    per_test = max(16 * S, 4 * n_msg_planes("ot2s", S, W))
-    monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", BLOCK * per_test)
+    n, K = 4096, 32
+    monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", _blocks(1))
     ahead = {"gb": [0, 0], "ev": [0, 0]}  # role: now, the high-water mark
 
     def counting(role, real):
@@ -537,7 +590,7 @@ def test_a_send_stage_keeps_two_frames_with_the_writer_and_no_third(monkeypatch)
             monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", WHOLE)
             whole = await pair.level(0, path="ot2s")
             pair.set_ot_state(before)
-            monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", BLOCK * 64)
+            monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", _blocks(1))
             # u's direction: server 1 writes, server 0 reads
             for sock, opt in ((pair.s1._peer._socks[0], socket.SO_SNDBUF),
                               (pair.s0._peer._socks[1], socket.SO_RCVBUF)):
@@ -580,12 +633,9 @@ def test_on_sent_follows_each_frame_in_chunk_order(monkeypatch):
     the evaluator's unopened chunks (``secure_t_rows_held_bytes``) stay
     within the ``CHUNKS_AHEAD`` of its queue and the one that waits to
     get in."""
-    from fuzzyheavyhitters_tpu.parallel.kernel_shard import n_msg_planes
-
     cls = rpc.CollectorServer
-    n, S, W, K = 4096, 2, secure.payload_words(FE62), 32
-    per_test = max(16 * S, 4 * n_msg_planes("ot2s", S, W))
-    monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", BLOCK * per_test)
+    n, S, K = 4096, 2, 32
+    monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", _blocks(1))
     rows, ends, calls = [], [], []
     real_extend, real_finish = secure.ev_chunk_extend, cls._dp_send_finish
     real_senders = cls._chunk_senders
@@ -671,7 +721,7 @@ def test_a_fault_in_the_recording_of_a_fetch_resolves_it_and_the_level_is_exact(
     the run report's ``account_errors``) and logged."""
     from fuzzyheavyhitters_tpu.obs import report as obsreport
 
-    monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", BLOCK * 64)
+    monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", _blocks(1))
     armed = {"calls": None}
     monkeypatch.setattr(
         rpc.CollectorServer, "_record_fetch", staticmethod(_fail_second(
@@ -703,7 +753,7 @@ def test_a_fetch_thread_that_does_not_return_fails_both_verbs_within_the_bound(m
     verb fails with the stage and chunk it stood in, the peer's fails
     with the leader's quiesce, no chunk task is left, and after a plane
     reset the same level gives the exact counts."""
-    monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", BLOCK * 64)
+    monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", _blocks(1))
     release = threading.Event()
     armed = {"calls": None}
     real = rpc._fetch_on_thread
@@ -777,10 +827,7 @@ def test_a_level_of_32_chunks_on_two_threads_each_is_the_whole_level(monkeypatch
     ``device_waits_high``."""
     import concurrent.futures
 
-    from fuzzyheavyhitters_tpu.parallel.kernel_shard import n_msg_planes
-
-    n, S, W, K = 4096, 2, secure.payload_words(FE62), 32
-    small = BLOCK * max(16 * S, 4 * n_msg_planes("ot2s", S, W))
+    n, K, small = 4096, 32, _blocks(1)
 
     async def run():
         asyncio.get_running_loop().set_default_executor(
@@ -827,7 +874,7 @@ def test_peer_with_another_cut_gets_connection_error(monkeypatch):
     def uneven(B, S, W, path):
         calls.append(B)
         monkeypatch.setattr(
-            secure, "CHUNK_FRAME_BYTES", BLOCK * 64 * (1 + len(calls) % 2)
+            secure, "CHUNK_FRAME_BYTES", _blocks(1 + len(calls) % 2)
         )
         return real(B, S, W, path)
 
@@ -858,36 +905,51 @@ def test_peer_with_another_cut_gets_connection_error(monkeypatch):
 @pytest.mark.parametrize(
     "B,S,W,path,want",
     [
-        # the flagship's steady bucket: 32 nodes x 2 patterns x 16,384
-        (32 * 2 * 16384, 2, 4, "ot2s", [262144] * 4),
-        (16 * 2 * 16384, 2, 4, "ot2s", [262144] * 2),
-        (64 * 2 * 16384, 2, 4, "ot2s", [262144] * 8),
+        # the flagship's steady bucket: 32 nodes x 2 patterns x 16,384;
+        # an FE62 payload is two words, so table and u rows weigh the
+        # same 32 bytes a test and a frame holds 524,288 tests
+        (32 * 2 * 16384, 2, 2, "ot2s", [524288] * 2),
+        (16 * 2 * 16384, 2, 2, "ot2s", [524288]),
+        (64 * 2 * 16384, 2, 2, "ot2s", [524288] * 4),
         # buckets 2-8 go whole
-        (8 * 2 * 16384, 2, 4, "ot2s", [262144]),
-        (2 * 2 * 16384, 2, 4, "ot2s", [65536]),
-        # the leaf level's table is twice as wide a test
+        (8 * 2 * 16384, 2, 2, "ot2s", [262144]),
+        (2 * 2 * 16384, 2, 2, "ot2s", [65536]),
+        # the same buckets at N = 131,072 (the hbm cell): K = 16 and 32
+        (32 * 2 * 131072, 2, 2, "ot2s", [524288] * 16),
+        (64 * 2 * 131072, 2, 2, "ot2s", [524288] * 32),
+        # the leaf level's F255 table is four times as wide a test
         (32 * 2 * 16384, 2, 8, "ot2s", [131072] * 8),
+        (32 * 2 * 131072, 2, 8, "ot2s", [131072] * 64),
         # two dimensions (S = 4, four patterns a node) at N = 131,072: a
-        # 1-of-16 table of 256 bytes a test, 65,536 tests a frame, and
+        # 1-of-16 table of 128 bytes a test, 131,072 tests a frame, and
         # 512 bytes and 32,768 at the leaf level
-        (32 * 4 * 131072, 4, 4, "ot2s", [65536] * 256),
-        (64 * 4 * 131072, 4, 4, "ot2s", [65536] * 512),
+        (32 * 4 * 131072, 4, 2, "ot2s", [131072] * 128),
+        (64 * 4 * 131072, 4, 2, "ot2s", [131072] * 256),
         (32 * 4 * 131072, 4, 8, "ot2s", [32768] * 512),
-        # the garbled batch of S = 8: 97 words a test
-        (1 << 20, 8, 4, "gc", [40960] * 25 + [24576]),
+        # the geo cell's one-node levels: 4 patterns x 131,072 clients
+        (1 * 4 * 131072, 4, 2, "ot2s", [131072] * 4),
+        # the garbled batch of S = 8: 93 words a test (105 at the leaf)
+        (1 << 20, 8, 2, "gc", [40960] * 25 + [24576]),
+        (1 << 20, 8, 8, "gc", [32768] * 32),
         # a tail that is no whole block
-        (262144 + 5, 2, 4, "ot2s", [262144, 5]),
+        (524288 + 5, 2, 2, "ot2s", [524288, 5]),
     ],
 )
 def test_level_chunks_from_the_level_dimensions(B, S, W, path, want):
     """K and the boundaries at the shipped 16 MiB: whole planar blocks,
-    the larger frame at most the budget, every test once."""
+    the larger frame at most the budget, every test once, and every cut
+    a whole block of the b2a stream's draw (``field.SAMPLE_WORDS``: 4
+    or 8 words a test, whatever the wire's ``W``)."""
+    from fuzzyheavyhitters_tpu.parallel.kernel_shard import n_msg_planes
+
     assert secure.CHUNK_FRAME_BYTES == 16 << 20
     chunks = secure.level_chunks(B, S, W, path)
     assert [n for _, n in chunks] == want
     assert [t0 for t0, _ in chunks] == list(np.cumsum([0] + want[:-1]))
     assert all(t0 % BLOCK == 0 for t0, _ in chunks)
-    assert all(t0 * S % 512 == 0 and t0 * W % 16 == 0 for t0, _ in chunks)
+    assert all(t0 * S % 512 == 0 and t0 * 4 % 16 == 0 for t0, _ in chunks)
+    per_test = max(16 * S, 4 * n_msg_planes(path, S, W))
+    assert max(want) * per_test <= secure.CHUNK_FRAME_BYTES
 
 
 # -- the 64-bit OT index, and levels of many chunks --------------------------
@@ -972,13 +1034,17 @@ async def _three_levels(pair, monkeypatch, sent, start, frame_bytes):
 
 _INDEX_N = 3072              # B = 4 * 2 * 3072 tests = 3 planar blocks
 _INDEX_ROWS = 4 * 2 * _INDEX_N * 2
-_TWO_BLOCKS = 2 * BLOCK * 64  # the ot2s table: 64 bytes a test
-# frames and shares of the three levels below, recorded on the parent of
-# the 64-bit index (commit ff34183: the index a uint32) with the pad
-# indices starting 4 levels under 2^32, so that none passes it
+_TWO_BLOCKS = _blocks(2)  # 32 bytes a test, u rows and table alike
+# frames and shares of the three levels below, with the pad indices
+# starting 4 levels under 2^32, so that none passes it.  Recorded on the
+# FOUR-WORD code (commit 67a3f69, the parent of the payload at the width
+# of its field), whose own frames and shares hashed to what the parent
+# of the 64-bit index recorded (commit ff34183: the index a uint32), with
+# every table frame cut to planes c*4 + {0, 1} of its 16 before it was
+# hashed: what a two-word table must be if the kept words did not move
 _RECORDED = {
-    "whole": "bd49df05774aac0c4db11fd5b791c79e173d52014fb7b0a6b988d2bb7be9e495",
-    "K2": "2eb7b63ef4c7796191d8ab5c4ca455a54c9d3304316c0c45603fcc77c8485914",
+    "whole": "d2910c5f7c5b1872a5e687758bbb8c0f9e57aacf4fa70398eab087196ba5d685",
+    "K2": "5aa7e101ebd5b1d678847afa001871c74a58de7be707205cf00d485032337e1b",
 }
 
 
@@ -986,7 +1052,9 @@ _RECORDED = {
 @pytest.mark.parametrize("flow", ["whole", "K2"])
 def test_levels_under_2_32_are_the_recorded_bytes(monkeypatch, flow, engine):
     """An index under 2^32 hashes as it did when the index was 32 bits
-    wide: frames and shares byte for byte, on either engine."""
+    wide and the payload four words: shares byte for byte, u frames
+    byte for byte, the table the kept planes of that table, on either
+    engine."""
     if engine == "pallas_interpret":
         _interpret_engines(monkeypatch)
     sent = _spy_frames(monkeypatch)
@@ -1079,7 +1147,7 @@ def test_chunked_and_whole_agree_across_2_32_on_both_engines(monkeypatch):
         else:
             u = np.concatenate([f[2] for f in frames if f[2].ndim == 2], axis=1)
             msg = np.concatenate(
-                [f[2].reshape(16, -1) for f in frames if f[2].ndim == 1], axis=1
+                [f[2].reshape(8, -1) for f in frames if f[2].ndim == 1], axis=1
             ).reshape(-1)
         assert np.array_equal(u, u_whole), (engine, flow)
         assert np.array_equal(msg, msg_whole), (engine, flow)
@@ -1095,7 +1163,6 @@ def test_level_of_many_chunks_is_the_whole_level(monkeypatch, K, f):
 
     sent = _spy_frames(monkeypatch)
     n, S, W = 4096, 2, secure.payload_words(FE62)
-    per_test = max(16 * S, 4 * n_msg_planes("ot2s", S, W))
 
     async def run():
         async with _Pair(BASE_PORT + 620 + 20 * (K == 64), n) as pair:
@@ -1107,7 +1174,7 @@ def test_level_of_many_chunks_is_the_whole_level(monkeypatch, K, f):
             after_whole, frames_whole = pair.ot_state(), list(sent)
             pair.set_ot_state(before)
             del sent[:]
-            monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", BLOCK * per_test)
+            monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", _blocks(1))
             cut = await pair.level(0, path="ot2s")
             held = [cs.obs.gauge_value("secure_t_rows_held_bytes", level=0)
                     for cs in pair.sessions]
@@ -1244,7 +1311,7 @@ def test_two_dimensional_crawl_in_chunks_matches_the_driver_and_the_reference(mo
 
     n, S = 1024, 4
     pts = _points_2d(n)
-    monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", BLOCK * 4 * 16 * 4)
+    monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", _blocks(1, S=4))
 
     async def run():
         async with _Pair(BASE_PORT + 780, n, pts=pts) as pair:
@@ -1311,13 +1378,11 @@ def test_a_chunk_is_one_program_a_span(monkeypatch, caplog, S, last, garbler, K)
     again compiles nothing; (a) ``secure_chunk_programs`` reads 2 x K
     on either server."""
     from fuzzyheavyhitters_tpu.ops import otext
-    from fuzzyheavyhitters_tpu.parallel.kernel_shard import n_msg_planes
 
     n, f = (2048, 4) if S == 2 else (1024, 4)
     C = 1 << (S // 2)
     assert f * C * n == 2 * BLOCK
-    W = secure.payload_words(F255 if last else FE62)
-    one_block = BLOCK * max(16 * S, 4 * n_msg_planes("ot2s", S, W))
+    one_block = _blocks(1, S=S, field=F255 if last else FE62)
     first, second = (WHOLE, one_block) if K == 2 else (one_block, WHOLE)
     case = ((S == 4) * 2 + last) * 4 + garbler * 2 + (K == 1)
     port = BASE_PORT + _PROGRAM_PORTS[case % len(_PROGRAM_PORTS)]

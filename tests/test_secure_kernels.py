@@ -428,8 +428,11 @@ def test_two_dim_secure_crawl_matches_trusted_oracle(rng):
     res, _, servers = _crawl(cfg, BASE_PORT + 120, k0, k1)
     rep = obsreport.run_report([s.obs for s in servers])
     assert rep["secure_kernels"]["ot_path"] == "ot2s"  # no GC engaged
+    # the report's shape is the LAST level's; the widths are every
+    # level's: the inner levels' two words beside the F255 leaf's eight
     assert (rep["secure_kernels"]["string_bits"],
-            rep["secure_kernels"]["child_patterns"]) == (4, 4)
+            rep["secure_kernels"]["child_patterns"],
+            rep["secure_kernels"]["payload_words"]) == (4, 4, [2, 8])
     got = {
         tuple(int(v) for v in r): int(c)
         for r, c in zip(res.decode_ints(), res.counts)

@@ -888,7 +888,7 @@ def test_chunk_spans_carry_the_level_and_lie_inside_gc_ot(rng, trace_dir, monkey
     k0, k1 = _client_keys(rng, L, n)
     cfg = _cfg(port, secure_exchange=True, addkey_batch_size=1024)
     block = gc_pallas.R_BLK * gc_pallas.GROUP
-    monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", block * 64)
+    monkeypatch.setattr(secure, "CHUNK_FRAME_BYTES", block * 32)
 
     async def run():
         lead, c0, c1, live = await _bring_up(cfg, port)
@@ -918,7 +918,8 @@ def test_chunk_spans_carry_the_level_and_lie_inside_gc_ot(rng, trace_dir, monkey
     assert set(held) == {"0", "1"} and held["0"] > 0 and held["1"] > 0
     assert rep["secure_kernels"]["ot_index_high"] == 0
     assert (rep["secure_kernels"]["string_bits"],
-            rep["secure_kernels"]["child_patterns"]) == (2, 2)
+            rep["secure_kernels"]["child_patterns"],
+            rep["secure_kernels"]["payload_words"]) == (2, 2, [2])
     evs = _events(trace_dir)
     assert tracemod.validate(evs)["ok"]
     spans = [e for e in evs if e["ph"] == "X"]
@@ -984,7 +985,8 @@ def test_chunk_spans_carry_the_level_and_lie_inside_gc_ot(rng, trace_dir, monkey
         assert row["levels"] == 2 and row["chunks_max"] == K
         assert row["secure_chunk_programs_max"] == 2 * K
         assert row["ot_index_high"] == 0
-        assert (row["string_bits_max"], row["child_patterns_max"]) == (2, 2)
+        assert (row["string_bits_max"], row["child_patterns_max"],
+                row["payload_words"]) == (2, 2, [2])
     assert max(r["t_rows_held_bytes_max"] for r in rows.values()) == max(
         held.values())
     # the streams by the span log against the registries' own account:
